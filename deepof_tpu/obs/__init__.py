@@ -31,9 +31,8 @@ holds the instruments that can:
                 router and replica (`tools/trace_summary.py --merge`).
 
 Import discipline: this __init__, trace.py, export.py, and aggregate.py
-import only the stdlib (`bench.py`'s orchestrating parent and
-`analyze.py` may import them without initializing an accelerator
-backend); telemetry.py defers its jax imports into the sampling
+import only the stdlib (`analyze.py` and the jax-free CLI verbs may
+import them without initializing an accelerator backend); telemetry.py defers its jax imports into the sampling
 functions for the same reason.
 """
 

@@ -26,7 +26,8 @@ Per lowering, a row records:
     executable, not per process.
   - **XLA cost analysis**: FLOPs and bytes accessed, their ratio
     (arithmetic intensity), and the nominal roofline seconds one call
-    would take at obs/telemetry.py's ``NOMINAL_BF16_TFLOPS`` — the
+    would take at the device's published peak (obs/telemetry.py's
+    ``PEAK_BF16_TFLOPS``; None off the table) — the
     drift signal is the COST MODEL, not wall time, because cost
     analysis is deterministic in the lowering while wall time is host
     noise (DESIGN.md has the rationale).
@@ -59,7 +60,7 @@ import threading
 import time
 from typing import Any, Callable
 
-from .telemetry import NOMINAL_BF16_TFLOPS
+from .telemetry import peak_bf16_tflops
 
 #: Ledger schema version (rows carry it; diff refuses nothing on
 #: mismatch but reports it — older baselines stay comparable on the
@@ -255,9 +256,12 @@ def lowering_row(name: str, lowered=None, compiled=None,
         byt = float(ca.get("bytes accessed", 0.0))
         if flops > 0:
             row["flops"] = flops
-            # the time a perfectly-utilized nominal chip would take per
-            # call: measured wall / roofline_s = per-executable MFU
-            row["roofline_s"] = flops / (NOMINAL_BF16_TFLOPS * 1e12)
+            # the time this device at its published peak would take per
+            # call: measured wall / roofline_s = per-executable MFU.
+            # None on a device outside the peak table (the cpu included)
+            peak = peak_bf16_tflops()
+            if peak:
+                row["roofline_s"] = flops / (peak * 1e12)
         if byt > 0:
             row["bytes_accessed"] = byt
         if flops > 0 and byt > 0:

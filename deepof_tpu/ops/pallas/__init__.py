@@ -8,14 +8,14 @@ Kernel inventory (and why each op is/isn't a kernel):
     while the kernel holds one haloed row-window of f2 in VMEM and sweeps
     all displacements from on-chip memory.
 
-  - The bilinear warp (`ops/warp.py`) deliberately stays an XLA gather:
-    flow magnitude is unbounded (the reference clips eval flow at +-300 px,
-    `flyingChairsTrain.py:265`), so windowed VMEM loads cannot be sized
-    statically without changing semantics, and a one-hot matmul
-    decomposition is impossible for jointly spatially-varying (u, v) index
-    fields. XLA lowers the single fused `take_along_axis` gather natively;
-    the surrounding Charbonnier/smoothness elementwise+reduce work fuses
-    into it.
+  - `warp.py` — the bilinear backward warp and its flow gradient at the
+    coarse pyramid levels (W <= 128: one lane register), as a bounded row
+    sweep. Fine levels stay an XLA gather (`ops/warp.py`): flow magnitude
+    is unbounded, so windowed VMEM loads cannot be sized statically
+    without changing semantics, and Mosaic has no arbitrary 2D gather.
+
+Under a mesh every kernel runs per batch shard through
+`parallel.spatial.shard_over_batch`.
 """
 
 from .corr import correlation_pallas

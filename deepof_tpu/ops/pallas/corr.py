@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ...parallel.spatial import current_mesh, shard_over_batch
 
 
 def _sweep_offsets(n: int, stride: int) -> jnp.ndarray:
@@ -57,14 +57,20 @@ def _corr_kernel(f1_ref, f2p_ref, out_ref, win_ref, sem, *,
     f1 = f1_ref[0].astype(jnp.float32)  # (TILE_H, W, C)
     inv_c = 1.0 / c
 
-    def body(idx, _):
-        dy = (idx // n) * stride
-        dx = (idx % n) * stride
-        sl = win_ref[pl.ds(dy, tile_h), pl.ds(dx, w), :].astype(jnp.float32)
-        out_ref[0, idx] = jnp.sum(f1 * sl, axis=-1) * inv_c
+    # dy indexes the window's LEADING (untiled) axis, so it may be a loop
+    # variable; dx lands on the sublane axis of the (W, C) tiles, where
+    # Mosaic only takes offsets it can prove tile-aligned — a traced
+    # `pl.ds(dx, w)` is refused on the chip ("cannot statically prove
+    # that index in dimension 1 is a multiple of 8"). So the dx sweep is
+    # unrolled in Python with static slices.
+    def body(i, _):
+        for j in range(n):
+            sl = win_ref[pl.ds(i * stride, tile_h),
+                         j * stride:j * stride + w, :].astype(jnp.float32)
+            out_ref[0, i * n + j] = jnp.sum(f1 * sl, axis=-1) * inv_c
         return 0
 
-    lax.fori_loop(0, n * n, body, 0)
+    lax.fori_loop(0, n, body, 0)
 
 
 def _pallas_corr_fwd(f1: jnp.ndarray, f2: jnp.ndarray, max_disp: int,
@@ -106,65 +112,41 @@ def _pallas_corr_fwd(f1: jnp.ndarray, f2: jnp.ndarray, max_disp: int,
     return jnp.moveaxis(out[:, :, :h], 1, -1).astype(f1.dtype)
 
 
-@functools.lru_cache(maxsize=None)
-def _partitioned_fwd(max_disp: int, stride: int, tile_h: int, interpret: bool):
-    """Batch-data-parallel partitioning rule for the opaque pallas_call.
-
-    GSPMD cannot see inside a Pallas kernel; without a rule it would
-    all-gather and replicate the cost volume on every chip. Correlation is
-    independent per batch element (but needs full H/W/C per shard — the
-    displacement window crosses any spatial split), so: keep the batch
-    axis sharding, replicate everything else, and run the same kernel on
-    each per-shard batch slice.
-    """
-    fwd = custom_partitioning(
-        lambda f1, f2: _pallas_corr_fwd(f1, f2, max_disp, stride, tile_h,
-                                        interpret))
-
-    def _batch_axis(arg_infos):
-        for info in arg_infos:
-            sharding = getattr(info, "sharding", None)
-            spec = getattr(sharding, "spec", None)
-            if spec and len(spec) and spec[0] is not None:
-                return spec[0]
-        return None
-
-    def infer(mesh, arg_infos, result_infos):
-        return NamedSharding(mesh, P(_batch_axis(arg_infos), None, None, None))
-
-    def partition(mesh, arg_infos, result_infos):
-        sh = NamedSharding(mesh, P(_batch_axis(arg_infos), None, None, None))
-
-        def lower(f1, f2):
-            return _pallas_corr_fwd(f1, f2, max_disp, stride, tile_h, interpret)
-
-        return mesh, lower, sh, (sh, sh)
-
-    # Shardy propagation rule: only the batch factor `b` is shardable;
-    # spatial/channel/displacement dims must be replicated per shard (the
-    # displacement window crosses any spatial split).
-    fwd.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=partition,
-        sharding_rule="b h w c, b i j c -> b h w k",
-        need_replication_factors=("h", "w", "c", "i", "j", "k"),
-    )
-    return fwd
+def _launch(f1, f2, max_disp, stride, tile_h, interpret, mesh):
+    return shard_over_batch(
+        lambda a, b: _pallas_corr_fwd(a, b, max_disp, stride, tile_h,
+                                      interpret),
+        mesh, f1.shape[0])(f1, f2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
 def correlation_pallas(f1, f2, max_disp: int = 20, stride: int = 2,
-                       tile_h: int = 8, interpret: bool = False):
-    """Pallas cost volume: (B,H,W,C) x2 -> (B,H,W,(2K+1)^2), K=max_disp//stride."""
-    return _partitioned_fwd(max_disp, stride, tile_h, interpret)(f1, f2)
+                       tile_h: int = 8, interpret: bool | None = None):
+    """Pallas cost volume: (B,H,W,C) x2 -> (B,H,W,(2K+1)^2), K=max_disp//stride.
+
+    interpret=None auto-selects interpreter mode off-TPU (CPU test
+    meshes), exactly as `backward_warp_pallas` does. Under a
+    `mesh_context` the kernel runs per batch shard
+    (`parallel.spatial.shard_over_batch`); the mesh is resolved HERE and
+    carried as a static argument because the VJP's backward rule is
+    traced after the context has exited.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _correlation(f1, f2, max_disp, stride, tile_h, interpret,
+                        current_mesh())
 
 
-def _fwd(f1, f2, max_disp, stride, tile_h, interpret):
-    return (_partitioned_fwd(max_disp, stride, tile_h, interpret)(f1, f2),
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _correlation(f1, f2, max_disp, stride, tile_h, interpret, mesh):
+    return _launch(f1, f2, max_disp, stride, tile_h, interpret, mesh)
+
+
+def _fwd(f1, f2, max_disp, stride, tile_h, interpret, mesh):
+    return (_launch(f1, f2, max_disp, stride, tile_h, interpret, mesh),
             (f1, f2))
 
 
-def _bwd(max_disp, stride, tile_h, interpret, res, g):
+def _bwd(max_disp, stride, tile_h, interpret, mesh, res, g):
     f1, f2 = res
     b, h, w, c = f1.shape
     k = max_disp // stride
@@ -197,4 +179,4 @@ def _bwd(max_disp, stride, tile_h, interpret, res, g):
     return df1.astype(f1.dtype), df2.astype(f2.dtype)
 
 
-correlation_pallas.defvjp(_fwd, _bwd)
+_correlation.defvjp(_fwd, _bwd)

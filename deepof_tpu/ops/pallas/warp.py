@@ -48,8 +48,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ...parallel.spatial import current_mesh, shard_over_batch
 
 LANES = 128
 
@@ -198,83 +198,52 @@ def _pallas_warp_fwd(image: jnp.ndarray, flow: jnp.ndarray,
     return jnp.transpose(out, (0, 2, 3, 1))[:, :h, :w].astype(image.dtype)
 
 
-def _batch_partitioned(lower_fn, n_in: int, sharding_rule: str):
-    """Batch-data-parallel custom_partitioning wrapper shared by both warp
-    kernels (same rationale as pallas/corr.py: GSPMD cannot see inside a
-    kernel; the warp is independent per batch element but the row sweep
-    needs the full H per shard)."""
-    fn = custom_partitioning(lower_fn)
-
-    def _batch_axis(arg_infos):
-        for info in arg_infos:
-            sharding = getattr(info, "sharding", None)
-            spec = getattr(sharding, "spec", None)
-            if spec and len(spec) and spec[0] is not None:
-                return spec[0]
-        return None
-
-    def infer(mesh, arg_infos, result_infos):
-        return NamedSharding(mesh, P(_batch_axis(arg_infos), None, None, None))
-
-    def partition(mesh, arg_infos, result_infos):
-        sh = NamedSharding(mesh, P(_batch_axis(arg_infos), None, None, None))
-        return mesh, lower_fn, sh, (sh,) * n_in
-
-    fn.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=partition,
-        sharding_rule=sharding_rule,
-        need_replication_factors=("h", "w", "c", "k"),
-    )
-    return fn
+def _fwd_launch(image, flow, interpret, mesh, axes):
+    return shard_over_batch(
+        lambda im, fl: _pallas_warp_fwd(im, fl, interpret),
+        mesh, image.shape[0], axes)(image, flow)
 
 
-@functools.lru_cache(maxsize=None)
-def _partitioned_fwd(interpret: bool):
-    return _batch_partitioned(
-        lambda image, flow: _pallas_warp_fwd(image, flow, interpret),
-        n_in=2, sharding_rule="b h w c, b h w k -> b h w c")
-
-
-@functools.lru_cache(maxsize=None)
-def _partitioned_flow_grad(interpret: bool):
-    return _batch_partitioned(
-        lambda image, flow, ct: _pallas_warp_flow_grad(image, flow, ct,
-                                                       interpret),
-        n_in=3, sharding_rule="b h w c, b h w k, b h w c -> b h w k")
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def backward_warp_pallas(image: jnp.ndarray, flow: jnp.ndarray,
-                         interpret: bool | None = None) -> jnp.ndarray:
+                         interpret: bool | None = None,
+                         batch_axes: tuple[str, ...] = ("data",)
+                         ) -> jnp.ndarray:
     """Pallas warp: image (B,H,W,C), *scaled* flow (B,H,W,2) -> (B,H,W,C).
 
     Exact `ops.warp.backward_warp` semantics for W <= 128 (any flow
     magnitude — border clipping bounds the sweep), including gradients
     with respect to both arguments. interpret=None auto-selects
-    interpreter mode off-TPU (CPU test meshes).
+    interpreter mode off-TPU (CPU test meshes). Under a `mesh_context`
+    both kernels run per shard of the leading axis over `batch_axes`, the
+    mesh axes the caller shards it over
+    (`parallel.spatial.shard_over_batch`); the mesh is resolved HERE and
+    carried as a static argument because the backward rule is traced
+    after the context has exited.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _partitioned_fwd(interpret)(image, flow)
+    return _warp(image, flow, interpret, current_mesh(), tuple(batch_axes))
 
 
-def _fwd(image, flow, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _partitioned_fwd(interpret)(image, flow), (image, flow)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _warp(image, flow, interpret, mesh, axes):
+    return _fwd_launch(image, flow, interpret, mesh, axes)
 
 
-def _bwd(interpret, res, g):
+def _fwd(image, flow, interpret, mesh, axes):
+    return _fwd_launch(image, flow, interpret, mesh, axes), (image, flow)
+
+
+def _bwd(interpret, mesh, axes, res, g):
     from ..warp import backward_warp  # jnp formulation; same a.e. gradient
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     image, flow = res
     g32 = g.astype(jnp.float32)
     # flow cotangent: the training hot path (the model's only gradient
     # route through the warp) — fused Pallas sweep, no scatter
-    gf = _partitioned_flow_grad(interpret)(image, flow, g32)
+    gf = shard_over_batch(
+        lambda im, fl, ct: _pallas_warp_flow_grad(im, fl, ct, interpret),
+        mesh, image.shape[0], axes)(image, flow, g32)
     # image cotangent: XLA bilinear scatter; under jit it is dead-code-
     # eliminated when the image operand is data (the default loss). Eager
     # op-by-op grads do pay it — debug-only territory
@@ -283,4 +252,4 @@ def _bwd(interpret, res, g):
     return gi.astype(image.dtype), gf.astype(flow.dtype)
 
 
-backward_warp_pallas.defvjp(_fwd, _bwd)
+_warp.defvjp(_fwd, _bwd)

@@ -56,7 +56,8 @@ PALLAS_AUTO_MAX_H = 128
 
 
 def backward_warp(image: jnp.ndarray, flow: jnp.ndarray,
-                  impl: str = "xla") -> jnp.ndarray:
+                  impl: str = "xla",
+                  batch_axes: tuple[str, ...] = ("data",)) -> jnp.ndarray:
     """Warp `image` (B, H, W, C) backward by `flow` (B, H, W, 2).
 
     `flow` must already include any flow_scale factor (the caller applies it,
@@ -67,6 +68,10 @@ def backward_warp(image: jnp.ndarray, flow: jnp.ndarray,
     "pallas" (VMEM row-sweep kernel, requires W <= 128), or "auto"
     (pallas where admissible, xla for fine levels — the measured-fastest
     choice and the `LossConfig.warp_impl` default).
+
+    batch_axes: the mesh axes the leading axis is sharded over — the
+    Pallas kernel launches once per shard of them under a `mesh_context`
+    (the XLA formulation is partitioned by GSPMD and ignores it).
     """
     b, h, w, c = image.shape
     # "auto" = the measured-fastest choice, and the measurement is a TPU
@@ -80,7 +85,7 @@ def backward_warp(image: jnp.ndarray, flow: jnp.ndarray,
                             and jax.default_backend() == "tpu"):
         from .pallas.warp import backward_warp_pallas
 
-        return backward_warp_pallas(image, flow)
+        return backward_warp_pallas(image, flow, batch_axes=batch_axes)
     elif impl not in ("xla", "auto"):
         raise ValueError(f"unknown warp impl {impl!r}")
     flow_flat = flow.reshape(b, h * w, 2)
@@ -131,7 +136,8 @@ def backward_warp_volume(volume: jnp.ndarray, flows: jnp.ndarray,
     returns (B, H, W, 3*(T-1)) — channel c is gathered from volume channel
     c+3 using flow channels (2*(c//3), 2*(c//3)+1).
     """
-    from ..parallel.spatial import pair_axis_constraint
+    from ..parallel.spatial import (current_mesh, pair_axes,
+                                    pair_axis_constraint)
 
     b, h, w, c3t = volume.shape
     t = c3t // 3
@@ -144,5 +150,8 @@ def backward_warp_volume(volume: jnp.ndarray, flows: jnp.ndarray,
         jnp.moveaxis(frames[..., 1:, :], 3, 1).reshape(b * (t - 1), h, w, 3))
     flw = pair_axis_constraint(
         jnp.moveaxis(pairs, 3, 1).reshape(b * (t - 1), h, w, 2))
-    rec = backward_warp(nxt, flw, impl=impl).reshape(b, t - 1, h, w, 3)
+    rec = backward_warp(
+        nxt, flw, impl=impl,
+        batch_axes=pair_axes(current_mesh(), b * (t - 1)),
+    ).reshape(b, t - 1, h, w, 3)
     return jnp.moveaxis(rec, 1, 3).reshape(b, h, w, 3 * (t - 1))

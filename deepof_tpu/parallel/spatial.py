@@ -26,6 +26,8 @@ equivalents (SURVEY.md §5.7):
 from __future__ import annotations
 
 import contextlib
+import math
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -145,6 +147,17 @@ def constrain_batch(batch: dict, mesh: Mesh | None = None,
     return {k: put(v) for k, v in batch.items()}
 
 
+def pair_axes(mesh: Mesh | None, folded: int) -> tuple[str, ...]:
+    """Mesh axes a folded (B*(T-1), ...) pair axis is sharded over:
+    ("data", "time") when the time axis is populated and data*time divides
+    it, else the batch's own ("data",)."""
+    if mesh is None or mesh.shape.get("time", 1) <= 1:
+        return ("data",)
+    if folded % (mesh.shape["time"] * mesh.shape.get("data", 1)):
+        return ("data",)
+    return ("data", "time")
+
+
 def pair_axis_constraint(x: jnp.ndarray) -> jnp.ndarray:
     """Constrain a (B*(T-1), H, W, C) folded pair-axis array to shard over
     ("data", "time") so the T-1 per-pair warps run pair-parallel.
@@ -153,13 +166,42 @@ def pair_axis_constraint(x: jnp.ndarray) -> jnp.ndarray:
     does not divide the folded axis.
     """
     mesh = current_mesh()
-    if mesh is None or mesh.shape.get("time", 1) <= 1:
-        return x
-    shards = mesh.shape["time"] * mesh.shape.get("data", 1)
-    if x.shape[0] % shards:
+    if pair_axes(mesh, x.shape[0]) != ("data", "time"):
         return x
     return lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(("data", "time"),)))
+
+
+def shard_over_batch(fn, mesh: Mesh | None, batch: int,
+                     axes: tuple[str, ...] = ("data",)):
+    """Run `fn` (rank-4 (B, ...) arrays in, one out) once per batch shard.
+
+    The one multi-device form of the Pallas kernels: GSPMD cannot see
+    inside a `pallas_call` and Mosaic refuses to be partitioned
+    automatically, so the kernel wrappers hand the launcher here with the
+    mesh of the enclosing `mesh_context`. The kernels are independent per
+    batch element but need full H/W/C per shard (row sweep, displacement
+    window), so only the leading axis is split, over `axes` — the axes the
+    CALLER shards that axis over: "data" for a batch, `pair_axes` for the
+    folded pair axis of `backward_warp_volume`. Naming them keeps the
+    operands where they are; a split guessed from divisibility would
+    reshard a data-sharded batch over "time" and gather it back. Every
+    other mesh axis sees a replica. A leading axis `axes` do not divide
+    (a lone image under a mesh) runs whole on every device, and says so.
+    No mesh: `fn` itself (single-device jit, eager tests).
+    """
+    if mesh is None:
+        return fn
+    shards = math.prod(mesh.shape.get(a, 1) for a in axes)
+    spec = P(axes)
+    if batch % shards:
+        spec = P()
+        warnings.warn(
+            f"Pallas kernel: leading axis {batch} is not divisible by mesh "
+            f"axes {axes} = {shards}; every device runs the whole batch",
+            stacklevel=2)
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 def halo_exchange(x: jnp.ndarray, halo: int, axis_name: str = "spatial",

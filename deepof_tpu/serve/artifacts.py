@@ -348,13 +348,19 @@ def _serialize_compiled(compiled) -> bytes:
     return pickle.dumps((payload, in_tree, out_tree))
 
 
-def _deserialize_compiled(blob: bytes):
+def _deserialize_compiled(blob: bytes, device):
+    """Load a published executable onto `device`. Every published
+    executable is a single-device lowering; left to its default,
+    `deserialize_and_load` spreads the load over ALL devices of the
+    backend, and a one-device executable then fails at its first call on
+    any host with more than one ("expected ... N shards")."""
     import pickle
 
     from jax.experimental import serialize_executable as se
 
     payload, in_tree, out_tree = pickle.loads(blob)
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    return se.deserialize_and_load(payload, in_tree, out_tree,
+                                   execution_devices=[device])
 
 
 def params_aval_sig(params, extra: tuple = ()) -> str:
@@ -381,7 +387,10 @@ def params_aval_sig(params, extra: tuple = ()) -> str:
 
 
 class ArtifactStore:
-    """Fingerprint-keyed executable store bound to one backend.
+    """Fingerprint-keyed executable store bound to one backend and, for
+    its consumers, to the one device fetched executables are loaded onto
+    (the engine's; a store that only publishes or verifies files needs
+    none).
 
     ``fetch(fingerprint)`` -> ``(compiled | None, verdict)`` where
     verdict is ``"hit"``, ``"miss"`` (no entry — including every
@@ -392,9 +401,10 @@ class ArtifactStore:
     ``"exists"`` / ``"error:<why>"`` — never raises into the warmup.
     """
 
-    def __init__(self, root: str, backend: str | None = None):
+    def __init__(self, root: str, backend: str | None = None, device=None):
         self.root = str(root)
         self.backend = backend
+        self.device = device
         self._index = None  # lazy; one load per store instance
 
     # consumers ------------------------------------------------------
@@ -494,7 +504,8 @@ class ArtifactStore:
                                  or BLOB)
         try:
             with open(blob_path, "rb") as f:
-                compiled = _deserialize_compiled(f.read())
+                compiled = _deserialize_compiled(
+                    f.read(), self.device or jax.local_devices()[0])
         except Exception as e:  # noqa: BLE001 - any failure = fall back
             return None, self._reject(fingerprint,
                                       f"deserialize_failed: {e}")
@@ -551,13 +562,15 @@ class ArtifactStore:
             return f"error:{type(e).__name__}"
 
 
-def store_for_config(cfg) -> ArtifactStore | None:
+def store_for_config(cfg, device=None) -> ArtifactStore | None:
     """The store a serve config asks for, or None when the plane is off
     (``serve.artifacts_dir`` empty). Resolved to an absolute path so
-    replica subprocesses (their own cwd) read the same store."""
+    replica subprocesses (their own cwd) read the same store. `device`:
+    where fetched executables are loaded (default: the first local
+    device — the warmup's publish-then-verify round trip)."""
     root = getattr(cfg.serve, "artifacts_dir", "")
     if not root:
         return None
     import jax
     return ArtifactStore(os.path.abspath(os.path.expanduser(root)),
-                         backend=jax.default_backend())
+                         backend=jax.default_backend(), device=device)

@@ -408,7 +408,7 @@ class InferenceEngine:
             # would mismatch that compiled input spec, so serving
             # canonicalizes them onto one device; scale-out is N engine
             # processes, not in-engine batch sharding.
-            dev = jax.devices()[0]
+            dev = self._device = jax.devices()[0]
             self._params = jax.device_put(self._params, dev)
             # one quantized params tree per tier, staged once (int8 is a
             # quarter, bf16 half the f32 bytes); the tier trees' avals
@@ -466,7 +466,8 @@ class InferenceEngine:
         if not self._forward_custom:
             from .artifacts import store_for_config
 
-            self._artifacts = store_for_config(cfg)
+            # fetched executables load onto the device the params live on
+            self._artifacts = store_for_config(cfg, device=self._device)
         # executable index (trace-free boot): resolve each lattice entry
         # by its jax-free resolution key BEFORE building avals or
         # lowering anything — an index hit is fetch + gates +

@@ -144,15 +144,11 @@ def precompile_stages(cfg: ExperimentConfig, mesh=None,
     summary (per-stage compile seconds, fingerprints, cache verdict).
     """
     import jax
-    import jax.numpy as jnp
 
-    from ..models.registry import build_model
     from ..obs.ledger import ExecutableLedger
     from ..parallel.mesh import build_mesh
-    from .schedule import step_decay_schedule
-    from .state import create_train_state, make_optimizer
-    from .step import make_eval_fn, make_train_step
-    from .warmup import _sds, cache_delta, example_train_batch
+    from .step import make_eval_fn
+    from .warmup import _sds, abstract_train_step, cache_delta
 
     mesh = mesh if mesh is not None else build_mesh(cfg.mesh)
     ledger = ExecutableLedger(cfg.train.log_dir, enabled=cfg.obs.ledger,
@@ -166,38 +162,17 @@ def precompile_stages(cfg: ExperimentConfig, mesh=None,
                 continue
             scfg = stage_config(cfg, stage)
             dataset = stage_dataset(scfg, stage)
-            t = scfg.data.time_step
-            dtype = (jnp.bfloat16
-                     if scfg.train.compute_dtype == "bfloat16"
-                     else jnp.float32)
-            model = build_model(scfg.model, flow_channels=2 * (t - 1),
-                                dtype=dtype, width_mult=scfg.width_mult,
-                                corr_max_disp=scfg.corr_max_disp,
-                                corr_stride=scfg.corr_stride)
-            steps_per_epoch = max(
-                dataset.num_train // scfg.data.batch_size, 1)
-            tx = make_optimizer(scfg.optim,
-                                step_decay_schedule(scfg.optim,
-                                                    steps_per_epoch))
-            h, w = scfg.data.crop_size or scfg.data.image_size
-            channels = 3 if scfg.model == "ucf101_spatial" else 3 * t
-            example = jax.ShapeDtypeStruct(
-                (scfg.data.batch_size, h, w, channels), jnp.float32)
-            state_sds = jax.eval_shape(
-                lambda x, m=model, o=tx, s=scfg: create_train_state(
-                    m, x, o, seed=s.train.seed),
-                example)
-            smooth_border = scfg.model in ("st_single", "st_baseline")
-            step = make_train_step(model, scfg, dataset.mean, mesh,
-                                   smooth_border)
-            batch_sds = _sds(example_train_batch(scfg, dataset))
+            model, tx, step, state_sds, batch_sds = abstract_train_step(
+                scfg, mesh, dataset)
             train_compiled, row = ledger.record_aot(
                 f"train_step_stage{i}",
                 lambda s=step, a=state_sds, b=batch_sds: s.lower(a, b))
             shards = mesh.shape["data"]
             eval_bs = max(scfg.train.eval_batch_size // shards, 1) * shards
-            eval_fn = make_eval_fn(model, scfg, dataset.mean, mesh=mesh,
-                                   smooth_border_mask=smooth_border)
+            eval_fn = make_eval_fn(
+                model, scfg, dataset.mean, mesh=mesh,
+                smooth_border_mask=scfg.model in ("st_single",
+                                                  "st_baseline"))
             eval_sds = _sds({k: np.asarray(v) for k, v in
                              dataset.sample_val(eval_bs, 0).items()})
             eval_compiled, erow = ledger.record_aot(
@@ -213,7 +188,7 @@ def precompile_stages(cfg: ExperimentConfig, mesh=None,
                         "eval_fn": eval_compiled}
             report["stages"].append(
                 {"stage": i, "name": stage.name, "model": scfg.model,
-                 "time_step": t,
+                 "time_step": scfg.data.time_step,
                  "train_compile_s": row["compile_s"],
                  "eval_compile_s": erow["compile_s"],
                  "train_fingerprint": row["fingerprint"],

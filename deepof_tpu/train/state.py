@@ -71,13 +71,23 @@ def create_train_state(
     (`flyingChairsTrain.py:106-118`).
     """
     rng, init_rng = jax.random.split(jax.random.PRNGKey(seed))
-    params = model.init({"params": init_rng}, example_input)["params"]
+
+    # ONE jitted executable for params + optimizer state. Eagerly,
+    # `model.init` runs the whole forward op by op at the example's full
+    # resolution and `tx.init` adds a dispatch per leaf: hundreds of tiny
+    # compiles on an accelerator. Under jit the forward is dead code (only
+    # the initializers feed the outputs) and XLA drops it.
+    def init(key, x):
+        params = model.init({"params": key}, x)["params"]
+        return params, tx.init(params)
+
+    params, opt_state = jax.jit(init)(init_rng, example_input)
     if log:
         log(f"model parameters: {count_params(params):,}")
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params=params,
-        opt_state=tx.init(params),
+        opt_state=opt_state,
         rng=rng,
         tx=tx,
     )

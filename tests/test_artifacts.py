@@ -50,6 +50,24 @@ from deepof_tpu.serve.artifacts import (BLOB, INDEX, MANIFEST, gc_store,
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_compile_cache():
+    """What these tests publish must come from a real compile. On the CPU
+    backend (jax 0.9) an executable that was LOADED from the persistent
+    compile cache re-serializes into a payload whose functions are gone
+    ("Function ... not found" at its first call), so with the suite's
+    warm cache the round trips below failed on every run but the first."""
+    import jax
+
+    from deepof_tpu.train import warmup
+
+    prev = jax.config.jax_compilation_cache_dir
+    warmup.disable_compile_cache()
+    yield
+    if prev is not None:
+        warmup.enable_compile_cache(prev)
+
+
 # ----------------------------------------------------------- helpers
 
 

@@ -127,7 +127,9 @@ def test_lowering_row_schema_cost_memory_and_donation():
     assert row["cache_misses"] == 1 and row["cache_hits"] == 0
     assert row["flops"] and row["flops"] > 0  # 8x8 matmul ~ 2*8^3
     assert row["bytes_accessed"] and row["arith_intensity"] > 0
-    assert row["roofline_s"] and row["roofline_s"] > 0
+    # no roofline against a chip that is not there: the cpu has no entry
+    # in obs/telemetry.py's peak table
+    assert row["roofline_s"] is None
     assert row["donated_args"] == 1 and row["num_args"] == 2
     # cpu PJRT reports memory_analysis: argument/output/temp are ints
     assert isinstance(row["argument_bytes"], int)

@@ -298,14 +298,10 @@ def test_fit_writes_trace_heartbeat_and_telemetry(tmp_path):
     overlapping spans, a fresh heartbeat.json at exit, and model_tflops
     + device-memory fields in periodic train records.
 
-    Runs the CLI in a SUBPROCESS, deliberately: the suite process has
-    the persistent compile cache enabled (conftest/force_cpu_devices),
-    and warm cross-process cache READS reproducibly corrupt the heap on
-    this host's cpu jaxlib (hostmesh.py's documented residual risk —
-    bisected here to rc=139/134 at steady-state pjit dispatch with every
-    obs feature disabled). The CLI's auto gate keeps the cache OFF on
-    cpu, so the child pays a fresh ~15 s compile instead of a coin-flip
-    segfault — and the test exercises the real `--trace` entry path."""
+    Runs the CLI in a SUBPROCESS, deliberately: the test exercises the
+    real `--trace` entry path, in a process whose signal handlers,
+    threads and compile cache (off on cpu by the CLI's auto gate) are
+    its own and not the suite's."""
     period = 0.2
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH",

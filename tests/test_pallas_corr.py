@@ -52,10 +52,12 @@ def test_pallas_corr_grad_matches_xla(feats):
 
 
 def test_pallas_corr_sharded_over_batch_mesh(feats):
-    """custom_partitioning rule: under pjit with the batch sharded over the
-    8-device mesh, the kernel runs per-shard (GSPMD must not all-gather or
-    choke on the opaque pallas_call) and matches the oracle."""
+    """shard_map form (parallel.spatial.shard_over_batch): under jit with
+    the batch sharded over the 8-device mesh and the mesh published by
+    `mesh_context`, the kernel runs per shard (GSPMD must not all-gather
+    or choke on the opaque pallas_call) and matches the oracle."""
     from deepof_tpu.parallel.mesh import batch_sharding, local_mesh
+    from deepof_tpu.parallel.spatial import mesh_context
 
     f1, f2 = feats
     f1 = np.concatenate([f1] * 4)  # batch 8 over 8 devices
@@ -65,8 +67,9 @@ def test_pallas_corr_sharded_over_batch_mesh(feats):
 
     fn = jax.jit(lambda a, b: correlation_pallas(a, b, 2, 1, 4, True),
                  in_shardings=(sharding, sharding))
-    got = fn(jax.device_put(jnp.asarray(f1), sharding),
-             jax.device_put(jnp.asarray(f2), sharding))
+    with mesh_context(mesh):  # read at trace time, as the step builders do
+        got = fn(jax.device_put(jnp.asarray(f1), sharding),
+                 jax.device_put(jnp.asarray(f2), sharding))
     assert got.sharding.spec[0] == "data"
     want = correlation_oracle(f1, f2, max_disp=2, stride=1)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)

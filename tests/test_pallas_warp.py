@@ -115,3 +115,76 @@ def test_pallas_flow_grad_clipped_and_flow_only(rng):
     gx = jax.grad(lambda f: jnp.sum(backward_warp(img, f) ** 2))(flow)
     np.testing.assert_allclose(np.asarray(gp), np.asarray(gx),
                                rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------- the multi-device form on a mesh
+
+
+def _data_time_mesh():
+    from deepof_tpu.core.config import MeshConfig
+    from deepof_tpu.parallel.mesh import build_mesh
+
+    return build_mesh(MeshConfig(time=2))  # 8 virtual devices: data 4 x time 2
+
+
+def test_pallas_warp_keeps_a_data_sharded_batch_on_a_data_time_mesh(rng):
+    """A batch is sharded over "data" only. On a mesh that also has a
+    "time" axis the kernel must launch per DATA shard and leave the
+    operands where they are — not split them over ("data","time") because
+    8 happens to divide, and gather them back."""
+    from deepof_tpu.parallel.mesh import batch_sharding
+    from deepof_tpu.parallel.spatial import mesh_context
+
+    mesh = _data_time_mesh()
+    data = batch_sharding(mesh)
+    img = jax.device_put(jnp.asarray(rng.rand(8, 10, 14, 3), jnp.float32), data)
+    flow = jax.device_put(
+        jnp.asarray(rng.randn(8, 10, 14, 2) * 2.0, jnp.float32), data)
+    fn = jax.jit(backward_warp_pallas, in_shardings=(data, data))
+    with mesh_context(mesh):  # read at trace time, as the step builders do
+        text = fn.lower(img, flow).compile().as_text()
+        out = fn(img, flow)
+    assert out.sharding.spec[0] in ("data", ("data",))
+    assert "all-gather" not in text
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(backward_warp(img, flow)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_pallas_volume_warp_shards_the_folded_pair_axis_over_data_time(rng):
+    """`backward_warp_volume` folds the T-1 pairs into the batch and shards
+    that axis over ("data","time"): the kernel follows, and matches XLA."""
+    from deepof_tpu.ops.warp import backward_warp_volume
+    from deepof_tpu.parallel.spatial import mesh_context, pair_axes
+
+    mesh = _data_time_mesh()
+    assert pair_axes(mesh, 8) == ("data", "time")
+    assert pair_axes(mesh, 4) == ("data",)  # data*time does not divide
+    assert pair_axes(None, 8) == ("data",)
+    vol = jnp.asarray(rng.rand(4, 10, 14, 9), jnp.float32)        # T = 3
+    flows = jnp.asarray(rng.randn(4, 10, 14, 4) * 2.0, jnp.float32)
+    fn = jax.jit(lambda v, f: backward_warp_volume(v, f, impl="pallas"))
+    with mesh_context(mesh):
+        text = fn.lower(vol, flows).as_text()
+        out = fn(vol, flows)
+    launch = next(ln for ln in text.splitlines() if "manual_computation" in ln)
+    assert 'in_shardings=[<@mesh, [{"data", "time"}, {}, {}, {}]>' in launch
+    assert "tensor<1x10x14x3xf32>" in launch  # one pair per device
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(backward_warp_volume(vol, flows)),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_pallas_warp_says_so_when_the_batch_cannot_be_split(rng):
+    """Three images over data=4: every device runs the whole batch. That
+    is correct and slow, so it is announced when the step is traced."""
+    from deepof_tpu.parallel.spatial import mesh_context
+
+    img = jnp.asarray(rng.rand(3, 10, 14, 3), jnp.float32)
+    flow = jnp.asarray(rng.randn(3, 10, 14, 2), jnp.float32)
+    with mesh_context(_data_time_mesh()):
+        with pytest.warns(UserWarning, match="every device runs the whole"):
+            out = jax.jit(backward_warp_pallas)(img, flow)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(backward_warp(img, flow)),
+                               rtol=1e-5, atol=1e-5)

@@ -529,10 +529,10 @@ def test_bench_trend_schema_and_regression_flag(tmp_path):
         "worse_frac"] == pytest.approx(0.5)
     # the improved proxies did not flag
     assert "bench_data_w0_batches_per_s" not in report["regressions"]
-    # the real repo's BENCH files parse without error
-    real = mod.bench_trend(REPO)
-    assert real["latest_round"] >= 12
-    assert real["series"]["bench_serve_requests_per_s"]
+    # a directory with no BENCH_rNN.json (the repo itself, since the proxy
+    # records were deleted) is an empty trend, not an error
+    empty = mod.bench_trend(REPO)
+    assert empty["rounds"] == [] and empty["latest_round"] is None
 
 
 def test_serve_bench_quality_schema(tmp_path):

@@ -436,14 +436,10 @@ def test_run_recipe_budget_cap_intersects_stage_budget(tmp_path,
 # --------------------------------------------------------------------------
 # end-to-end recipe runs (slow; CLI subprocess)
 #
-# Deliberately subprocess-shaped: the suite process has the persistent
-# compile cache enabled (conftest/force_cpu_devices) and warm
-# cross-process cache READS corrupt the heap on this host's cpu jaxlib
-# (hostmesh.py's documented residual risk; reproduced here as rc=134 at
-# steady-state dispatch inside an in-process run_recipe). The CLI's
-# auto gate keeps the cache OFF on cpu, so the child pays a fresh
-# compile instead of a coin-flip segfault — and the tests exercise the
-# real `train --recipe` / `predict --action` entry paths.
+# Deliberately subprocess-shaped: the tests exercise the real
+# `train --recipe` / `predict --action` entry paths, each run in a
+# process of its own (own signal handlers, threads and — off on cpu by
+# the CLI's auto gate — compile cache).
 # --------------------------------------------------------------------------
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -4,9 +4,9 @@ The fast-tier chaos suite (`-m "chaos and not slow"`) exercises every
 injection site once — decode, assemble, fetch, dispatch, ckpt_save,
 ckpt_restore, and the two post-commit tamper sites — against the exact
 recovery path that guards it. The slow-tier acceptance drives a full
-fit() through all four operational sites in a subprocess (the suite's
-warm compile cache makes in-process fits segfault on this host's cpu
-jaxlib — hostmesh.py r07 addendum) and pins the determinism contract:
+fit() through all four operational sites in a subprocess (the real CLI
+entry path, isolated from the suite's process state) and pins the
+determinism contract:
 recoverable data faults leave the final params bit-identical to a
 fault-free run.
 """
@@ -542,13 +542,13 @@ def test_counter_summary_surfaces_resilience():
     assert out["resilience"]["fault_decode"] == 5
 
 
-# --------------------------------------------------- acceptance (slow)
+# ------------------------------------------------- train CLI helpers
 
-def _train_cli(log_dir, steps, extra, timeout=420):
+def _run_train_cli(log_dir, steps, extra, timeout=420, **env_over):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH",
-                                                             ""))
-    res = subprocess.run(
+                                                             ""), **env_over)
+    return subprocess.run(
         [sys.executable, "-m", "deepof_tpu", "train", "--preset",
          "flyingchairs", "--synthetic", "--max-steps", str(steps),
          "--log-dir", str(log_dir),
@@ -558,9 +558,49 @@ def _train_cli(log_dir, steps, extra, timeout=420):
          "--set", "resilience.data_backoff_s=0.001",
          *extra],
         capture_output=True, text=True, timeout=timeout, env=env, cwd=REPO)
+
+
+def _train_cli(log_dir, steps, extra, timeout=420, **env_over):
+    res = _run_train_cli(log_dir, steps, extra, timeout, **env_over)
     assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-3000:])
     return json.loads(res.stdout.strip().splitlines()[-1])
 
+
+# ------------------------------------------- the final checkpoint check
+
+# the smallest size that still walks fit() end to end: ~15 s on ONE cpu
+# device (XLA_FLAGS="" drops the suite's 8 virtual ones: 80 s there)
+_TINY = ["--set", "data.image_size=32,32", "--set", "data.gt_size=32,32",
+         "--set", "data.batch_size=2",
+         "--set", "resilience.faults.enabled=true"]
+
+
+def test_fit_with_one_skipped_update_returns_and_final_ckpt_verifies(
+        tmp_path):
+    """First rung of the divergence ladder under the default
+    max_consecutive_skips: one poisoned batch costs one skipped update.
+    The final checkpoint is named after state.step (5), which trails the
+    loop's step count (6) — the fit must still return normally."""
+    d = tmp_path / "skip"
+    out = _train_cli(d, 6, _TINY + [
+        "--set", "resilience.faults.dispatch_at=(3,)"], XLA_FLAGS="")
+    assert out["fault_dispatch"] == 1 and out["skipped_updates"] == 1
+    assert not out.get("rollbacks")
+    rep = ckpt_verify.verify_run(str(d))
+    assert rep["ok"], rep
+    assert max(rep["valid_steps"]) == 5
+
+
+def test_fit_whose_final_save_fails_does_not_return_zero(tmp_path):
+    """Periodic saves may degrade to a warning; the final one is the run's
+    product, so a fit that could not commit it exits non-zero."""
+    res = _run_train_cli(tmp_path / "nosave", 3, _TINY + [
+        "--set", "resilience.faults.ckpt_save_at=(3,)"], XLA_FLAGS="")
+    assert res.returncode != 0
+    assert "final checkpoint (step 3) did not commit" in res.stderr
+
+
+# --------------------------------------------------- acceptance (slow)
 
 @pytest.mark.slow
 @pytest.mark.chaos
