@@ -30,6 +30,7 @@ from __future__ import annotations
 import math
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
 from ..core.config import LossConfig
@@ -190,8 +191,9 @@ def loss_interp(
     # Byte-halving bf16 warp operand iff the gather is byte-bound —
     # perf_probe warpscan answers which; the Pallas path upcasts
     # internally either way (see _warp_operand).
-    recon = backward_warp(_warp_operand(outputs, cfg), scaled,
-                          impl=cfg.warp_impl).astype(inputs.dtype)
+    with jax.named_scope("warp"):
+        recon = backward_warp(_warp_operand(outputs, cfg), scaled,
+                              impl=cfg.warp_impl).astype(inputs.dtype)
     # needImageGradients (`flyingChairsWrapFlow_vgg.py:226-301`): the same
     # per-sample gradient-magnitude mask weights the photometric term by
     # |grad| and BOTH smoothness terms by 1-|grad| (edges may move freely).
@@ -200,112 +202,114 @@ def loss_interp(
             "loss.edge_aware_photo pairs only with photometric='charbonnier' "
             f"(got {cfg.photometric!r}); the census branch would silently "
             "skip the photometric weighting")
-    gmask = _photo_gradient_mask(inputs) if cfg.edge_aware_photo else None
+    with jax.named_scope("photometric"):
+        gmask = _photo_gradient_mask(inputs) if cfg.edge_aware_photo else None
 
-    bmask = border_mask(h, w, cfg.border_ratio)  # (h, w)
-    # guard: at very coarse pyramid levels (h <= 2) the border mask has no
-    # interior (the reference never ran levels this small); such a level
-    # contributes exactly 0 to photometric AND smoothness terms.
-    n_interior = jnp.sum(bmask)
-    level_on = (n_interior > 0).astype(inputs.dtype)
-    num_valid = jnp.maximum(b * c * n_interior, 1.0)
-    if cfg.photometric == "census":
-        from ..ops.census import census_distance, census_transform
+        bmask = border_mask(h, w, cfg.border_ratio)  # (h, w)
+        # guard: at very coarse pyramid levels (h <= 2) the border mask has no
+        # interior (the reference never ran levels this small); such a level
+        # contributes exactly 0 to photometric AND smoothness terms.
+        n_interior = jnp.sum(bmask)
+        level_on = (n_interior > 0).astype(inputs.dtype)
+        num_valid = jnp.maximum(b * c * n_interior, 1.0)
+        if cfg.photometric == "census":
+            from ..ops.census import census_distance, census_transform
 
-        # census neighborhoods reach window//2 pixels: widen the mask so
-        # edge-replicated descriptor components never enter the loss
-        # (at coarse levels ceil(0.1*h) can be narrower than the window)
-        cmask = jnp.broadcast_to(
-            border_mask(h, w, cfg.border_ratio,
-                        min_width=cfg.census_window // 2)[None, :, :, None],
-            (b, h, w, 1))
-        vis = cmask
-        if occ_mask is not None:
-            vis = cmask * occ_mask
-        dist = census_distance(census_transform(recon, cfg.census_window),
-                               census_transform(inputs, cfg.census_window))
-        photo = jnp.sum(dist * vis) / jnp.maximum(jnp.sum(vis), 1.0)
-        if occ_mask is not None:
-            # occluded pixels must not be free (see LossConfig.occ_penalty)
-            photo = photo + cfg.occ_penalty * (
-                jnp.sum(cmask * (1.0 - occ_mask))
-                / jnp.maximum(jnp.sum(cmask), 1.0))
-    elif cfg.photometric == "charbonnier":
-        pmask = bmask[None, :, :, None]
-        if occ_mask is not None:
-            pmask = pmask * occ_mask
-            photo_norm = jnp.maximum(c * jnp.sum(pmask), 1.0)
+            # census neighborhoods reach window//2 pixels: widen the mask so
+            # edge-replicated descriptor components never enter the loss
+            # (at coarse levels ceil(0.1*h) can be narrower than the window)
+            cmask = jnp.broadcast_to(
+                border_mask(h, w, cfg.border_ratio,
+                            min_width=cfg.census_window // 2)[None, :, :, None],
+                (b, h, w, 1))
+            vis = cmask
+            if occ_mask is not None:
+                vis = cmask * occ_mask
+            dist = census_distance(census_transform(recon, cfg.census_window),
+                                   census_transform(inputs, cfg.census_window))
+            photo = jnp.sum(dist * vis) / jnp.maximum(jnp.sum(vis), 1.0)
+            if occ_mask is not None:
+                # occluded pixels must not be free (see LossConfig.occ_penalty)
+                photo = photo + cfg.occ_penalty * (
+                    jnp.sum(cmask * (1.0 - occ_mask))
+                    / jnp.maximum(jnp.sum(cmask), 1.0))
+        elif cfg.photometric == "charbonnier":
+            pmask = bmask[None, :, :, None]
+            if occ_mask is not None:
+                pmask = pmask * occ_mask
+                photo_norm = jnp.maximum(c * jnp.sum(pmask), 1.0)
+            else:
+                photo_norm = num_valid
+            diff = 255.0 * (recon - inputs)
+            ele = charbonnier(diff, cfg.epsilon, cfg.alpha_c) * pmask
+            if gmask is not None:
+                # normalizer stays numValidPixels — the weight reduces the sum
+                # only (`flyingChairsWrapFlow_vgg.py:269-276`)
+                ele = ele * gmask
+            photo = jnp.sum(ele) / photo_norm
+            if occ_mask is not None:
+                photo = photo + cfg.occ_penalty * (
+                    jnp.sum(bmask[None, :, :, None] * (1.0 - occ_mask))
+                    / jnp.maximum(b * n_interior, 1.0))
         else:
-            photo_norm = num_valid
-        diff = 255.0 * (recon - inputs)
-        ele = charbonnier(diff, cfg.epsilon, cfg.alpha_c) * pmask
-        if gmask is not None:
-            # normalizer stays numValidPixels — the weight reduces the sum
-            # only (`flyingChairsWrapFlow_vgg.py:269-276`)
-            ele = ele * gmask
-        photo = jnp.sum(ele) / photo_norm
-        if occ_mask is not None:
-            photo = photo + cfg.occ_penalty * (
-                jnp.sum(bmask[None, :, :, None] * (1.0 - occ_mask))
-                / jnp.maximum(b * n_interior, 1.0))
-    else:
-        raise ValueError(f"unknown photometric variant {cfg.photometric!r}")
+            raise ValueError(f"unknown photometric variant {cfg.photometric!r}")
 
-    sflow = scaled if cfg.smooth_scaled_flow else flow
-    diff_x, diff_y, mx, my = _smoothness_diffs(cfg, h, w)
+    with jax.named_scope("smooth"):
+        sflow = scaled if cfg.smooth_scaled_flow else flow
+        diff_x, diff_y, mx, my = _smoothness_diffs(cfg, h, w)
 
-    if cfg.smoothness == "canonical":
-        if cfg.edge_aware:
-            raise ValueError(
-                "loss.edge_aware pairs only with smoothness='depthwise' "
-                "(the gen-1 variant it comes from, `version1/model/"
-                "warpflow.py:93-157`); the canonical branch would silently "
-                "skip the Sobel weighting")
-        # x-diff of U masked at last col, y-diff of V masked at last row;
-        # optional border mask pre-Charbonnier (UCF variant).
-        du = diff_x(sflow[..., 0:1]) * mx
-        dv = diff_y(sflow[..., 1:2]) * my
-        if smooth_border_mask:
-            du = du * bmask[None, :, :, None]
-            dv = dv * bmask[None, :, :, None]
-        ele_u = charbonnier(du, cfg.epsilon, cfg.alpha_s)
-        ele_v = charbonnier(dv, cfg.epsilon, cfg.alpha_s)
-        if gmask is not None:
-            ele_u = ele_u * (1.0 - gmask)
-            ele_v = ele_v * (1.0 - gmask)
-        u_loss = jnp.sum(ele_u) / num_valid
-        v_loss = jnp.sum(ele_v) / num_valid
-    elif cfg.smoothness == "depthwise":
-        # both-direction gradients per component; border mask multiplies
-        # *after* the Charbonnier power; normalizer is 2/3 of the image one
-        # (`version1/model/warpflow.py:133-163`).
-        num_valid_flow = num_valid / 3.0 * 2.0
-        gx = diff_x(sflow)  # (B,h,w,2): dU/dx, dV/dx
-        gy = diff_y(sflow)
-        u_delta = jnp.stack([gx[..., 0] * mx[..., 0], gy[..., 0] * my[..., 0]], axis=-1)
-        v_delta = jnp.stack([gx[..., 1] * mx[..., 0], gy[..., 1] * my[..., 0]], axis=-1)
-        ele_u = charbonnier(u_delta, cfg.epsilon, cfg.alpha_s)
-        ele_v = charbonnier(v_delta, cfg.epsilon, cfg.alpha_s)
-        if cfg.edge_aware:
-            emx, emy = _edge_aware_masks(inputs)
-            emask = jnp.concatenate([emx, emy], axis=-1)  # (B,h,w,2)
-            ele_u = ele_u * emask
-            ele_v = ele_v * emask
-        if gmask is not None:
-            # vgg-variant pairing: 1 - magnitude mask, identical for the
-            # x- and y-gradient channels (`flyingChairsWrapFlow_vgg.py:
-            # 259-260,293-301`) — distinct from `edge_aware`'s directional
-            # 1-|gx| / 1-|gy| masks
-            ele_u = ele_u * (1.0 - gmask)
-            ele_v = ele_v * (1.0 - gmask)
-        bflow = bmask[None, :, :, None]
-        u_loss = jnp.sum(ele_u * bflow) / num_valid_flow
-        v_loss = jnp.sum(ele_v * bflow) / num_valid_flow
-    else:
-        raise ValueError(f"unknown smoothness variant {cfg.smoothness!r}")
+        if cfg.smoothness == "canonical":
+            if cfg.edge_aware:
+                raise ValueError(
+                    "loss.edge_aware pairs only with smoothness='depthwise' "
+                    "(the gen-1 variant it comes from, `version1/model/"
+                    "warpflow.py:93-157`); the canonical branch would silently "
+                    "skip the Sobel weighting")
+            # x-diff of U masked at last col, y-diff of V masked at last row;
+            # optional border mask pre-Charbonnier (UCF variant).
+            du = diff_x(sflow[..., 0:1]) * mx
+            dv = diff_y(sflow[..., 1:2]) * my
+            if smooth_border_mask:
+                du = du * bmask[None, :, :, None]
+                dv = dv * bmask[None, :, :, None]
+            ele_u = charbonnier(du, cfg.epsilon, cfg.alpha_s)
+            ele_v = charbonnier(dv, cfg.epsilon, cfg.alpha_s)
+            if gmask is not None:
+                ele_u = ele_u * (1.0 - gmask)
+                ele_v = ele_v * (1.0 - gmask)
+            u_loss = jnp.sum(ele_u) / num_valid
+            v_loss = jnp.sum(ele_v) / num_valid
+        elif cfg.smoothness == "depthwise":
+            # both-direction gradients per component; border mask multiplies
+            # *after* the Charbonnier power; normalizer is 2/3 of the image one
+            # (`version1/model/warpflow.py:133-163`).
+            num_valid_flow = num_valid / 3.0 * 2.0
+            gx = diff_x(sflow)  # (B,h,w,2): dU/dx, dV/dx
+            gy = diff_y(sflow)
+            u_delta = jnp.stack([gx[..., 0] * mx[..., 0], gy[..., 0] * my[..., 0]], axis=-1)
+            v_delta = jnp.stack([gx[..., 1] * mx[..., 0], gy[..., 1] * my[..., 0]], axis=-1)
+            ele_u = charbonnier(u_delta, cfg.epsilon, cfg.alpha_s)
+            ele_v = charbonnier(v_delta, cfg.epsilon, cfg.alpha_s)
+            if cfg.edge_aware:
+                emx, emy = _edge_aware_masks(inputs)
+                emask = jnp.concatenate([emx, emy], axis=-1)  # (B,h,w,2)
+                ele_u = ele_u * emask
+                ele_v = ele_v * emask
+            if gmask is not None:
+                # vgg-variant pairing: 1 - magnitude mask, identical for the
+                # x- and y-gradient channels (`flyingChairsWrapFlow_vgg.py:
+                # 259-260,293-301`) — distinct from `edge_aware`'s directional
+                # 1-|gx| / 1-|gy| masks
+                ele_u = ele_u * (1.0 - gmask)
+                ele_v = ele_v * (1.0 - gmask)
+            bflow = bmask[None, :, :, None]
+            u_loss = jnp.sum(ele_u * bflow) / num_valid_flow
+            v_loss = jnp.sum(ele_v * bflow) / num_valid_flow
+        else:
+            raise ValueError(f"unknown smoothness variant {cfg.smoothness!r}")
 
-    u_loss = u_loss * level_on
-    v_loss = v_loss * level_on
+        u_loss = u_loss * level_on
+        v_loss = v_loss * level_on
     total = photo + cfg.lambda_smooth * (u_loss + v_loss)
     return (
         # "smooth" aliases U+V as one number — the per-scale training
@@ -366,47 +370,50 @@ def loss_interp_multi(
     b, h, w, c3t = volume.shape
     t = c3t // 3
     scaled = flows * flow_scale
-    recon = backward_warp_volume(_warp_operand(volume, cfg), scaled,
-                                 impl=cfg.warp_impl).astype(volume.dtype)
+    with jax.named_scope("warp"):
+        recon = backward_warp_volume(_warp_operand(volume, cfg), scaled,
+                                     impl=cfg.warp_impl).astype(volume.dtype)
 
-    bmask = border_mask(h, w, cfg.border_ratio)
-    n_interior = jnp.sum(bmask)
-    level_on = (n_interior > 0).astype(recon.dtype)
-    num_valid = jnp.maximum(b * 3 * (t - 1) * n_interior, 1.0)
-    if cfg.photometric == "census":
-        from ..ops.census import census_distance, census_transform
+    with jax.named_scope("photometric"):
+        bmask = border_mask(h, w, cfg.border_ratio)
+        n_interior = jnp.sum(bmask)
+        level_on = (n_interior > 0).astype(recon.dtype)
+        num_valid = jnp.maximum(b * 3 * (t - 1) * n_interior, 1.0)
+        if cfg.photometric == "census":
+            from ..ops.census import census_distance, census_transform
 
-        # Per-pair census: the descriptor is per-image (grayscale over a
-        # 3-channel frame), so fold the T-1 reconstructed frames into the
-        # batch axis and compare each against its source frame. Same
-        # widened border mask as the 2-frame census branch.
-        cmask = border_mask(h, w, cfg.border_ratio,
-                            min_width=cfg.census_window // 2)[None, :, :, None]
-        rec_f = jnp.moveaxis(
-            recon.reshape(b, h, w, t - 1, 3), 3, 1
-        ).reshape(b * (t - 1), h, w, 3)
-        src_f = jnp.moveaxis(
-            volume[..., : 3 * (t - 1)].reshape(b, h, w, t - 1, 3), 3, 1
-        ).reshape(b * (t - 1), h, w, 3)
-        dist = census_distance(
-            census_transform(rec_f, cfg.census_window),
-            census_transform(src_f, cfg.census_window))
-        vis = jnp.broadcast_to(cmask, dist.shape)
-        photo = jnp.sum(dist * vis) / jnp.maximum(jnp.sum(vis), 1.0)
-    elif cfg.photometric == "charbonnier":
-        diff = 255.0 * (recon - volume[..., : 3 * (t - 1)])
-        ele = charbonnier(diff, cfg.epsilon, cfg.alpha_c) * bmask[None, :, :, None]
-        photo = jnp.sum(ele) / num_valid
-    else:
-        raise ValueError(f"unknown photometric variant {cfg.photometric!r}")
+            # Per-pair census: the descriptor is per-image (grayscale over a
+            # 3-channel frame), so fold the T-1 reconstructed frames into the
+            # batch axis and compare each against its source frame. Same
+            # widened border mask as the 2-frame census branch.
+            cmask = border_mask(h, w, cfg.border_ratio,
+                                min_width=cfg.census_window // 2)[None, :, :, None]
+            rec_f = jnp.moveaxis(
+                recon.reshape(b, h, w, t - 1, 3), 3, 1
+            ).reshape(b * (t - 1), h, w, 3)
+            src_f = jnp.moveaxis(
+                volume[..., : 3 * (t - 1)].reshape(b, h, w, t - 1, 3), 3, 1
+            ).reshape(b * (t - 1), h, w, 3)
+            dist = census_distance(
+                census_transform(rec_f, cfg.census_window),
+                census_transform(src_f, cfg.census_window))
+            vis = jnp.broadcast_to(cmask, dist.shape)
+            photo = jnp.sum(dist * vis) / jnp.maximum(jnp.sum(vis), 1.0)
+        elif cfg.photometric == "charbonnier":
+            diff = 255.0 * (recon - volume[..., : 3 * (t - 1)])
+            ele = charbonnier(diff, cfg.epsilon, cfg.alpha_c) * bmask[None, :, :, None]
+            photo = jnp.sum(ele) / num_valid
+        else:
+            raise ValueError(f"unknown photometric variant {cfg.photometric!r}")
 
-    sflow = scaled if cfg.smooth_scaled_flow else flows
-    diff_x, diff_y, mx, my = _smoothness_diffs(cfg, h, w)
-    bflow = bmask[None, :, :, None]
-    du = diff_x(sflow[..., 0::2]) * mx * bflow  # (B,h,w,T-1)
-    dv = diff_y(sflow[..., 1::2]) * my * bflow
-    u_loss = jnp.sum(charbonnier(du, cfg.epsilon, cfg.alpha_s)) / num_valid * level_on
-    v_loss = jnp.sum(charbonnier(dv, cfg.epsilon, cfg.alpha_s)) / num_valid * level_on
+    with jax.named_scope("smooth"):
+        sflow = scaled if cfg.smooth_scaled_flow else flows
+        diff_x, diff_y, mx, my = _smoothness_diffs(cfg, h, w)
+        bflow = bmask[None, :, :, None]
+        du = diff_x(sflow[..., 0::2]) * mx * bflow  # (B,h,w,T-1)
+        dv = diff_y(sflow[..., 1::2]) * my * bflow
+        u_loss = jnp.sum(charbonnier(du, cfg.epsilon, cfg.alpha_s)) / num_valid * level_on
+        v_loss = jnp.sum(charbonnier(dv, cfg.epsilon, cfg.alpha_s)) / num_valid * level_on
 
     total = photo + cfg.lambda_smooth * (u_loss + v_loss)
     return (

@@ -68,13 +68,17 @@ def pyramid_loss(
     total = jnp.zeros(())
     for k, (flow, scale) in enumerate(flow_pyramid):
         h, w = flow.shape[1:3]
-        li = _resize(inputs_norm, h, w)
-        lo = _resize(outputs_norm, h, w)
-        occ = None
-        if flow_pyramid_bw is not None:
-            occ = occlusion_mask(flow * scale, flow_pyramid_bw[k] * scale, cfg)
-        ld, recon = loss_interp(flow, li, lo, scale, cfg, smooth_border_mask,
-                                occ_mask=occ)
+        # one scope a level (0 = finest): a profile's operations carry it
+        # in their op_name, the backward pass as transpose(jvp(...))
+        with jax.named_scope(f"loss_level_{k}"):
+            li = _resize(inputs_norm, h, w)
+            lo = _resize(outputs_norm, h, w)
+            occ = None
+            if flow_pyramid_bw is not None:
+                occ = occlusion_mask(flow * scale, flow_pyramid_bw[k] * scale,
+                                     cfg)
+            ld, recon = loss_interp(flow, li, lo, scale, cfg,
+                                    smooth_border_mask, occ_mask=occ)
         losses.append(ld)
         if k == 0:
             recon_finest = recon
@@ -94,8 +98,9 @@ def pyramid_loss_multi(
     total = jnp.zeros(())
     for k, (flow, scale) in enumerate(flow_pyramid):
         h, w = flow.shape[1:3]
-        vol = _resize(volume_norm, h, w)
-        ld, recon = loss_interp_multi(flow, vol, scale, cfg)
+        with jax.named_scope(f"loss_level_{k}"):
+            vol = _resize(volume_norm, h, w)
+            ld, recon = loss_interp_multi(flow, vol, scale, cfg)
         losses.append(ld)
         if k == 0:
             recon_finest = recon

@@ -9,7 +9,14 @@ holds the instruments that can:
                 trace-event JSON (load artifacts' trace.json in Perfetto
                 / chrome://tracing) — the cross-thread timeline that
                 makes dispatch/put/fetch/assemble overlap visible
-                instead of inferred from phase totals.
+                instead of inferred from phase totals. Set-up
+                (trainer_init, first_step, relower), compiles
+                (jax_trace, jax_lower, xla_compile, xla_cache_load) and
+                the main thread's waits (submit_wait, drain) are spans
+                of the same file, and while a tracer is installed every
+                span is mirrored as a jax.profiler.TraceAnnotation
+                (dispatch as a numbered step), so a profiler trace holds
+                them beside the device's operations.
   heartbeat.py  background thread atomically rewriting heartbeat.json
                 (step, rates, queue depths, device memory, RSS) plus a
                 wedge watchdog: no step within k x a robust recent
@@ -33,7 +40,8 @@ holds the instruments that can:
 Import discipline: this __init__, trace.py, export.py, and aggregate.py
 import only the stdlib (`analyze.py` and the jax-free CLI verbs may
 import them without initializing an accelerator backend); telemetry.py defers its jax imports into the sampling
-functions for the same reason.
+functions for the same reason. trace.py's profiler mirror finds jax
+in `sys.modules` and never imports it.
 """
 
 from . import trace

@@ -26,7 +26,20 @@ Design constraints, in order:
     which flushes mid-run — never reads a torn file. Perfetto and
     chrome://tracing both load it directly.
 
-Stdlib-only at import (see obs/__init__ docstring).
+One timeline: while a real `Tracer` is installed every span also enters a
+`jax.profiler.TraceAnnotation` of the same name, so a profile taken by
+anyone meanwhile (`--profile-steps`, the benchmark's short trace) holds the
+program's spans on the host plane's thread lines, on the profiler's own
+clock, beside `PjitFunction(step)` and the device's operations. A span
+given `step_trace=(name, n)` (the loop's `dispatch`) is wrapped in a
+`StepTraceAnnotation` besides, which numbers the step in the profile. The
+mirror costs about a microsecond a span and nothing with no tracer
+installed; `flush()` exports the tracer's `perf_counter` epoch so
+`trace.json` can be laid over any other `perf_counter` record.
+
+Stdlib-only at import (see obs/__init__ docstring): the mirror finds jax
+in `sys.modules` and never imports it, so a process that has not loaded
+jax (the fleet router) cannot be running its profiler and mirrors nothing.
 """
 
 from __future__ import annotations
@@ -34,6 +47,7 @@ from __future__ import annotations
 import json
 import os
 import re
+import sys
 import threading
 import time
 from collections import deque
@@ -84,16 +98,22 @@ class NullTracer:
 
 
 class _Span:
-    """One live span: created by Tracer.span, records on __exit__."""
+    """One live span: created by Tracer.span, records on __exit__, and
+    for its lifetime is an annotation in the profiler's trace too."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_step", "_mirror")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict | None):
+    def __init__(self, tracer: "Tracer", name: str, args: dict | None,
+                 step_trace: tuple[str, int] | None = None):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._step = step_trace
 
     def __enter__(self) -> "_Span":
+        self._mirror = _profiler_annotations(self._name, self._step)
+        for ann in self._mirror:
+            ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -107,7 +127,24 @@ class _Span:
     def __exit__(self, *exc) -> bool:
         self._tracer._record(self._name, self._t0, time.perf_counter(),
                              self._args)
+        for ann in reversed(self._mirror):
+            ann.__exit__(*exc)
         return False
+
+
+def _profiler_annotations(name: str, step_trace) -> tuple:
+    """The span's twins in the profiler's trace, outermost first: a
+    `StepTraceAnnotation(step name, step_num=n)` where the span is a step
+    of the loop, then a `TraceAnnotation(name)`. Empty where this process
+    never loaded jax."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return ()
+    ann = jax.profiler.TraceAnnotation(name)
+    if step_trace is None:
+        return (ann,)
+    return (jax.profiler.StepTraceAnnotation(
+        step_trace[0], step_num=int(step_trace[1])), ann)
 
 
 class Tracer:
@@ -143,8 +180,9 @@ class Tracer:
         self._dropped = 0  # informational; deque eviction is implicit
 
     # ------------------------------------------------------------ record
-    def span(self, name: str, **args) -> _Span:
-        return _Span(self, name, args or None)
+    def span(self, name: str, step_trace: tuple[str, int] | None = None,
+             **args) -> _Span:
+        return _Span(self, name, args or None, step_trace)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker (ph='i') — e.g. the watchdog's wedge."""
@@ -251,6 +289,9 @@ class Tracer:
             "displayTimeUnit": "ms",
             "otherData": {
                 "trace_epoch_unix": self._epoch_unix,
+                # ts 0 on time.perf_counter(): lays this file over any
+                # other perf_counter record without a marker instant
+                "trace_epoch_perf_counter": self._epoch,
                 "ring_size": self.ring_size,
                 "dropped_spans": self._dropped,
                 # process identity for obs/aggregate.py's fleet merge
@@ -333,6 +374,14 @@ def current() -> Tracer | NullTracer:
 def span(name: str, **args):
     """Record a span on the current tracer (no-op when none installed)."""
     return _current.span(name, **args)
+
+
+def record_span(name: str, t0: float, t1: float, **args) -> None:
+    """A span that already happened, [t0, t1] on `time.perf_counter`, on
+    the calling thread (jax reports a compile's seconds when it is over).
+    No-op when no tracer is installed; not mirrored into the profiler."""
+    if _current is not _NULL:
+        _current._record(name, t0, t1, args or None)
 
 
 def instant(name: str, **args) -> None:

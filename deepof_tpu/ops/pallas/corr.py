@@ -88,9 +88,10 @@ def _pallas_corr_fwd(f1: jnp.ndarray, f2: jnp.ndarray, max_disp: int,
     f2p = jnp.pad(f2, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
 
     grid = (b, hp // tile_h)
+    kernel = functools.partial(_corr_kernel, n=n, stride=stride,
+                               tile_h=tile_h, w=w, c=c)
     out = pl.pallas_call(
-        functools.partial(_corr_kernel, n=n, stride=stride, tile_h=tile_h,
-                          w=w, c=c),
+        kernel, name="corr_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tile_h, w, c), lambda bi, ti: (bi, ti, 0, 0),

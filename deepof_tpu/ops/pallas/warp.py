@@ -150,8 +150,9 @@ def _pallas_warp_flow_grad(image: jnp.ndarray, flow: jnp.ndarray,
                            ct: jnp.ndarray, interpret: bool) -> jnp.ndarray:
     b, h, w, c = image.shape
     hp = -(-h // 8) * 8
-    out = pl.pallas_call(
-        functools.partial(_warp_flow_grad_kernel, h=h, w=w, c=c, hp=hp),
+    kernel = functools.partial(_warp_flow_grad_kernel, h=h, w=w, c=c, hp=hp)
+    out = pl.pallas_call(  # name=: the HLO instruction's, so a trace event's
+        kernel, name="warp_flow_grad",
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, c, hp, LANES), lambda bi: (bi, 0, 0, 0),
@@ -181,8 +182,9 @@ def _pallas_warp_fwd(image: jnp.ndarray, flow: jnp.ndarray,
     imgp = _to_planar(image, h, w, hp)   # (B, C, Hp, 128)
     flowp = _to_planar(flow, h, w, hp)   # (B, 2, Hp, 128)
 
+    kernel = functools.partial(_warp_kernel, h=h, w=w, c=c, hp=hp)
     out = pl.pallas_call(
-        functools.partial(_warp_kernel, h=h, w=w, c=c, hp=hp),
+        kernel, name="warp_fwd",
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, c, hp, LANES), lambda bi: (bi, 0, 0, 0),

@@ -12,6 +12,7 @@ NaN handling upgrades the reference's crash-on-NaN assert
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import signal
@@ -51,7 +52,7 @@ from .elastic import maybe_host_fault, pace_to_world
 from .schedule import step_decay_schedule
 from .state import create_train_state, make_optimizer
 from .step import make_eval_fn, make_train_step
-from .warmup import cache_delta, enable_for_config
+from .warmup import cache_delta, enable_for_config, install_cache_counters
 
 
 # Early-preemption latch (ADVICE r03): model build + the first TPU
@@ -165,6 +166,52 @@ class Trainer:
                  train_step=None, eval_fn=None, tx=None,
                  manifest_extra: dict | None = None,
                  extra_stats=None, on_eval=None):
+        # Span tracer from the first line of set-up (DESIGN.md
+        # "Observability"): model build, the state's init, restore and
+        # every compile on the way are spans of the same trace.json the
+        # loop writes. `fit` takes this tracer over and flushes and
+        # uninstalls it when it ends; a later `fit` starts a fresh one.
+        self._tracer = self._start_tracer(cfg)
+        try:
+            with obs_trace.span("trainer_init"):
+                self._build(cfg, dataset, mesh, profile, profile_steps,
+                            ckpt_dir, train_step, eval_fn, tx,
+                            manifest_extra, extra_stats, on_eval)
+        except BaseException:
+            # the process-global tracer must not outlive a failed build
+            self._stop_tracer(self._tracer)
+            raise
+
+    @staticmethod
+    def _start_tracer(cfg: ExperimentConfig):
+        """Install the run's span tracer where `cfg.obs.trace` asks for
+        one. Single-writer (primary process only), same rationale as
+        MetricsLogger. (role, index) stamp the trace so obs/aggregate.py
+        can merge an elastic pool's per-host timelines; host_index < 0
+        (plain single-process training) stamps trainer-0."""
+        if not cfg.obs.trace or jax.process_index() != 0:
+            return None
+        install_cache_counters()  # compiles become spans from here on
+        return obs_trace.install(obs_trace.Tracer(
+            path=os.path.join(cfg.train.log_dir, "trace.json"),
+            ring_size=cfg.obs.trace_ring, role="trainer",
+            index=max(cfg.elastic.host_index, 0)))
+
+    @staticmethod
+    def _stop_tracer(tracer) -> None:
+        """Uninstall first (this run's tracer must not keep collecting
+        from a later fit()/eval), then a best-effort flush (a read-only
+        tree must not mask a body exception)."""
+        if tracer is not None:
+            obs_trace.uninstall()
+            try:
+                tracer.flush()
+            except OSError:
+                pass
+
+    def _build(self, cfg, dataset, mesh, profile, profile_steps, ckpt_dir,
+               train_step, eval_fn, tx, manifest_extra, extra_stats,
+               on_eval) -> None:
         # The recipe engine (train/recipe.py) drives one Trainer per
         # stage through these hooks: ckpt_dir isolates each stage's
         # checkpoint lineage, train_step/eval_fn inject the stage's
@@ -204,11 +251,6 @@ class Trainer:
         flow_channels = 2 * (t - 1)
         dtype = (jnp.bfloat16 if cfg.train.compute_dtype == "bfloat16"
                  else jnp.float32)
-        self.model = build_model(cfg.model, flow_channels=flow_channels,
-                                 dtype=dtype, width_mult=cfg.width_mult,
-                                 corr_max_disp=cfg.corr_max_disp,
-                                 corr_stride=cfg.corr_stride)
-
         self.logger = MetricsLogger(cfg.train.log_dir)
         self.profiler = ProfilerSession(cfg.train.log_dir, enabled=profile,
                                         steps=profile_steps)
@@ -220,9 +262,14 @@ class Trainer:
         schedule = step_decay_schedule(cfg.optim, self.steps_per_epoch)
         self.schedule = schedule
         tx = tx if tx is not None else make_optimizer(cfg.optim, schedule)
-        self.state = create_train_state(
-            self.model, _example_input(cfg), tx, seed=cfg.train.seed,
-            log=lambda m: self.logger.log("info", 0, message=m))
+        with obs_trace.span("model_init"):
+            self.model = build_model(cfg.model, flow_channels=flow_channels,
+                                     dtype=dtype, width_mult=cfg.width_mult,
+                                     corr_max_disp=cfg.corr_max_disp,
+                                     corr_stride=cfg.corr_stride)
+            self.state = create_train_state(
+                self.model, _example_input(cfg), tx, seed=cfg.train.seed,
+                log=lambda m: self.logger.log("info", 0, message=m))
 
         # Deterministic fault injector (resilience/faults.py): None when
         # disabled — every site below guards on one `is not None`, the
@@ -295,7 +342,8 @@ class Trainer:
                 message=f"transfer init from {cfg.train.init_from}: "
                         f"{n_copied} tensors copied, {n_skipped} re-init")
 
-        restored = self.ckpt.restore(self.state)
+        with obs_trace.span("ckpt_restore"):
+            restored = self.ckpt.restore(self.state)
         if restored is not None:
             self.state = restored
             self.logger.log("info", int(self.state.step),
@@ -319,8 +367,9 @@ class Trainer:
             # the mesh yet and the (replicated, on-mesh) state the step
             # returns are different input types, so the second call would
             # retrace and compile the whole step a second time.
-            self.state = jax.device_put(self.state,
-                                        replicated_sharding(self.mesh))
+            with obs_trace.span("state_place"):
+                self.state = jax.device_put(self.state,
+                                            replicated_sharding(self.mesh))
 
         # Sharded eval requires eval_batch_size % data-axis size == 0; adjust
         # to the nearest multiple (minimum one sample per shard) rather than
@@ -358,14 +407,15 @@ class Trainer:
 
         smooth_border = cfg.model in ("st_single", "st_baseline")
         self._injected_step = train_step is not None
-        self.train_step = (train_step if train_step is not None else
-                           make_train_step(self.model, cfg,
-                                           self.dataset.mean,
-                                           self.mesh, smooth_border))
-        self.eval_fn = (eval_fn if eval_fn is not None else
-                        make_eval_fn(self.model, cfg, self.dataset.mean,
-                                     mesh=self.mesh,
-                                     smooth_border_mask=smooth_border))
+        with obs_trace.span("step_build"):
+            self.train_step = (train_step if train_step is not None else
+                               make_train_step(self.model, cfg,
+                                               self.dataset.mean,
+                                               self.mesh, smooth_border))
+            self.eval_fn = (eval_fn if eval_fn is not None else
+                            make_eval_fn(self.model, cfg, self.dataset.mean,
+                                         mesh=self.mesh,
+                                         smooth_border_mask=smooth_border))
         if jax.process_count() > 1:
             # Multi-host eval: every host loads the same full val batch
             # (deterministic), contributes its rows to the global array,
@@ -500,31 +550,22 @@ class Trainer:
             return {key: _stack([b[key] for b in bs]) for key in bs[0]}
 
         # --- Observability (DESIGN.md "Observability") ---
-        # Span tracer installed BEFORE the pipeline: its workers start
-        # assembling eagerly at construction, and those spans belong on
-        # the timeline. Single-writer (primary process only), same
-        # rationale as MetricsLogger; uninstalled + flushed in finally.
+        # The span tracer `__init__` installed (a later fit of the same
+        # Trainer starts a fresh one), in place BEFORE the pipeline: its
+        # workers start assembling eagerly at construction, and those
+        # spans belong on the timeline. Uninstalled + flushed in finally.
         primary = jax.process_index() == 0
-        tracer = None
-        if cfg.obs.trace and primary:
-            # (role, index) stamp the trace so obs/aggregate.py can
-            # merge an elastic pool's per-host timelines; host_index < 0
-            # (plain single-process training) stamps trainer-0
-            tracer = obs_trace.install(obs_trace.Tracer(
-                path=os.path.join(cfg.train.log_dir, "trace.json"),
-                ring_size=cfg.obs.trace_ring, role="trainer",
-                index=max(cfg.elastic.host_index, 0)))
+        tracer, self._tracer = self._tracer, None
+        if tracer is None:
+            tracer = self._start_tracer(cfg)
+        elif obs_trace.current() is not tracer:
+            obs_trace.install(tracer)  # another Trainer was built since
 
         def _obs_teardown() -> None:
             # construction-failure path: the process-global tracer must
             # not outlive this fit (a later fit/eval would silently
             # record into the dead run's ring); flush what was collected
-            if tracer is not None:
-                obs_trace.uninstall()
-                try:
-                    tracer.flush()
-                except OSError:
-                    pass
+            self._stop_tracer(tracer)
         timer = StepTimer(cfg.data.batch_size, len(self.mesh.devices.flat))
         # Multi-worker host assembly (data/pipeline.py): N threads
         # decode/augment/stack out-of-order, delivery stays in index
@@ -692,6 +733,10 @@ class Trainer:
         if _EARLY_SIGTERM["sig"] is not None:
             stop_sig["sig"] = _EARLY_SIGTERM["sig"]
             _EARLY_SIGTERM["sig"] = None
+        # `first_step` span: the first iteration up to its info record
+        # (input wait, compile or cache load + one run, the lower-only
+        # retrace); closed there, or by the finally
+        first_span = contextlib.ExitStack()
         try:
             total_steps = (num_epochs or cfg.train.num_epochs) * self.steps_per_epoch
             if max_steps is not None:
@@ -822,6 +867,8 @@ class Trainer:
                     world_floor = floor if floor is not None else gstep
                     if stop_sig["sig"] is not None:
                         break
+                if first_step:
+                    first_span.enter_context(obs_trace.span("first_step"))
                 self.profiler.observe(gstep, k)  # --profile-steps window
                 t0 = time.perf_counter()
                 with obs_trace.span("input_wait"):
@@ -854,40 +901,14 @@ class Trainer:
                 if first_step:  # XLA compile-time report (SURVEY.md §5.1)
                     cache_watch = cache_delta()
                     with obs_trace.span("dispatch", step=gstep + k,
-                                        compile=True):
+                                        compile=True,
+                                        step_trace=("train", gstep)):
                         self.state, metrics = self.train_step(self.state,
                                                               batch)
                         jax.block_until_ready(metrics["total"])
                     dc = cache_watch.stats()
                     first_wall = time.perf_counter() - t0
-                    lowered = None
-                    if cfg.obs.flops or ledger is not None:
-                        # ONE lower-only retrace (no second backend
-                        # compile) serves both the FLOPs telemetry and
-                        # the ledger's provenance row
-                        try:
-                            lowered = self.train_step.lower(self.state,
-                                                            batch)
-                        except Exception:  # noqa: BLE001 - telemetry only
-                            lowered = None
-                    if cfg.obs.flops and lowered is not None:
-                        # every periodic record then carries model_tflops
-                        self._flops_per_step = lowered_flops(lowered)
-                    if ledger is not None and not self._injected_step:
-                        # compile_kind="first_step": first_wall includes
-                        # one EXECUTED step stride, a different unit
-                        # from warmup's pure lower+compile "aot" rows —
-                        # diff_ledgers only bounds like against like.
-                        # An INJECTED pre-compiled step (recipe engine)
-                        # records nothing: its compile already owns an
-                        # "aot" row (train_step_stage<i>) and its first
-                        # dispatch is execution, not compile — keeping
-                        # the ledger a pure compile record is what
-                        # makes "a stage switch added zero rows"
-                        # provable from it
-                        ledger.record("train_step", lowered=lowered,
-                                      compile_s=first_wall,
-                                      compile_kind="first_step", cache=dc)
+                    self._relower(ledger, batch, first_wall, dc)
                     # hit/miss counters surfaced in metrics: a warmed
                     # process shows compile_cache_misses == 0 here
                     self.logger.log(
@@ -899,8 +920,10 @@ class Trainer:
                         compile_cache_misses=dc["misses"],
                         flops_per_step=self._flops_per_step)
                     first_step = False
+                    first_span.close()
                 else:
-                    with obs_trace.span("dispatch", step=gstep + k):
+                    with obs_trace.span("dispatch", step=gstep + k,
+                                        step_trace=("train", gstep)):
                         self.state, metrics = self.train_step(self.state,
                                                               batch)
                 timer.phase("dispatch", time.perf_counter() - t0)
@@ -946,7 +969,8 @@ class Trainer:
                 # target; at most log_every-1 + depth*K steps of NaN
                 # training are lost (all rewound by the restore).
                 if eval_due or ckpt_due or nan_event["m"] is not None:
-                    fetcher.drain()
+                    with obs_trace.span("drain"):
+                        fetcher.drain()
 
                 if nan_event["m"] is not None:
                     # a NaN callback may land between the drain trigger
@@ -954,7 +978,8 @@ class Trainer:
                     # drained) so every in-flight fetch — possibly from a
                     # step dispatched off the diverged state — lands
                     # before the rewind, never after it
-                    fetcher.drain()
+                    with obs_trace.span("drain"):
+                        fetcher.drain()
                     nan_step, _ = nan_event["m"]
                     nan_event["m"] = None
                     streak["ok"] = False
@@ -1051,7 +1076,8 @@ class Trainer:
             # all in-flight NaN checks land before finalize — but bounded:
             # a consumer wedged in a hung device_get must not hang
             # this path away from the finally's close()/ckpt.finalize()
-            drained = fetcher.drain(timeout=120.0)
+            with obs_trace.span("drain"):
+                drained = fetcher.drain(timeout=120.0)
             if not drained:
                 self.logger.log(
                     "warn", gstep,
@@ -1115,16 +1141,8 @@ class Trainer:
             pipeline.close()
             prefetch.close()
             self.ckpt.finalize()  # commit any in-flight async save
-            if tracer is not None:
-                # uninstall first: this fit's tracer must not keep
-                # collecting from a later fit()/eval; flush is
-                # best-effort (a read-only tree must not mask a body
-                # exception)
-                obs_trace.uninstall()
-                try:
-                    tracer.flush()
-                except OSError:
-                    pass
+            first_span.close()  # a fit that ended inside its first step
+            self._stop_tracer(tracer)
             # restore only AFTER finalize(): the final async-save commit
             # must stay protected by the graceful handler. A C-level
             # previous handler cannot be re-installed from Python
@@ -1165,6 +1183,36 @@ class Trainer:
                 # float()-able for CLI printing
                 **{k: v for k, v in self._telemetry(timer).items()
                    if v is not None}}
+
+    def _relower(self, ledger, batch, first_wall: float, cache: dict) -> None:
+        """After the first step: ONE lower-only retrace (no second
+        backend compile) serves both the FLOPs telemetry and the
+        ledger's provenance row. A span of its own, `relower`: it is
+        part of what a user waits for before the second step."""
+        cfg = self.cfg
+        with obs_trace.span("relower"):
+            lowered = None
+            if cfg.obs.flops or ledger is not None:
+                try:
+                    lowered = self.train_step.lower(self.state, batch)
+                except Exception:  # noqa: BLE001 - telemetry only
+                    lowered = None
+            if cfg.obs.flops and lowered is not None:
+                # every periodic record then carries model_tflops
+                self._flops_per_step = lowered_flops(lowered)
+            if ledger is not None and not self._injected_step:
+                # compile_kind="first_step": first_wall includes one
+                # EXECUTED step stride, a different unit from warmup's
+                # pure lower+compile "aot" rows — diff_ledgers only
+                # bounds like against like. An INJECTED pre-compiled
+                # step (recipe engine) records nothing: its compile
+                # already owns an "aot" row (train_step_stage<i>) and
+                # its first dispatch is execution, not compile —
+                # keeping the ledger a pure compile record is what
+                # makes "a stage switch added zero rows" provable from it
+                ledger.record("train_step", lowered=lowered,
+                              compile_s=first_wall,
+                              compile_kind="first_step", cache=cache)
 
     def _telemetry(self, timer: StepTimer) -> dict:
         """Device-memory / RSS / model-FLOP fields for a train record

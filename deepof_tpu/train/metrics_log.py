@@ -289,8 +289,12 @@ class AsyncFetcher:
         # can never go negative or miss a peak, and a submit blocked in
         # wait() is by definition NOT in flight (that block is the bound)
         with self._cv:
-            while self._in_flight >= self._depth:
-                self._cv.wait()
+            if self._in_flight >= self._depth:
+                # the bound at work: where a loop that runs ahead of the
+                # device spends its time
+                with obs_trace.span("submit_wait"):
+                    while self._in_flight >= self._depth:
+                        self._cv.wait()
             self._in_flight += 1
             self._max_in_flight = max(self._max_in_flight, self._in_flight)
         self._q.put((tag, tree, callback))
@@ -440,7 +444,13 @@ class ProfilerSession:
             self._stop()
 
     def _start(self) -> None:
-        jax.profiler.start_trace(self.log_dir)
+        # the Python tracer off, the host tracer at 2: the program's
+        # spans are annotations of their own (obs/trace.py), and a profile
+        # then costs and shows what the benchmark's short trace does
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.log_dir, profiler_options=opts)
         self._active = True
 
     def _stop(self) -> None:

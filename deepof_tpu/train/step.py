@@ -69,9 +69,11 @@ def model_losses(
 
     def fwd(x, **kw):
         def inner(xx):
-            out = model.apply({"params": params}, xx.astype(compute_dtype),
-                              rngs=rngs, **kw)
-            return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), out)
+            with jax.named_scope("forward"):
+                out = model.apply({"params": params},
+                                  xx.astype(compute_dtype), rngs=rngs, **kw)
+                return jax.tree_util.tree_map(
+                    lambda a: a.astype(jnp.float32), out)
 
         # rematerialize the encoder-decoder in backward instead of storing
         # its activations (TrainConfig.remat; params are closure-captured,
@@ -82,21 +84,27 @@ def model_losses(
 
     if "volume" in batch:  # multi-frame Sintel volume
         vol = batch["volume"]
-        scaled = preprocess(vol, _tiled_mean(mean, vol.shape[-1]))
+        with jax.named_scope("preprocess"):
+            scaled = preprocess(vol, _tiled_mean(mean, vol.shape[-1]))
         flows = fwd(scaled)
         pyramid = list(zip(flows, model.flow_scales))
-        total, losses, recon = pyramid_loss_multi(pyramid, lrn_normalize(scaled), loss_cfg)
+        with jax.named_scope("preprocess"):
+            vol_norm = lrn_normalize(scaled)
+        total, losses, recon = pyramid_loss_multi(pyramid, vol_norm, loss_cfg)
         aux.update(losses=losses, flow=flows[0] * model.flow_scales[0], recon=recon)
         return total, aux
 
     # Dual-stream augmentation (reference `flyingChairsTrain_vgg.py:186-195`):
     # the photo-augmented pair (net_*) feeds the network; the geo-only pair
     # (source/target) feeds the photometric loss.
-    src = preprocess(batch["source"], mean)
-    tgt = preprocess(batch["target"], mean)
-    net_src = preprocess(batch["net_source"], mean) if "net_source" in batch else src
-    net_tgt = preprocess(batch["net_target"], mean) if "net_target" in batch else tgt
-    pair = jnp.concatenate([net_src, net_tgt], axis=-1)
+    with jax.named_scope("preprocess"):
+        src = preprocess(batch["source"], mean)
+        tgt = preprocess(batch["target"], mean)
+        net_src = (preprocess(batch["net_source"], mean)
+                   if "net_source" in batch else src)
+        net_tgt = (preprocess(batch["net_target"], mean)
+                   if "net_target" in batch else tgt)
+        pair = jnp.concatenate([net_src, net_tgt], axis=-1)
 
     if getattr(model, "classifier_only", False):
         logits = fwd(src, train=train)
@@ -118,8 +126,10 @@ def model_losses(
         flows_bw = fwd(jnp.concatenate([net_tgt, net_src], axis=-1))
 
     pyramid = list(zip(flows, model.flow_scales))
+    with jax.named_scope("preprocess"):
+        src_norm, tgt_norm = lrn_normalize(src), lrn_normalize(tgt)
     total, losses, recon = pyramid_loss(
-        pyramid, lrn_normalize(src), lrn_normalize(tgt), loss_cfg,
+        pyramid, src_norm, tgt_norm, loss_cfg,
         smooth_border_mask=smooth_border_mask, flow_pyramid_bw=flows_bw)
     aux.update(losses=losses, flow=flows[0] * model.flow_scales[0], recon=recon)
 
@@ -154,6 +164,27 @@ def make_train_step(model, cfg: ExperimentConfig, mean: Mean, mesh,
             f"model={cfg.model!r} time_step={cfg.data.time_step} would "
             "silently skip the masking")
 
+    def apply_update(state: TrainState, grads, total, rng):
+        """(new state, gradient norm, 1.0 where the update was skipped)."""
+        grad_norm = optax.global_norm(grads)
+        if not cfg.resilience.skip_nonfinite:
+            return (state.apply_gradients(grads).replace(rng=rng), grad_norm,
+                    jnp.float32(0.0))
+        # Divergence-ladder rung 1 (DESIGN.md "Resilience"): detect
+        # non-finite loss/grads BEFORE the update and skip it in place —
+        # params, opt_state, and step stay exactly the previous state's
+        # (rng still advances so a retried batch doesn't replay the same
+        # dropout draw), and the host sees `update_skipped` per inner
+        # step. One bad batch then costs one skipped update, not a
+        # checkpoint rollback. The select is a no-op bitwise when finite:
+        # jnp.where(True, new, old) returns `new` exactly.
+        finite = jnp.isfinite(total) & jnp.isfinite(grad_norm)
+        applied = state.apply_gradients(grads).replace(rng=rng)
+        kept = state.replace(rng=rng)
+        new_state = jax.tree_util.tree_map(
+            lambda n, o: jnp.where(finite, n, o), applied, kept)
+        return new_state, grad_norm, 1.0 - finite.astype(jnp.float32)
+
     def step(state: TrainState, batch):
         rng, dropout_rng = jax.random.split(state.rng)
 
@@ -167,26 +198,9 @@ def make_train_step(model, cfg: ExperimentConfig, mean: Mean, mesh,
             return total, aux
 
         (total, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
-        grad_norm = optax.global_norm(grads)
-        if cfg.resilience.skip_nonfinite:
-            # Divergence-ladder rung 1 (DESIGN.md "Resilience"): detect
-            # non-finite loss/grads BEFORE the update and skip it in
-            # place — params, opt_state, and step stay exactly the
-            # previous state's (rng still advances so a retried batch
-            # doesn't replay the same dropout draw), and the host sees
-            # `update_skipped` per inner step. One bad batch then costs
-            # one skipped update, not a checkpoint rollback. The select
-            # is a no-op bitwise when finite: jnp.where(True, new, old)
-            # returns `new` exactly.
-            finite = jnp.isfinite(total) & jnp.isfinite(grad_norm)
-            applied = state.apply_gradients(grads).replace(rng=rng)
-            kept = state.replace(rng=rng)
-            new_state = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(finite, n, o), applied, kept)
-            skipped = 1.0 - finite.astype(jnp.float32)
-        else:
-            new_state = state.apply_gradients(grads).replace(rng=rng)
-            skipped = jnp.float32(0.0)
+        with jax.named_scope("optimizer"):
+            new_state, grad_norm, skipped = apply_update(state, grads, total,
+                                                         rng)
         metrics = {"total": total, "grad_norm": grad_norm,
                    "update_skipped": skipped}
         if "losses" in aux:

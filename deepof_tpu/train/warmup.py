@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import os
 import sys
+import threading
 import time
 from typing import Any, NamedTuple
 
@@ -33,6 +34,7 @@ import numpy as np
 
 from ..core.config import ExperimentConfig
 from ..core.hostmesh import compile_cache_dir
+from ..obs import trace as obs_trace
 
 # jax.monitoring event names emitted by jax/_src/compiler.py for every
 # compile request that consults the persistent cache, and for each hit.
@@ -43,19 +45,65 @@ _EVENT_HITS = "/jax/compilation_cache/cache_hits"
 _counts = {"requests": 0, "hits": 0}
 _listener_installed = False
 
+# jax.monitoring duration events (jax/_src/dispatch.py) -> the span each
+# becomes in the program's trace: "which step recompiled" is an
+# `xla_compile` span inside the window. The backend event covers both a
+# compile and a load from the persistent cache; it is an `xla_cache_load`
+# when a cache-hit event arrived since the previous backend event. Tracing
+# one step fires the trace event for every jitted function inside it, and
+# lowering it for every function a lowering rule traces (10,000 together):
+# only a trace or lowering that is not inside another becomes a span. jax
+# announces the start of each as a scalar event of the same name.
+_EVENT_BACKEND = "/jax/core/compile/backend_compile_duration"
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower",
+    _EVENT_BACKEND: "xla_compile",
+}
+# per thread: .cache_hit (a hit event awaiting its backend event) and
+# .depth (traces and lowerings begun and not finished)
+_compiling = threading.local()
+
 
 def _on_event(event: str, **kw) -> None:
     if event == _EVENT_REQUESTS:
         _counts["requests"] += 1
     elif event == _EVENT_HITS:
         _counts["hits"] += 1
+        _compiling.cache_hit = True
+
+
+def _on_scalar(event: str, value: float, **kw) -> None:
+    if event in _COMPILE_SPANS and event != _EVENT_BACKEND:
+        _compiling.depth = getattr(_compiling, "depth", 0) + 1
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    """One finished trace/lower/compile -> a span on the thread that did
+    it, recorded after the fact as [now - duration, now]. With no tracer
+    installed nothing is recorded."""
+    now = time.perf_counter()
+    name = _COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    if event != _EVENT_BACKEND:
+        _compiling.depth = depth = max(getattr(_compiling, "depth", 1) - 1, 0)
+        if depth:
+            return  # traced or lowered inside another trace or lowering
+    elif _compiling.__dict__.pop("cache_hit", False):
+        name = "xla_cache_load"
+    obs_trace.record_span(name, now - float(duration), now,
+                          fun_name=str(kw.get("fun_name")))
 
 
 def install_cache_counters() -> None:
-    """Idempotently register the hit/miss counting listener."""
+    """Idempotently register the hit/miss counting listener and the
+    listener that turns jax's compile phases into spans."""
     global _listener_installed
     if not _listener_installed:
         jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_scalar_listener(_on_scalar)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _listener_installed = True
 
 
@@ -116,16 +164,27 @@ def enable_compile_cache(cache_dir: str | None = None,
 
     if prev != d or _cc._cache is None:
         _cc.reset_cache()
-    # A Pallas kernel's Mosaic payload carries the source location of
-    # every op, and by default a location is the whole Python call stack
-    # of the trace: the same step traced from `cli train`, bench.py or an
-    # AOT lowering got a different cache key each (measured on the chip,
-    # PR 23: an AOT lowering of the step the trainer had just compiled
-    # missed and paid the 73 s again). One frame per location makes the
-    # key a function of the program, not of who traced it.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    one_frame_locations()
     install_cache_counters()
     return d
+
+
+def one_frame_locations() -> None:
+    """Make a compile's cache key a function of the program, not of who
+    traced it. A Pallas kernel's Mosaic payload carries the source
+    location of every op, and by default a location is ten frames of the
+    Python call stack of the trace: the same step traced from `cli train`,
+    bench.py or an AOT lowering got a different key each (measured on the
+    chip, PR 23: an AOT lowering of the step the trainer had just compiled
+    missed and paid the 73 s again). ONE frame per location, the innermost,
+    cures that. Through the frame limit, not by turning
+    `jax_include_full_tracebacks_in_locations` off: with that off jax 0.9
+    hands XLA the bare primitive as an operation's `op_name` (the
+    `jax.named_scope`s are lost to a profile) and the Mosaic custom call is
+    called `%tpu_custom_call.N`, not by its kernel's `name=` (read on the
+    chip, PR 28)."""
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
 
 
 def disable_compile_cache() -> None:

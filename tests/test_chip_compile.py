@@ -17,6 +17,7 @@ A compile that passes is not a run: numbers on the chip come from
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -37,11 +38,20 @@ def topo():
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no libtpu / lock held elsewhere
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    prev = jax.config.jax_enable_compilation_cache
+    from deepof_tpu.train.warmup import one_frame_locations
+
+    prev = (jax.config.jax_enable_compilation_cache,
+            jax.config.jax_include_full_tracebacks_in_locations,
+            jax.config.jax_traceback_in_locations_limit)
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
+    # locations as the program sets them wherever it enables its compile
+    # cache, i.e. on the chip: they decide what a Mosaic call is named
+    one_frame_locations()
     yield desc
-    jax.config.update("jax_enable_compilation_cache", prev)
+    jax.config.update("jax_enable_compilation_cache", prev[0])
+    jax.config.update("jax_include_full_tracebacks_in_locations", prev[1])
+    jax.config.update("jax_traceback_in_locations_limit", prev[2])
     cc.reset_cache()
 
 
@@ -57,9 +67,21 @@ def _mesh(topo, n, time=1):
     return build_mesh(MeshConfig(time=time), devices=list(topo.devices[:n]))
 
 
-def _compiled_text(fn, *args):
-    text = jax.jit(fn).lower(*args).compile().as_text()
+def _compiled_text(fn, *args, kernels=()):
+    """Compile for the described chip. `kernels`: the `name=` of each
+    `pallas_call` inside, which the lowering must carry as `kernel_name`
+    and the compiled HLO as the custom call's instruction name — the name
+    a profile's event then starts with (`%warp_fwd.1 = ... custom-call`)."""
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled text"
+    if kernels:
+        assert set(re.findall(r'kernel_name\s*=\s*"([^"]+)"',
+                              lowered.as_text())) == set(kernels)
+        calls = [ln.strip() for ln in text.splitlines()
+                 if "custom-call(" in ln and "tpu_custom_call" in ln]
+        assert {re.match(r"(?:ROOT )?%([A-Za-z_]+)", c).group(1)
+                for c in calls} == set(kernels), calls
     return text
 
 
@@ -77,10 +99,10 @@ def test_warp_kernels_compile_for_v5e(one_chip, which, hw):
                                 sharding=one_chip)
     if which == "fwd":
         _compiled_text(lambda im, fl: _pallas_warp_fwd(im, fl, False),
-                       img, flow)
+                       img, flow, kernels=["warp_fwd"])
     else:
         _compiled_text(lambda im, fl, ct: _pallas_warp_flow_grad(
-            im, fl, ct, False), img, flow, img)
+            im, fl, ct, False), img, flow, img, kernels=["warp_flow_grad"])
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -95,7 +117,8 @@ def test_corr_kernel_compiles_for_v5e(one_chip, hw, dtype):
 
     h, w = hw
     f = jax.ShapeDtypeStruct((BATCH, h, w, 256), dtype, sharding=one_chip)
-    _compiled_text(lambda a, b: _pallas_corr_fwd(a, b, 20, 2, 8, False), f, f)
+    _compiled_text(lambda a, b: _pallas_corr_fwd(a, b, 20, 2, 8, False), f, f,
+                   kernels=["corr_fwd"])
 
 
 @pytest.mark.parametrize("n_dev,time", [(1, 1), (4, 1), (4, 2)])
@@ -119,7 +142,8 @@ def test_warp_vjp_compiles_through_shard_map(topo, n_dev, time):
             backward_warp_pallas(im, x, False) ** 2))(fl)
 
     with mesh_context(mesh):  # read at trace time, as in train/step.py
-        text = _compiled_text(flow_grad, img, flow)
+        text = _compiled_text(flow_grad, img, flow,
+                              kernels=["warp_fwd", "warp_flow_grad"])
     assert text.count("tpu_custom_call") >= 2  # forward + flow-grad kernels
     assert "all-gather" not in text  # each shard warps its own batch rows
 
@@ -134,8 +158,60 @@ def test_corr_compiles_through_shard_map_on_four_chips(topo):
                              sharding=batch_sharding(mesh))
     with mesh_context(mesh):
         text = _compiled_text(
-            lambda a, b: correlation_pallas(a, b, 20, 2, 8, False), f, f)
+            lambda a, b: correlation_pallas(a, b, 20, 2, 8, False), f, f,
+            kernels=["corr_fwd"])
     assert "all-gather" not in text
+
+
+def _mosaic_payloads(fn, *args):
+    return re.findall(r'backend_config\s*=\s*"([^"]*)"',
+                      jax.jit(fn).lower(*args).as_text())
+
+
+def test_mosaic_payload_does_not_depend_on_who_traced(one_chip):
+    """The compile cache's key holds the Mosaic payload, which holds its
+    ops' source locations: the same warp (forward and flow-gradient
+    kernels) traced from two call stacks must lower to the same bytes
+    (`one_frame_locations`; PR 23 read two keys on the chip before it)."""
+    from deepof_tpu.ops.pallas.warp import backward_warp_pallas
+
+    img = jax.ShapeDtypeStruct((BATCH, 40, 56, 3), jnp.float32,
+                               sharding=one_chip)
+    flow = jax.ShapeDtypeStruct((BATCH, 40, 56, 2), jnp.float32,
+                                sharding=one_chip)
+
+    def flow_grad(im, fl):
+        return jax.grad(lambda x: jnp.sum(
+            backward_warp_pallas(im, x, False) ** 2))(fl)
+
+    def from_deeper(im, fl):
+        def one_more_frame(im, fl):
+            return flow_grad(im, fl)
+        return one_more_frame(im, fl)
+
+    direct = _mosaic_payloads(flow_grad, img, flow)
+    assert len(direct) == 2 and direct == _mosaic_payloads(from_deeper, img, flow)
+
+
+@pytest.mark.parametrize("level", [0, 3])
+def test_kernel_name_wins_over_the_scope_it_is_called_in(one_chip, level):
+    """Inside a `jax.named_scope` (every loss level is one) the custom
+    call still takes the kernel's `name=`, not the scope's: without
+    `name=` the chip's event was `%name.N` or `%loss_level_<k>.N`, which
+    no reduction can tell apart."""
+    from deepof_tpu.ops.pallas.warp import _pallas_warp_fwd
+
+    img = jax.ShapeDtypeStruct((BATCH, 40, 56, 3), jnp.float32,
+                               sharding=one_chip)
+    flow = jax.ShapeDtypeStruct((BATCH, 40, 56, 2), jnp.float32,
+                                sharding=one_chip)
+
+    def in_scope(im, fl):
+        with jax.named_scope(f"loss_level_{level}"), jax.named_scope("warp"):
+            return _pallas_warp_fwd(im, fl, False)
+
+    text = _compiled_text(in_scope, img, flow, kernels=["warp_fwd"])
+    assert f"loss_level_{level}/warp" in text  # the scope is in op_name
 
 
 @pytest.mark.slow
@@ -162,6 +238,12 @@ def test_whole_train_step_compiles_for_v5e(topo, monkeypatch, model, n_dev):
     compiled = lower_train_step(cfg, _mesh(topo, n_dev)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 10
+    assert "%warp_fwd." in text and "%warp_flow_grad." in text
+    assert ("%corr_fwd." in text) == (model == "flownet_c")
+    assert "%name." not in text  # what an unnamed Mosaic call was called
+    for scope in ("jvp(forward)", "transpose(jvp(forward))", "optimizer",
+                  "jvp(loss_level_0)/warp", "transpose(jvp(loss_level_5))"):
+        assert f"jit(step)/{scope}/" in text, scope
     assert ("all-reduce" in text) == (n_dev > 1)
     assert "all-gather" not in text
     ma = compiled.memory_analysis()

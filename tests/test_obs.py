@@ -115,6 +115,171 @@ def test_module_level_tracer_install_uninstall(tmp_path):
     assert obs_trace.flush_current() is None  # null tracer again
 
 
+# ------------------------------------- one timeline with the profiler
+
+class _Annotations:
+    """Stands where jax.profiler's two annotation classes stand and
+    records what was entered and exited, in order."""
+
+    def __init__(self, monkeypatch):
+        import jax
+
+        self.log = log = []
+
+        class Recorded:
+            def __init__(self, name, **kw):
+                self.what = (name, kw)
+
+            def __enter__(self):
+                log.append(("enter", *self.what))
+
+            def __exit__(self, *exc):
+                log.append(("exit", *self.what))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorded)
+        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Recorded)
+
+
+def test_span_under_a_tracer_is_a_trace_annotation_too(monkeypatch):
+    ann = _Annotations(monkeypatch)
+    tr = Tracer()
+    with tr.span("input_wait", depth=2):
+        assert ann.log == [("enter", "input_wait", {})]
+    assert ann.log[-1] == ("exit", "input_wait", {})
+    assert [e["name"] for e in tr.events() if e["ph"] == "X"] == [
+        "input_wait"]
+
+
+def test_step_span_is_wrapped_in_a_step_annotation(monkeypatch):
+    ann = _Annotations(monkeypatch)
+    tr = obs_trace.install(Tracer())
+    try:
+        with obs_trace.span("dispatch", step=8, step_trace=("train", 7)):
+            pass
+    finally:
+        obs_trace.uninstall()
+    assert ann.log == [("enter", "train", {"step_num": 7}),
+                       ("enter", "dispatch", {}),
+                       ("exit", "dispatch", {}),
+                       ("exit", "train", {"step_num": 7})]
+    (span,) = [e for e in tr.events() if e["ph"] == "X"]
+    assert span["args"] == {"step": 8}  # step_trace is no argument of the span
+
+
+def test_null_tracer_span_makes_no_annotation(monkeypatch):
+    ann = _Annotations(monkeypatch)
+    with NullTracer().span("dispatch", step=1, step_trace=("train", 0)):
+        pass
+    with obs_trace.span("dispatch", step=1, step_trace=("train", 0)):
+        pass  # nothing installed
+    obs_trace.record_span("xla_compile", 0.0, 1.0, fun_name="f")
+    assert ann.log == []
+
+
+def test_importing_obs_imports_no_jax():
+    """Subprocess: this suite has jax loaded already. A process without
+    jax (the fleet router) records spans and mirrors nothing."""
+    code = ("import sys\n"
+            "import deepof_tpu.obs\n"
+            "from deepof_tpu.obs.trace import Tracer\n"
+            "tr = Tracer()\n"
+            "with tr.span('route', step_trace=('serve', 1)):\n"
+            "    pass\n"
+            "assert len(tr.events()) == 3\n"
+            "bad = [m for m in sys.modules if m == 'jax'"
+            " or m.startswith('jax.') or m == 'jaxlib']\n"
+            "assert not bad, bad\n")
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=REPO,
+                   timeout=120)
+
+
+def test_flush_exports_the_perf_counter_epoch(tmp_path):
+    before = time.perf_counter()
+    tr = Tracer(path=str(tmp_path / "trace.json"))
+    after = time.perf_counter()
+    t0 = time.perf_counter()
+    with tr.span("dispatch"):
+        pass
+    payload = _strict_loads(open(tr.flush()).read())
+    epoch = payload["otherData"]["trace_epoch_perf_counter"]
+    assert before <= epoch <= after
+    (span,) = [e for e in payload["traceEvents"] if e["ph"] == "X"]
+    # ts is microseconds after the epoch, on perf_counter
+    assert epoch + span["ts"] * 1e-6 == pytest.approx(t0, abs=1e-3)
+
+
+# -------------------------------------------- compiles become spans
+
+BACKEND = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _spans(tr):
+    return [(e["name"], e["ts"] * 1e-6, (e["ts"] + e["dur"]) * 1e-6,
+             e.get("args")) for e in tr.events() if e["ph"] == "X"]
+
+
+@pytest.mark.parametrize("hit,name", [(False, "xla_compile"),
+                                      (True, "xla_cache_load")])
+def test_backend_compile_event_becomes_a_span(hit, name):
+    """A synthetic backend-compile event of 2 s, reported when it is
+    over: a span that ENDS at the report and began 2 s earlier; after a
+    cache-hit event it is a load, and the next one a compile again."""
+    from deepof_tpu.train import warmup
+
+    tr = obs_trace.install(Tracer())
+    try:
+        t_before = time.perf_counter() - tr._epoch
+        if hit:
+            warmup._on_event(CACHE_HIT)
+        warmup._on_duration(BACKEND, 2.0, fun_name="step")
+        t_after = time.perf_counter() - tr._epoch
+        warmup._on_duration(BACKEND, 0.5, fun_name="eval")
+        warmup._on_duration("/jax/some/other_duration", 1.0)
+    finally:
+        obs_trace.uninstall()
+    first, second = _spans(tr)
+    assert first[0] == name and first[3] == {"fun_name": "step"}
+    assert t_before <= first[2] <= t_after  # ends "now", never after it
+    assert first[2] - first[1] == pytest.approx(2.0, abs=1e-5)
+    assert second[0] == "xla_compile" and second[3] == {"fun_name": "eval"}
+
+
+def test_compile_events_with_no_tracer_record_nothing():
+    from deepof_tpu.train import warmup
+
+    warmup._on_event(CACHE_HIT)
+    warmup._on_duration(BACKEND, 1.0, fun_name="step")  # consumes the hit
+    tr = obs_trace.install(Tracer())
+    try:
+        warmup._on_duration(BACKEND, 1.0, fun_name="step")
+    finally:
+        obs_trace.uninstall()
+    assert [s[0] for s in _spans(tr)] == ["xla_compile"]
+
+
+def test_a_real_jit_compile_under_a_tracer_is_traced_and_compiled():
+    import jax
+    import jax.numpy as jnp
+
+    from deepof_tpu.train.warmup import install_cache_counters
+
+    install_cache_counters()
+
+    def toy_fn_for_the_span_test(x):  # a fresh function: nothing cached
+        return jnp.tanh(x) * 3.0 + 1.0
+
+    tr = obs_trace.install(Tracer())
+    try:
+        jax.jit(toy_fn_for_the_span_test)(jnp.ones((7, 3))).block_until_ready()
+    finally:
+        obs_trace.uninstall()
+    mine = {s[0] for s in _spans(tr)
+            if "toy_fn_for_the_span_test" in s[3]["fun_name"]}
+    assert {"jax_trace", "jax_lower", "xla_compile"} <= mine
+    assert all(s[2] >= s[1] >= 0 for s in _spans(tr))
+
+
 # ------------------------------------------------------------ heartbeat
 
 def test_heartbeat_file_schema_and_atomicity(tmp_path):
@@ -227,9 +392,13 @@ def test_metrics_logger_serializes_nonfinite_as_null(tmp_path):
 def test_profiler_session_step_window(tmp_path, monkeypatch):
     import jax
 
-    calls = []
-    monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
+    calls, options = [], []
+
+    def start(d, profiler_options=None):
+        calls.append(("start", d))
+        options.append(profiler_options)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start)
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append(("stop", None)))
 
@@ -240,6 +409,10 @@ def test_profiler_session_step_window(tmp_path, monkeypatch):
     p.observe(0)
     p.observe(2)  # window opens
     assert [c[0] for c in calls] == ["start"]
+    # the options the benchmark's own short trace takes: the program's
+    # spans are annotations, so the Python tracer stays off
+    assert options[0].python_tracer_level == 0
+    assert options[0].host_tracer_level == 2
     p.observe(3)
     p.observe(4)  # window closes
     assert [c[0] for c in calls] == ["start", "stop"]
@@ -287,6 +460,112 @@ def test_trace_summary_tool(tmp_path):
     assert res.returncode == 0, res.stderr[-500:]
     assert "dispatch" in res.stdout and "fetch" in res.stdout
     assert "longest spans" in res.stdout
+
+
+# ------------------------------------- set-up and the loop as spans
+
+def test_toy_fit_spans_setup_first_step_and_nests(tmp_path):
+    """A 4-step fit at the smallest size that walks fit() end to end
+    (one cpu device; tests/test_resilience.py's recipe), tracing on:
+    `Trainer.__init__` and the first step are spans with their children,
+    compiles are spans, and the main thread's spans nest: no two of them
+    partially overlap, which is what lets a reader take their union."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="",
+               PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH",
+                                                             ""))
+    res = subprocess.run(
+        [sys.executable, "-m", "deepof_tpu", "train", "--preset",
+         "flyingchairs", "--synthetic", "--max-steps", "4",
+         "--log-dir", str(tmp_path), "--trace",
+         "--set", "model=flownet_s", "--set", "width_mult=0.25",
+         "--set", "data.image_size=32,32", "--set", "data.gt_size=32,32",
+         "--set", "data.batch_size=2", "--set", "train.log_every=1",
+         "--set", "train.eval_every=0",
+         "--set", "train.ckpt_every_epochs=1000000"],
+        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
+    assert res.returncode == 0, (res.stdout[-1000:], res.stderr[-2000:])
+    payload = _strict_loads(open(str(tmp_path / "trace.json")).read())
+    assert payload["otherData"]["trace_epoch_perf_counter"] > 0
+    events = payload["traceEvents"]
+    named = {e["tid"]: e["args"]["name"] for e in events
+             if e["ph"] == "M" and e["name"] == "thread_name"}
+    main = [(e["name"], e["ts"], e["ts"] + e["dur"], e.get("args", {}))
+            for e in events
+            if e["ph"] == "X" and named.get(e["tid"]) == "MainThread"]
+
+    def one(name):
+        (found,) = [s for s in main if s[0] == name]
+        return found
+
+    def inside(child, parent, slack=0.2):  # microseconds: ts is rounded
+        return parent[1] - slack <= child[1] and child[2] <= parent[2] + slack
+
+    init, first = one("trainer_init"), one("first_step")
+    for child in ("model_init", "ckpt_restore", "state_place", "step_build"):
+        assert inside(one(child), init), child
+    assert init[2] <= first[1]
+    compiling = [s for s in main if s[0] == "dispatch"
+                 and s[3].get("compile")]
+    assert len(compiling) == 1 and inside(compiling[0], first)
+    assert inside(one("relower"), first)
+    assert any(s[0] == "input_wait" and inside(s, first) for s in main)
+    assert sum(s[0] == "dispatch" for s in main) == 4
+    # the state's jitted init compiled inside model_init, the step inside
+    # the first dispatch; the retrace for the ledger traced again but
+    # did not compile again
+    assert any(s[0] == "xla_compile" and inside(s, one("model_init"))
+               for s in main)
+    assert any(s[0] == "xla_compile" and inside(s, compiling[0])
+               and "step" in s[3]["fun_name"] for s in main)
+    in_relower = {s[0] for s in main if inside(s, one("relower"))}
+    assert "jax_trace" in in_relower and "xla_compile" not in in_relower
+    assert any(s[0] == "drain" for s in main)  # the bounded one at the end
+    for i, a in enumerate(main):
+        for b in main[i + 1:]:
+            apart = a[2] <= b[1] + 0.2 or b[2] <= a[1] + 0.2
+            assert apart or inside(a, b) or inside(b, a), (a, b)
+
+
+# ------------------------------------- names inside the device program
+
+@pytest.mark.parametrize("time_step,loss_fn", [(2, "pyramid_loss"),
+                                               (3, "pyramid_loss_multi")])
+def test_lowered_train_step_carries_the_scopes(time_step, loss_fn):
+    """Lower (never compile) a toy train step: the debug-info text holds
+    `forward`, `optimizer`, `preprocess` and one `loss_level_<k>` per flow
+    scale, forward (`jvp(...)`) and backward (`transpose(jvp(...))`),
+    with `warp`, `photometric` and `smooth` inside each level."""
+    import dataclasses
+    import re
+
+    import jax
+
+    from deepof_tpu.core.config import get_config
+    from deepof_tpu.models.registry import build_model
+    from deepof_tpu.parallel.mesh import build_mesh
+    from deepof_tpu.train.warmup import lower_train_step
+
+    cfg = get_config("flyingchairs")
+    cfg = cfg.replace(
+        model="flownet_s", width_mult=0.25,
+        data=dataclasses.replace(cfg.data, dataset="synthetic",
+                                 image_size=(32, 32), gt_size=(32, 32),
+                                 batch_size=2, time_step=time_step))
+    lowered = lower_train_step(cfg, build_mesh(devices=jax.devices()[:1]))
+    names = set(re.findall(r'loc\("(jit\(step\)/[^"]+)"',
+                           lowered.as_text(debug_info=True)))
+    parts = {tuple(n.split("/")[1:3]) for n in names if n.count("/") >= 2}
+    outer = {p[0] for p in parts}
+    n_scales = len(build_model("flownet_s", flow_channels=2).flow_scales)
+    assert n_scales == 6
+    for k in range(n_scales):
+        assert f"jvp(loss_level_{k})" in outer, (loss_fn, k)
+        assert f"transpose(jvp(loss_level_{k}))" in outer, (loss_fn, k)
+        for inner in ("warp", "photometric", "smooth"):
+            assert (f"jvp(loss_level_{k})", inner) in parts, (k, inner)
+    assert f"jvp(loss_level_{n_scales})" not in outer
+    assert {"jvp(forward)", "transpose(jvp(forward))", "optimizer",
+            "jvp(preprocess)"} <= outer
 
 
 # ---------------------------------------------- fit() acceptance (slow)
