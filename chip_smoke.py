@@ -421,7 +421,8 @@ def main(argv=None) -> int:
         model, extra = None, []  # the preset's own: inception_v3, full width
         size, batch, steps = (320, 448), 16, 3
         native = [(320, 448), (384, 512), (240, 320)]
-        warp_sizes, corr_size = [(40, 56), (80, 112)], (40, 56, 256, 20, 2)
+        warp_sizes, corr_size = ([(40, 56), (80, 112), (160, 224)],
+                                 (40, 56, 256, 20, 2))
         kbatch = 16
     os.makedirs(OUT, exist_ok=True)
 

@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.config import LossConfig
-from ..ops.warp import backward_warp, backward_warp_volume
+from ..ops.warp import (backward_warp, backward_warp_volume,
+                        warp_sweep_stats)
 from ..ops.smoothness import (
     forward_diff_x,
     forward_diff_y,
@@ -194,6 +195,7 @@ def loss_interp(
     with jax.named_scope("warp"):
         recon = backward_warp(_warp_operand(outputs, cfg), scaled,
                               impl=cfg.warp_impl).astype(inputs.dtype)
+        sweep_rows, gather_fallback = warp_sweep_stats(scaled, cfg.warp_impl)
     # needImageGradients (`flyingChairsWrapFlow_vgg.py:226-301`): the same
     # per-sample gradient-magnitude mask weights the photometric term by
     # |grad| and BOTH smoothness terms by 1-|grad| (edges may move freely).
@@ -316,8 +318,12 @@ def loss_interp(
         # telemetry's smoothness component ("Models Matter, So Does
         # Training": the loss-term decomposition is what predicts EPE);
         # the reference-named keys stay untouched for parity consumers
+        # the warp_* pair is no loss term: what the level's warp launch did
+        # (`ops.warp.warp_sweep_stats`), riding the same per-scale fetch
         {"total": total, "Charbonnier_reconstruct": photo,
-         "U_loss": u_loss, "V_loss": v_loss, "smooth": u_loss + v_loss},
+         "U_loss": u_loss, "V_loss": v_loss, "smooth": u_loss + v_loss,
+         "warp_sweep_rows": sweep_rows,
+         "warp_gather_fallback": gather_fallback},
         recon,
     )
 
@@ -373,6 +379,7 @@ def loss_interp_multi(
     with jax.named_scope("warp"):
         recon = backward_warp_volume(_warp_operand(volume, cfg), scaled,
                                      impl=cfg.warp_impl).astype(volume.dtype)
+        sweep_rows, gather_fallback = warp_sweep_stats(scaled, cfg.warp_impl)
 
     with jax.named_scope("photometric"):
         bmask = border_mask(h, w, cfg.border_ratio)
@@ -418,6 +425,8 @@ def loss_interp_multi(
     total = photo + cfg.lambda_smooth * (u_loss + v_loss)
     return (
         {"total": total, "Charbonnier_reconstruct": photo,
-         "U_loss": u_loss, "V_loss": v_loss, "smooth": u_loss + v_loss},
+         "U_loss": u_loss, "V_loss": v_loss, "smooth": u_loss + v_loss,
+         "warp_sweep_rows": sweep_rows,
+         "warp_gather_fallback": gather_fallback},
         recon,
     )

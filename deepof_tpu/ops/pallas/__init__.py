@@ -8,11 +8,11 @@ Kernel inventory (and why each op is/isn't a kernel):
     while the kernel holds one haloed row-window of f2 in VMEM and sweeps
     all displacements from on-chip memory.
 
-  - `warp.py` — the bilinear backward warp and its flow gradient at the
-    coarse pyramid levels (W <= 128: one lane register), as a bounded row
-    sweep. Fine levels stay an XLA gather (`ops/warp.py`): flow magnitude
-    is unbounded, so windowed VMEM loads cannot be sized statically
-    without changing semantics, and Mosaic has no arbitrary 2D gather.
+  - `warp.py` — the bilinear backward warp and its flow gradient at
+    every pyramid level of up to two lane tiles (W <= 256), as a sweep
+    over the row offsets the flow field holds (Mosaic has no arbitrary 2D
+    gather; border clipping bounds the offsets, so the sweep is exact for
+    any flow). Wider images stay an XLA gather (`ops/warp.py`).
 
 Under a mesh every kernel runs per batch shard through
 `parallel.spatial.shard_over_batch`.

@@ -76,10 +76,16 @@ STARVED_WAIT_S = 1e-3
 #: photometric-vs-smoothness trajectories are what predicts EPE — and
 #: the signal ROADMAP item 3's EPE-driven curriculum switch points will
 #: consume. Written into every periodic train record by _on_metrics.
+#: The warp_* pair says what the data-dependent warp did at each level
+#: (`ops.warp.warp_sweep_stats`): the largest row sweep of the batch (0 =
+#: the level ran on XLA by shape) and 1.0 where a two-tile launch's sweep
+#: was over `PALLAS_AUTO_MAX_SWEEP` and the gather took it.
 SCALE_RECORD_FIELDS: tuple[tuple[str, str], ...] = (
     ("loss_total_by_scale", "scale_total"),
     ("loss_photo_by_scale", "scale_Charbonnier_reconstruct"),
     ("loss_smooth_by_scale", "scale_smooth"),
+    ("warp_sweep_rows_by_scale", "scale_warp_sweep_rows"),
+    ("warp_gather_fallback_by_scale", "scale_warp_gather_fallback"),
 )
 
 

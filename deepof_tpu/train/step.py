@@ -207,9 +207,12 @@ def make_train_step(model, cfg: ExperimentConfig, mean: Mean, mesh,
             # per-pyramid-scale decomposition (finest first): photometric
             # ("Charbonnier_reconstruct") and smoothness ("smooth" = U+V)
             # components ride every metrics fetch — the loop folds them
-            # into each periodic train record as loss_*_by_scale lists
+            # into each periodic train record as loss_*_by_scale lists,
+            # and beside them what each level's warp launch did: the rows
+            # its sweep visited and whether the gather took it over
             for key in ("total", "Charbonnier_reconstruct", "U_loss",
-                        "V_loss", "smooth"):
+                        "V_loss", "smooth", "warp_sweep_rows",
+                        "warp_gather_fallback"):
                 metrics[f"scale_{key}"] = jnp.stack([d[key] for d in aux["losses"]])
         for key in ("action_loss", "accuracy"):
             if key in aux:

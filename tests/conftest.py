@@ -49,3 +49,26 @@ def free_port(host: str = "127.0.0.1") -> int:
 @pytest.fixture
 def rng():
     return np.random.RandomState(0)
+
+
+@pytest.fixture
+def on_a_tpu(monkeypatch):
+    """Steer `ops.warp`'s `impl="auto"` as a TPU would take it, with the
+    Pallas warp kernels in interpret mode (the gate asks
+    `jax.default_backend()`; steering belongs to the test, not to an
+    option of the program). Yields the list of `sweep_limit`s the kernel
+    wrapper was called with."""
+    import jax
+
+    import deepof_tpu.ops.pallas.warp as kernel_mod
+
+    calls, real = [], kernel_mod.backward_warp_pallas
+
+    def recording(image, flow, batch_axes=("data",), sweep_limit=None):
+        calls.append(sweep_limit)
+        return real(image, flow, interpret=True, batch_axes=batch_axes,
+                    sweep_limit=sweep_limit)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel_mod, "backward_warp_pallas", recording)
+    return calls

@@ -85,11 +85,14 @@ def _compiled_text(fn, *args, kernels=()):
     return text
 
 
-@pytest.mark.parametrize("hw", [(80, 112), (40, 56)])
+@pytest.mark.parametrize("hw", [(80, 112), (40, 56), (160, 224), (112, 240)])
 @pytest.mark.parametrize("which", ["fwd", "flow_grad"])
 def test_warp_kernels_compile_for_v5e(one_chip, which, hw):
-    """Both warp kernels at the two pyramid levels `warp_impl=auto` admits
-    for a 320x448 input (W <= 128): B16, C3, f32."""
+    """Both warp kernels at pyramid levels `warp_impl=auto` admits: two of
+    one lane tile (W <= 128) and the finest of a 320x448 input, two tiles
+    (160x224), with the Sintel crop's (112x240): B16, C3, f32. Each holds
+    a vector-to-scalar min/max (the sweep's bounds) and a loop whose trip
+    count is data."""
     from deepof_tpu.ops.pallas.warp import (_pallas_warp_flow_grad,
                                             _pallas_warp_fwd)
 
@@ -121,31 +124,50 @@ def test_corr_kernel_compiles_for_v5e(one_chip, hw, dtype):
                    kernels=["corr_fwd"])
 
 
+def _auto_flow_grad(hw):
+    """Gradient of the public warp as `impl="auto"` launches it on a TPU:
+    two lane tiles (W > 128) go under the per-launch sweep limit."""
+    from deepof_tpu.ops.pallas.warp import backward_warp_pallas
+    from deepof_tpu.ops.warp import PALLAS_AUTO_MAX_SWEEP
+
+    limit = PALLAS_AUTO_MAX_SWEEP if hw[1] > 128 else None
+
+    def flow_grad(im, fl):
+        return jax.grad(lambda x: jnp.sum(backward_warp_pallas(
+            im, x, False, sweep_limit=limit) ** 2))(fl)
+
+    return flow_grad
+
+
+@pytest.mark.parametrize("hw", [(40, 56), (160, 224)])
 @pytest.mark.parametrize("n_dev,time", [(1, 1), (4, 1), (4, 2)])
-def test_warp_vjp_compiles_through_shard_map(topo, n_dev, time):
+def test_warp_vjp_compiles_through_shard_map(topo, n_dev, time, hw):
     """The public warp (custom_vjp: forward kernel + flow-grad kernel) in
     its one multi-device form — `shard_over_batch` over the mesh the step
     builders publish — on a one-device mesh, on all four described chips
     with the batch sharded over "data", and on a data 2 x time 2 mesh,
-    where a data-sharded batch must stay on its "data" shards."""
-    from deepof_tpu.ops.pallas.warp import backward_warp_pallas
+    where a data-sharded batch must stay on its "data" shards. At two
+    lane tiles each shard decides alone between the kernels and the
+    gather (`lax.cond` inside the shard), forward and backward: both
+    branches are in the program and no collective carries the decision."""
     from deepof_tpu.parallel.mesh import batch_sharding
     from deepof_tpu.parallel.spatial import mesh_context
 
     mesh = _mesh(topo, n_dev, time)
     data = batch_sharding(mesh)
-    img = jax.ShapeDtypeStruct((BATCH, 40, 56, 3), jnp.float32, sharding=data)
-    flow = jax.ShapeDtypeStruct((BATCH, 40, 56, 2), jnp.float32, sharding=data)
-
-    def flow_grad(im, fl):
-        return jax.grad(lambda x: jnp.sum(
-            backward_warp_pallas(im, x, False) ** 2))(fl)
+    img = jax.ShapeDtypeStruct((BATCH, *hw, 3), jnp.float32, sharding=data)
+    flow = jax.ShapeDtypeStruct((BATCH, *hw, 2), jnp.float32, sharding=data)
 
     with mesh_context(mesh):  # read at trace time, as in train/step.py
-        text = _compiled_text(flow_grad, img, flow,
+        text = _compiled_text(_auto_flow_grad(hw), img, flow,
                               kernels=["warp_fwd", "warp_flow_grad"])
     assert text.count("tpu_custom_call") >= 2  # forward + flow-grad kernels
     assert "all-gather" not in text  # each shard warps its own batch rows
+    two_tiles = hw[1] > 128
+    assert len(re.findall(r" conditional\(", text)) == (2 if two_tiles else 0)
+    # the gather's branch: 12-wide rows of the 2x2 patches
+    assert (re.search(r"f32\[\d+,\d+,12\]\S* gather\(", text)
+            is not None) == two_tiles
 
 
 def test_corr_compiles_through_shard_map_on_four_chips(topo):
@@ -168,21 +190,18 @@ def _mosaic_payloads(fn, *args):
                       jax.jit(fn).lower(*args).as_text())
 
 
-def test_mosaic_payload_does_not_depend_on_who_traced(one_chip):
+@pytest.mark.parametrize("hw", [(40, 56), (160, 224)])
+def test_mosaic_payload_does_not_depend_on_who_traced(one_chip, hw):
     """The compile cache's key holds the Mosaic payload, which holds its
     ops' source locations: the same warp (forward and flow-gradient
-    kernels) traced from two call stacks must lower to the same bytes
-    (`one_frame_locations`; PR 23 read two keys on the chip before it)."""
-    from deepof_tpu.ops.pallas.warp import backward_warp_pallas
-
-    img = jax.ShapeDtypeStruct((BATCH, 40, 56, 3), jnp.float32,
+    kernels, one lane tile and two under the `cond`) traced from two call
+    stacks must lower to the same bytes (`one_frame_locations`; PR 23 read
+    two keys on the chip before it)."""
+    img = jax.ShapeDtypeStruct((BATCH, *hw, 3), jnp.float32,
                                sharding=one_chip)
-    flow = jax.ShapeDtypeStruct((BATCH, 40, 56, 2), jnp.float32,
+    flow = jax.ShapeDtypeStruct((BATCH, *hw, 2), jnp.float32,
                                 sharding=one_chip)
-
-    def flow_grad(im, fl):
-        return jax.grad(lambda x: jnp.sum(
-            backward_warp_pallas(im, x, False) ** 2))(fl)
+    flow_grad = _auto_flow_grad(hw)
 
     def from_deeper(im, fl):
         def one_more_frame(im, fl):
@@ -237,8 +256,10 @@ def test_whole_train_step_compiles_for_v5e(topo, monkeypatch, model, n_dev):
         train=dataclasses.replace(cfg.train, compute_dtype="bfloat16"))
     compiled = lower_train_step(cfg, _mesh(topo, n_dev)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 10
+    assert text.count("tpu_custom_call") >= 12  # every level, the finest too
     assert "%warp_fwd." in text and "%warp_flow_grad." in text
+    # kernels or gather, decided inside the shard where there is a mesh
+    assert re.search(r"jvp\(loss_level_0\)/warp/(shard_map/)?cond", text)
     assert ("%corr_fwd." in text) == (model == "flownet_c")
     assert "%name." not in text  # what an unnamed Mosaic call was called
     for scope in ("jvp(forward)", "transpose(jvp(forward))", "optimizer",

@@ -16,6 +16,9 @@ from deepof_tpu.losses.photometric import loss_interp, loss_interp_multi
 from deepof_tpu.ops.warp import backward_warp
 from deepof_tpu.ops.pallas.warp import backward_warp_pallas
 
+LOSS_TERMS = ("total", "Charbonnier_reconstruct", "U_loss", "V_loss",
+              "smooth")
+
 
 @pytest.mark.parametrize(
     "shape,mag",
@@ -36,10 +39,89 @@ def test_pallas_warp_matches_xla(rng, shape, mag):
 
 
 def test_pallas_warp_rejects_wide_levels(rng):
-    img = jnp.zeros((1, 8, 256, 3))
-    flow = jnp.zeros((1, 8, 256, 2))
-    with pytest.raises(ValueError, match="W <= 128"):
+    """Two lane tiles are the kernel's limit; 256 itself is admitted."""
+    img = jnp.zeros((1, 8, 257, 3))
+    flow = jnp.zeros((1, 8, 257, 2))
+    with pytest.raises(ValueError, match="W <= 256"):
         backward_warp_pallas(img, flow)
+    full = jnp.asarray(rng.rand(1, 8, 256, 3), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(backward_warp_pallas(full, jnp.zeros((1, 8, 256, 2)))),
+        np.asarray(full))
+
+
+# ------------------------ the data-bounded sweep, one and two lane tiles
+
+SWEEP_SHAPES = [(16, 112), (24, 160), (16, 224), (40, 56)]
+SWEEP_FLOWS = ["zero", "subpixel", "six_px", "one_image_spans_the_frame",
+               "every_image_spans_the_frame", "far_outside"]
+
+
+def _sweep_flow(rng, kind, b, h, w):
+    def uniform(reach_x, reach_y, n=b):
+        return np.stack([rng.uniform(-reach_x, reach_x, (n, h, w)),
+                         rng.uniform(-reach_y, reach_y, (n, h, w))], -1)
+
+    if kind == "zero":
+        flow = np.zeros((b, h, w, 2))
+    elif kind == "subpixel":
+        flow = uniform(0.99, 0.99)
+    elif kind == "six_px":
+        flow = uniform(6.0, 6.0)
+    elif kind == "one_image_spans_the_frame":  # per-image bounds
+        flow = uniform(0.99, 0.99)
+        flow[1] = uniform(w, h, n=1)[0]
+        flow[1, 0, 0, 1], flow[1, h - 1, 0, 1] = 2.0 * h, -2.0 * h
+    elif kind == "every_image_spans_the_frame":  # the full sweep
+        flow = uniform(w, h)
+        flow[:, 0, 0, 1], flow[:, h - 1, 0, 1] = 2.0 * h, -2.0 * h
+    else:  # every neighbour clipped to the border
+        flow = np.stack([np.full((b, h, w), -3.0 * w),
+                         np.full((b, h, w), 5.0 * h)], -1)
+        flow[1] = -flow[1]
+    return jnp.asarray(flow, jnp.float32)
+
+
+def _value_and_grads(warp, img, flow, ct):
+    val, vjp = jax.vjp(warp, img, flow)
+    return (val, *vjp(ct))
+
+
+@pytest.mark.parametrize("kind", SWEEP_FLOWS)
+@pytest.mark.parametrize("hw", SWEEP_SHAPES)
+def test_bounded_sweep_is_exact(rng, monkeypatch, hw, kind):
+    """The sweep visits only the row offsets the image's flow holds.
+    Value and both gradients equal the XLA warp's, and equal the FULL
+    sweep of all 2H-1 offsets (what the kernel ran before) to the bit:
+    the skipped offsets are exactly those whose masks are all zero."""
+    import deepof_tpu.ops.pallas.warp as kernel_mod
+    from deepof_tpu.ops.warp import row_sweep_lengths
+
+    (h, w), b = hw, 3
+    img = jnp.asarray(rng.rand(b, h, w, 3), jnp.float32)
+    ct = jnp.asarray(rng.randn(b, h, w, 3), jnp.float32)
+    flow = _sweep_flow(rng, kind, b, h, w)
+
+    rows = np.asarray(row_sweep_lengths(flow[..., 1]))
+    want = {"zero": [2] * b, "far_outside": [h] * b,
+            "every_image_spans_the_frame": [2 * h - 1] * b}.get(kind)
+    if want is not None:
+        assert rows.tolist() == want
+    elif kind == "one_image_spans_the_frame":
+        assert rows[1] == 2 * h - 1 and rows[0] <= 3 and rows[2] <= 3
+    else:
+        assert rows.max() <= (3 if kind == "subpixel" else 14)
+
+    bounded = _value_and_grads(backward_warp_pallas, img, flow, ct)
+    xla = _value_and_grads(backward_warp, img, flow, ct)
+    for got, ref in zip(bounded, xla):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+    monkeypatch.setattr(kernel_mod, "_sweep_bounds",
+                        lambda setups, h, w, hp: (-(h - 1), h))
+    full = _value_and_grads(backward_warp_pallas, img, flow, ct)
+    for got, ref in zip(bounded, full):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 def test_pallas_warp_gradients_match(rng):
@@ -73,9 +155,13 @@ def test_loss_interp_pallas_impl_matches(rng):
     lp, rp = loss_interp(flow, prev, nxt, 2.5, cfg_p)
     np.testing.assert_allclose(np.asarray(rp), np.asarray(rx),
                                rtol=1e-5, atol=1e-5)
-    for k in lx:
+    for k in LOSS_TERMS:
         np.testing.assert_allclose(float(lp[k]), float(lx[k]),
                                    rtol=1e-5, atol=1e-6)
+    # what the warp launch did rides beside the terms: XLA sweeps nothing
+    assert float(lx["warp_sweep_rows"]) == 0.0
+    assert 2.0 <= float(lp["warp_sweep_rows"]) <= 2 * 20 - 1
+    assert float(lp["warp_gather_fallback"]) == 0.0
 
 
 def test_loss_interp_multi_pallas_impl_matches(rng):
@@ -86,22 +172,38 @@ def test_loss_interp_multi_pallas_impl_matches(rng):
     vol = jnp.asarray(rng.rand(2, 10, 14, 3 * t), jnp.float32)
     lx, _ = loss_interp_multi(flows, vol, 1.25, cfg_x)
     lp, _ = loss_interp_multi(flows, vol, 1.25, cfg_p)
-    for k in lx:
+    for k in LOSS_TERMS:
         np.testing.assert_allclose(float(lp[k]), float(lx[k]),
                                    rtol=1e-5, atol=1e-6)
+    # the volume's counter spans every pair's vertical flow (odd channels)
+    from deepof_tpu.ops.warp import row_sweep_lengths
+    assert float(lp["warp_sweep_rows"]) == float(jnp.max(row_sweep_lengths(
+        flows[..., 1::2] * 1.25)))
 
 
-def test_auto_impl_dispatch(rng):
-    # auto: small level -> pallas path must agree; wide level -> xla path runs
-    img = jnp.asarray(rng.rand(1, 12, 16, 3), jnp.float32)
-    flow = jnp.asarray(rng.randn(1, 12, 16, 2), jnp.float32)
-    np.testing.assert_allclose(
-        np.asarray(backward_warp(img, flow, impl="auto")),
-        np.asarray(backward_warp(img, flow)), rtol=1e-5, atol=1e-5)
-    wide = jnp.asarray(rng.rand(1, 8, 200, 3), jnp.float32)
-    wflow = jnp.zeros((1, 8, 200, 2))
-    out = backward_warp(wide, wflow, impl="auto")  # falls back to xla
-    np.testing.assert_allclose(np.asarray(out), np.asarray(wide), atol=1e-6)
+@pytest.mark.parametrize("hw,takes", [
+    ((12, 16), "kernel"),         # one lane tile: the kernel, no limit
+    ((8, 128), "kernel"),
+    ((8, 200), "kernel_or_gather"),   # two tiles: the kernel under the cond
+    ((256, 130), "kernel_or_gather"),
+    ((8, 300), "xla"),            # W > 256
+    ((260, 16), "xla"),           # H > 256
+])
+def test_auto_impl_dispatch(rng, request, hw, takes):
+    from deepof_tpu.ops.warp import PALLAS_AUTO_MAX_SWEEP
+
+    h, w = hw
+    img = jnp.asarray(rng.rand(1, h, w, 3), jnp.float32)
+    flow = jnp.asarray(rng.randn(1, h, w, 2), jnp.float32)
+    ref = np.asarray(backward_warp(img, flow))
+    # off a TPU `auto` is the XLA gather at every size, to the bit
+    np.testing.assert_array_equal(
+        np.asarray(backward_warp(img, flow, impl="auto")), ref)
+    calls = request.getfixturevalue("on_a_tpu")  # only from here on
+    out = backward_warp(img, flow, impl="auto")
+    assert calls == {"kernel": [None], "xla": [],
+                     "kernel_or_gather": [PALLAS_AUTO_MAX_SWEEP]}[takes]
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
 
 
 def test_pallas_flow_grad_clipped_and_flow_only(rng):

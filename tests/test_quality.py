@@ -451,7 +451,8 @@ def test_per_scale_record_fields_shape():
 
     assert [f for f, _ in SCALE_RECORD_FIELDS] == [
         "loss_total_by_scale", "loss_photo_by_scale",
-        "loss_smooth_by_scale"]
+        "loss_smooth_by_scale", "warp_sweep_rows_by_scale",
+        "warp_gather_fallback_by_scale"]
     v = np.array([1.0, 0.5, 0.25])
     assert per_scale_last(v) == [1.0, 0.5, 0.25]
     stacked = np.stack([v, v * 2.0])  # [K=2, S=3]: last step wins

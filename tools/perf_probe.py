@@ -16,6 +16,9 @@ Sections:
   warpscan warp timing with 20 warps chained inside one jit (per-call
            dispatch amortized away), incl. the finest 160x224 level —
            supersedes `warp` for decisions
+  warpsweep the warp kernels' time against the rows their sweep visits,
+           beside the XLA gather, at 160x224 (two lane tiles) and 80x112,
+           batch 64: the measurement behind `PALLAS_AUTO_MAX_SWEEP`
   spc      steps_per_call sweep (1/4/8): dispatch amortization
   corr     XLA vs Pallas correlation kernel, fwd + grad, FlowNet-C
            shapes
@@ -152,6 +155,58 @@ def sec_warp_scan() -> None:
                   flush=True)
 
 
+def sec_warp_sweep(cases=(((160, 224), (0.4, 5.0, 39.0, 79.0, 160.0)),
+                          ((80, 112), (0.4, 1.0, 80.0))),
+                   batch: int = 64) -> None:
+    """Kernel time is linear in the row offsets the flow holds; the
+    gather's does not depend on them. Times warp forward+flow-gradient
+    (both kernels, as a loss level runs them) chained 8 deep inside one
+    jit, for flows whose vertical part is uniform in [-a, a): a sweep of
+    about 2a+2 rows, 2H-1 once a >= H. `auto` is the kernel under its
+    per-launch `cond`. Also prints the largest error against XLA."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from deepof_tpu.ops.warp import backward_warp, row_sweep_lengths
+
+    n_inner = 8
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
+
+    def both(impl):
+        def f(i, fl):
+            def body(f_, _):
+                val, g = jax.value_and_grad(lambda q: jnp.sum(
+                    backward_warp(i, q, impl=impl) ** 2))(f_)
+                return f_ + 1e-30 * (g + val), None
+            return lax.scan(body, fl, None, length=n_inner)[0].sum()
+        return jax.jit(f)
+
+    def one(impl):
+        return jax.jit(lambda i, fl: jax.value_and_grad(lambda q: jnp.sum(
+            backward_warp(i, q, impl=impl) ** 2))(fl))
+
+    for (h, w), reaches in cases:
+        img = jax.random.uniform(k1, (batch, h, w, 3))
+        for a in reaches:
+            flow = jnp.stack(
+                [jax.random.uniform(k2, (batch, h, w), minval=-4., maxval=4.),
+                 jax.random.uniform(k3, (batch, h, w), minval=-a, maxval=a)],
+                axis=-1)
+            rows = int(jnp.max(row_sweep_lengths(flow[..., 1])))
+            (vx, gx) = one("xla")(img, flow)
+            (vp, gp) = one("pallas")(img, flow)
+            print(f"{h}x{w} sweep {rows}: pallas vs xla value rel "
+                  f"{abs(float(vp - vx)) / abs(float(vx)):.2e}, flow-grad "
+                  f"max abs {float(jnp.max(jnp.abs(gp - gx))):.2e} of "
+                  f"{float(jnp.max(jnp.abs(gx))):.2e}", flush=True)
+            for impl in ("pallas", "auto", "xla"):
+                per = timeit(f"warp fwd+grad {impl} {h}x{w} sweep {rows}",
+                             both(impl), img, flow, steps=3, windows=2)
+                print(f"{'  -> per fwd+grad':44s} {per/n_inner*1e3:8.3f} ms",
+                      flush=True)
+
+
 def sec_decomp() -> None:
     import jax
     import jax.numpy as jnp
@@ -276,7 +331,7 @@ def sec_multiframe() -> None:
 
 
 # Execution order: the headline + its MFU fields first, then
-# calibration context, then the decision sections (decomp/warpscan/spc/
+# calibration context, then the decision sections (decomp/warpscan/warpsweep/spc/
 # corr), then sweeps; the per-call warp table is superseded by warpscan
 # and runs last.
 SECTIONS = {
@@ -284,6 +339,7 @@ SECTIONS = {
     "calib": sec_calib,
     "decomp": sec_decomp,
     "warpscan": sec_warp_scan,
+    "warpsweep": sec_warp_sweep,
     "spc": sec_spc,
     "corr": sec_corr,
     "batch": sec_batch,
