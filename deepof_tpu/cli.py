@@ -119,11 +119,24 @@ def _build_cfg(args) -> ExperimentConfig:
             cfg = _apply_override(cfg, dotted, repr(value))
     if getattr(args, "autoscale", False):
         cfg = _apply_override(cfg, "serve.fleet.autoscale", "true")
+    overrides = []
     for item in args.set or []:
         if "=" not in item:
             raise SystemExit(f"bad --set {item!r}: use section.field=value")
-        dotted, raw = item.split("=", 1)
-        cfg = _apply_override(cfg, dotted, raw)
+        overrides.append(item.split("=", 1))
+    # lm.config_file first: the file fills the section, every other
+    # override (lm.* among them) then wins over what the file says
+    for dotted, raw in overrides:
+        if dotted == "lm.config_file":
+            from .core.config import fill_lm_from_file
+
+            try:
+                cfg = cfg.replace(lm=fill_lm_from_file(cfg.lm, raw))
+            except (OSError, ValueError, TypeError) as e:
+                raise SystemExit(f"--set lm.config_file={raw!r}: {e}")
+    for dotted, raw in overrides:
+        if dotted != "lm.config_file":
+            cfg = _apply_override(cfg, dotted, raw)
     return cfg
 
 

@@ -118,7 +118,7 @@ class OptimConfig:
 
 @dataclass(frozen=True)
 class DataConfig:
-    dataset: str = "flyingchairs"  # flyingchairs | sintel | ucf101 | synthetic
+    dataset: str = "flyingchairs"  # flyingchairs | sintel | ucf101 | synthetic | tokens
     data_path: str = ""
     image_size: tuple[int, int] = (384, 512)  # (H, W) network input
     gt_size: tuple[int, int] = (384, 512)  # native ground-truth resolution
@@ -882,10 +882,88 @@ class RecipeConfig:
 
 
 @dataclass(frozen=True)
+class LMConfig:
+    """A decoder-only language model of the latent-attention / sparse-expert
+    family (`models/lm/`), under the keys of the model's own public
+    `config.json` (`model_type: deepseek_v3`) and with their meaning.
+    `config_file` names a JSON file of that shape: `fill_lm_from_file`
+    copies every key of the file that is a field here (the file may hold
+    more: a benchmark configuration keeps its notes beside the sizes).
+
+    The chip's share of an expert-parallel deployment: `n_routed_experts`
+    counts the experts HELD here, `n_routed_experts_published` is the
+    router's width (0: all are held) and `first_expert` the index of the
+    first one held. The layer scores and chooses over all of them,
+    normalises over every chosen one and adds only what its own experts
+    give (`models/lm/layers.py::MoE`). `vocab_size` is the rows of the
+    embedding and of the head held here; ids, logits and loss are over them.
+    """
+
+    config_file: str = ""
+    # --- published keys (defaults: a toy of the same shape, for tests) ---
+    vocab_size: int = 256
+    hidden_size: int = 64
+    intermediate_size: int = 128  # the leading dense layers' SwiGLU width
+    moe_intermediate_size: int = 32  # one routed expert's width
+    num_hidden_layers: int = 3
+    num_attention_heads: int = 4
+    q_lora_rank: int | None = None  # None: queries are not compressed
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    n_routed_experts: int = 8
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 2
+    first_k_dense_replace: int = 1
+    moe_layer_freq: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_interleave: bool = True
+    rope_scaling: Any = None
+    attention_bias: bool = False
+    tie_word_embeddings: bool = False
+    max_position_embeddings: int = 32768
+    # --- the share held here ---
+    n_routed_experts_published: int = 0
+    first_expert: int = 0
+    # --- not in config.json: the job's own sizes ---
+    seq_len: int = 32  # positions a row trains on (a row holds seq_len + 1 ids)
+    init_std: float = 0.02  # normal initialisation of every matrix
+    # ... but the embedding: at init_std the causal mean that attention
+    # adds to every position alike is several times a token's own vector,
+    # every token then routes alike and one expert takes every slot
+    embed_std: float = 1.0
+    bias_std: float = 0.01  # e_score_correction_bias: drawn once, then fixed
+    attn_block_q: int = 512  # queries a block of the attention; scores never
+    # exist for more than one block, and the backward recomputes them
+    loss_block: int = 2048  # positions a block of the head and the loss
+
+
+def fill_lm_from_file(lm: LMConfig, path: str) -> LMConfig:
+    """`lm` with every key of the JSON file at `path` that names a field."""
+    import json
+
+    with open(path) as f:
+        d = json.load(f)
+    names = {f.name for f in dataclasses.fields(LMConfig)} - {"config_file"}
+    return dataclasses.replace(
+        lm, config_file=path, **{k: v for k, v in d.items() if k in names})
+
+
+@dataclass(frozen=True)
 class ExperimentConfig:
     name: str = "flyingchairs_flownet_s"
     # any models/registry.py name: flownet_s | vgg16 | inception_v3 |
-    # flownet_c | flownet_cs | st_single | st_baseline | ucf101_spatial
+    # flownet_c | flownet_cs | st_single | st_baseline | ucf101_spatial |
+    # latent_moe_lm (sized by the `lm` section)
     model: str = "flownet_s"
     # Thin-variant channel multiplier — honored by models declaring a
     # width_mult field (flownet_s, flownet_c; the parity backbones keep
@@ -914,6 +992,7 @@ class ExperimentConfig:
     serve: ServeConfig = field(default_factory=ServeConfig)
     elastic: ElasticConfig = field(default_factory=ElasticConfig)
     recipe: RecipeConfig = field(default_factory=RecipeConfig)
+    lm: LMConfig = field(default_factory=LMConfig)
 
     def replace(self, **kw: Any) -> "ExperimentConfig":
         return dataclasses.replace(self, **kw)
@@ -974,6 +1053,21 @@ UCF101 = ExperimentConfig(
     train=TrainConfig(num_epochs=1000, eval_amplifier=1.0, eval_clip=(-1e9, 1e9)),
 )
 
+
+# A language model (`models/lm/`): next-token cross-entropy on rows of
+# `lm.seq_len` + 1 ids. The sizes come from `--set lm.config_file=FILE`
+# (a JSON file of the public config.json's shape) or `--set lm.<key>=...`;
+# the defaults are a toy. Constant learning rate, no clipping: what a
+# config.json does not give is the job's to set.
+LM = ExperimentConfig(
+    name="lm_latent_moe",
+    model="latent_moe_lm",
+    optim=OptimConfig(learning_rate=1e-4, decay_factor=1.0),
+    data=DataConfig(dataset="tokens", batch_size=2),
+    train=TrainConfig(num_epochs=1, log_every=50, eval_every=0,
+                      eval_batch_size=2, remat=True),
+)
+
 # gen-1 per-model loss-weight alternates (`version1/trainOF.py:76-87`),
 # selectable via LossConfig.weights overrides.
 GEN1_LOSS_WEIGHTS = {
@@ -987,6 +1081,7 @@ PRESETS: dict[str, ExperimentConfig] = {
     "flyingchairs_vgg": FLYINGCHAIRS_VGG,
     "sintel": SINTEL,
     "ucf101": UCF101,
+    "lm": LM,
 }
 
 

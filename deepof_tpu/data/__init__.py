@@ -22,6 +22,7 @@ from .datasets import (
     FlyingChairsData,
     SintelData,
     SyntheticData,
+    TokenData,
     UCF101Data,
     build_dataset,
 )
@@ -40,6 +41,7 @@ __all__ = [
     "FlyingChairsData",
     "SintelData",
     "SyntheticData",
+    "TokenData",
     "UCF101Data",
     "build_dataset",
     "MixtureDataset",

@@ -708,13 +708,59 @@ class SyntheticData:
                 "entries": 0}
 
 
-def build_dataset(cfg: DataConfig) -> Dataset:
+class TokenData:
+    """Rows of `seq_len + 1` token ids for a language model, made from a
+    fixed seed: `POOL_ROWS` train rows, ids drawn from a Zipf law of
+    exponent `ZIPF_EXPONENT` over the vocabulary held (frequent ids repeat,
+    as on text), packed with no padding. A batch is {"tokens": int32[b, seq_len + 1]}: position t's
+    target is position t + 1's id. Same protocol as the frame datasets, so
+    the pipeline, the staging and the `put` are theirs; `mean` is unused."""
+
+    mean = (0.0, 0.0, 0.0)
+    POOL_ROWS = 64
+    ZIPF_EXPONENT = 1.1
+
+    def __init__(self, cfg: DataConfig, lm, num_val: int = 16, seed: int = 0):
+        self.cfg = cfg
+        self.num_train, self.num_val = self.POOL_ROWS, num_val
+        p = 1.0 / np.arange(1, lm.vocab_size + 1,
+                            dtype=np.float64) ** self.ZIPF_EXPONENT
+        cdf = np.cumsum(p / p.sum())
+        u = np.random.RandomState(seed).random_sample(
+            (self.num_train + num_val, lm.seq_len + 1))
+        self.rows = np.minimum(np.searchsorted(cdf, u), lm.vocab_size - 1
+                               ).astype(np.int32)
+
+    def sample_train(self, batch_size, iteration=None, rng=None):
+        if iteration is not None:
+            idx = (iteration * batch_size + np.arange(batch_size)) % self.num_train
+        else:
+            idx = (rng or np.random).randint(0, self.num_train, batch_size)
+        return {"tokens": self.rows[idx]}
+
+    def sample_val(self, batch_size, batch_id):
+        idx = self.num_train + (batch_id * batch_size
+                                + np.arange(batch_size)) % self.num_val
+        return {"tokens": self.rows[idx]}
+
+    def cache_stats(self) -> dict:
+        return {"hits": 0, "misses": 0, "evictions": 0, "bytes": 0,
+                "entries": 0}
+
+
+def build_dataset(cfg: DataConfig, lm=None) -> Dataset:
+    """`lm`: the experiment's `lm` section, which sizes the `tokens`
+    dataset (vocabulary held, row length); the frame datasets ignore it."""
     builders = {
         "flyingchairs": FlyingChairsData,
         "sintel": SintelData,
         "ucf101": UCF101Data,
         "synthetic": SyntheticData,
+        "tokens": lambda c: TokenData(c, lm),
     }
     if cfg.dataset not in builders:
         raise KeyError(f"unknown dataset {cfg.dataset!r}; available: {sorted(builders)}")
+    if cfg.dataset == "tokens" and lm is None:
+        raise ValueError("dataset 'tokens' is sized by the experiment's `lm` "
+                         "section: build_dataset(cfg.data, lm=cfg.lm)")
     return builders[cfg.dataset](cfg)

@@ -1,0 +1,250 @@
+"""The layers of the latent-attention / sparse-expert family
+(`model_type: deepseek_v3`), by their equations.
+
+Precision: norm statistics, rotary angles, router scores, softmax and loss
+in float32; every other matrix product takes operands in the compute dtype
+(float32 masters are cast on use) and accumulates in float32; the residual
+stream stays float32. No bias anywhere.
+
+Scopes (what the per-layer readers find in a profile; flax names a
+module's scope after the module, the rest are `jax.named_scope`s):
+`layer_<i>` > `mla` > `mla_proj`, `mla_scores`, `mla_out`; `dense_ffn`;
+`moe` > `moe_route`, `moe_dispatch`, `moe_experts`, `moe_shared`,
+`moe_combine`; beside them `embed`, `lm_head`, `loss_ce`, `optimizer`.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax import lax
+
+from ...core.config import LMConfig
+
+F32 = jnp.float32
+_NEG = -1e30  # masked score: finite, so a fully masked row cannot give NaN
+ragged_dot = lax.ragged_dot  # a name of this module's: a test stands in for the chip's
+
+
+def _init(cfg: LMConfig):
+    return nn.initializers.normal(cfg.init_std)
+
+
+def dot(x, w, dtype):
+    """x[..., k] @ w[k, n]: operands in `dtype`, float32 accumulation."""
+    return lax.dot_general(x.astype(dtype), w.astype(dtype),
+                           (((x.ndim - 1,), (0,)), ((), ())),
+                           preferred_element_type=F32)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), F32)
+        x = x.astype(F32)
+        return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.eps) * scale
+
+
+def rope(x, theta: float):
+    """Rotary positions on x[b, s, h, d], float32; channel pairs
+    (2i, 2i+1) are the rotated pairs (`rope_interleave` true)."""
+    s, d = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
+    ang = jnp.arange(s, dtype=F32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x = x.astype(F32)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape)
+
+
+def _attend_block(qn, qr, kn, kr, v, q0: int, scale: float, dtype):
+    """Queries q0.. of one block against the keys 0..L that a causal mask
+    lets them see. qn[b,q,h,dn] qr[b,q,h,dr] kn[b,L,h,dn] kr[b,L,dr]
+    v[b,L,h,dv] -> [b,q,h,dv] float32. The rotary key is one head's,
+    shared by all: k = [k_nope | k_rope] is never built."""
+    s = (jnp.einsum("bqhd,bkhd->bhqk", qn, kn, preferred_element_type=F32)
+         + jnp.einsum("bqhd,bkd->bhqk", qr, kr, preferred_element_type=F32))
+    qpos = q0 + jnp.arange(qn.shape[1])[:, None]
+    s = jnp.where(qpos >= jnp.arange(kn.shape[1])[None, :], s * scale, _NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(dtype), v,
+                      preferred_element_type=F32)
+
+
+def causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int, dtype):
+    """Blocks of `block_q` queries, each against its own prefix of keys:
+    the scores of one block are all that exists at a time, the masked half
+    above the diagonal blocks is never computed, and the backward
+    recomputes a block's scores (`jax.checkpoint`) instead of keeping them."""
+    s = qn.shape[1]
+    bq = min(block_q, s)
+    if s % bq:
+        raise ValueError(f"lm.attn_block_q={block_q} does not divide "
+                         f"the {s} positions of a row")
+    block = jax.checkpoint(_attend_block, static_argnums=(5, 6, 7))
+    outs = []
+    for q0 in range(0, s, bq):
+        hi = q0 + bq
+        outs.append(block(qn[:, q0:hi], qr[:, q0:hi], kn[:, :hi], kr[:, :hi],
+                          v[:, :hi], q0, scale, dtype))
+    return jnp.concatenate(outs, axis=1)
+
+
+class MLA(nn.Module):
+    """Latent attention without query compression (`q_lora_rank` null)."""
+
+    cfg: LMConfig
+    dtype: Any = F32
+
+    @nn.compact
+    def __call__(self, h):
+        c, dt = self.cfg, self.dtype
+        if c.q_lora_rank is not None or not c.rope_interleave:
+            raise NotImplementedError(
+                "models/lm: only uncompressed queries (q_lora_rank null) "
+                "and interleaved rotary pairs (rope_interleave true) are "
+                "written here")
+        b, s, d = h.shape
+        nh, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
+                          c.qk_rope_head_dim, c.v_head_dim)
+        init = _init(c)
+        wq = self.param("wq", init, (d, nh * (dn + dr)), F32)
+        wkva = self.param("wkva", init, (d, c.kv_lora_rank + dr), F32)
+        wkvb = self.param("wkvb", init, (c.kv_lora_rank, nh * (dn + dv)), F32)
+        wo = self.param("wo", init, (nh * dv, d), F32)
+        with jax.named_scope("mla_proj"):
+            q = dot(h, wq, dt).reshape(b, s, nh, dn + dr)
+            qn = q[..., :dn].astype(dt)
+            qr = rope(q[..., dn:], c.rope_theta).astype(dt)
+            ckv = dot(h, wkva, dt)
+            kr = rope(ckv[:, :, None, c.kv_lora_rank:],
+                      c.rope_theta)[:, :, 0].astype(dt)
+            lat = RMSNorm(c.rms_norm_eps, name="kv_norm")(
+                ckv[..., :c.kv_lora_rank])
+            kv = dot(lat, wkvb, dt).reshape(b, s, nh, dn + dv)
+            kn, v = kv[..., :dn].astype(dt), kv[..., dn:].astype(dt)
+        with jax.named_scope("mla_scores"):
+            o = causal_attention(qn, qr, kn, kr, v,
+                                 1.0 / math.sqrt(dn + dr), c.attn_block_q, dt)
+        with jax.named_scope("mla_out"):
+            return dot(o.reshape(b, s, nh * dv), wo, dt)
+
+
+def swiglu(h, w_gate, w_up, w_down, dtype):
+    a = jax.nn.silu(dot(h, w_gate, dtype)) * dot(h, w_up, dtype)
+    return dot(a, w_down, dtype)
+
+
+class SwiGLU(nn.Module):
+    cfg: LMConfig
+    width: int
+    dtype: Any = F32
+
+    @nn.compact
+    def __call__(self, h):
+        d, init = h.shape[-1], _init(self.cfg)
+        return swiglu(h, self.param("w_gate", init, (d, self.width), F32),
+                      self.param("w_up", init, (d, self.width), F32),
+                      self.param("w_down", init, (self.width, d), F32),
+                      self.dtype)
+
+
+def route(h, router, bias, cfg: LMConfig):
+    """(chosen expert ids [t, k], their weights [t, k]) over ALL the
+    router's experts, float32. Sigmoid scores; the k largest of score +
+    bias are chosen (ties: the lower id), the bias is a buffer and the
+    gradient does not reach it; the weights are the scores without it,
+    divided by their sum over all k chosen, times the scaling factor."""
+    if (cfg.scoring_func != "sigmoid" or cfg.topk_method != "noaux_tc"
+            or cfg.n_group != 1 or cfg.topk_group != 1):
+        raise NotImplementedError(
+            "models/lm: the router written here is scoring_func=sigmoid, "
+            "topk_method=noaux_tc with n_group = topk_group = 1")
+    scores = jax.nn.sigmoid(jnp.dot(h.astype(F32), router,
+                                    precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(scores + lax.stop_gradient(bias), cfg.num_experts_per_tok)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return idx, w * cfg.routed_scaling_factor
+
+
+class MoE(nn.Module):
+    """The expert layer of ONE share of an expert-parallel deployment: it
+    holds experts `first_expert .. first_expert + n_routed_experts` of the
+    router's `n_routed_experts_published`. It scores, chooses and
+    normalises over all of them and adds only the products of the chosen
+    experts it holds, plus the whole shared expert; what absent experts
+    would add is left out (their chips add it, after the exchange that one
+    chip does not have). No capacity: the token-slots are sorted by expert
+    and each projection is one grouped product over the held experts'
+    rows (`lax.ragged_dot`), so no token is dropped or padded.
+
+    Returns (y[b, s, d] float32, counters of this layer)."""
+
+    cfg: LMConfig
+    dtype: Any = F32
+
+    @nn.compact
+    def __call__(self, h):
+        c, dt = self.cfg, self.dtype
+        b, s, d = h.shape
+        held = c.n_routed_experts
+        width = c.n_routed_experts_published or held
+        k, we = c.num_experts_per_tok, c.moe_intermediate_size
+        if not 0 <= c.first_expert <= width - held:
+            raise ValueError(f"lm: experts {c.first_expert}.."
+                             f"{c.first_expert + held} are not among {width}")
+        init = _init(c)
+        router = self.param("router", init, (d, width), F32)
+        bias = self.param("bias", nn.initializers.normal(c.bias_std), (width,), F32)
+        w_gate = self.param("experts_w_gate", init, (held, d, we), F32)
+        w_up = self.param("experts_w_up", init, (held, d, we), F32)
+        w_down = self.param("experts_w_down", init, (held, we, d), F32)
+        x = h.reshape(b * s, d)
+        t = b * s
+        with jax.named_scope("moe_route"):
+            idx, w = route(x, router, bias, c)
+            self.sow("intermediates", "chosen", idx)
+            local = idx - c.first_expert
+            mine = (local >= 0) & (local < held)
+            gid = jnp.where(mine, local, held).reshape(-1)  # absent: sorts last
+            sizes = jnp.sum(jax.nn.one_hot(gid, held + 1, dtype=jnp.int32),
+                            axis=0)[:held]
+        with jax.named_scope("moe_dispatch"):
+            order = jnp.argsort(gid, stable=True)
+            tok = order // k
+            # Rows past the held experts' are no expert's. The chip's
+            # grouped product neither reads nor WRITES them, forward or
+            # transposed: what it leaves there (in the output, and in the
+            # cotangent of its input) is whatever the memory held. So every
+            # value that enters or leaves a grouped product is selected by
+            # `held_row`, which also zeroes the cotangent on the way back.
+            held_row = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
+            xs = jnp.where(held_row, x.astype(dt)[tok], 0)
+            ws = w.reshape(-1)[order]
+        with jax.named_scope("moe_experts"):
+            grouped = lambda a, m: jnp.where(held_row, ragged_dot(  # noqa: E731
+                a, m.astype(dt), sizes, preferred_element_type=F32), 0.0)
+            a = (jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)).astype(dt)
+            o = grouped(a, w_down)
+        with jax.named_scope("moe_shared"):
+            shared = SwiGLU(c, c.n_shared_experts * we, dt, name="shared")(x)
+        with jax.named_scope("moe_combine"):
+            y = shared.at[tok].add(o * ws[:, None])
+        load = sizes.astype(F32)
+        counters = {
+            "moe_slots_held_share": jnp.sum(load) / (t * k),
+            "moe_load_max_over_mean": jnp.max(load) / jnp.maximum(
+                jnp.mean(load), 1e-9),
+            "moe_tokens_none_held_share": 1.0 - jnp.mean(
+                jnp.any(mine, axis=-1).astype(F32)),
+        }
+        return y.reshape(b, s, d), counters

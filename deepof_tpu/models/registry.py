@@ -1,5 +1,12 @@
 """Model registry — replaces the reference's string-dispatch in
 `version1/trainOF.py:76-90` and the per-dataset trainer imports.
+
+What a model IS it declares on its class, and nothing outside asks for it
+by name: `task` ("flow" where absent; "action", "classify", "lm") picks the
+objective, the example input and the evaluation; `input_frames` the frames
+it takes (absent: the dataset's `time_step`); `smooth_border_mask` and
+`vgg16_trunk_path` what the trainer does around it. `model_for` and
+`example_input` are the one way from an ExperimentConfig to a model.
 """
 
 from __future__ import annotations
@@ -13,6 +20,7 @@ from .vgg16_flow import VGG16Flow
 from .inception_v3_flow import InceptionV3Flow
 from .flownet_c import FlowNetC
 from .flownet2 import FlowNetCS
+from .lm import LatentMoELM
 from .two_stream import STBaseline, STSingle, UCF101Spatial
 
 MODELS = {
@@ -24,6 +32,7 @@ MODELS = {
     "st_single": STSingle,
     "st_baseline": STBaseline,
     "ucf101_spatial": UCF101Spatial,
+    "latent_moe_lm": LatentMoELM,
 }
 
 
@@ -61,6 +70,51 @@ def build_model(name: str, flow_channels: int = 2, dtype: Any = jnp.float32,
             raise ValueError(
                 f"model {name!r} does not support {knob} (={value}); "
                 f"models honoring it: {supported}")
-    if name == "ucf101_spatial":
-        return cls(dtype=dtype, **kw)
-    return cls(flow_channels=flow_channels, dtype=dtype, **kw)
+    if "flow_channels" in fields:
+        kw["flow_channels"] = flow_channels
+    return cls(dtype=dtype, **kw)
+
+
+def task_of(model) -> str:
+    """The task a model (class or instance) declares; "flow" where it
+    declares none."""
+    return getattr(model, "task", "flow")
+
+
+def compute_dtype(cfg):
+    return jnp.bfloat16 if cfg.train.compute_dtype == "bfloat16" else jnp.float32
+
+
+def model_for(cfg, dtype: Any = None):
+    """The model an ExperimentConfig names, built the one way the trainer,
+    the warmup and the recipe engine all build it."""
+    if cfg.model not in MODELS:
+        raise KeyError(f"unknown model {cfg.model!r}; available: {sorted(MODELS)}")
+    dtype = compute_dtype(cfg) if dtype is None else dtype
+    if task_of(MODELS[cfg.model]) == "lm":
+        return MODELS[cfg.model](cfg=cfg.lm, dtype=dtype, remat=cfg.train.remat)
+    return build_model(cfg.model, flow_channels=2 * (cfg.data.time_step - 1),
+                       dtype=dtype, width_mult=cfg.width_mult,
+                       corr_max_disp=cfg.corr_max_disp,
+                       corr_stride=cfg.corr_stride)
+
+
+def example_input(model, cfg) -> jnp.ndarray:
+    """Zeros of the shape and type of what the model takes for a train
+    batch of `cfg`: a row of ids for a language model, else frames."""
+    if task_of(model) == "lm":
+        return jnp.zeros((cfg.data.batch_size, cfg.lm.seq_len), jnp.int32)
+    h, w = cfg.data.crop_size or cfg.data.image_size
+    frames = getattr(model, "input_frames", None) or cfg.data.time_step
+    return jnp.zeros((cfg.data.batch_size, h, w, 3 * frames), jnp.float32)
+
+
+class NoServingPath(NotImplementedError):
+    """`predict` / `serve` asked for a model family that only trains."""
+
+
+def require_flow_serving(cfg) -> None:
+    if cfg.model in MODELS and task_of(MODELS[cfg.model]) == "lm":
+        raise NoServingPath(
+            f"model {cfg.model!r} is a language model: it trains "
+            "(`train --preset lm`) and has no predict/serve path yet")

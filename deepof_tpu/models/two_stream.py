@@ -81,6 +81,9 @@ class UCF101Spatial(nn.Module):
 
     classifier_only = True  # step dispatch: logits, no flow pyramid
     max_downsample = 32
+    task = "classify"  # eval: accuracy (models/registry.py)
+    input_frames = 1  # one frame in, whatever the dataset's time_step
+    vgg16_trunk_path = ("encoder",)  # where `train.vgg16_npz` lands
 
     @nn.compact
     def __call__(self, frame: jnp.ndarray, train: bool = False) -> jnp.ndarray:
@@ -98,6 +101,9 @@ class STSingle(nn.Module):
     flow_scales: tuple[float, ...] = VGG_SCALES
     max_downsample = 32
     has_action_head = True  # step dispatch: returns (flows, logits)
+    task = "action"
+    smooth_border_mask = True
+    vgg16_trunk_path = ("encoder",)
 
     @nn.compact
     def __call__(self, pair: jnp.ndarray, train: bool = False):
@@ -127,6 +133,9 @@ class STBaseline(nn.Module):
     flow_scales: tuple[float, ...] = FLOWNET_SCALES
     max_downsample = 64
     has_action_head = True  # step dispatch: returns (flows, logits)
+    task = "action"
+    smooth_border_mask = True
+    vgg16_trunk_path = ("spatial",)
 
     @nn.compact
     def __call__(self, pair: jnp.ndarray, train: bool = False):

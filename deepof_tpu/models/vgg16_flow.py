@@ -47,6 +47,7 @@ class VGG16Flow(nn.Module):
 
     flow_scales: tuple[float, ...] = FLOW_SCALES
     max_downsample = 32  # five maxpools; spatial-CP gradient-safety bound
+    vgg16_trunk_path = ("encoder",)  # where `train.vgg16_npz` lands
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> list[jnp.ndarray]:

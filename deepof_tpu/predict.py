@@ -86,15 +86,11 @@ def restore_action_params(cfg: ExperimentConfig, ckpt_dir: str | None = None):
     final stage lives under <log_dir>/ckpt-stage<i> (train/recipe.py),
     not the plain Trainer's <log_dir>/ckpt.
     """
-    from .models.registry import build_model
+    from .models.registry import model_for, require_flow_serving
 
+    require_flow_serving(cfg)
     t = cfg.data.time_step
-    dtype = (jnp.bfloat16 if cfg.train.compute_dtype == "bfloat16"
-             else jnp.float32)
-    model = build_model(cfg.model, flow_channels=2 * (t - 1), dtype=dtype,
-                        width_mult=cfg.width_mult,
-                        corr_max_disp=cfg.corr_max_disp,
-                        corr_stride=cfg.corr_stride)
+    model = model_for(cfg)
     if not (getattr(model, "has_action_head", False)
             or getattr(model, "classifier_only", False)):
         raise ValueError(

@@ -176,8 +176,9 @@ def build_serve_model(cfg: ExperimentConfig):
     """The inference model for a config — the same build the serial
     predict path and `warmup --serve` use, so executables compiled by
     either are interchangeable cache entries."""
-    from ..models.registry import build_model
+    from ..models.registry import build_model, require_flow_serving
 
+    require_flow_serving(cfg)
     t = cfg.data.time_step
     return build_model(cfg.model, flow_channels=2 * (t - 1),
                       width_mult=cfg.width_mult,

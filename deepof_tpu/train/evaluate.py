@@ -10,7 +10,11 @@ compared against GT flow with mean endpoint error:
     (`flyingChairsTrain.py:264-296`);
   - Sintel: x3, clip [-420.621, 426.311], resize to 436x1024, averaged over
     all T-1 flow pairs (`sintelTrain.py:264-328`);
-  - UCF-101: action accuracy over per-class batches (`ucf101train.py:210-287`).
+  - UCF-101: action accuracy over per-class batches (`ucf101train.py:210-287`);
+  - language models: mean next-token cross-entropy over the held-out rows.
+
+`EVALUATORS` maps the task a model declares (`models/registry.py`) to its
+protocol; the trainer asks for none by a model's name.
 
 Visual artifacts (flow color images, warped frames) mirror the reference's
 cv2.imwrite dumps (`flyingChairsTrain.py:272-291`).
@@ -177,3 +181,26 @@ def evaluate_ucf101(eval_fn, params, dataset, cfg: ExperimentConfig,
         "accuracy": correct / max(seen, 1),
         "val_loss": _wmean(totals),
     }
+
+
+def evaluate_lm(eval_fn, params, dataset, cfg: ExperimentConfig,
+                dump_dir: str | None = None) -> dict[str, float]:
+    """Mean cross-entropy per position over the held-out rows, each once."""
+    bs = cfg.train.eval_batch_size
+    totals = []
+    for bid in range(-(-max(dataset.num_val, 1) // bs)):
+        valid = min(bs, dataset.num_val - bid * bs)
+        rows = np.asarray(eval_fn(params, dataset.sample_val(bs, bid))["loss_rows"])
+        totals.append((float(rows[:valid].mean()), valid))
+    loss = _wmean(totals)
+    return {"val_loss": loss, "val_perplexity": float(np.exp(min(loss, 50.0)))}
+
+
+def _evaluate_action(eval_fn, params, dataset, cfg: ExperimentConfig,
+                     dump_dir: str | None = None) -> dict[str, float]:
+    return evaluate_ucf101(eval_fn, params, dataset, cfg)
+
+
+#: task a model declares -> (eval_fn, params, dataset, cfg, dump_dir) -> metrics
+EVALUATORS = {"flow": evaluate_aee, "action": _evaluate_action,
+              "classify": _evaluate_action, "lm": evaluate_lm}

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from ..core.config import ExperimentConfig
 from ..data import InputPipeline, Prefetcher, build_dataset, derive_batch_rng
-from ..models.registry import build_model
+from ..models.registry import example_input, model_for, task_of
 from ..obs import incident as obs_incident
 from ..obs import trace as obs_trace
 from ..obs.heartbeat import Heartbeat
@@ -40,7 +40,7 @@ from ..resilience.faults import build_injector
 from ..resilience.healing import HealingSampler
 from ..resilience.verify import config_digest
 from .checkpoint import CheckpointManager
-from .evaluate import evaluate_aee, evaluate_ucf101
+from .evaluate import EVALUATORS
 from .metrics_log import (
     AsyncFetcher,
     MetricsLogger,
@@ -51,7 +51,7 @@ from .metrics_log import (
 from .elastic import maybe_host_fault, pace_to_world
 from .schedule import step_decay_schedule
 from .state import create_train_state, make_optimizer
-from .step import make_eval_fn, make_train_step
+from .step import LAYER_METRIC_PREFIX, make_eval_fn, make_train_step
 from .warmup import cache_delta, enable_for_config, install_cache_counters
 
 
@@ -87,7 +87,6 @@ SCALE_RECORD_FIELDS: tuple[tuple[str, str], ...] = (
     ("warp_sweep_rows_by_scale", "scale_warp_sweep_rows"),
     ("warp_gather_fallback_by_scale", "scale_warp_gather_fallback"),
 )
-
 
 def per_scale_last(v) -> list[float]:
     """Last inner step's per-scale vector (finest first) as a JSON-ready
@@ -155,13 +154,6 @@ def data_stream_rng(mesh, seed: int, start_step: int) -> np.random.RandomState:
     without the batch-indexed pipeline, e.g. tools/synthetic_fit.py).
     Array seeding is exact and order-sensitive."""
     return np.random.RandomState(data_stream_seed(mesh, seed, start_step))
-
-
-def _example_input(cfg: ExperimentConfig) -> jnp.ndarray:
-    h, w = cfg.data.crop_size or cfg.data.image_size
-    t = cfg.data.time_step
-    channels = 3 if cfg.model == "ucf101_spatial" else 3 * t
-    return jnp.zeros((cfg.data.batch_size, h, w, channels), jnp.float32)
 
 
 class Trainer:
@@ -252,11 +244,7 @@ class Trainer:
             self.mesh = local_mesh(el.virtual_devices)
         else:
             self.mesh = build_mesh(cfg.mesh)
-        self.dataset = dataset if dataset is not None else build_dataset(cfg.data)
-        t = cfg.data.time_step
-        flow_channels = 2 * (t - 1)
-        dtype = (jnp.bfloat16 if cfg.train.compute_dtype == "bfloat16"
-                 else jnp.float32)
+        self.dataset = dataset if dataset is not None else build_dataset(cfg.data, lm=cfg.lm)
         self.logger = MetricsLogger(cfg.train.log_dir)
         self.profiler = ProfilerSession(cfg.train.log_dir, enabled=profile,
                                         steps=profile_steps)
@@ -269,12 +257,10 @@ class Trainer:
         self.schedule = schedule
         tx = tx if tx is not None else make_optimizer(cfg.optim, schedule)
         with obs_trace.span("model_init"):
-            self.model = build_model(cfg.model, flow_channels=flow_channels,
-                                     dtype=dtype, width_mult=cfg.width_mult,
-                                     corr_max_disp=cfg.corr_max_disp,
-                                     corr_stride=cfg.corr_stride)
+            self.model = model_for(cfg)
             self.state = create_train_state(
-                self.model, _example_input(cfg), tx, seed=cfg.train.seed,
+                self.model, example_input(self.model, cfg), tx,
+                seed=cfg.train.seed,
                 log=lambda m: self.logger.log("info", 0, message=m))
 
         # Deterministic fault injector (resilience/faults.py): None when
@@ -315,16 +301,14 @@ class Trainer:
             writer=ckpt_writer, manifest_extra=manifest_extra)
         # VGG16 pretrained conv-trunk init (`flyingChairsTrain.py:60-76`);
         # fresh starts only — a checkpoint to resume from takes precedence.
-        _vgg_trunks = {"vgg16": ("encoder",), "st_single": ("encoder",),
-                       "ucf101_spatial": ("encoder",),
-                       "st_baseline": ("spatial",)}
-        if (cfg.train.vgg16_npz and cfg.model in _vgg_trunks
+        trunk_path = getattr(self.model, "vgg16_trunk_path", None)
+        if (cfg.train.vgg16_npz and trunk_path
                 and self.ckpt.latest_step() is None):
             from ..models.common import load_vgg16_npz
 
             self.state = self.state.replace(params=load_vgg16_npz(
                 self.state.params, cfg.train.vgg16_npz,
-                trunk_path=_vgg_trunks[cfg.model]))
+                trunk_path=trunk_path))
             self.logger.log("info", 0,
                             message=f"VGG16 trunk init from {cfg.train.vgg16_npz}")
 
@@ -411,7 +395,7 @@ class Trainer:
                             "shard — parallel/spatial.py); those devices "
                             "only replicate work")
 
-        smooth_border = cfg.model in ("st_single", "st_baseline")
+        smooth_border = getattr(self.model, "smooth_border_mask", False)
         self._injected_step = train_step is not None
         with obs_trace.span("step_build"):
             self.train_step = (train_step if train_step is not None else
@@ -442,7 +426,9 @@ class Trainer:
         self._augment = None  # set by enable_augmentation()
 
     def enable_augmentation(self) -> None:
-        if self.cfg.data.augment_geo or self.cfg.data.augment_photo:
+        # geometric and photometric augmentation are stages for frames
+        if task_of(self.model) != "lm" and (
+                self.cfg.data.augment_geo or self.cfg.data.augment_photo):
             from ..data.augmentation import make_augment_fn
 
             self._augment = make_augment_fn(self.cfg.data)
@@ -469,11 +455,8 @@ class Trainer:
         # visuals are identical on every host (replicated state): one writer
         dump = dump and jax.process_index() == 0
         dump_dir = (self.cfg.train.log_dir + "/visuals") if dump else None
-        if self.cfg.model in ("st_single", "st_baseline", "ucf101_spatial"):
-            return evaluate_ucf101(self.eval_fn, self.state.params,
-                                   self.dataset, self.cfg)
-        return evaluate_aee(self.eval_fn, self.state.params, self.dataset,
-                            self.cfg, dump_dir)
+        return EVALUATORS[task_of(self.model)](
+            self.eval_fn, self.state.params, self.dataset, self.cfg, dump_dir)
 
     def fit(self, num_epochs: int | None = None,
             max_steps: int | None = None) -> dict[str, float]:
@@ -837,6 +820,9 @@ class Trainer:
                         **{field: per_scale_last(m_host[src])
                            for field, src in SCALE_RECORD_FIELDS
                            if src in m_host},
+                        **{key[len(LAYER_METRIC_PREFIX):]: per_scale_last(v)
+                           for key, v in m_host.items()
+                           if key.startswith(LAYER_METRIC_PREFIX)},
                         **timer.rates(), **timer.phases(),
                         **timer.counters(), **resilience_stats(),
                         **cache_kw, **self._telemetry(timer))

@@ -92,7 +92,7 @@ def stage_dataset(scfg: ExperimentConfig, stage: StageConfig):
         return build_mixture(scfg.data, stage)
     from ..data import build_dataset
 
-    return build_dataset(scfg.data)
+    return build_dataset(scfg.data, lm=scfg.lm)
 
 
 def plateau_reached(stage: StageConfig, evals: list[dict]) -> bool:
@@ -171,8 +171,8 @@ def precompile_stages(cfg: ExperimentConfig, mesh=None,
             eval_bs = max(scfg.train.eval_batch_size // shards, 1) * shards
             eval_fn = make_eval_fn(
                 model, scfg, dataset.mean, mesh=mesh,
-                smooth_border_mask=scfg.model in ("st_single",
-                                                  "st_baseline"))
+                smooth_border_mask=getattr(model, "smooth_border_mask",
+                                           False))
             eval_sds = _sds({k: np.asarray(v) for k, v in
                              dataset.sample_val(eval_bs, 0).items()})
             eval_compiled, erow = ledger.record_aot(

@@ -9,7 +9,10 @@ session loop per dataset, SURVEY.md §2.2):
   - two-stream action models (STsingle/STbaseline): pyramid loss + action
     cross-entropy weighted by the finest flow weight, matching
     `ucf101wrapFlow.py:186-188`;
-  - spatial-only classifier: cross-entropy.
+  - spatial-only classifier: cross-entropy;
+  - language models (`models/lm/`, task "lm"): next-token cross-entropy
+    over the vocabulary held, float32; the model takes its loss itself, in
+    blocks of positions, and hands back its expert layers' counters.
 
 Data parallelism: the step is `jax.jit`-ed with the batch sharded over the
 mesh "data" axis and the state replicated; XLA inserts the gradient
@@ -32,9 +35,15 @@ from ..losses.pyramid import (
     pyramid_loss,
     pyramid_loss_multi,
 )
+from ..models.registry import compute_dtype as compute_dtype_of, task_of
 from ..parallel.mesh import batch_sharding, replicated_sharding
 from ..parallel.spatial import constrain_batch, mesh_context
 from .state import TrainState
+
+#: Step metrics that hold one value a LAYER of the model (a model's own
+#: counters, whatever their names) carry this prefix; the loop writes each
+#: into the periodic record under its name without it.
+LAYER_METRIC_PREFIX = "layer_"
 
 Mean = tuple[float, float, float]
 
@@ -59,6 +68,14 @@ def model_losses(
     """Forward + objective. Returns (total_loss, aux dict with per-scale
     loss dicts, finest flow, reconstruction, and optional action logits)."""
     rngs = {"dropout": dropout_rng} if (train and dropout_rng is not None) else None
+    if task_of(model) == "lm":
+        # batch["tokens"][b, s + 1] int32: position t's logits against
+        # position t + 1's id. The model casts its float32 masters to its
+        # own compute dtype and recomputes per block (`model.remat`).
+        ids = batch["tokens"]
+        out = model.apply({"params": params}, ids[:, :-1], ids[:, 1:])
+        rows = out.pop("loss_rows")
+        return jnp.mean(rows), {"loss_rows": rows, "counters": out}
     # Spatial context parallelism: shard H over the "spatial" mesh axis (if
     # populated) so GSPMD partitions the convs with compiler-inserted halo
     # exchanges (SURVEY.md §5.7). Reads the mesh from the enclosing
@@ -153,7 +170,7 @@ def make_train_step(model, cfg: ExperimentConfig, mean: Mean, mesh,
     dispatch + one value fetch then serves K steps — amortizing per-step
     host/transport overhead (DESIGN.md "Benchmark honesty").
     """
-    compute_dtype = jnp.bfloat16 if cfg.train.compute_dtype == "bfloat16" else jnp.float32
+    compute_dtype = compute_dtype_of(cfg)
 
     if cfg.loss.occlusion and (
             getattr(model, "has_action_head", False)
@@ -214,9 +231,14 @@ def make_train_step(model, cfg: ExperimentConfig, mean: Mean, mesh,
                         "V_loss", "smooth", "warp_sweep_rows",
                         "warp_gather_fallback"):
                 metrics[f"scale_{key}"] = jnp.stack([d[key] for d in aux["losses"]])
-        for key in ("action_loss", "accuracy"):
+        for key in ("action_loss", "accuracy", "loss_rows"):
             if key in aux:
                 metrics[key] = aux[key]
+        # a model's own counters, one value a layer (a language model's
+        # routing): they ride the loss fetch as the warp's counters do, and
+        # the loop records every `layer_*` metric without knowing its name
+        metrics.update({LAYER_METRIC_PREFIX + key: v
+                        for key, v in aux.get("counters", {}).items()})
         return new_state, metrics
 
     repl, data = replicated_sharding(mesh), batch_sharding(mesh)
@@ -256,7 +278,7 @@ def make_eval_fn(model, cfg: ExperimentConfig, mean: Mean, mesh=None,
                 model, params, batch, mean, cfg.loss, train=False,
                 smooth_border_mask=smooth_border_mask)
         out = {"total": total}
-        for key in ("flow", "recon", "logits"):
+        for key in ("flow", "recon", "logits", "loss_rows"):
             if key in aux:
                 out[key] = aux[key]
         return out

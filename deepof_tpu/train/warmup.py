@@ -277,35 +277,25 @@ def abstract_train_step(cfg: ExperimentConfig, mesh,
     `test_warmup_then_trainer_compiles_nothing` pins it to the Trainer's
     cache key.
     """
-    import jax.numpy as jnp
-
-    from ..models.registry import build_model
+    from ..models.registry import example_input, model_for
     from ..parallel.mesh import (batch_sharding, replicated_sharding,
                                  stacked_batch_sharding)
     from .schedule import step_decay_schedule
     from .state import create_train_state, make_optimizer
     from .step import make_train_step
 
-    t = cfg.data.time_step
-    dtype = (jnp.bfloat16 if cfg.train.compute_dtype == "bfloat16"
-             else jnp.float32)
-    model = build_model(cfg.model, flow_channels=2 * (t - 1), dtype=dtype,
-                        width_mult=cfg.width_mult,
-                        corr_max_disp=cfg.corr_max_disp,
-                        corr_stride=cfg.corr_stride)
+    model = model_for(cfg)
     steps_per_epoch = max(dataset.num_train // cfg.data.batch_size, 1)
     tx = make_optimizer(cfg.optim, step_decay_schedule(cfg.optim,
                                                        steps_per_epoch))
-    h, w = cfg.data.crop_size or cfg.data.image_size
-    channels = 3 if cfg.model == "ucf101_spatial" else 3 * t
-    example = jax.ShapeDtypeStruct((cfg.data.batch_size, h, w, channels),
-                                   jnp.float32)
+    x = example_input(model, cfg)
+    example = jax.ShapeDtypeStruct(x.shape, x.dtype)
     # abstract state: eval_shape traces create_train_state without
     # allocating params or touching the backend
     state = _sds(jax.eval_shape(
         lambda x: create_train_state(model, x, tx, seed=cfg.train.seed),
         example), replicated_sharding(mesh))
-    smooth_border = cfg.model in ("st_single", "st_baseline")
+    smooth_border = getattr(model, "smooth_border_mask", False)
     step = make_train_step(model, cfg, dataset.mean, mesh, smooth_border)
     batch = _sds(example_train_batch(cfg, dataset),
                  stacked_batch_sharding(mesh)
@@ -320,7 +310,7 @@ def lower_train_step(cfg: ExperimentConfig, mesh=None):
     from ..parallel.mesh import build_mesh
 
     mesh = mesh if mesh is not None else build_mesh(cfg.mesh)
-    a = abstract_train_step(cfg, mesh, build_dataset(cfg.data))
+    a = abstract_train_step(cfg, mesh, build_dataset(cfg.data, lm=cfg.lm))
     return a.step.lower(a.state, a.batch)
 
 
@@ -341,7 +331,7 @@ def warmup_compile(cfg: ExperimentConfig, mesh=None, dataset=None,
 
     enable_for_config(cfg)
     mesh = mesh if mesh is not None else build_mesh(cfg.mesh)
-    dataset = dataset if dataset is not None else build_dataset(cfg.data)
+    dataset = dataset if dataset is not None else build_dataset(cfg.data, lm=cfg.lm)
     model, _, step, state_sds, batch_sds = abstract_train_step(cfg, mesh,
                                                                dataset)
 
@@ -372,7 +362,8 @@ def warmup_compile(cfg: ExperimentConfig, mesh=None, dataset=None,
             eval_bs = max(cfg.train.eval_batch_size // shards, 1) * shards
             eval_fn = make_eval_fn(
                 model, cfg, dataset.mean, mesh=mesh,
-                smooth_border_mask=cfg.model in ("st_single", "st_baseline"))
+                smooth_border_mask=getattr(model, "smooth_border_mask",
+                                           False))
             eval_sds = _sds({key: np.asarray(v)
                              for key, v in dataset.sample_val(eval_bs, 0).items()})
             _, row = ledger.record_aot(

@@ -270,3 +270,88 @@ def test_whole_train_step_compiles_for_v5e(topo, monkeypatch, model, n_dev):
     ma = compiled.memory_analysis()
     assert (ma.argument_size_in_bytes + ma.temp_size_in_bytes
             + ma.generated_code_size_in_bytes) < 16e9  # fits one v5e's HBM
+
+
+KANANA = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                      "benchmark", "configs", "kanana2_30b_a3b_ep8.json")
+
+
+def test_grouped_expert_product_compiles_for_v5e(one_chip):
+    """The expert layer's one grouped product a projection at the cell's
+    size (`lax.ragged_dot`: 8192 tokens x 6 slots sorted by expert, the 16
+    held experts of width 768), forward and both gradients: XLA's own
+    Mosaic fusion stands where a kernel of the repo's would."""
+    m, k, n, g = 8192 * 6, 2048, 768, 16
+    x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((g, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, sizes):
+        return jnp.sum(jax.lax.ragged_dot(
+            x, w, sizes, preferred_element_type=jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, w, sizes).compile().as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" in text
+
+
+@pytest.mark.slow
+def test_expert_block_compiles_for_v5e(one_chip):
+    """One expert block of `kanana2_30b_a3b_ep8` (latent attention blocked
+    over 512 queries, router, sort, gather, the grouped products, scatter)
+    at the cell's size, 2 rows of 4096 positions in bfloat16, forward and
+    backward with its recomputation. Four minutes under this suite's
+    `--xla_force_host_platform_device_count=8` (which slows the TPU
+    compiler four times: the whole step below takes 88 s in a plain
+    process and 6.5 min here), hence slow."""
+    from deepof_tpu.core.config import LMConfig, fill_lm_from_file
+    from deepof_tpu.models.lm.model import Block
+
+    lm = fill_lm_from_file(LMConfig(), KANANA)
+    block = Block(lm, True, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, 4096, lm.hidden_size), jnp.float32,
+                             sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), jnp.zeros(
+            (2, 4096, lm.hidden_size)))["params"]))
+
+    def loss(p, x):
+        y, counters = jax.checkpoint(
+            lambda pp, xx: block.apply({"params": pp}, xx))(p, x)
+        return jnp.sum(y * y) + counters["moe_slots_held_share"]
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "moe_dispatch" in text
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 8e9, ma
+
+
+@pytest.mark.slow
+def test_language_model_step_fits_one_v5e(topo):
+    """The whole train step of `kanana2_30b_a3b_ep8.train_4k` (the
+    Trainer's own step through `lower_train_step`: 5 layers at the published
+    widths, 2 rows of 4096 positions, bfloat16, per-layer recomputation,
+    Adam) compiles for one described v5e, and arguments + temporaries +
+    code stay under the chip's 16.9 GB (`bytes_limit` 16,909,336,064)."""
+    from deepof_tpu import cli
+    from deepof_tpu.train.warmup import lower_train_step
+
+    cfg = cli.config_for([
+        "train", "--preset", "lm", "--set", f"lm.config_file={KANANA}",
+        "--set", "lm.seq_len=4096", "--set", "data.batch_size=2",
+        "--set", "train.compute_dtype=bfloat16", "--set", "train.remat=true",
+        "--set", "lm.attn_block_q=512", "--set", "lm.loss_block=2048"])
+    compiled = lower_train_step(cfg, _mesh(topo, 1)).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+             + ma.generated_code_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes)
+    assert total < 16_909_336_064, ma
+    assert ma.argument_size_in_bytes == pytest.approx(12 * 575955968, rel=1e-3)
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    for scope in ("mla/mla_scores", "moe/moe_experts", "moe/moe_dispatch",
+                  "lm_head", "loss_ce", "optimizer"):
+        assert scope in text, scope
