@@ -942,8 +942,9 @@ class LMConfig:
     # every token then routes alike and one expert takes every slot
     embed_std: float = 1.0
     bias_std: float = 0.01  # e_score_correction_bias: drawn once, then fixed
-    attn_block_q: int = 512  # queries a block of the attention; scores never
-    # exist for more than one block, and the backward recomputes them
+    attn_block_q: int = 512  # queries a block of the attention (the fused
+    # kernels' query block on a TPU, `ops/attention.py`); scores never exist
+    # for more than one block, and the backward recomputes them
     loss_block: int = 2048  # positions a block of the head and the loss
 
 

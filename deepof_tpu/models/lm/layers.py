@@ -4,13 +4,17 @@
 Precision: norm statistics, rotary angles, router scores, softmax and loss
 in float32; every other matrix product takes operands in the compute dtype
 (float32 masters are cast on use) and accumulates in float32; the residual
-stream stays float32. No bias anywhere.
+stream stays float32. No bias anywhere. The attention's scores, mask and
+softmax are `ops/attention.py`'s, which states the same for both of its
+paths (fused kernels on a TPU, XLA blocks elsewhere).
 
 Scopes (what the per-layer readers find in a profile; flax names a
 module's scope after the module, the rest are `jax.named_scope`s):
 `layer_<i>` > `mla` > `mla_proj`, `mla_scores`, `mla_out`; `dense_ffn`;
 `moe` > `moe_route`, `moe_dispatch`, `moe_experts`, `moe_shared`,
 `moe_combine`; beside them `embed`, `lm_head`, `loss_ce`, `optimizer`.
+On the chip `mla_scores` holds the Mosaic calls `mla_attn_fwd` and, under
+`transpose(jvp(...))`, `mla_attn_bwd`, with the layout copies around them.
 """
 
 from __future__ import annotations
@@ -24,9 +28,9 @@ from flax import linen as nn
 from jax import lax
 
 from ...core.config import LMConfig
+from ...ops.attention import causal_attention
 
 F32 = jnp.float32
-_NEG = -1e30  # masked score: finite, so a fully masked row cannot give NaN
 ragged_dot = lax.ragged_dot  # a name of this module's: a test stands in for the chip's
 
 
@@ -62,39 +66,6 @@ def rope(x, theta: float):
     x1, x2 = x[..., 0::2], x[..., 1::2]
     return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                      axis=-1).reshape(x.shape)
-
-
-def _attend_block(qn, qr, kn, kr, v, q0: int, scale: float, dtype):
-    """Queries q0.. of one block against the keys 0..L that a causal mask
-    lets them see. qn[b,q,h,dn] qr[b,q,h,dr] kn[b,L,h,dn] kr[b,L,dr]
-    v[b,L,h,dv] -> [b,q,h,dv] float32. The rotary key is one head's,
-    shared by all: k = [k_nope | k_rope] is never built."""
-    s = (jnp.einsum("bqhd,bkhd->bhqk", qn, kn, preferred_element_type=F32)
-         + jnp.einsum("bqhd,bkd->bhqk", qr, kr, preferred_element_type=F32))
-    qpos = q0 + jnp.arange(qn.shape[1])[:, None]
-    s = jnp.where(qpos >= jnp.arange(kn.shape[1])[None, :], s * scale, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(dtype), v,
-                      preferred_element_type=F32)
-
-
-def causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int, dtype):
-    """Blocks of `block_q` queries, each against its own prefix of keys:
-    the scores of one block are all that exists at a time, the masked half
-    above the diagonal blocks is never computed, and the backward
-    recomputes a block's scores (`jax.checkpoint`) instead of keeping them."""
-    s = qn.shape[1]
-    bq = min(block_q, s)
-    if s % bq:
-        raise ValueError(f"lm.attn_block_q={block_q} does not divide "
-                         f"the {s} positions of a row")
-    block = jax.checkpoint(_attend_block, static_argnums=(5, 6, 7))
-    outs = []
-    for q0 in range(0, s, bq):
-        hi = q0 + bq
-        outs.append(block(qn[:, q0:hi], qr[:, q0:hi], kn[:, :hi], kr[:, :hi],
-                          v[:, :hi], q0, scale, dtype))
-    return jnp.concatenate(outs, axis=1)
 
 
 class MLA(nn.Module):

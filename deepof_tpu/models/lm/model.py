@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ...core.config import LMConfig
+from ...ops.attention import RESIDUALS, attention_route
 from .layers import F32, MLA, MoE, RMSNorm, SwiGLU, _init, dot
 
 COUNTERS = ("moe_slots_held_share", "moe_load_max_over_mean",
@@ -74,6 +75,14 @@ class LatentMoELM(nn.Module):
 
     task = "lm"  # what `models/registry.py` and the trainer dispatch on
 
+    def routes(self) -> dict:
+        """The paths this model's layers take for rows of `lm.seq_len`, by
+        the layers' own rule: what the trainer writes at step 0."""
+        c = self.cfg
+        return {"attention_route": attention_route(
+            c.seq_len, c.attn_block_q,
+            (c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim))}
+
     @nn.compact
     def __call__(self, ids, targets=None):
         """ids[b, s] int32 -> logits[b, s, v] float32, or with
@@ -89,7 +98,11 @@ class LatentMoELM(nn.Module):
                          (c.vocab_size, c.hidden_size), F32)
         with jax.named_scope("embed"):
             x = emb[ids]
-        block_cls = nn.remat(Block) if self.remat else Block
+        # a recomputed layer keeps what the fused attention names (its
+        # output and logsumexp), so its forward kernel runs once
+        block_cls = nn.remat(
+            Block, policy=jax.checkpoint_policies.save_only_these_names(
+                RESIDUALS)) if self.remat else Block
         per_layer = []
         for i in range(c.num_hidden_layers):
             expert = is_expert_layer(c, i)

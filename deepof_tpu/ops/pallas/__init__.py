@@ -14,6 +14,17 @@ Kernel inventory (and why each op is/isn't a kernel):
     gather; border clipping bounds the offsets, so the sweep is exact for
     any flow). Wider images stay an XLA gather (`ops/warp.py`).
 
+  - `attention.py` — the latent-attention layer's causal attention
+    (`models/lm`), forward and backward: queries and keys both blocked, an
+    online softmax, a tile's scores only ever in VMEM, tiles above the
+    diagonal skipped. XLA's blocked formulation (`ops/attention.py`, the
+    path off the chip) writes float32 score blocks to HBM four times a
+    step; the kernels took the language-model cell's `mla_scores` from
+    523 to 63 ms a step (PR 32).
+
+  The expert layer's grouped products are XLA's own Mosaic fusion for
+  `lax.ragged_dot`, not a kernel of this package.
+
 Under a mesh every kernel runs per batch shard through
 `parallel.spatial.shard_over_batch`.
 """

@@ -1,0 +1,288 @@
+"""The `fused` path of `ops/attention.py`: the latent-attention layer's
+causal attention as two Mosaic kernels of the repo's own.
+
+  o = softmax_k(scale * (qn . kn + qr . kr))[k <= q] . v
+
+Forward (`mla_attn_fwd`), grid (row, head, query block, key block), the
+key blocks innermost: per query row a running max and sum (online
+softmax) and a float32 accumulator of the output live in VMEM scratch
+across the key blocks; a tile's scores and probabilities exist only in
+VMEM; key tiles wholly above the diagonal are neither fetched (their
+block index is clamped to the last visible one, which Pallas does not
+fetch twice) nor computed, tiles wholly below it skip the mask. Out: o in
+the compute dtype and the row's logsumexp in float32.
+
+Backward (`mla_attn_bwd`, one kernel, the custom VJP's), grid (row, head,
+key block, query block), the query blocks innermost: each tile's
+probabilities are recomputed from q, k and the logsumexp, transposed
+(keys in sublanes, queries in lanes, so the per-query logsumexp and
+`di = sum(o * do)` broadcast as rows); dk and dv accumulate in VMEM over
+the query blocks; dq leaves as one float32 part a key block, summed
+outside the kernel. Nothing of size S x S is kept or re-materialised.
+
+The rotary key is ONE head's, shared by all: the kernels take it as its
+own [row, position, dr] operand (k = [k_nope | k_rope] is never built),
+the score is the sum of two products, and its cotangent leaves per head
+in float32 and is summed over the heads outside.
+
+Precision, as `ops/attention.py` states it: every product takes operands
+in the compute dtype and accumulates in float32; the scale multiplies
+float32 scores; mask, max, exp, sum, logsumexp in float32; the
+probabilities (and, backward, their cotangents) are cast to the compute
+dtype only as operands of the next product.
+
+Why not the kernels jax ships (`pallas.ops.tpu.splash_attention`; read on
+a v5e at the cell's layer, `tools/perf_probe.py --only attn`, PR 32: 7.2
+ms forward, 17.8 forward + backward, against the XLA blocks' 18.6 and
+56.9): their custom calls carry a `kernel_metadata` attribute that the
+compiled text prints over three lines, so the benchmark's scope join
+(`benchmark/harness/scope_share.py`, one instruction a line) does not
+find their `op_name` and `mla_device_pct.lm_train` loses them; their
+forward multiplies float32 probabilities by v cast up to float32, not
+the stated product; and they need the rotary key broadcast over the
+heads (100 MB a layer).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...parallel.spatial import current_mesh, shard_over_batch
+from ..attention import _NEG, RESIDUALS
+
+F32 = jnp.float32
+LANES = 128
+SUBLANES = 8
+#: Keys of a key block that one pair of products takes at a time: what
+#: bounds a tile's float32 scores in VMEM (512 x 512 x 4 B = 1 MB each of
+#: scores, probabilities and, backward, their two cotangents).
+COMPUTE_KV = 512
+_NT = (((1,), (1,)), ((), ()))  # a[m, k] . b[n, k] -> [m, n]
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return lax.dot_general(a, b, dims, preferred_element_type=F32)
+
+
+def _lanes(x, width: int):
+    """x[r, 128], every lane alike -> [r, width]."""
+    return jnp.tile(x, (1, width // LANES))
+
+
+def _tiles(q0, k0, bq: int, bkv: int, tile):
+    """Run `tile(keys, first_key, masked)` for every chunk of `COMPUTE_KV`
+    keys (a slice of the key block at k0, and the chunk's first position)
+    that the query block at q0 sees: not at all where the chunk lies above
+    the diagonal, without the mask where wholly below it."""
+    compute = min(bkv, COMPUTE_KV)
+    for c in range(bkv // compute):
+        c0 = k0 + c * compute
+        keys = pl.ds(c * compute, compute)
+        visible = c0 <= q0 + (bq - 1)
+        diagonal = c0 + (compute - 1) > q0
+        pl.when(visible & diagonal)(functools.partial(tile, keys, c0, True))
+        pl.when(visible & jnp.logical_not(diagonal))(
+            functools.partial(tile, keys, c0, False))
+
+
+def _fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                m_ref, l_ref, acc_ref, *, scale: float, bq: int, bkv: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(keys, first_key, masked):
+        s = (_dot(qn_ref[...], kn_ref[keys, :], _NT)
+             + _dot(qr_ref[...], kr_ref[keys, :], _NT)) * scale
+        if masked:
+            qpos = i * bq + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            kpos = first_key + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(qpos >= kpos, s, _NEG)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_next, s.shape[1]))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = (acc_ref[...] * _lanes(alpha, acc_ref.shape[1])
+                        + _dot(p.astype(v_ref.dtype), v_ref[keys, :]))
+
+    _tiles(i * bq, j * bkv, bq, bkv, tile)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] * _lanes(1.0 / l, acc_ref.shape[1])
+                      ).astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
+
+
+def _bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                di_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+                dkn_acc, dkr_acc, dv_acc, *, scale: float, bq: int, bkv: int):
+    j, i = pl.program_id(2), pl.program_id(3)
+    dt = qn_ref.dtype
+
+    @pl.when(i == 0)
+    def _():
+        dkn_acc[...] = jnp.zeros_like(dkn_acc)
+        dkr_acc[...] = jnp.zeros_like(dkr_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    dqn_ref[...] = jnp.zeros_like(dqn_ref)  # this key block's part of dq
+    dqr_ref[...] = jnp.zeros_like(dqr_ref)
+
+    def tile(keys, first_key, masked):
+        st = (_dot(kn_ref[keys, :], qn_ref[...], _NT)
+              + _dot(kr_ref[keys, :], qr_ref[...], _NT)) * scale
+        if masked:
+            kpos = first_key + lax.broadcasted_iota(jnp.int32, st.shape, 0)
+            qpos = i * bq + lax.broadcasted_iota(jnp.int32, st.shape, 1)
+            st = jnp.where(qpos >= kpos, st, _NEG)
+        pt = jnp.exp(st - lse_ref[:1, :])
+        do = do_ref[...]
+        dv_acc[keys, :] += _dot(pt.astype(dt), do)
+        dst = (_dot(v_ref[keys, :], do, _NT) - di_ref[:1, :]) * pt * scale
+        ds_k = dst.astype(dt)
+        dkn_acc[keys, :] += _dot(ds_k, qn_ref[...])
+        dkr_acc[keys, :] += _dot(ds_k, qr_ref[...])
+        ds_q = dst.T.astype(dt)
+        dqn_ref[...] += _dot(ds_q, kn_ref[keys, :])
+        dqr_ref[...] += _dot(ds_q, kr_ref[keys, :])
+
+    _tiles(i * bq, j * bkv, bq, bkv, tile)
+
+    @pl.when(i == pl.num_programs(3) - 1)
+    def _():
+        dkn_ref[...] = dkn_acc[...].astype(dkn_ref.dtype)
+        dkr_ref[...] = dkr_acc[...]
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _specs(bq: int, bkv: int, dims, q_of, k_of):
+    """BlockSpecs of (qn, qr, kn, kr, v) [row, head, position, d] (kr
+    without heads) for a grid whose ids `q_of` / `k_of` turn into the
+    query / key block to fetch."""
+    dn, dr, dv = dims
+
+    def q(d):
+        return pl.BlockSpec((None, None, bq, d),
+                            lambda b, h, x, y: (b, h, q_of(x, y), 0))
+
+    def k(d):
+        return pl.BlockSpec((None, None, bkv, d),
+                            lambda b, h, x, y: (b, h, k_of(x, y), 0))
+
+    kr = pl.BlockSpec((None, bkv, dr), lambda b, h, x, y: (b, k_of(x, y), 0))
+    return q, k, [q(dn), q(dr), k(dn), kr, k(dv)]
+
+
+def _forward(qn, qr, kn, kr, v, scale, bq, bkv, interpret):
+    b, h, s, dn = qn.shape
+    dr, dv = qr.shape[-1], v.shape[-1]
+    # the last key block a query block sees: later grid steps fetch nothing
+    q, _, ins = _specs(bq, bkv, (dn, dr, dv), lambda i, j: i,
+                       lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bkv))
+    o, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, bq=bq, bkv=bkv),
+        grid=(b, h, s // bq, s // bkv),
+        in_specs=ins,
+        out_specs=[q(dv), q(LANES)],
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, dv), qn.dtype),
+                   jax.ShapeDtypeStruct((b, h, s, LANES), F32)],
+        scratch_shapes=[pltpu.VMEM((bq, LANES), F32),
+                        pltpu.VMEM((bq, LANES), F32),
+                        pltpu.VMEM((bq, dv), F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        name="mla_attn_fwd", interpret=interpret,
+    )(qn, qr, kn, kr, v)
+    return o, lse[..., 0]
+
+
+def _backward(qn, qr, kn, kr, v, o, lse, do, scale, bq, bkv, interpret):
+    b, h, s, dn = qn.shape
+    dr, dv = qr.shape[-1], v.shape[-1]
+    nkv = s // bkv
+    do = do.astype(qn.dtype)
+    di = jnp.sum(o.astype(F32) * do.astype(F32), axis=-1)
+    # per-query rows for the transposed tiles, sublane-expanded as Mosaic
+    # wants a block's second-minor dimension
+    rows = [jnp.broadcast_to(x[:, :, None, :], (b, h, SUBLANES, s))
+            for x in (lse, di)]
+    # the first query block that sees a key block: earlier steps fetch it
+    q, k, ins = _specs(bq, bkv, (dn, dr, dv),
+                       lambda j, i: jnp.maximum(i, (j * bkv) // bq),
+                       lambda j, i: j)
+    row = pl.BlockSpec(
+        (None, None, SUBLANES, bq),
+        lambda b, h, j, i: (b, h, 0, jnp.maximum(i, (j * bkv) // bq)))
+
+    def part(d):  # dq's part of key block j, every query block written
+        return pl.BlockSpec((None, None, None, bq, d),
+                            lambda b, h, j, i: (b, h, j, i, 0))
+
+    dqn, dqr, dkn, dkr, dvv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, bq=bq, bkv=bkv),
+        grid=(b, h, nkv, s // bq),
+        in_specs=ins + [q(dv), row, row],
+        out_specs=[part(dn), part(dr), k(dn), k(dr), k(dv)],
+        out_shape=[jax.ShapeDtypeStruct((b, h, nkv, s, dn), F32),
+                   jax.ShapeDtypeStruct((b, h, nkv, s, dr), F32),
+                   jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+                   jax.ShapeDtypeStruct((b, h, s, dr), F32),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bkv, dn), F32), pltpu.VMEM((bkv, dr), F32),
+                        pltpu.VMEM((bkv, dv), F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
+        name="mla_attn_bwd", interpret=interpret,
+    )(qn, qr, kn, kr, v, do, *rows)
+    return (jnp.sum(dqn, axis=2).astype(qn.dtype),
+            jnp.sum(dqr, axis=2).astype(qr.dtype), dkn,
+            jnp.sum(dkr, axis=1).astype(kr.dtype), dvv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _attend(qn, qr, kn, kr, v, scale, bq, bkv, interpret):
+    return _forward(qn, qr, kn, kr, v, scale, bq, bkv, interpret)[0]
+
+
+def _attend_fwd(qn, qr, kn, kr, v, scale, bq, bkv, interpret):
+    o, lse = _forward(qn, qr, kn, kr, v, scale, bq, bkv, interpret)
+    o, lse = checkpoint_name(o, RESIDUALS), checkpoint_name(lse, RESIDUALS)
+    return o, (qn, qr, kn, kr, v, o, lse)
+
+
+def _attend_bwd(scale, bq, bkv, interpret, res, do):
+    return _backward(*res, do, scale, bq, bkv, interpret)
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def fused_causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int,
+                           block_kv: int, interpret: bool = False):
+    """qn[b,s,h,dn] qr[b,s,h,dr] kn[b,s,h,dn] kr[b,s,dr] v[b,s,h,dv], one
+    dtype -> [b,s,h,dv] in it. `block_q` and `block_kv` are multiples of
+    128 that divide s. Under a `mesh_context` the kernels run once per
+    batch shard."""
+    def rows(qn, qr, kn, kr, v):
+        heads_first = [jnp.swapaxes(a, 1, 2) for a in (qn, qr, kn, v)]
+        o = _attend(*heads_first[:3], kr, heads_first[3], scale, block_q,
+                    block_kv, interpret)
+        return jnp.swapaxes(o, 1, 2)
+
+    return shard_over_batch(rows, current_mesh(), qn.shape[0])(
+        qn, qr, kn, kr, v)

@@ -262,6 +262,11 @@ class Trainer:
                 self.model, example_input(self.model, cfg), tx,
                 seed=cfg.train.seed,
                 log=lambda m: self.logger.log("info", 0, message=m))
+        # static routes a model's layers decide by backend and shape (the
+        # language model's attention: fused kernels or XLA blocks)
+        routes = getattr(self.model, "routes", None)
+        if routes is not None:
+            self.logger.log("info", 0, message="routes", **routes())
 
         # Deterministic fault injector (resilience/faults.py): None when
         # disabled — every site below guards on one `is not None`, the

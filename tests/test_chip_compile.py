@@ -295,18 +295,75 @@ def test_grouped_expert_product_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text and "ragged-dot" in text
 
 
+def _attention_operands(sharding, rows=2, positions=4096, heads=32):
+    """The latent attention's five operands at the cell's layer: 2 rows of
+    4096 positions, 32 heads of 128 + 64 / 128, bfloat16."""
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+
+    return (of(rows, positions, heads, 128), of(rows, positions, heads, 64),
+            of(rows, positions, heads, 128), of(rows, positions, 64),
+            of(rows, positions, heads, 128))
+
+
+@pytest.mark.parametrize("block_kv", [512, 1024, 2048])
+@pytest.mark.parametrize("which", ["fwd", "vjp"])
+def test_attention_kernels_compile_for_v5e(one_chip, which, block_kv):
+    """The fused causal attention at the cell's layer and `lm.attn_block_q`
+    512, at each key block the probe sweeps (2048 is the route's): the
+    forward kernel alone, and the custom VJP's pair under a gradient with
+    respect to all five operands."""
+    from deepof_tpu.ops.pallas.attention import fused_causal_attention
+
+    def attend(*o):
+        with jax.named_scope("mla_scores"):  # as the layer calls it
+            return fused_causal_attention(*o, 192 ** -0.5, 512, block_kv)
+
+    if which == "fwd":
+        _compiled_text(attend, *_attention_operands(one_chip),
+                       kernels=["mla_attn_fwd"])
+    else:
+        _compiled_text(jax.grad(lambda *o: jnp.sum(attend(*o).astype(
+            jnp.float32) ** 2), argnums=range(5)),
+            *_attention_operands(one_chip),
+            kernels=["mla_attn_fwd", "mla_attn_bwd"])
+
+
+def test_attention_compiles_through_shard_map_on_four_chips(topo):
+    """Under a mesh the kernels run per batch shard: 4 rows over the four
+    described chips' "data" axis, no row gathered."""
+    from deepof_tpu.ops.pallas.attention import fused_causal_attention
+    from deepof_tpu.parallel.mesh import batch_sharding
+    from deepof_tpu.parallel.spatial import mesh_context
+
+    def attend(*o):
+        with jax.named_scope("mla_scores"):
+            return fused_causal_attention(*o, 192 ** -0.5, 512, 2048)
+
+    mesh = _mesh(topo, 4)
+    with mesh_context(mesh):
+        text = _compiled_text(
+            jax.grad(lambda *o: jnp.sum(attend(*o).astype(jnp.float32) ** 2),
+                     argnums=range(5)),
+            *_attention_operands(batch_sharding(mesh), rows=4, positions=2048,
+                                 heads=4),
+            kernels=["mla_attn_fwd", "mla_attn_bwd"])
+    assert "all-gather" not in text
+
+
 @pytest.mark.slow
-def test_expert_block_compiles_for_v5e(one_chip):
-    """One expert block of `kanana2_30b_a3b_ep8` (latent attention blocked
-    over 512 queries, router, sort, gather, the grouped products, scatter)
-    at the cell's size, 2 rows of 4096 positions in bfloat16, forward and
-    backward with its recomputation. Four minutes under this suite's
-    `--xla_force_host_platform_device_count=8` (which slows the TPU
-    compiler four times: the whole step below takes 88 s in a plain
-    process and 6.5 min here), hence slow."""
+def test_expert_block_compiles_for_v5e(one_chip, monkeypatch):
+    """One expert block of `kanana2_30b_a3b_ep8` (latent attention on the
+    chip's route: the fused kernels; router, sort, gather, the grouped
+    products, scatter) at the cell's size, 2 rows of 4096 positions in
+    bfloat16, forward and backward with its recomputation. Four minutes
+    under this suite's `--xla_force_host_platform_device_count=8` (which
+    slows the TPU compiler four times: the whole step below takes 51 s in
+    a plain process), hence slow."""
     from deepof_tpu.core.config import LMConfig, fill_lm_from_file
     from deepof_tpu.models.lm.model import Block
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     lm = fill_lm_from_file(LMConfig(), KANANA)
     block = Block(lm, True, jnp.bfloat16)
     x = jax.ShapeDtypeStruct((2, 4096, lm.hidden_size), jnp.float32,
@@ -324,20 +381,27 @@ def test_expert_block_compiles_for_v5e(one_chip):
     compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
     text = compiled.as_text()
     assert "ragged-dot" in text and "moe_dispatch" in text
+    assert "%mla_attn_fwd." in text and "%mla_attn_bwd." in text
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < 8e9, ma
 
 
 @pytest.mark.slow
-def test_language_model_step_fits_one_v5e(topo):
+def test_language_model_step_fits_one_v5e(topo, monkeypatch):
     """The whole train step of `kanana2_30b_a3b_ep8.train_4k` (the
     Trainer's own step through `lower_train_step`: 5 layers at the published
     widths, 2 rows of 4096 positions, bfloat16, per-layer recomputation,
     Adam) compiles for one described v5e, and arguments + temporaries +
-    code stay under the chip's 16.9 GB (`bytes_limit` 16,909,336,064)."""
+    code stay under the chip's 16.9 GB (`bytes_limit` 16,909,336,064). The
+    attention's route asks `jax.default_backend()` and is steered to the
+    chip's here, never through an option: ten Mosaic calls under
+    `mla/mla_scores` (a forward and a backward kernel a layer: the
+    recomputed layer keeps the forward's output and logsumexp) and no
+    block of float32 scores anywhere."""
     from deepof_tpu import cli
     from deepof_tpu.train.warmup import lower_train_step
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = cli.config_for([
         "train", "--preset", "lm", "--set", f"lm.config_file={KANANA}",
         "--set", "lm.seq_len=4096", "--set", "data.batch_size=2",
@@ -355,3 +419,11 @@ def test_language_model_step_fits_one_v5e(topo):
     for scope in ("mla/mla_scores", "moe/moe_experts", "moe/moe_dispatch",
                   "lm_head", "loss_ce", "optimizer"):
         assert scope in text, scope
+    kernels = [ln for ln in text.splitlines()
+               if "tpu_custom_call" in ln and "mla/mla_scores/mla_attn" in ln]
+    assert sum("%mla_attn_fwd." in ln and "jvp(LatentMoELM)/layer_" in ln
+               and "transpose(" not in ln for ln in kernels) == 5, kernels
+    assert sum("%mla_attn_bwd." in ln and "transpose(jvp(" in ln
+               for ln in kernels) == 5, kernels
+    assert len(kernels) == 10  # the layer's recomputation reran no forward
+    assert "f32[2,32,512," not in text  # a block of scores in HBM

@@ -25,6 +25,9 @@ Sections:
   batch    batch-size throughput curve (16/96)
   multiframe Sintel-shaped T=10 volume train step
   warp     per-call XLA vs Pallas warp table (includes dispatch)
+  attn     the latent attention's causal scores at the language-model
+           cell's layer shape: XLA blocks vs the fused kernels by key
+           block, forward and forward + backward, and their difference
 """
 
 from __future__ import annotations
@@ -207,6 +210,57 @@ def sec_warp_sweep(cases=(((160, 224), (0.4, 5.0, 39.0, 79.0, 160.0)),
                       flush=True)
 
 
+def sec_attn(block_kvs=(512, 1024, 2048), s=4096, h=32, bq=512,
+             interpret=False) -> None:
+    """The latent attention's causal scores at one layer of
+    `kanana2_30b_a3b_ep8.train_4k` (2 rows x 4096 positions, 32 heads,
+    128+64 / 128, bfloat16, queries in blocks of 512): the XLA blocks
+    against the fused kernels at each key block, forward and forward +
+    backward with respect to all five operands, and the largest
+    difference between the two paths' outputs and gradients. The keyword
+    sizes are for a rehearsal off the chip (`interpret=True`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepof_tpu.ops.attention import xla_blocks_attention
+    from deepof_tpu.ops.pallas.attention import fused_causal_attention
+
+    b, dn, dr, dv, dt = 2, 128, 64, 128, jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    shapes = ((b, s, h, dn), (b, s, h, dr), (b, s, h, dn), (b, s, dr),
+              (b, s, h, dv))
+    ops = tuple(jax.random.normal(k, sh).astype(dt) for k, sh in zip(ks, shapes))
+    scale = (dn + dr) ** -0.5
+
+    def both(attend):
+        fwd = jax.jit(lambda o: attend(*o))
+        # the loss's weight is an operand too: a closed-over array would
+        # be a 134 MB constant of the executable (and of its cache entry)
+        grad = jax.jit(jax.grad(lambda o: jnp.sum(
+            attend(*o).astype(jnp.float32) * o[4].astype(jnp.float32))))
+        # timed: a scalar that every gradient feeds (cheap beside them)
+        timed = jax.jit(lambda o: sum(jnp.sum(g.astype(jnp.float32))
+                                      for g in grad(o)))
+        return fwd, grad, timed
+
+    paths = {"xla_blocks": both(lambda *o: xla_blocks_attention(
+        *o, scale, bq, dt))}
+    for bkv in block_kvs:
+        paths[f"fused kv{bkv}"] = both(lambda *o, bkv=bkv: (
+            fused_causal_attention(*o, scale, bq, bkv, interpret=interpret)))
+    ref_out, ref_grad = (f(ops) for f in paths["xla_blocks"][:2])
+    for name, (fwd, grad, timed) in paths.items():
+        t_f = timeit(f"attn {name} fwd", fwd, ops, steps=5)
+        t_g = timeit(f"attn {name} fwd+bwd", timed, ops, steps=5)
+        err = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                     - r.astype(jnp.float32))))
+               for a, r in zip((fwd(ops), *grad(ops)), (ref_out, *ref_grad))]
+        print(f"attn {name}: step share 5 x (2 fwd + bwd) = "
+              f"{5e3 * (t_f + t_g):.1f} ms; max |diff| to xla_blocks: out "
+              f"{err[0]:.2e}, d(qn, qr, kn, kr, v) "
+              + " ".join(f"{e:.2e}" for e in err[1:]), flush=True)
+
+
 def sec_decomp() -> None:
     import jax
     import jax.numpy as jnp
@@ -345,6 +399,7 @@ SECTIONS = {
     "batch": sec_batch,
     "multiframe": sec_multiframe,
     "warp": sec_warp,
+    "attn": sec_attn,
 }
 
 
