@@ -82,16 +82,14 @@ def calibrate(n: int = 4096, reps: int = 10) -> dict:
 
 
 def headline_setup(model_name: str = "inception_v3", batch: int = 16,
-                   image_size=(320, 448), steps_per_call: int = 1,
-                   warp_impl: str | None = None, time_step: int = 2,
+                   image_size=(320, 448), warp_impl: str | None = None,
+                   time_step: int = 2,
                    weights: tuple = (16, 8, 4, 2, 1, 1)):
     """The headline workload, shared with tools/perf_probe.py so the
     decomposition there always measures the same config as the headline.
 
-    With steps_per_call = K > 1 the returned step takes K stacked batches
-    ([K, B, ...]) and the returned sharded batch is stacked accordingly
-    (the perf_probe dispatch-amortization sweep). warp_impl overrides
-    `LossConfig.warp_impl` (None = the config default). time_step > 2
+    warp_impl overrides `LossConfig.warp_impl` (None = the config
+    default). time_step > 2
     builds the multi-frame T-volume variant (2(T-1) flow channels, 3T
     input channels — the probe's Sintel-shaped section) on the same
     pipeline, so multiframe timings share every other headline setting.
@@ -103,8 +101,7 @@ def headline_setup(model_name: str = "inception_v3", batch: int = 16,
     from deepof_tpu.data.datasets import SyntheticData
     from deepof_tpu.models.registry import build_model
     from deepof_tpu.parallel.mesh import (
-        batch_sharding, build_mesh, replicated_sharding,
-        stacked_batch_sharding)
+        batch_sharding, build_mesh, replicated_sharding)
     from deepof_tpu.train.state import create_train_state, make_optimizer
     from deepof_tpu.train.step import make_train_step
 
@@ -117,8 +114,7 @@ def headline_setup(model_name: str = "inception_v3", batch: int = 16,
         optim=OptimConfig(learning_rate=1.6e-5),
         data=DataConfig(dataset="synthetic", image_size=(h, w), gt_size=(h, w),
                         batch_size=batch, time_step=time_step),
-        train=TrainConfig(seed=0, compute_dtype="bfloat16",
-                          steps_per_call=steps_per_call),
+        train=TrainConfig(seed=0, compute_dtype="bfloat16"),
     )
     mesh = build_mesh(cfg.mesh)
     model = build_model(cfg.model, flow_channels=2 * (time_step - 1),
@@ -134,13 +130,8 @@ def headline_setup(model_name: str = "inception_v3", batch: int = 16,
         replicated_sharding(mesh))
     ds = SyntheticData(cfg.data)
     step = make_train_step(model, cfg, ds.mean, mesh)
-    one = ds.sample_train(batch, iteration=0)
-    if steps_per_call > 1:
-        b = jax.device_put({k: np.stack([v] * steps_per_call)
-                            for k, v in one.items()},
-                           stacked_batch_sharding(mesh))
-    else:
-        b = jax.device_put(one, batch_sharding(mesh))
+    b = jax.device_put(ds.sample_train(batch, iteration=0),
+                       batch_sharding(mesh))
     return cfg, mesh, ds, model, state, step, b
 
 
@@ -183,38 +174,28 @@ def step_flops(step, state, b) -> float | None:
 
 def bench(model_name: str = "inception_v3", batch: int = 16,
           image_size=(320, 448), steps: int = 20, warmup: int = 3,
-          windows: int = 4, steps_per_call: int = 4,
-          warp_impl: str | None = None) -> dict:
+          windows: int = 4, warp_impl: str | None = None) -> dict:
     """Time the headline train step on the attached TPU (SystemExit on
-    any other backend). steps_per_call = K optimizer steps per dispatch
-    (the Trainer's own `lax.scan` path); throughput stays
-    per-optimizer-step either way. warp_impl None = the config default."""
+    any other backend). warp_impl None = the config default."""
     devs = _require_tpu()
     n_chips = len(devs)
-    spc = max(int(steps_per_call), 1)
     # cache accounting around everything that can compile (setup + the
     # timed fn's first call): a warmed run shows misses == 0
     from deepof_tpu.train.warmup import cache_delta
 
     cache_watch = cache_delta()
     cfg, mesh, ds, model, state, step, b = headline_setup(
-        model_name, batch, image_size, steps_per_call=spc,
-        warp_impl=warp_impl)
+        model_name, batch, image_size, warp_impl=warp_impl)
 
-    # keep the optimizer-step work roughly constant across spc values
-    # (each timed CALL runs K steps)
-    calls = max(steps // spc, 5)
-    per_call, state, total = time_train_step(
-        step, state, b, steps=calls, windows=windows, warmup=warmup)
+    per_step, state, total = time_train_step(
+        step, state, b, steps=steps, windows=windows, warmup=warmup)
     cache_d = cache_watch.stats()
-    per_step = per_call / spc
     pairs_per_sec = batch / per_step
     per_chip = pairs_per_sec / n_chips
     assert np.isfinite(total).all(), total
     res = {"pairs_per_sec_per_chip": per_chip, "pairs_per_sec": pairs_per_sec,
            "platform": devs[0].platform, "device_kind": devs[0].device_kind,
            "n_chips": n_chips, "batch": batch, "steps_per_sec": 1.0 / per_step,
-           "steps_per_call": spc,
            "warp_impl": cfg.loss.warp_impl, **calibrate(),
            # requests disambiguates: misses == 0 with requests == 0 means
            # the counters never saw a compile, NOT that the run was warm
@@ -246,10 +227,7 @@ def bench(model_name: str = "inception_v3", batch: int = 16,
         # verified: an 8-way-sharded einsum reports the full count from
         # .lower().cost_analysis() and 1/8 of it from
         # .compile().cost_analysis(). Per-chip rate therefore divides by
-        # n_chips. No spc normalization: XLA counts a lax.scan body ONCE
-        # (verified on this jax: K=4 scan reports 528386 flops vs 528384
-        # for the single step), so the K-step program already reports
-        # per-step flops.
+        # n_chips.
         model_tflops = flops * res["steps_per_sec"] / n_chips / 1e12
         res.update(
             flops_per_step=flops,
@@ -429,7 +407,7 @@ def data_main(argv: list[str]) -> int:
 
 
 _EXTRA_KEYS = ("platform", "device_kind", "n_chips", "matmul_tflops",
-               "batch", "warp_impl", "steps_per_call", "model_tflops",
+               "batch", "warp_impl", "model_tflops",
                "mfu_nominal", "mfu_vs_matmul", "compile_cache_requests",
                "compile_cache_hits", "compile_cache_misses",
                "decode_cache_hits", "decode_cache_misses",

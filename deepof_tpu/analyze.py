@@ -69,9 +69,7 @@ def _phase_breakdown(rec: dict) -> dict | None:
 
 def _counter_summary(rec: dict) -> dict | None:
     """Starvation + input-pipeline counters from one (cumulative) train
-    record. `starvation_rate` approximates starved dispatches per
-    trained step (with steps_per_call=K one dispatch serves K steps, so
-    the per-dispatch rate is at most 1/K of the per-step figure)."""
+    record. `starvation_rate` is starved dispatches per trained step."""
     out: dict = {}
     step = rec.get("step", 0)
     starved = rec.get("starved")

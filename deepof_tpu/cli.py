@@ -7,8 +7,8 @@ Usage:
     python -m deepof_tpu eval  --preset sintel --data-path /data/sintel \
         --log-dir /tmp/run1          # restores latest checkpoint
     python -m deepof_tpu bench --model inception_v3
-    python -m deepof_tpu warmup --preset flyingchairs --synthetic \
-        --set train.steps_per_call=4   # AOT-compile into the on-disk cache
+    python -m deepof_tpu warmup --preset flyingchairs --synthetic
+        # AOT-compile into the on-disk cache
 
 `warmup` populates the persistent compilation cache (artifacts/xla_cache)
 for a config ahead of time — lower + compile only, no data movement, no

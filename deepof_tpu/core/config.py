@@ -138,7 +138,7 @@ class DataConfig:
     crop_size: tuple[int, int] | None = None
     prefetch: int = 2
     # Host input-pipeline worker threads (data/pipeline.py): N workers
-    # decode/resize/augment/stack (super-)batches out-of-order and
+    # decode/resize/augment batches out-of-order and
     # deliver them in order through a bounded reorder buffer, with
     # deterministic per-batch seeding — the delivered stream is
     # bit-identical for any worker count. 0 = assemble inline on the
@@ -155,7 +155,7 @@ class DataConfig:
     # back the cursor). 0 = auto (2 x num_workers). NOTE: with
     # on-device augmentation (augment_geo/augment_photo) the buffered
     # batches are DEVICE arrays, so this bound spends HBM, not host
-    # RAM — at large batch x steps_per_call, size it (and num_workers)
+    # RAM — at large batch, size it (and num_workers)
     # against the chip's memory headroom.
     reorder_depth: int = 0
     cache_decoded: bool = True
@@ -214,11 +214,6 @@ class TrainConfig:
     # instead of storing them — trades FLOPs for HBM (for high-res /
     # long-T configs that would not otherwise fit).
     remat: bool = False
-    # Optimizer steps per jit call (lax.scan over stacked batches). >1
-    # amortizes per-dispatch host overhead (DESIGN.md "Benchmark
-    # honesty") at the cost of log/eval granularity rounding up to a
-    # multiple of K.
-    steps_per_call: int = 1
     # --- Latency-hiding execution layer (DESIGN.md "Execution layer") ---
     # Persistent on-disk XLA compilation cache: a process whose graphs
     # were compiled before (same config, jax/XLA version, backend) loads
@@ -233,14 +228,6 @@ class TrainConfig:
     # variable always wins); "" = <repo>/artifacts/xla_cache
     # (hostmesh.compile_cache_dir).
     compile_cache_dir: str = ""
-    # Max in-flight async metric fetches: the loop dispatches the next
-    # step(s) while previous calls' metric values are still in transit,
-    # draining them on a background consumer. 0 = fetch synchronously
-    # (the pre-r06 serial dispatch->fetch->dispatch loop). Bounded depth
-    # keeps the dispatch clock honest: a full queue blocks dispatch, so
-    # host-side progress can never run more than `pipeline_depth` calls
-    # ahead of device completion (DESIGN.md "Benchmark honesty").
-    pipeline_depth: int = 2
 
 
 @dataclass(frozen=True)

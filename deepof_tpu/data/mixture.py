@@ -9,7 +9,7 @@ per-batch rng the caller passes in (`derive_batch_rng(seed, batch_index)`
 — pipeline.py), then delegates the draw to the chosen member with the
 SAME rng. The whole mixed batch is therefore a pure function of the
 batch index, which is what makes the mixed stream bit-identical for any
-`data.num_workers`, any `steps_per_call` regrouping, and across elastic
+`data.num_workers` and across elastic
 generation bumps — exactly the contract the single-dataset stream
 already pins (tests/test_recipe.py pins the mixed one).
 

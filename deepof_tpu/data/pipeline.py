@@ -2,10 +2,8 @@
 
 PR 1's execution layer hid device-side latency (compile cache, AOT
 warmup, pipelined dispatch/fetch), which moves the wall-clock ceiling to
-the host: a single thread decoding/resizing/augmenting/stacking every
-(super-)batch is exactly the per-step host-decode starvation SURVEY.md
-§7.3.4 flags — and with `steps_per_call` scans it must assemble
-`steps_per_call x batch` images per dispatch.
+the host: a single thread decoding/resizing/augmenting every batch is
+exactly the per-step host-decode starvation SURVEY.md §7.3.4 flags.
 
 `InputPipeline` is the host-side fan-out: a pool of N worker threads
 (cv2 imdecode/resize and the native C++ batch IO both release the GIL,

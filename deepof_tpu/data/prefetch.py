@@ -7,8 +7,8 @@ and batches are placed on device (optionally with a NamedSharding) ahead of
 use so the train step never waits on host IO.
 
 With `stage=True` the producer thread additionally *blocks on transfer
-completion* (`jax.block_until_ready`): the next super-batch is fully
-resident in device memory while the current scan executes, so dispatching
+completion* (`jax.block_until_ready`): the next batch is fully
+resident in device memory while the current step executes, so dispatching
 the next call never overlaps its own input transfer with its compute
 warm-up. The wait happens off the critical path (background thread), and
 its wall time is reported to the StepTimer as the `put` phase — one of

@@ -379,7 +379,7 @@ def _dotted(node: ast.AST) -> str | None:
       "losses/ops/train-step modules")
 def determinism(ctx: FileContext) -> Iterator[Finding]:
     """The pinned contract: the sample/augment stream is bit-identical
-    for any worker count, any steps_per_call regrouping, any elastic
+    for any worker count and any elastic
     re-shard (derive_batch_rng). One module-level `np.random.shuffle`
     or `time.time()`-derived seed silently voids all of it. Only the
     contract-bearing module subtrees are in scope; obs/timing helpers

@@ -55,9 +55,8 @@ def step_flops(step, *example_args) -> float | None:
     """XLA's FLOPs estimate for one call of a jitted `step`, from the
     LOWERED module (`jit(...).lower(...).cost_analysis()`) — traces but
     never compiles on the backend. Lowered cost analysis reports GLOBAL
-    (pre-partition) FLOPs, and a lax.scan body is counted ONCE, so the
-    value is per-optimizer-step for any steps_per_call (bench.py has the
-    verification notes). None when the backend does not report it."""
+    (pre-partition) FLOPs (bench.py has the verification notes). None
+    when the backend does not report it."""
     try:
         return lowered_flops(step.lower(*example_args))
     except Exception:  # noqa: BLE001 - cost model is best-effort

@@ -54,12 +54,6 @@ def batch_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P("data"))
 
 
-def stacked_batch_sharding(mesh: Mesh) -> NamedSharding:
-    """Sharding for K stacked batches [K, B, ...] (steps_per_call > 1):
-    the scan axis is replicated, the batch axis sharded over "data"."""
-    return NamedSharding(mesh, P(None, "data"))
-
-
 def replicated_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 

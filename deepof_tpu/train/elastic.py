@@ -576,7 +576,6 @@ class ElasticCoordinator:
              "survivors": len(survivors)})
         self._barrier(survivors)
         self.resumed_step = self._newest_ckpt_step()
-        stride = max(int(self.cfg.train.steps_per_call), 1)
         lost_now = max(0, self.max_step_seen - self.resumed_step)
         self._counters["steps_lost"] += lost_now
         # the world genuinely rewound to the resume point: max_step_seen
@@ -598,7 +597,7 @@ class ElasticCoordinator:
                   f"{len(survivors)} survivor(s) "
                   f"{sorted(h.idx for h in survivors)} from checkpoint "
                   f"step {self.resumed_step} ({lost_now} step(s) of the "
-                  f"furthest host discarded; dispatch stride {stride})")
+                  f"furthest host discarded)")
         for h in survivors:
             h.state = "spawning"
             h.last_step = self.resumed_step  # where the respawn resumes

@@ -42,11 +42,11 @@ from ..resilience.verify import config_digest
 from .checkpoint import CheckpointManager
 from .evaluate import EVALUATORS
 from .metrics_log import (
+    FETCH_DEPTH,
     AsyncFetcher,
     MetricsLogger,
     ProfilerSession,
     StepTimer,
-    SyncFetcher,
 )
 from .elastic import maybe_host_fault, pace_to_world
 from .schedule import step_decay_schedule
@@ -89,14 +89,10 @@ SCALE_RECORD_FIELDS: tuple[tuple[str, str], ...] = (
 )
 
 def per_scale_last(v) -> list[float]:
-    """Last inner step's per-scale vector (finest first) as a JSON-ready
-    list — the loss_*_by_scale record fields. Arrays carry a leading K
-    axis when steps_per_call > 1; 6 significant figures keep the record
-    compact without rounding a 1e-5-scale term to zero."""
-    a = np.asarray(v)
-    if a.ndim == 2:  # [K, S] under steps_per_call stacking
-        a = a[-1]
-    return [float(f"{float(x):.6g}") for x in np.atleast_1d(a)]
+    """The step's per-scale vector (finest first) as a JSON-ready list —
+    the loss_*_by_scale record fields. 6 significant figures keep the
+    record compact without rounding a 1e-5-scale term to zero."""
+    return [float(f"{float(x):.6g}") for x in np.atleast_1d(v)]
 
 
 def _poison_batch(batch: dict) -> dict:
@@ -485,7 +481,7 @@ class Trainer:
             seed_arr = data_stream_seed(self.mesh, cfg.train.seed,
                                         start_step)
         inj = self._inj
-        # Self-healing data path (resilience/healing.py): per micro-batch
+        # Self-healing data path (resilience/healing.py): per batch
         # index, bounded retries with backoff — the rng is RE-DERIVED per
         # attempt, so a recovered transient fault yields the bit-identical
         # batch — then quarantine + a deterministic substitute drawn from
@@ -504,32 +500,15 @@ class Trainer:
             substitutes=cfg.resilience.data_substitutes,
             injector=inj,
             log=lambda m: self.logger.log("warn", cur_step["s"], message=m))
-        k = max(cfg.train.steps_per_call, 1)
-        if k == 1:
-            sharding = batch_sharding(self.mesh)
-        else:
-            from ..parallel.mesh import stacked_batch_sharding
-
-            sharding = stacked_batch_sharding(self.mesh)
-
-        def _stack(xs):
-            # On-device augmentation output stays on device (D2D stack);
-            # np.stack would silently read full image batches back to host.
-            # Multi-process must take the host path: put_global's
-            # device-array assembly treats axis 0 as the data-sharded batch
-            # axis, which the stacked [K, B, ...] layout violates.
-            if isinstance(xs[0], jax.Array) and jax.process_count() == 1:
-                return jnp.stack(xs)
-            return np.stack([np.asarray(x) for x in xs])
+        sharding = batch_sharding(self.mesh)
 
         def assemble(call_idx: int) -> dict:
-            """One dispatch's input, a pure function of its index: each
-            micro-batch i draws from derive_batch_rng(seed_arr, i), so
-            the stream is identical for any num_workers AND any
-            steps_per_call regrouping. Runs on pipeline workers (or
-            inline on the prefetch thread at num_workers=0) — decode,
-            augmentation, and the K-stack all happen off the main
-            thread. A NaN rollback resumes dispatching from the next
+            """One dispatch's input, a pure function of its index:
+            batch i draws from derive_batch_rng(seed_arr, i), so the
+            stream is identical for any num_workers. Runs on pipeline
+            workers (or inline on the prefetch thread at num_workers=0)
+            — decode and augmentation happen off the main thread. A NaN
+            rollback resumes dispatching from the next
             unconsumed index (the stream continues forward, exactly like
             the pre-pipeline sequential rng did). Sample draws go
             through the HealingSampler (retry/quarantine/substitute);
@@ -537,11 +516,7 @@ class Trainer:
             assembly fault exercises the pipeline-worker retry path."""
             if inj is not None:
                 inj.check("assemble", call_idx)
-            if k == 1:
-                return healer(call_idx)
-            # steps_per_call: K batches stacked on a leading scan axis
-            bs = [healer(i) for i in range(call_idx * k, call_idx * k + k)]
-            return {key: _stack([b[key] for b in bs]) for key in bs[0]}
+            return healer(call_idx)
 
         # --- Observability (DESIGN.md "Observability") ---
         # The span tracer `__init__` installed (a later fit of the same
@@ -562,14 +537,14 @@ class Trainer:
             self._stop_tracer(tracer)
         timer = StepTimer(cfg.data.batch_size, len(self.mesh.devices.flat))
         # Multi-worker host assembly (data/pipeline.py): N threads
-        # decode/augment/stack out-of-order, delivery stays in index
+        # decode/augment out-of-order, delivery stays in index
         # order through the bounded reorder buffer.
         pipeline = InputPipeline(assemble, num_workers=cfg.data.num_workers,
                                  reorder_depth=cfg.data.reorder_depth,
                                  retries=cfg.resilience.pipeline_retries,
                                  backoff_s=cfg.resilience.data_backoff_s)
-        # stage=True: the next (super-)batch is transferred AND resident
-        # on device while the current call's scan executes, its wait spent
+        # stage=True: the next batch is transferred AND resident on
+        # device while the current step executes, its wait spent
         # on the prefetch thread and accounted as the `put` phase. The
         # pipeline's workers start assembling eagerly at construction, so
         # a failure before the main try/finally takes ownership must not
@@ -583,16 +558,14 @@ class Trainer:
             _obs_teardown()
             raise
         # In-flight metrics pipelining (DESIGN.md "Execution layer"):
-        # depth > 0 drains value fetches on a background consumer so the
-        # next dispatch never waits on the previous fetch's RTT; the
-        # bounded queue blocks dispatch at `depth` in-flight calls,
-        # keeping host progress honest. depth 0 = serial fetch inline.
-        depth = max(cfg.train.pipeline_depth, 0)
-        fetch_kw = dict(timer=timer, retries=cfg.resilience.fetch_retries,
-                        backoff_s=cfg.resilience.data_backoff_s, injector=inj)
+        # value fetches drain on a background consumer so the next
+        # dispatch never waits on the previous fetch's round trip; the
+        # bounded queue blocks dispatch at FETCH_DEPTH in-flight calls.
         try:
-            fetcher = (AsyncFetcher(depth=depth, **fetch_kw) if depth > 0
-                       else SyncFetcher(**fetch_kw))
+            fetcher = AsyncFetcher(
+                depth=FETCH_DEPTH, timer=timer,
+                retries=cfg.resilience.fetch_retries,
+                backoff_s=cfg.resilience.data_backoff_s, injector=inj)
         except BaseException:  # same leak guard as the Prefetcher above
             pipeline.close()
             prefetch.close()
@@ -749,16 +722,10 @@ class Trainer:
             def _crossed(prev: int, new: int, every: int) -> bool:
                 return every > 0 and prev // every != new // every
 
-            def _scalar_last(v) -> float:
-                """Last inner step's value (arrays carry a leading K axis
-                when steps_per_call > 1); v is already host-side."""
-                a = np.asarray(v)
-                return float(a) if a.ndim == 0 else float(a[-1])
-
             def _on_metrics(tag, m_host):
                 """Fetch-completion consumer: divergence triage + the
-                train log record. Runs on the fetcher thread (or inline
-                at depth 0) once the device values for `tag`'s step have
+                train log record. Runs on the fetcher thread once the
+                device values for `tag`'s step have
                 ARRIVED — the honest value-fetch clock (DESIGN.md).
 
                 The graduated ladder: updates the step fn already
@@ -771,8 +738,7 @@ class Trainer:
                 gs, ep, log_due_ = tag
                 skipped = 0
                 if "update_skipped" in m_host:
-                    skipped = int(round(float(
-                        np.asarray(m_host["update_skipped"]).sum())))
+                    skipped = int(round(float(m_host["update_skipped"])))
                 if skipped:
                     timer.count("skipped_updates", skipped)
                     skip_state["streak"] += skipped
@@ -784,7 +750,7 @@ class Trainer:
                                 f"{skip_state['streak']}/"
                                 f"{cfg.resilience.max_consecutive_skips})")
                 nonfinite = cfg.train.nan_guard and not np.isfinite(
-                    np.asarray(m_host["total"])).all()
+                    m_host["total"])
                 if nonfinite and not skipped:
                     nan_event["m"] = (gs, m_host)
                     return  # never log a diverged record
@@ -814,10 +780,10 @@ class Trainer:
                                 if cache_s is not None else {})
                     self.logger.log(
                         "train", gs, epoch=ep,
-                        loss=_scalar_last(m_host["total"]),
+                        loss=float(m_host["total"]),
                         lr=float(self.schedule(gs - 1)),
-                        grad_norm=_scalar_last(m_host["grad_norm"]),
-                        **{key: _scalar_last(v) for key, v in m_host.items()
+                        grad_norm=float(m_host["grad_norm"]),
+                        **{key: float(v) for key, v in m_host.items()
                            if key in ("action_loss", "accuracy")},
                         # per-pyramid-scale loss decomposition (finest
                         # first): photometric vs smoothness trajectories
@@ -866,7 +832,7 @@ class Trainer:
                         break
                 if first_step:
                     first_span.enter_context(obs_trace.span("first_step"))
-                self.profiler.observe(gstep, k)  # --profile-steps window
+                self.profiler.observe(gstep)  # --profile-steps window
                 t0 = time.perf_counter()
                 with obs_trace.span("input_wait"):
                     batch = prefetch.get()
@@ -881,23 +847,17 @@ class Trainer:
                     # dispatch-site fault: poison the staged batch with
                     # one NaN — the deterministic stand-in for "the
                     # device produced non-finite grads at this step",
-                    # exercising the skip-in-place rung end to end. The
-                    # whole dispatched window [gstep, gstep+k) is checked
-                    # so a scheduled step inside a steps_per_call stride
-                    # still fires (the poison lands in the first
-                    # micro-batch — the skip ladder doesn't care which).
-                    hits = [s for s in range(gstep, gstep + k)
-                            if inj.hit("dispatch", s)]
-                    if hits:
+                    # exercising the skip-in-place rung end to end.
+                    if inj.hit("dispatch", gstep):
                         batch = _poison_batch(batch)
                         self.logger.log(
                             "warn", gstep,
                             message=f"fault injection: dispatch batch at "
-                                    f"step(s) {hits} poisoned with NaN")
+                                    f"step {gstep} poisoned with NaN")
                 t0 = time.perf_counter()
                 if first_step:  # XLA compile-time report (SURVEY.md §5.1)
                     cache_watch = cache_delta()
-                    with obs_trace.span("dispatch", step=gstep + k,
+                    with obs_trace.span("dispatch", step=gstep + 1,
                                         compile=True,
                                         step_trace=("train", gstep)):
                         self.state, metrics = self.train_step(self.state,
@@ -909,7 +869,7 @@ class Trainer:
                     # hit/miss counters surfaced in metrics: a warmed
                     # process shows compile_cache_misses == 0 here
                     self.logger.log(
-                        "info", gstep + k,
+                        "info", gstep + 1,
                         message=f"first step (compile + run): "
                                 f"{time.perf_counter() - t0:.1f}s",
                         compile_cache_requests=dc["requests"],
@@ -919,13 +879,13 @@ class Trainer:
                     first_step = False
                     first_span.close()
                 else:
-                    with obs_trace.span("dispatch", step=gstep + k,
+                    with obs_trace.span("dispatch", step=gstep + 1,
                                         step_trace=("train", gstep)):
                         self.state, metrics = self.train_step(self.state,
                                                               batch)
                 timer.phase("dispatch", time.perf_counter() - t0)
-                timer.tick(k)
-                prev, gstep = gstep, gstep + k
+                timer.tick()
+                prev, gstep = gstep, gstep + 1
                 cur_step["s"] = gstep  # live step for healer warn records
                 if heartbeat is not None:
                     heartbeat.beat(gstep)
@@ -963,7 +923,7 @@ class Trainer:
                 # Sync points: eval and checkpoint decisions must see every
                 # host-visible metric first, so divergence never reaches an
                 # eval record and a NaN state is never saved as a rollback
-                # target; at most log_every-1 + depth*K steps of NaN
+                # target; at most log_every-1 + FETCH_DEPTH steps of NaN
                 # training are lost (all rewound by the restore).
                 if eval_due or ckpt_due or nan_event["m"] is not None:
                     with obs_trace.span("drain"):
@@ -1199,7 +1159,7 @@ class Trainer:
                 self._flops_per_step = lowered_flops(lowered)
             if ledger is not None and not self._injected_step:
                 # compile_kind="first_step": first_wall includes one
-                # EXECUTED step stride, a different unit from warmup's
+                # EXECUTED step, a different unit from warmup's
                 # pure lower+compile "aot" rows — diff_ledgers only
                 # bounds like against like. An INJECTED pre-compiled
                 # step (recipe engine) records nothing: its compile

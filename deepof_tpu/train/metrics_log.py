@@ -11,7 +11,7 @@ AsyncFetcher is the loop's latency-hiding half (DESIGN.md "Execution
 layer"): a synchronous `device_get` between dispatches serializes
 dispatch->fetch->dispatch, and the slower the fetch the more of the step
 it costs; draining metric values on
-a bounded background consumer lets the next super-batch dispatch while the
+a bounded background consumer lets the next batch dispatch while the
 previous call's fetch is still in flight.
 """
 
@@ -148,12 +148,12 @@ class StepTimer:
         return {f"phase_{k}_s": round(v, 4)
                 for k, v in sorted(dict(self._phases).items())}
 
-    def tick(self, n: int = 1) -> None:
-        """Record n completed steps (n>1 for steps_per_call batched calls)."""
+    def tick(self) -> None:
+        """Record one completed step."""
         now = time.perf_counter()
         if self._last is not None:
             self._elapsed += now - self._last
-            self._steps += n
+            self._steps += 1
         self._last = now
 
     def pause(self) -> None:
@@ -200,6 +200,13 @@ def _fetch_with_retry(fetch, tree, seq: int, retries: int, backoff_s: float,
 
     return retry_bounded(once, retries=retries, backoff_s=backoff_s,
                          on_retry=count_retry)
+
+
+#: In-flight fetches the train loop allows. A full queue blocks the next
+#: dispatch (`submit`'s `submit_wait` span, which the benchmark's
+#: `loop_self_pct.*` reads), so the host never runs more than two calls
+#: ahead of the device.
+FETCH_DEPTH = 2
 
 
 class AsyncFetcher:
@@ -340,51 +347,6 @@ class AsyncFetcher:
         self._thread.join(timeout=5.0)
 
 
-class SyncFetcher:
-    """Depth-0 stand-in: fetch + callback inline on the caller's thread
-    (the pre-r06 serial dispatch->fetch->dispatch loop, selectable via
-    `TrainConfig.pipeline_depth = 0`). Same interface as AsyncFetcher so
-    the train loop has one code path."""
-
-    def __init__(self, fetch_fn=None, timer: StepTimer | None = None,
-                 retries: int = 0, backoff_s: float = 0.05, injector=None):
-        self._fetch = fetch_fn if fetch_fn is not None else jax.device_get
-        self._timer = timer
-        self._retries = max(int(retries), 0)
-        self._backoff = max(float(backoff_s), 0.0)
-        self._inj = injector
-        self._retry_count = 0
-        self._fetches = 0
-        self._fetch_s = 0.0
-
-    def _count_retry(self) -> None:
-        self._retry_count += 1
-
-    def submit(self, tag, tree, callback) -> None:
-        t0 = time.perf_counter()
-        with obs_trace.span("fetch"):
-            host = _fetch_with_retry(self._fetch, tree, self._fetches,
-                                     self._retries, self._backoff,
-                                     self._inj, self._count_retry)
-        dt = time.perf_counter() - t0
-        self._fetches += 1
-        self._fetch_s += dt
-        if self._timer is not None:
-            self._timer.phase("fetch", dt)
-        callback(tag, host)
-
-    def drain(self, timeout: float | None = None) -> bool:
-        return True
-
-    def stats(self) -> dict[str, float]:
-        return {"fetches": self._fetches, "fetch_s": round(self._fetch_s, 4),
-                "fetch_retries": self._retry_count,
-                "max_in_flight": 1 if self._fetches else 0}
-
-    def close(self) -> None:
-        pass
-
-
 class ProfilerSession:
     """Optional `jax.profiler` trace capture (SURVEY.md §5.1).
 
@@ -395,7 +357,7 @@ class ProfilerSession:
       - step window (`steps=(K, N)`, e.g. `--profile-steps 5:10`): the
         loop reports progress via `observe(gstep)`; the trace starts at
         the first iteration with gstep >= K and stops once gstep >= N.
-        K >= steps_per_call excludes the compile step, and the bounded
+        K >= 1 excludes the compile step, and the bounded
         window keeps the profile small enough to bring back from the chip
         (a whole-run trace of a long fit can run to GBs).
     """
@@ -421,14 +383,10 @@ class ProfilerSession:
         if self.enabled and self.steps is None and not self._active:
             self._start()
 
-    def observe(self, gstep: int, steps_per_call: int = 1) -> None:
+    def observe(self, gstep: int) -> None:
         """Step-window driver, called once per loop iteration with the
-        completed-step count and the dispatch stride. Starts when the
-        NEXT dispatch would overlap [start, stop) — stride-proof: with
-        steps_per_call=K the observed gsteps advance by K, and a window
-        narrower than K must still capture the one dispatch that
-        contains it, not be silently skipped. Idempotent; one window per
-        session."""
+        completed-step count. Starts when the NEXT dispatch falls in
+        [start, stop). Idempotent; one window per session."""
         if not self.enabled or self.steps is None or self._done:
             return
         start, stop = self.steps
@@ -436,7 +394,7 @@ class ProfilerSession:
             if gstep >= stop:
                 self._stop()
                 self._done = True  # one window; never restart
-        elif gstep < stop and gstep + max(steps_per_call, 1) > start:
+        elif start <= gstep < stop:
             self._start()
 
     def maybe_stop(self) -> None:

@@ -164,11 +164,11 @@ def make_train_step(model, cfg: ExperimentConfig, mean: Mean, mesh,
                     smooth_border_mask: bool = False):
     """Build the jitted, sharded train step: (state, batch) -> (state, metrics).
 
-    With `cfg.train.steps_per_call = K > 1` the returned fn instead takes K
-    stacked batches ([K, B, ...] leaves) and runs K optimizer steps in one
-    call via `lax.scan`, returning metrics with a leading K axis. One
-    dispatch + one value fetch then serves K steps — amortizing per-step
-    host/transport overhead (DESIGN.md "Benchmark honesty").
+    One optimizer step a call. The state is replicated over the mesh and
+    donated, the batch split over "data"; the metrics come back replicated:
+    `total`, `grad_norm`, `update_skipped` (and a two-stream model's
+    `action_loss`, `accuracy`) scalars, every `scale_*` and `layer_*` metric
+    one value a loss level or layer: what `Trainer._on_metrics` reads.
     """
     compute_dtype = compute_dtype_of(cfg)
 
@@ -191,7 +191,7 @@ def make_train_step(model, cfg: ExperimentConfig, mean: Mean, mesh,
         # non-finite loss/grads BEFORE the update and skip it in place —
         # params, opt_state, and step stay exactly the previous state's
         # (rng still advances so a retried batch doesn't replay the same
-        # dropout draw), and the host sees `update_skipped` per inner
+        # dropout draw), and the host sees `update_skipped` per
         # step. One bad batch then costs one skipped update, not a
         # checkpoint rollback. The select is a no-op bitwise when finite:
         # jnp.where(True, new, old) returns `new` exactly.
@@ -242,23 +242,9 @@ def make_train_step(model, cfg: ExperimentConfig, mean: Mean, mesh,
         return new_state, metrics
 
     repl, data = replicated_sharding(mesh), batch_sharding(mesh)
-    k = max(cfg.train.steps_per_call, 1)
-    if k == 1:
-        return jax.jit(
-            step,
-            in_shardings=(repl, data),
-            out_shardings=(repl, repl),
-            donate_argnums=(0,),
-        )
-
-    from ..parallel.mesh import stacked_batch_sharding
-
-    def multi_step(state: TrainState, batches):
-        return jax.lax.scan(step, state, batches)
-
     return jax.jit(
-        multi_step,
-        in_shardings=(repl, stacked_batch_sharding(mesh)),
+        step,
+        in_shardings=(repl, data),
         out_shardings=(repl, repl),
         donate_argnums=(0,),
     )

@@ -226,25 +226,17 @@ def _sds(tree: Any, sharding=None) -> Any:
 
 def example_train_batch(cfg: ExperimentConfig, dataset) -> dict:
     """One host batch assembled exactly as Trainer.fit()'s producer does
-    (sample -> optional augment -> K-stack), so the lowered avals — and
-    therefore the compile-cache key — match the real first step.
+    (sample -> optional augment), so the lowered avals — and therefore
+    the compile-cache key — match the real first step.
     `test_warmup_then_trainer_compiles_nothing` pins this equivalence.
     """
-    rng = np.random.RandomState(0)
-    k = max(cfg.train.steps_per_call, 1)
+    b = dataset.sample_train(cfg.data.batch_size,
+                             rng=np.random.RandomState(0))
+    if cfg.data.augment_geo or cfg.data.augment_photo:
+        from ..data.augmentation import make_augment_fn
 
-    def one() -> dict:
-        b = dataset.sample_train(cfg.data.batch_size, rng=rng)
-        if cfg.data.augment_geo or cfg.data.augment_photo:
-            from ..data.augmentation import make_augment_fn
-
-            b = make_augment_fn(cfg.data)(b, np.int64(0))
-        return {key: np.asarray(v) for key, v in b.items()}
-
-    b = one()
-    if k == 1:
-        return b
-    return {key: np.stack([v] * k) for key, v in b.items()}
+        b = make_augment_fn(cfg.data)(b, np.int64(0))
+    return {key: np.asarray(v) for key, v in b.items()}
 
 
 class AbstractTrainStep(NamedTuple):
@@ -278,8 +270,7 @@ def abstract_train_step(cfg: ExperimentConfig, mesh,
     cache key.
     """
     from ..models.registry import example_input, model_for
-    from ..parallel.mesh import (batch_sharding, replicated_sharding,
-                                 stacked_batch_sharding)
+    from ..parallel.mesh import batch_sharding, replicated_sharding
     from .schedule import step_decay_schedule
     from .state import create_train_state, make_optimizer
     from .step import make_train_step
@@ -297,9 +288,7 @@ def abstract_train_step(cfg: ExperimentConfig, mesh,
         example), replicated_sharding(mesh))
     smooth_border = getattr(model, "smooth_border_mask", False)
     step = make_train_step(model, cfg, dataset.mean, mesh, smooth_border)
-    batch = _sds(example_train_batch(cfg, dataset),
-                 stacked_batch_sharding(mesh)
-                 if cfg.train.steps_per_call > 1 else batch_sharding(mesh))
+    batch = _sds(example_train_batch(cfg, dataset), batch_sharding(mesh))
     return AbstractTrainStep(model, tx, step, state, batch)
 
 
@@ -336,7 +325,6 @@ def warmup_compile(cfg: ExperimentConfig, mesh=None, dataset=None,
                                                                dataset)
 
     out: dict[str, Any] = {"model": cfg.model,
-                           "steps_per_call": max(cfg.train.steps_per_call, 1),
                            "backend": jax.default_backend(),
                            "cache_dir": jax.config.jax_compilation_cache_dir}
     # executable ledger (obs/ledger.py): every AOT compile below appends

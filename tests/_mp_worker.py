@@ -3,8 +3,8 @@
 Launched with a clean environment (no inherited XLA flags or platform);
 forces 2 virtual CPU devices, joins the distributed runtime, and runs the
 multi-host data-path plumbing (SURVEY.md §5.8): `local_batch_rows` row
-slicing -> `put_global` assembly -> sharded train step, the stacked
-[K, B, ...] `steps_per_call` layout, and the allgathered eval. Writes its
+slicing -> `put_global` assembly -> sharded train step, and the
+allgathered eval. Writes its
 metrics as JSON for the parent test to compare against a single-process
 run of the identical batches.
 
@@ -13,7 +13,6 @@ reference run — the equality asserts are only meaningful if both sides
 build the identical config/model/optimizer/initial state.
 """
 
-import dataclasses
 import json
 import os
 import sys
@@ -98,7 +97,6 @@ def main() -> None:
         process_seed,
         put_global,
         put_global_from_full,
-        stacked_batch_sharding,
     )
     from deepof_tpu.train.step import make_eval_fn, make_train_step
 
@@ -123,22 +121,12 @@ def main() -> None:
     # execute. gloo's context init has a hard 30s kv-store deadline that
     # fires at the FIRST collective *execution*; per-worker compile-time
     # skew (AOT-cache hit vs miss, scheduler contention) routinely
-    # exceeds it (the r05 full-suite flake). Compiling all three legs
+    # exceeds it (the r05 full-suite flake). Compiling both legs
     # first and crossing a coordination-service barrier (10 min budget,
     # no gloo involved) brings both workers to the gloo key exchange
     # within milliseconds of each other.
     b = local_global(0)
     step_exec = step.lower(state, b).compile()
-
-    kcfg = cfg.replace(train=dataclasses.replace(cfg.train, steps_per_call=2))
-    kstate = new_state()
-    kstep = make_train_step(model, kcfg, ds.mean, mesh)
-    g0 = ds.sample_train(BATCH, iteration=0)
-    g1 = ds.sample_train(BATCH, iteration=1)
-    stacked = {key: np.stack([np.asarray(g0[key])[rows],
-                              np.asarray(g1[key])[rows]]) for key in g0}
-    kb = put_global(stacked, stacked_batch_sharding(mesh))
-    kstep_exec = kstep.lower(kstate, kb).compile()
 
     from jax.experimental import multihost_utils
 
@@ -165,11 +153,6 @@ def main() -> None:
         results[f"step{k}_param_checksum"] = float(
             jax.device_get(jnp.abs(flat).sum()))
 
-    # steps_per_call=2: stacked [K, local_B, ...] leaves under
-    # P(None, "data") via make_array_from_process_local_data (the
-    # non-leading sharded axis layout).
-    kstate, km = kstep_exec(kstate, kb)
-    results["scan_totals"] = np.asarray(jax.device_get(km["total"])).tolist()
     # assembly diagnostics: the global array each host sees must be the
     # full val batch, byte-identical to the host-local copy
     gsrc = np.asarray(multihost_utils.process_allgather(gvb["source"],

@@ -43,11 +43,10 @@ def _fake_tpu(kind):
 
 @pytest.mark.parametrize("kind,has_mfu", [("TPU v5 lite", True),
                                           ("TPU v9 imaginary", False)])
-def test_bench_spc_math_and_peak_by_device_kind(monkeypatch, kind, has_mfu):
-    """bench() with steps_per_call=K: throughput normalizes per optimizer
-    step (per_call / K), lowered FLOPs are NOT divided by K (XLA counts a
-    scan body once), the line names its device, and `mfu_nominal` exists
-    only for a device kind in the peak table."""
+def test_bench_math_and_peak_by_device_kind(monkeypatch, kind, has_mfu):
+    """bench(): throughput is batch over the timed step, the line names
+    its device, and `mfu_nominal` exists only for a device kind in the
+    peak table."""
     bench._import_compute()  # conftest forced the cpu backend already
     monkeypatch.setattr(bench, "_require_tpu", lambda: _fake_tpu(kind))
     monkeypatch.setattr(bench, "calibrate", lambda: {"matmul_tflops": 100.0})
@@ -55,22 +54,21 @@ def test_bench_spc_math_and_peak_by_device_kind(monkeypatch, kind, has_mfu):
         warp_impl="auto"))
     seen = {}
 
-    def setup(model, batch, size, steps_per_call, warp_impl):
-        seen.update(spc=steps_per_call, warp_impl=warp_impl)
+    def setup(model, batch, size, warp_impl):
+        seen.update(warp_impl=warp_impl)
         return fake_cfg, None, None, None, "state", "step", "b"
 
     monkeypatch.setattr(bench, "headline_setup", setup)
     monkeypatch.setattr(
         bench, "time_train_step",
-        lambda step, state, b, steps, windows, warmup: (0.4, state,
+        lambda step, state, b, steps, windows, warmup: (0.1, state,
                                                         np.array([1.0])))
     monkeypatch.setattr(bench, "step_flops", lambda *a: 8e12)
-    res = bench.bench(steps_per_call=4)
-    assert seen == {"spc": 4, "warp_impl": None}
-    assert res["steps_per_call"] == 4
-    assert abs(res["steps_per_sec"] - 10.0) < 1e-9   # 4 steps / 0.4 s call
+    res = bench.bench()
+    assert seen == {"warp_impl": None}
+    assert abs(res["steps_per_sec"] - 10.0) < 1e-9   # 0.1 s a step
     assert abs(res["pairs_per_sec"] - 160.0) < 1e-9  # batch 16 x 10
-    assert res["flops_per_step"] == 8e12             # scan body counted once
+    assert res["flops_per_step"] == 8e12
     assert res["model_tflops"] == 80.0               # 8e12 x 10 / 1 chip
     assert (res["platform"], res["device_kind"], res["n_chips"]) == (
         "tpu", kind, 1)

@@ -11,7 +11,7 @@ import time
 import numpy as np
 import pytest
 
-from deepof_tpu.train.metrics_log import AsyncFetcher, StepTimer, SyncFetcher
+from deepof_tpu.train.metrics_log import AsyncFetcher, StepTimer
 
 FETCH_DELAY = 0.05  # the ISSUE-specified injected 50 ms value-fetch RTT
 N_STEPS = 10
@@ -22,7 +22,12 @@ def _delayed_fetch(tree):
     return tree
 
 
-def _run_loop(fetcher, dispatch_s=0.04):
+def _serial_submit(tag, tree, callback):
+    """The loop without a fetcher: fetch, then the callback, inline."""
+    callback(tag, _delayed_fetch(tree))
+
+
+def _run_loop(submit, drain=lambda: None, dispatch_s=0.04):
     """A train loop skeleton: dispatch-side host work then the metrics
     fetch submit; every step is host-visible (log_every=1). Dispatch and
     fetch delays are comparable (40 vs 50 ms), so overlap should cut wall-clock to ~max(sum_dispatch,
@@ -31,20 +36,19 @@ def _run_loop(fetcher, dispatch_s=0.04):
     t0 = time.perf_counter()
     for i in range(N_STEPS):
         time.sleep(dispatch_s)  # stand-in for the async dispatch call
-        fetcher.submit(i, {"total": np.float32(i)},
-                       lambda tag, host: done.append(tag))
-    fetcher.drain()
-    wall = time.perf_counter() - t0
-    fetcher.close()
-    return wall, done
+        submit(i, {"total": np.float32(i)},
+               lambda tag, host: done.append(tag))
+    drain()
+    return time.perf_counter() - t0, done
 
 
 def test_pipelined_loop_beats_serial_under_fetch_delay():
     """The acceptance pin: >= 2 calls in flight, and wall-clock clearly
     under the serial dispatch+fetch sum (which is ~N*(dispatch+fetch))."""
-    serial_wall, serial_done = _run_loop(SyncFetcher(fetch_fn=_delayed_fetch))
+    serial_wall, serial_done = _run_loop(_serial_submit)
     pipe = AsyncFetcher(depth=2, fetch_fn=_delayed_fetch)
-    pipe_wall, pipe_done = _run_loop(pipe)
+    pipe_wall, pipe_done = _run_loop(pipe.submit, pipe.drain)
+    pipe.close()
 
     assert serial_done == list(range(N_STEPS))
     assert pipe_done == list(range(N_STEPS))  # FIFO: records stay ordered
@@ -104,21 +108,6 @@ def test_async_fetcher_callback_error_surfaces():
     with pytest.raises(ValueError, match="callback exploded"):
         f.drain()  # join guarantees the callback ran before the re-raise
     f.close()
-
-
-def test_sync_fetcher_is_inline():
-    """Depth-0 fallback runs fetch+callback on the caller's thread."""
-    caller = threading.get_ident()
-    seen = {}
-
-    def cb(tag, host):
-        seen["thread"] = threading.get_ident()
-        seen["host"] = host
-
-    f = SyncFetcher(fetch_fn=lambda t: t + 1)
-    f.submit(0, 41, cb)
-    assert seen == {"thread": caller, "host": 42}
-    assert f.stats()["fetches"] == 1
 
 
 def test_step_timer_phases_accumulate_and_reset():

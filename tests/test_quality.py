@@ -445,8 +445,7 @@ def test_loss_dict_carries_smooth_alias(rng):
 
 def test_per_scale_record_fields_shape():
     """train/loop.py per_scale_last + SCALE_RECORD_FIELDS: per-scale
-    vectors fold into JSON lists, last inner step wins under
-    steps_per_call stacking."""
+    vectors fold into JSON lists."""
     from deepof_tpu.train.loop import SCALE_RECORD_FIELDS, per_scale_last
 
     assert [f for f, _ in SCALE_RECORD_FIELDS] == [
@@ -455,9 +454,62 @@ def test_per_scale_record_fields_shape():
         "warp_gather_fallback_by_scale"]
     v = np.array([1.0, 0.5, 0.25])
     assert per_scale_last(v) == [1.0, 0.5, 0.25]
-    stacked = np.stack([v, v * 2.0])  # [K=2, S=3]: last step wins
-    assert per_scale_last(stacked) == [2.0, 1.0, 0.5]
+    assert per_scale_last(np.float32(0.123456789)) == [0.123457]
     assert json.dumps(per_scale_last(v))  # JSON-ready
+
+
+def _abstract_step(family):
+    """(jitted step, state specs, batch specs) of a small model of
+    `family` on one device: traced by the test, never compiled."""
+    import jax
+
+    from deepof_tpu.data import build_dataset
+    from deepof_tpu.parallel.mesh import build_mesh
+    from deepof_tpu.train.warmup import abstract_train_step
+
+    if family == "lm":
+        cfg = get_config("lm")
+        cfg = cfg.replace(lm=dataclasses.replace(
+            cfg.lm, vocab_size=1000, seq_len=64, attn_block_q=16,
+            loss_block=16))
+    else:
+        cfg = get_config("flyingchairs")
+        cfg = cfg.replace(
+            model={"flow": "flownet_s", "two_stream": "st_single"}[family],
+            width_mult=0.25 if family == "flow" else 1.0,
+            data=dataclasses.replace(cfg.data, dataset="synthetic",
+                                     image_size=(64, 64), gt_size=(64, 64),
+                                     batch_size=2))
+    a = abstract_train_step(cfg, build_mesh(devices=jax.devices()[:1]),
+                            build_dataset(cfg.data, lm=cfg.lm))
+    return a.step, a.state, a.batch
+
+
+@pytest.mark.parametrize("family", ["flow", "two_stream", "lm"])
+def test_train_step_metric_ranks(family):
+    """The contract `Trainer._on_metrics` reads a fetched record by: one
+    step a dispatch, so `total`, `grad_norm`, `update_skipped` (and the
+    two-stream scalars) are rank 0 and every per-scale / per-layer
+    metric is a rank-1 vector — no leading axis of steps anywhere. (The
+    language model's `loss_rows`, one value a row, is no record field.)"""
+    import jax
+
+    from deepof_tpu.train.step import LAYER_METRIC_PREFIX
+
+    step, state, batch = _abstract_step(family)
+    _, metrics = jax.eval_shape(step, state, batch)
+    for key in ("total", "grad_norm", "update_skipped"):
+        assert metrics[key].shape == (), key
+    vectors = [k for k in metrics
+               if k.startswith(("scale_", LAYER_METRIC_PREFIX))]
+    assert vectors, sorted(metrics)
+    for key in vectors:
+        assert len(metrics[key].shape) == 1, (key, metrics[key].shape)
+    for key in set(metrics) - set(vectors) - {"loss_rows"}:
+        assert metrics[key].shape == (), key
+    assert ("accuracy" in metrics) == (family == "two_stream")
+    assert any(k.startswith(LAYER_METRIC_PREFIX) for k in vectors) == (
+        family == "lm")
 
 
 def test_train_step_metrics_carry_scale_smooth(rng):

@@ -204,6 +204,23 @@ def test_experiment_config_round_trip_carries_recipe():
     assert back.recipe.stages[0].mixture[1].sintel_pass == "clean"
 
 
+@pytest.mark.parametrize("field,value", [("steps_per_call", 4),
+                                         ("pipeline_depth", 0)])
+def test_removed_train_fields_are_refused(field, value):
+    """One step a dispatch and a fetch depth of two are not options: the
+    command line and a saved config that still hold either field fail
+    loudly, never run with it ignored."""
+    from deepof_tpu.cli import _apply_override
+
+    with pytest.raises(SystemExit, match="unknown config field"):
+        _apply_override(ExperimentConfig(), f"train.{field}", str(value))
+    saved = json.loads(json.dumps(dataclasses.asdict(ExperimentConfig())))
+    assert field not in saved["train"]
+    saved["train"][field] = value
+    with pytest.raises(ValueError, match=field):
+        config_from_dict(saved)
+
+
 def test_recipe_from_dict_rejects_unknown_keys_with_indexed_path():
     """A typo at ANY nesting level fails loudly with the exact indexed
     path — never a silently-defaulted field."""
@@ -474,7 +491,7 @@ def _cli_train(tmp_path, recipe: dict, *extra, model="flownet_s",
          "--log-dir", str(tmp_path / "run"),
          "--set", f"model={model}", "--set", f"width_mult={width}",
          "--set", "train.log_every=1", "--set", "train.eval_every=0",
-         "--set", "train.steps_per_call=1", *extra],
+         *extra],
         capture_output=True, text=True, timeout=560, env=env, cwd=REPO)
     assert res.returncode == 0, (res.stdout[-1000:], res.stderr[-2000:])
     return json.loads(res.stdout.strip().splitlines()[-1])

@@ -155,50 +155,6 @@ def test_remat_train_step_matches(tmp_path):
     assert np.isclose(results[False][1], results[True][1], rtol=1e-5)
 
 
-def test_steps_per_call_matches_single(tmp_path):
-    """K scanned steps in one call == K single-step calls (same batches)."""
-    import dataclasses
-
-    cfg = _cfg(tmp_path)
-    mesh = build_mesh(cfg.mesh)
-    ds = SyntheticData(cfg.data)
-    model = build_model("flownet_s", width_mult=0.25)
-    tx = make_optimizer(cfg.optim, lambda s: 1e-4)
-    b0 = ds.sample_train(8, iteration=0)
-    b1 = ds.sample_train(8, iteration=1)
-
-    state = create_train_state(model, jnp.zeros((8, H, W, 6)), tx, seed=0)
-    step1 = make_train_step(model, cfg, ds.mean, mesh)
-    for b in (b0, b1):
-        state, m = step1(state, jax.device_put(b, batch_sharding(mesh)))
-    single_params = jax.device_get(state.params)
-    single_total = float(m["total"])
-
-    from deepof_tpu.parallel.mesh import stacked_batch_sharding
-
-    c2 = cfg.replace(train=dataclasses.replace(cfg.train, steps_per_call=2))
-    state2 = create_train_state(model, jnp.zeros((8, H, W, 6)), tx, seed=0)
-    step2 = make_train_step(model, c2, ds.mean, mesh)
-    stacked = {k: np.stack([b0[k], b1[k]]) for k in b0}
-    state2, m2 = step2(state2, jax.device_put(stacked,
-                                              stacked_batch_sharding(mesh)))
-    assert m2["total"].shape == (2,)
-    assert int(state2.step) == 2
-    np.testing.assert_allclose(float(m2["total"][-1]), single_total, rtol=1e-5)
-    # scanned vs unrolled compiles reassociate float math, and the warp's
-    # floor/clip indexing turns a rounding flip at an integer flow
-    # boundary into a DISCRETE per-pixel gradient jump, which Adam's
-    # 1/(sqrt(v)+eps) then amplifies at isolated near-zero-v elements
-    # (seen: 1 of 36864 elements at 2.4e-3 relative after two steps).
-    # The bound absorbs those isolated discontinuities; a wiring bug
-    # (wrong batch order, missed optimizer update) is an O(1) error and
-    # still fails loudly.
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(a, jax.device_get(b),
-                                                rtol=1e-2, atol=3e-4),
-        single_params, state2.params)
-
-
 def test_occlusion_rejected_for_unsupported_models(tmp_path):
     """loss.occlusion only masks flow-only 2-frame models; anything else
     must fail at step-build time, not silently skip."""
@@ -346,19 +302,6 @@ def test_final_save_skipped_on_unchecked_nan(tmp_path):
     # was refused and the in-memory state rolled back to match it
     assert trainer.ckpt.latest_step() == 0
     assert int(trainer.state.step) == 0
-
-
-def test_trainer_fit_steps_per_call(tmp_path):
-    """Trainer end-to-end with K=2: step accounting, logging, checkpointing."""
-    import dataclasses
-
-    cfg = _cfg(tmp_path)
-    cfg = cfg.replace(train=dataclasses.replace(cfg.train, steps_per_call=2))
-    trainer = Trainer(cfg, profile=False)
-    out = trainer.fit(num_epochs=1, max_steps=4)
-    assert "steps_per_sec" in out
-    assert int(trainer.state.step) >= 4
-    assert trainer.ckpt.latest_step() is not None
 
 
 def test_flownet_c_learns_matching_below_zero_flow(tmp_path):

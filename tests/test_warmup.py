@@ -32,11 +32,11 @@ pytestmark = pytest.mark.slow  # full train-step XLA compiles; see pytest.ini
 def _cfg(tmp_path, **train_kw) -> ExperimentConfig:
     """The headline PIPELINE (inception flagship pipeline shape is pinned
     on TPU by bench.py; here the suite's thin-trunk convention keeps the
-    CPU mesh affordable) with the headline's steps_per_call=4 scan."""
+    CPU mesh affordable)."""
     train = dict(num_epochs=1, log_every=1, eval_every=0,
                  ckpt_every_epochs=10**6, log_dir=str(tmp_path / "run"),
                  eval_amplifier=1.0, eval_clip=(-1e4, 1e4),
-                 eval_batch_size=8, seed=0, steps_per_call=4,
+                 eval_batch_size=8, seed=0,
                  # explicit True: the auto default disables the cache on
                  # cpu (cross-process read corruption, TrainConfig
                  # comment); these tests exercise it in-process, which
@@ -113,7 +113,7 @@ def test_trainer_first_step_counters_present_cold(tmp_path,
     the observable that distinguishes a cold window from a warm one."""
     from deepof_tpu.train.loop import Trainer
 
-    cfg = _cfg(tmp_path, steps_per_call=1)
+    cfg = _cfg(tmp_path)
     trainer = Trainer(cfg, profile=False)
     trainer.fit(num_epochs=1, max_steps=2)
     records = [json.loads(ln) for ln in
@@ -177,17 +177,22 @@ def test_compile_cache_auto_disables_on_cpu(tmp_path, restore_cache_dir):
 
 
 def test_example_train_batch_matches_producer_stacking(tmp_path):
-    """steps_per_call stacking: [K, B, ...] leaves with the dataset's
-    dtypes — the aval contract the cache key depends on."""
+    """The example batch has the producer's keys, shapes and dtypes —
+    the aval contract the cache key depends on."""
     from deepof_tpu.data import build_dataset
 
     cfg = _cfg(tmp_path)
     ds = build_dataset(cfg.data)
     b = warmup.example_train_batch(cfg, ds)
+    produced = ds.sample_train(cfg.data.batch_size,
+                               rng=np.random.RandomState(1))
     # the FULL producer key set, label included — extra keys are part of
     # the jitted signature and therefore of the cache key
-    assert set(b) == {"source", "target", "flow", "label"}
-    assert b["source"].shape[:2] == (4, 8)  # [K, B]
+    assert set(b) == set(produced) == {"source", "target", "flow", "label"}
+    for key, v in produced.items():
+        assert b[key].shape == np.shape(v), key
+        assert b[key].dtype == np.asarray(v).dtype, key
+    assert b["source"].shape == (8, 64, 64, 3)
     assert b["source"].dtype == np.float32
 
 

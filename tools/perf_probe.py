@@ -19,7 +19,6 @@ Sections:
   warpsweep the warp kernels' time against the rows their sweep visits,
            beside the XLA gather, at 160x224 (two lane tiles) and 80x112,
            batch 64: the measurement behind `PALLAS_AUTO_MAX_SWEEP`
-  spc      steps_per_call sweep (1/4/8): dispatch amortization
   corr     XLA vs Pallas correlation kernel, fwd + grad, FlowNet-C
            shapes
   batch    batch-size throughput curve (16/96)
@@ -328,21 +327,6 @@ def sec_batch() -> None:
               f"{batch/per:9.1f} items/s", flush=True)
 
 
-def sec_spc() -> None:
-    # steps_per_call sweep: K optimizer steps per dispatch; the gap
-    # between K=1 and K->8 per-step times IS the per-dispatch host/
-    # transport overhead (DESIGN.md "Benchmark honesty"). K=2 dropped:
-    # each K is a distinct large remote compile; 1/4/8 brackets the
-    # amortization curve.
-    for k in (1, 4, 8):
-        cfg, mesh, ds, model, state, step, b = bench_mod.headline_setup(
-            steps_per_call=k)
-        per_call, _ = _time_full_step(step, state, b, steps=6, windows=2)
-        B = cfg.data.batch_size
-        print(f"{'steps_per_call K=%d' % k:44s} {per_call/k*1e3:8.2f} "
-              f"ms/step  {k*B/per_call:9.1f} items/s", flush=True)
-
-
 def sec_headline() -> None:
     res = bench_mod.bench()
     print("bench:", {k: round(v, 2) if isinstance(v, float) else v
@@ -385,7 +369,7 @@ def sec_multiframe() -> None:
 
 
 # Execution order: the headline + its MFU fields first, then
-# calibration context, then the decision sections (decomp/warpscan/warpsweep/spc/
+# calibration context, then the decision sections (decomp/warpscan/warpsweep/
 # corr), then sweeps; the per-call warp table is superseded by warpscan
 # and runs last.
 SECTIONS = {
@@ -394,7 +378,6 @@ SECTIONS = {
     "decomp": sec_decomp,
     "warpscan": sec_warp_scan,
     "warpsweep": sec_warp_sweep,
-    "spc": sec_spc,
     "corr": sec_corr,
     "batch": sec_batch,
     "multiframe": sec_multiframe,
