@@ -19,6 +19,7 @@ On the chip `mla_scores` holds the Mosaic calls `mla_attn_fwd` and, under
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -147,6 +148,115 @@ def route(h, router, bias, cfg: LMConfig):
     return idx, w * cfg.routed_scaling_factor
 
 
+#: The expert layer's sorted row list is this many times the held experts'
+#: EVEN share of the token-slots (`t*k*held/width`) wide, not `t*k`: a row
+#: gather or scatter-add on the chip is bound by its row count, not its
+#: bytes. A step whose held experts take more runs at the full width
+#: (`lax.cond`), so the factor trades rows moved every step against how
+#: often that happens, never a result. Measured on a v5e (PR 34, one layer
+#: of the 4k cell, 16 of 128 experts held, forward + recomputation +
+#: backward, ms at held shares of 0.12 / 0.24 / 0.33 / 0.46 of the slots):
+#: full width 31.5 / 33.9 / 35.9 / 38.7; factor 2 (12,288 of 49,152 rows)
+#: 18.2 / 20.4 / 35.5 / 38.3; 3: 20.6 / 22.9 / 24.7 / 38.4; 4: 23.0 / 25.5 /
+#: 27.3 / 30.1: 2.4 ms a layer for each 6,144 rows, and the fallback costs
+#: what the full width costs. Over the 240 layer-records of four seeds'
+#: windows (share over 0.25 in 12%, over 0.375 in 6%: the last layer's
+#: drifts up as the cut model trains) that is 20.5 ms a layer at 2, 22.0
+#: at 3, 23.9 at 4, 32.3 at the full width: PERF.md section 6, PR 34. A
+#: layer that holds half or more of its router's experts has no second
+#: width.
+EXPERT_ROWS_OVER_EVEN = 2
+#: and rounded up to the rows the chip's grouped product takes at a time
+#: (XLA's fusion for `lax.ragged_dot` on a v5e walks 96 row tiles for
+#: 49,152 rows and 24 for 12,288, in all three products of the 4k cell: its
+#: metadata holds tiles + groups - 1 entries, 111 and 39; sandbox compile
+#: for a described v5e, PR 34): rows up to the next tile's end cost nothing
+EXPERT_ROWS_MULTIPLE = 512
+
+
+def expert_row_cap(cfg: LMConfig, tokens: int) -> int:
+    """Rows of the expert layer's compact sorted list for `tokens` tokens;
+    `tokens * k`, all the slots, where that is no wider."""
+    held = cfg.n_routed_experts
+    slots = tokens * cfg.num_experts_per_tok
+    even = -(-slots * held // (cfg.n_routed_experts_published or held))
+    cap = EXPERT_ROWS_OVER_EVEN * even
+    return min(slots, -(-cap // EXPERT_ROWS_MULTIPLE) * EXPERT_ROWS_MULTIPLE)
+
+
+def add_routed(shared, x, w, order, sizes, experts, rows: int, dtype):
+    """shared[t, d] + the held experts' products of their token-slots,
+    weighted, over the first `rows` of the sorted slot list `order` (held
+    experts' slots first, in expert order). Exact whenever `sum(sizes) <=
+    rows`: the rows left out are no held expert's and would be selected to
+    zero before they were added. x[t, d], w[t, k], sizes[held],
+    experts = (w_gate, w_up, w_down)."""
+    k = w.shape[-1]
+    w_gate, w_up, w_down = experts
+    with jax.named_scope("moe_dispatch"):
+        order = order[:rows]
+        tok = order // k
+        # Rows past the held experts' are no expert's. The chip's
+        # grouped product neither reads nor WRITES them, forward or
+        # transposed: what it leaves there (in the output, and in the
+        # cotangent of its input) is whatever the memory held. So every
+        # value that enters or leaves a grouped product is selected by
+        # `held_row`, which also zeroes the cotangent on the way back.
+        held_row = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        xs = jnp.where(held_row, x.astype(dtype)[tok], 0)
+        ws = w.reshape(-1)[order]
+    with jax.named_scope("moe_experts"):
+        grouped = lambda a, m: jnp.where(held_row, ragged_dot(  # noqa: E731
+            a, m.astype(dtype), sizes, preferred_element_type=F32), 0.0)
+        a = (jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)).astype(dtype)
+        o = grouped(a, w_down)
+    with jax.named_scope("moe_combine"):
+        return shared.at[tok].add(o * ws[:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def add_routed_within(cap: int, dtype, fits, shared, x, w, order, sizes,
+                      experts):
+    """`add_routed` over `cap` rows in a step whose held experts' slots fit
+    there (`fits`: the step's own `sum(sizes) <= cap`), over all of `order`
+    in one where they do not (`lax.cond`): the same sum either way. The
+    backward is a `cond` of its own over each width's own backward, from
+    the operands. A `cond` differentiated as it stands hands its backward
+    the residuals of BOTH widths, the one not taken as zeros (2.4 GB a layer
+    in the 4k cell: 10.4 GB of temporaries, a step that no longer fits the
+    chip); with `jax.checkpoint` around each branch the residuals are
+    copies of the operands (18.8 ms a layer where this reads 18.2: PR 34)."""
+    return lax.cond(fits, lambda *a: add_routed(*a, cap, dtype),
+                    lambda *a: add_routed(*a, order.shape[0], dtype),
+                    shared, x, w, order, sizes, experts)
+
+
+def _add_routed_within_fwd(cap, dtype, fits, *args):
+    return add_routed_within(cap, dtype, fits, *args), (fits, args)
+
+
+def _add_routed_within_bwd(cap, dtype, res, ct):
+    fits, (shared, x, w, order, sizes, experts) = res
+
+    def back(rows):
+        return lambda: jax.vjp(
+            lambda sh, xx, ww, ex: add_routed(sh, xx, ww, order, sizes, ex,
+                                              rows, dtype),
+            shared, x, w, experts)[1](ct)
+
+    # The barrier keeps the optimizer's first use of the experts' gradients
+    # (the norm's sums, Adam's moments) out of the branches: XLA sinks it
+    # into both, where it writes two float32 copies a weight, and the step's
+    # temporaries read 7.14 GB against 4.99 with the barrier (3.98 with no
+    # `cond`; sandbox compile for a described v5e, PR 34).
+    d_shared, dx, dw, d_experts = lax.optimization_barrier(
+        lax.cond(fits, back(cap), back(order.shape[0])))
+    return None, d_shared, dx, dw, None, None, d_experts
+
+
+add_routed_within.defvjp(_add_routed_within_fwd, _add_routed_within_bwd)
+
+
 class MoE(nn.Module):
     """The expert layer of ONE share of an expert-parallel deployment: it
     holds experts `first_expert .. first_expert + n_routed_experts` of the
@@ -154,9 +264,14 @@ class MoE(nn.Module):
     normalises over all of them and adds only the products of the chosen
     experts it holds, plus the whole shared expert; what absent experts
     would add is left out (their chips add it, after the exchange that one
-    chip does not have). No capacity: the token-slots are sorted by expert
-    and each projection is one grouped product over the held experts'
-    rows (`lax.ragged_dot`), so no token is dropped or padded.
+    chip does not have). No capacity in the model's sense: the token-slots
+    are sorted by expert and each projection is one grouped product over
+    the held experts' rows (`lax.ragged_dot`), so no token is dropped or
+    padded. The sorted list that is gathered, multiplied and scatter-added
+    is `expert_row_cap` rows wide, the part of it the held experts' slots
+    usually fill (in the deployment: the exchange's receive buffer); a step
+    in which they take more runs the same function over all `t*k` slots,
+    so `cap` bounds what is moved, never what is computed.
 
     Returns (y[b, s, d] float32, counters of this layer)."""
 
@@ -191,25 +306,16 @@ class MoE(nn.Module):
                             axis=0)[:held]
         with jax.named_scope("moe_dispatch"):
             order = jnp.argsort(gid, stable=True)
-            tok = order // k
-            # Rows past the held experts' are no expert's. The chip's
-            # grouped product neither reads nor WRITES them, forward or
-            # transposed: what it leaves there (in the output, and in the
-            # cotangent of its input) is whatever the memory held. So every
-            # value that enters or leaves a grouped product is selected by
-            # `held_row`, which also zeroes the cotangent on the way back.
-            held_row = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
-            xs = jnp.where(held_row, x.astype(dt)[tok], 0)
-            ws = w.reshape(-1)[order]
-        with jax.named_scope("moe_experts"):
-            grouped = lambda a, m: jnp.where(held_row, ragged_dot(  # noqa: E731
-                a, m.astype(dt), sizes, preferred_element_type=F32), 0.0)
-            a = (jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)).astype(dt)
-            o = grouped(a, w_down)
         with jax.named_scope("moe_shared"):
             shared = SwiGLU(c, c.n_shared_experts * we, dt, name="shared")(x)
-        with jax.named_scope("moe_combine"):
-            y = shared.at[tok].add(o * ws[:, None])
+        args = (shared, x, w, order, sizes, (w_gate, w_up, w_down))
+        cap = expert_row_cap(c, t)
+        if cap < t * k:
+            fits = jnp.sum(sizes) <= cap
+            y = add_routed_within(cap, dt, fits, *args)
+            full_width = 1.0 - fits.astype(F32)
+        else:  # the one width: the program holds no `cond`
+            y, full_width = add_routed(*args, t * k, dt), jnp.ones((), F32)
         load = sizes.astype(F32)
         counters = {
             "moe_slots_held_share": jnp.sum(load) / (t * k),
@@ -217,5 +323,6 @@ class MoE(nn.Module):
                 jnp.mean(load), 1e-9),
             "moe_tokens_none_held_share": 1.0 - jnp.mean(
                 jnp.any(mine, axis=-1).astype(F32)),
+            "moe_full_width": full_width,
         }
         return y.reshape(b, s, d), counters

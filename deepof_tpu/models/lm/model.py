@@ -14,10 +14,11 @@ from flax import linen as nn
 
 from ...core.config import LMConfig
 from ...ops.attention import RESIDUALS, attention_route
-from .layers import F32, MLA, MoE, RMSNorm, SwiGLU, _init, dot
+from .layers import (F32, MLA, MoE, RMSNorm, SwiGLU, _init, dot,
+                     expert_row_cap)
 
 COUNTERS = ("moe_slots_held_share", "moe_load_max_over_mean",
-            "moe_tokens_none_held_share")
+            "moe_tokens_none_held_share", "moe_full_width")
 
 
 def is_expert_layer(cfg: LMConfig, i: int) -> bool:
@@ -81,7 +82,12 @@ class LatentMoELM(nn.Module):
         c = self.cfg
         return {"attention_route": attention_route(
             c.seq_len, c.attn_block_q,
-            (c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim))}
+            (c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim)),
+            # a row's share of the expert layer's sorted list: `cap` rows
+            # are gathered and scattered unless a step's held experts take
+            # more (`moe_full_width` 1.0); cap == slots: one width, by shape
+            "expert_rows": {"cap": expert_row_cap(c, c.seq_len),
+                            "slots": c.seq_len * c.num_experts_per_tok}}
 
     @nn.compact
     def __call__(self, ids, targets=None):

@@ -49,6 +49,15 @@ def hidden():
     return x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True))
 
 
+@pytest.fixture(scope="module")
+def long_hidden():
+    """1024 tokens, 2048 token-slots: the shortest the expert layer's sorted
+    list has a second width at (`expert_row_cap`: 2 held of 8, twice their
+    even share, is 1024 rows, two of the grouped product's row tiles)."""
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 512, 64), jnp.float32)
+    return x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True))
+
+
 TOKENS = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (2, 33), 0, 256))
 
 
@@ -109,11 +118,33 @@ def test_ties_and_bias_change_the_chosen_set_as_the_equations_say(which):
     assert np.asarray(idx).tolist() == [[6, 5]] * 3
 
 
-@pytest.mark.parametrize("dtype", sorted(DTYPES))
-def test_expert_layer_matches_reference(weights, hidden, dtype):
+# What the expert layer's `cond` sees, by a bias on the small model's
+# router (`long_hidden`: t*k = 2048 slots, held experts 2 and 3, cap = 2 *
+# 2048 * 2/8 = 1024 rows): the ordinary routing and every token on experts
+# (2, 6), 1024 slots held == cap exactly, run the compact list; every token
+# on (2, 3), all 2048 held, runs the full width under the same `cond`.
+PATHS = {"compact": ({}, 0.0), "boundary": ({2: 10.0, 6: 5.0}, 0.0),
+         "full": ({2: 10.0, 3: 10.0}, 1.0)}
+
+
+def on_path(weights, path):
+    """(reference's values, the layer's parameters, `moe_full_width`)
+    with `path`'s bias on layer_1's router."""
     vals, params = weights
-    got, counters = L.MoE(LM, DTYPES[dtype]).apply(
-        {"params": params["layer_1"]["moe"]}, hidden)
+    bias, full_width = PATHS[path]
+    if not bias:
+        return vals, params["layer_1"]["moe"], full_width
+    b = jnp.array([bias.get(i, 0.0) for i in range(8)])
+    return ({**vals, "layer_1/moe/bias": b},
+            {**params["layer_1"]["moe"], "bias": b}, full_width)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
+def test_expert_layer_matches_reference(weights, long_hidden, dtype, path):
+    vals, p, full_width = on_path(weights, path)
+    hidden = long_hidden
+    got, counters = L.MoE(LM, DTYPES[dtype]).apply({"params": p}, hidden)
     want = jnp.stack([ref.moe(vals, "layer_1", hidden[i], as_dict(LM))
                       for i in range(2)])
     assert rel(got, want) < TOL[dtype]["layer"]
@@ -125,16 +156,22 @@ def test_expert_layer_matches_reference(weights, hidden, dtype):
         float(mine.mean()))
     assert float(counters["moe_tokens_none_held_share"]) == pytest.approx(
         float(1 - mine.any(-1).mean()))
+    assert float(counters["moe_full_width"]) == full_width
+    if path == "boundary":
+        assert int(mine.sum()) == L.expert_row_cap(LM, 1024) == 1024
 
 
+@pytest.mark.parametrize("path", sorted(PATHS))
 def test_rows_the_chips_grouped_product_leaves_unwritten_reach_nothing(
-        weights, hidden, monkeypatch):
+        weights, long_hidden, monkeypatch, path):
     """On the chip `lax.ragged_dot` leaves the rows past its groups, and
     the matching rows of its input's cotangent, unwritten (PR 31's first
     chip run: the loss agreed to 1e-5 and gradient norms read 400 times the
     reference's). Stand-in: NaN there, forward and backward. The layer's
-    output and every gradient must not change."""
-    vals, params = weights
+    output and every gradient must not change, at either width of the
+    sorted list, and every gradient matches the reference's."""
+    vals, p, _ = on_path(weights, path)
+    hidden = long_hidden
     real = jax.lax.ragged_dot
 
     @jax.custom_vjp
@@ -155,16 +192,55 @@ def test_rows_the_chips_grouped_product_leaves_unwritten_reach_nothing(
         return jnp.where(rows, da, jnp.nan), dm, None
 
     dirty.defvjp(fwd, bwd)
-    p = params["layer_1"]["moe"]
     loss = lambda pp, h: jnp.sum(L.MoE(LM).apply({"params": pp}, h)[0] ** 2)  # noqa: E731
     clean = jax.grad(loss, argnums=(0, 1))(p, hidden)
     monkeypatch.setattr(L, "ragged_dot", lambda a, m, sizes, **kw: dirty(a, m, sizes))
     got = jax.grad(loss, argnums=(0, 1))(p, hidden)
-    assert float(loss(p, hidden)) == pytest.approx(float(jnp.sum(jnp.stack(
-        [ref.moe(vals, "layer_1", hidden[i], as_dict(LM)) for i in range(2)]) ** 2)),
-        rel=1e-5)
+    ref_loss = lambda v, h: jnp.sum(jnp.stack(  # noqa: E731
+        [ref.moe(v, "layer_1", h[i], as_dict(LM)) for i in range(2)]) ** 2)
+    assert float(loss(p, hidden)) == pytest.approx(float(ref_loss(vals, hidden)),
+                                                   rel=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(clean)):
         assert bool(jnp.all(jnp.isfinite(a))) and rel(a, b) < 1e-6
+    want_v, want_h = jax.grad(ref_loss, argnums=(0, 1))(vals, hidden)
+    assert rel(got[1], want_h) < TOL["float32"]["grad"]
+    for name in ("router", "experts_w_gate", "experts_w_up", "experts_w_down"):
+        assert rel(got[0][name], want_v[f"layer_1/moe/{name}"]) \
+            < TOL["float32"]["grad"], name
+    for name in ("w_gate", "w_up", "w_down"):
+        assert rel(got[0]["shared"][name], want_v[f"layer_1/moe/shared/{name}"]) \
+            < TOL["float32"]["grad"], name
+
+
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
+def test_compact_row_list_computes_what_the_full_width_computes(
+        weights, long_hidden, dtype):
+    """`add_routed`, the one function of a width, at the layer's two widths
+    and one between on the same sorted list (the ordinary routing holds
+    fewer slots than `cap`): output and every gradient equal to float32
+    rounding. In bfloat16 a sum that differs in its last float32 bit flips
+    the rounding of a few elements of `a` (4e-3 each): 1e-3 on a leaf."""
+    _, params = weights
+    p, x = params["layer_1"]["moe"], long_hidden.reshape(-1, 64)
+    idx, w = L.route(x, p["router"], p["bias"], LM)
+    gid = jnp.where((idx >= 2) & (idx < 4), idx - 2, 2).reshape(-1)
+    sizes = jnp.bincount(gid, length=3)[:2].astype(jnp.int32)
+    order = jnp.argsort(gid, stable=True)
+    cap = L.expert_row_cap(LM, x.shape[0])
+    assert int(sizes.sum()) <= cap < gid.size
+    experts = tuple(p[f"experts_w_{n}"] for n in ("gate", "up", "down"))
+    shared = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+
+    def at(rows):
+        f = lambda sh, xx, ww, ex: L.add_routed(  # noqa: E731
+            sh, xx, ww, order, sizes, ex, rows, DTYPES[dtype])
+        y, vjp = jax.vjp(f, shared, x, w, experts)
+        return [y, *jax.tree_util.tree_leaves(vjp(2.0 * y))]
+
+    full, tol = at(gid.size), {"float32": 1e-6, "bfloat16": 1e-3}[dtype]
+    for rows in (cap, cap + 512):
+        for a, b in zip(at(rows), full):
+            assert a.shape == b.shape and rel(a, b) < tol
 
 
 def uncut_weights():
@@ -182,10 +258,10 @@ def share_params(full: dict, first: int, held: int) -> dict:
 
 
 @pytest.mark.parametrize("held", [1, 2, 4, 8])
-def test_all_shares_add_up_to_the_uncut_layer(hidden, held):
+def test_all_shares_add_up_to_the_uncut_layer(long_hidden, held):
     """Over all shares of the small model, the routed parts summed with the
     shared expert counted once equal the uncut reference layer."""
-    full = uncut_weights()
+    full, hidden = uncut_weights(), long_hidden
     flat = {f"layer_1/moe/{k}": v for k, v in full.items()}
     c = as_dict(UNCUT)
     shared = jnp.stack([ref.swiglu(hidden[i], full["shared/w_gate"],
@@ -194,10 +270,18 @@ def test_all_shares_add_up_to_the_uncut_layer(hidden, held):
     total, slots = shared, 0.0
     for first in range(0, 8, held):
         lm = dataclasses.replace(LM, n_routed_experts=held, first_expert=first)
-        y, counters = L.MoE(lm).apply({"params": share_params(full, first, held)},
-                                      hidden)
+        apply = lambda h: L.MoE(lm).apply(  # noqa: E731
+            {"params": share_params(full, first, held)}, h)
+        y, counters = apply(hidden)
         total = total + (y - shared)
         slots += float(counters["moe_slots_held_share"])
+        # half or more of the router's experts held: the list is all the
+        # slots wide by shape, and the program holds no `cond`
+        one_width = L.expert_row_cap(lm, 1024) == 2048
+        assert one_width == (held >= 4)
+        assert ("cond[" in str(jax.make_jaxpr(apply)(hidden))) != one_width
+        if one_width:
+            assert float(counters["moe_full_width"]) == 1.0
     want = jnp.stack([ref.moe(flat, "layer_1", hidden[i], c) for i in range(2)])
     assert rel(total, want) < 2e-5
     assert slots == pytest.approx(1.0)  # every slot fell on exactly one share

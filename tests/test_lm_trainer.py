@@ -23,10 +23,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KANANA = os.path.join(ROOT, "benchmark", "configs", "kanana2_30b_a3b_ep8.json")
 
 
-def lm_cfg(tmp_path, **train):
+def lm_cfg(tmp_path, seq_len=32, **train):
     cfg = get_config("lm")
     return cfg.replace(
-        lm=dataclasses.replace(cfg.lm, attn_block_q=16, loss_block=16,
+        lm=dataclasses.replace(cfg.lm, attn_block_q=16, loss_block=16, seq_len=seq_len,
                                n_routed_experts=2, n_routed_experts_published=8),
         train=dataclasses.replace(cfg.train, log_dir=str(tmp_path), log_every=1,
                                   nan_guard=False, **train))
@@ -42,7 +42,9 @@ def one_device_mesh():
 def test_trainer_fits_logs_counters_checkpoints_and_restores(tmp_path):
     from deepof_tpu.train.loop import Trainer
 
-    cfg = lm_cfg(tmp_path)
+    # 2 rows of 512: 2048 token-slots, the shortest step whose expert
+    # layers hold both widths of the sorted list under a `cond`
+    cfg = lm_cfg(tmp_path, seq_len=512)
     trainer = Trainer(cfg, mesh=one_device_mesh())
     out = trainer.fit(max_steps=3)
     assert out["steps_per_sec"] >= 0
@@ -56,7 +58,12 @@ def test_trainer_fits_logs_counters_checkpoints_and_restores(tmp_path):
         assert all(0.0 <= v <= 1.0 for v in r["moe_slots_held_share"])
         assert all(v >= 1.0 for v in r["moe_load_max_over_mean"])
         assert all(0.0 <= v <= 1.0 for v in r["moe_tokens_none_held_share"])
+        assert r["moe_full_width"] == [0.0, 0.0]  # both expert layers ran the compact list
         assert "warp_sweep_rows_by_scale" not in r
+    routes, = [r for r in records if r.get("message") == "routes"]
+    assert routes["step"] == 0 and routes["attention_route"]["path"] == "xla_blocks"
+    # a row's share: 2 of 8 held, twice even, up to the grouped product's tile
+    assert routes["expert_rows"] == {"cap": 512, "slots": 1024}
     params = jax.device_get(trainer.state.params)
     again = Trainer(cfg, mesh=one_device_mesh())  # auto-resume from the final checkpoint
     assert int(again.state.step) == 3
