@@ -19,10 +19,12 @@ the configuration's file:
     while the weights are still normalised over all six chosen. Only
     `vocab_size` rows of embedding and head are held; ids, logits and loss
     are over them. `num_hidden_layers` is 5 of 48.
-  - `e_score_correction_bias` is a fixed buffer drawn from the seed (its
-    update rule is not in the config); attention crosses document
-    boundaries inside a packed row (there are none); initialisation normal,
-    sigma `init_std`, the embedding sigma `embed_std`.
+  - `e_score_correction_bias` is a fixed buffer (its update rule is not in
+    the config); attention crosses document boundaries inside a packed row
+    (there are none); initialisation normal, sigma `init_std`, the
+    embedding sigma `embed_std`, the buffer sigma `bias_std`: one base
+    draw from the configuration's `weights.base_key`, moved by the seed
+    (`make_leaf`).
   - so that one row's backward fits beside 16 bytes a parameter, the
     training steps (`make_trainer`) keep only each layer's input and take
     the attention `HEAD_BLOCK` heads at a time, each block's scores
@@ -109,11 +111,24 @@ def param_spec(c: dict) -> list[tuple[str, tuple, str]]:
 def make_leaf(c: dict, key, index: int, shape: tuple, kind: str):
     """One leaf from the seed's key and its place in `param_spec`: any leaf
     can be made again alone (the runner's tap and this file's `steps`
-    measure a leaf's change against a fresh copy, not a kept one)."""
+    measure a leaf's change against a fresh copy, not a kept one).
+
+    Where the configuration has `weights` (`base_key`, `seed_jitter` j) a
+    drawn leaf is std * (b + j*s) / sqrt(1 + j*j), b normal from the base
+    key, which the file fixes, and s normal from the seed's: its law is
+    N(0, std^2) as without, every seed moves every weight, and the routing,
+    which sets the expert layers' work, is the base draw's on every seed.
+    Without the entry the leaf is the seed's draw alone."""
     if kind == "ones":
         return jnp.ones(shape, F32)
     std = c[{"bias": "bias_std", "embed": "embed_std", "normal": "init_std"}[kind]]
-    return std * jax.random.normal(jax.random.fold_in(key, index), shape, F32)
+    draw = jax.random.normal(jax.random.fold_in(key, index), shape, F32)
+    w = c.get("weights")
+    if w:
+        j = float(w["seed_jitter"])
+        base = jax.random.fold_in(jax.random.PRNGKey(int(w["base_key"])), index)
+        draw = (jax.random.normal(base, shape, F32) + j * draw) / math.sqrt(1 + j * j)
+    return std * draw
 
 
 def make_params(c: dict, key) -> dict:
@@ -123,8 +138,9 @@ def make_params(c: dict, key) -> dict:
 
 
 def change_norms(c: dict, values: dict, key) -> dict:
-    """Per-leaf norm of `values` minus the seed's initial leaf, one jitted
-    call; no second copy of the parameters is held."""
+    """Per-leaf norm of `values` minus the initial leaf made again from the
+    seed's key (and the configuration's base key), one jitted call; no
+    second copy of the parameters is held."""
     return jax.jit(lambda v, k: {
         path: jnp.sqrt(jnp.sum(jnp.square(
             v[path].astype(F32) - make_leaf(c, k, i, shape, kind))))
@@ -360,7 +376,7 @@ def make_row_grad(c: dict, q=None):
 def make_trainer(c: dict, hp: dict, q=None):
     """`steps(values, key, batches) -> readings`: len(batches) Adam steps
     in float32, one row at a time, the mean taken over the rows. `key` is
-    the seed's key the values were made from."""
+    the seed's key that `make_params` made the values from."""
     row_grad = make_row_grad(c, q)
 
     def adam(values, m, vv, g, t):

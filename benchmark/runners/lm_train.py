@@ -2,9 +2,10 @@
 outside as `runners/train.py` drives the flow models (its window, its
 controller, its trace and its peak reading are used as they are).
 
-What differs: the data are rows of token ids (a pool made on the device
-from the seed, Zipf over the vocabulary held; a batch is neighbouring rows
-of it, a view); the weights and the plain reference come from
+What differs: the data are rows of token ids (a pool made on the device,
+Zipf over the vocabulary held; a batch is neighbouring rows of it, a view;
+from the seed, or, where the traffic's file has `window_data`, the checked
+steps' rows from the seed and the window's pool the same on every seed); the weights and the plain reference come from
 `benchmark/reference/<config>.py`'s own functions; the tap keeps each
 row's loss beside the total and measures the parameters' change against
 leaves made again from the seed, so that no second copy of the parameters
@@ -29,6 +30,7 @@ import numpy as np
 
 from ..harness import compare, lm_compare, scope_share, spans as span_tools
 from ..harness import trace_reduce, traffic as gen
+from ..harness.counters import window_means
 from . import train as base
 
 N_CHECK_STEPS = base.N_CHECK_STEPS
@@ -55,19 +57,28 @@ def token_pool(key, rows: int, seq_len: int, vocab: int, exponent: float):
 
 
 class PoolTokens:
-    """A pool of rows made from the seed; every batch is `batch_size`
-    neighbouring rows of it, the first drawn with the rng the program's
-    pipeline hands in, handed over as a view."""
+    """A pool of rows; every batch is `batch_size` neighbouring rows of it,
+    the first drawn with the rng the program's pipeline hands in, handed
+    over as a view. `first_rows`: rows that the first batches take in
+    turn, in the pool's place (the traffic's `window_data`: the steps that
+    `correct` follows see the seed's own rows, the window the same rows on
+    every seed; the rng is drawn from all the same, so that its stream
+    does not follow the seed either)."""
 
     mean = (0.0, 0.0, 0.0)
 
-    def __init__(self, rows: np.ndarray, epoch_pairs: int):
-        self.rows = rows
+    def __init__(self, rows: np.ndarray, epoch_pairs: int,
+                 first_rows: np.ndarray | None = None):
+        self.rows, self.first_rows, self.given = rows, first_rows, 0
         self.num_train, self.num_val = int(epoch_pairs), 0
 
     def sample_train(self, batch_size, iteration=None, rng=None, **_):
         rng = rng or np.random
         first = int(rng.randint(0, len(self.rows) - batch_size + 1))
+        if self.first_rows is not None and \
+                self.given + batch_size <= len(self.first_rows):
+            self.given += batch_size
+            return {"tokens": self.first_rows[self.given - batch_size:self.given]}
         return {"tokens": self.rows[first:first + batch_size]}
 
     def sample_val(self, batch_size, batch_id):
@@ -75,6 +86,12 @@ class PoolTokens:
 
     def cache_stats(self) -> dict:
         return {"hits": 0, "misses": 0, "evictions": 0, "bytes": 0, "entries": 0}
+
+
+def weights_key(ctx):
+    """The seed's key of the weights: the reference's `make_leaf` moves the
+    configuration's base draw by it (`weights` in the configuration's file)."""
+    return gen.jax_key(ctx.seed, 2)
 
 
 def flat_params(tree) -> dict:
@@ -167,10 +184,19 @@ def build_trainer(ctx, log_dir: str):
         "imports_s": time.perf_counter() - ctx.t_process_start}
     t = time.perf_counter()
     pcfg = program_config(ctx, log_dir)
-    pool = np.asarray(token_pool(gen.jax_key(ctx.seed, 1), tr["pool_rows"],
-                                 tr["seq_len"], c["vocab_size"],
-                                 tr["zipf_exponent"]))
-    ds = PoolTokens(pool, tr["epoch_pairs"])
+    rows_of = lambda seed, n: np.asarray(token_pool(  # noqa: E731
+        gen.jax_key(seed, 1), n, tr["seq_len"], c["vocab_size"],
+        tr["zipf_exponent"]))
+    fixed = tr.get("window_data")
+    if fixed:
+        # the window trains on one pool in one order on every seed (the
+        # order: `train.seed` in the traffic's `set`); the checked steps'
+        # rows are the seed's
+        ds = PoolTokens(rows_of(fixed["pool_seed"], tr["pool_rows"]),
+                        tr["epoch_pairs"], first_rows=rows_of(
+                            ctx.seed, N_CHECK_STEPS * tr["batch_per_chip"] * ctx.chips))
+    else:
+        ds = PoolTokens(rows_of(ctx.seed, tr["pool_rows"]), tr["epoch_pairs"])
     phases["pool_s"] = time.perf_counter() - t
     t = time.perf_counter()
     trainer = Trainer(pcfg, dataset=ds,
@@ -179,7 +205,7 @@ def build_trainer(ctx, log_dir: str):
     phases["trainer_init_s"] = time.perf_counter() - t
     t = time.perf_counter()
     ref = importlib.import_module("benchmark.reference." + c["reference"])
-    key = gen.jax_key(ctx.seed, 2)
+    key = weights_key(ctx)
     have = flat_params(trainer.state.params)
     spec = {path: shape for path, shape, _ in ref.param_spec(c)}
     if set(have) != set(spec) or any(tuple(have[k].shape) != spec[k] for k in have):
@@ -231,7 +257,7 @@ def run_reference(ctx, ref, batches, q=None) -> dict:
     if memo in _REFERENCE_READINGS:
         return _REFERENCE_READINGS[memo]
     t0 = time.perf_counter()
-    key = gen.jax_key(ctx.seed, 2)
+    key = weights_key(ctx)
     steps = ref.make_trainer(c, c["optim"], q=q)
     values = ref.make_params(c, key)
     t1 = time.perf_counter()
@@ -259,7 +285,7 @@ def choices_agree(ctx, ref, tokens) -> float | None:
     from deepof_tpu.models.registry import model_for
 
     c = ctx.config  # the reference reads the file the program's `lm` section is filled from
-    values = ref.make_params(c, gen.jax_key(ctx.seed, 2))
+    values = ref.make_params(c, weights_key(ctx))
     row = jnp.asarray(tokens[0])
     want = jax.jit(lambda v, r: ref.chosen_experts(v, r, c))(values, row)
     model = model_for(program_config(ctx, "unused"))
@@ -276,9 +302,12 @@ def choices_agree(ctx, ref, tokens) -> float | None:
     return float(sum(same) / len(same))
 
 
-def run(ctx, step_fault=None, also=None, agree: bool | None = None) -> dict:
+def run(ctx, step_fault=None, also=None, agree: bool | None = None,
+        reference: bool = True) -> dict:
     """`step_fault(tap, trainer)`: tests and the calibration plant a fault
-    under the tap; None in a run. `also(ctx, ref, batches,
+    under the tap; None in a run. `reference=False`: the window alone, no
+    number compared and so never `correct` (`tools/routing_by_base_key.py`
+    reads the routing of many draws in one process). `also(ctx, ref, batches,
     reference_readings, program_readings) -> dict`: the calibration's
     further readings on the same batches; None in a run. `agree`: also
     report `router_choices_agree` (two more whole-row compiles, half a
@@ -311,8 +340,8 @@ def run(ctx, step_fault=None, also=None, agree: bool | None = None) -> dict:
             inner_log(kind, step, **kw)
             if kind == "train":
                 now = time.perf_counter()
-                records.append((now, {k: v for k, v in kw.items()
-                                      if k.startswith("moe_")}))
+                records.append((now, int(step), {k: v for k, v in kw.items()
+                                                 if k.startswith("moe_")}))
                 ctl.on_record(int(step), now)
 
         trainer.logger.log = log
@@ -364,11 +393,11 @@ def run(ctx, step_fault=None, also=None, agree: bool | None = None) -> dict:
                 "train_pairs_per_s": steps * batch / window_s / ctx.chips,
                 "setup_s": ctl.t0 - ctx.t_process_start,
             }
-        in_window = [r for t, r in records
-                     if windowed and ctl.t0 < t <= ctl.t1]
+        inside = lambda t: windowed and ctl.t0 < t <= ctl.t1  # noqa: E731
+        in_window = [r for t, _, r in records if inside(t)]
         t_ref = time.perf_counter()
         numbers, extra_readings, reference_s = {}, {}, 0.0
-        if prog is not None and fit_error is None:
+        if reference and prog is not None and fit_error is None:
             with compiles_not_kept():
                 refr = run_reference(ctx, ref, batches)
                 numbers = lm_compare.train_numbers(prog, refr)
@@ -385,10 +414,6 @@ def run(ctx, step_fault=None, also=None, agree: bool | None = None) -> dict:
         limits = dict(ctx.cell["limits"])
         limits.update({"rows_distinct": 0.0, "window_closed": 0.0})
         correct, compared = compare.judge(numbers, limits)
-        mean = lambda key: (  # noqa: E731
-            [sum(r[key][i] for r in in_window) / len(in_window)
-             for i in range(len(in_window[0][key]))]
-            if in_window and key in in_window[0] else None)
         out = {
             "correct": correct, "attempted": steps, "failed": 0,
             "end_to_end": end_to_end, "compared": compared,
@@ -396,10 +421,8 @@ def run(ctx, step_fault=None, also=None, agree: bool | None = None) -> dict:
             "extra": {"window_s": window_s, "steps": steps,
                       "tokens_per_s": (steps * batch * tr["seq_len"] / window_s
                                        / ctx.chips) if windowed else None,
-                      "moe_slots_held_share": mean("moe_slots_held_share"),
-                      "moe_load_max_over_mean": mean("moe_load_max_over_mean"),
-                      "moe_tokens_none_held_share": mean(
-                          "moe_tokens_none_held_share"),
+                      # every `moe_*` counter of the window's records
+                      **window_means(in_window),
                       "reference_s": reference_s,
                       "teardown_s": t_fit_end - (ctl.t1 or t_fit_end),
                       "worst_leaf": where, "fit_error": fit_error,
@@ -407,6 +430,9 @@ def run(ctx, step_fault=None, also=None, agree: bool | None = None) -> dict:
                       "numbers": numbers,
                       **extra_readings},
         }
+        # every train record of the run, the window's marked: for the tools
+        out["records"] = [{"step": s, "in_window": inside(t), **r}
+                          for t, s, r in records]
         if ctx.trace:
             out.update(observe(ctx, ctl, marks, trace_dir, host_spans, batch,
                                scopes, in_window))

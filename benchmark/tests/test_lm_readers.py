@@ -90,6 +90,25 @@ def test_moe_load_counter_is_the_mean_over_records_and_layers():
     assert read("moe_load_max_over_mean.lm_train") is None  # the parent's line
 
 
+ROUTING = [{"moe_full_width": [0.0, 0.0, 1.0], "moe_slots_held_share": [0.10, 0.30, 0.20]},
+           {"moe_full_width": [0.0, 1.0, 1.0], "moe_slots_held_share": [0.12, 0.10, 0.20]}]
+
+
+@pytest.mark.parametrize("metric, want", [
+    ("moe_full_width_share.lm_train", 100.0),   # the third layer, both records
+    ("moe_slots_held_pct.lm_train", 20.0)])     # a layer's MEAN: not the 0.30
+def test_routing_readers_take_the_largest_window_mean_over_the_layers(metric, want):
+    assert read(metric, records=ROUTING) == pytest.approx(want)
+    assert read(metric, records=[{}]) is None   # a program without the counter
+    assert read(metric, records=[]) is None
+    assert read(metric) is None                 # a run that kept no records
+
+
+def test_full_width_share_reads_nought_where_no_layer_left_its_cap():
+    records = [{"moe_full_width": [0.0, 0.0]}, {"moe_full_width": [0.0, 0.0]}]
+    assert read("moe_full_width_share.lm_train", records=records) == 0.0
+
+
 def test_input_wait_first_step_and_mfu_readers():
     spans = [("first_step", M, 1.0, 8.5), ("input_wait", M, 9.0, 12.0),
              ("input_wait", P, 0.0, 100.0), ("dispatch", M, 12.0, 20.0)]

@@ -34,7 +34,22 @@ def test_sound_run_is_correct_and_counts_steps():
     assert out["extra"]["tokens_per_s"] == pytest.approx(steps * 2 * 32 / window)
     assert out["end_to_end"]["setup_s"] > 0
     assert out["extra"]["router_choices_agree"] == 1.0
-    assert len(out["extra"]["moe_slots_held_share"]) == 2  # two expert layers
+    # the window's mean of every `moe_*` counter of the program's records,
+    # one value an expert layer, none of them named by the runner
+    from deepof_tpu.models.lm.model import COUNTERS
+    assert "moe_full_width" in COUNTERS
+    for name in COUNTERS:
+        assert len(out["extra"][name]) == 2, name  # two expert layers
+    assert all(0.0 <= x <= 1.0 for x in out["extra"]["moe_slots_held_share"])
+
+
+def test_without_the_reference_a_run_is_never_correct():
+    ctx = toy_lm_context(CELL)
+    ctx.cell["limits"] = dict(TOY_LIMITS)
+    out = runner.run(ctx, reference=False)
+    assert not out["correct"] and out["attempted"] > 0
+    assert out["compared"]["loss_gap"]["value"] is None
+    assert out["compared"]["window_closed"]["value"] == 0.0
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
@@ -47,13 +62,43 @@ def test_planted_fault_is_not_correct(fault):
 
 def test_equal_rows_fail_rows_distinct():
     def equal_rows(tap, trainer):
-        rows = trainer.dataset.rows.copy()
+        rows = trainer.dataset.first_rows.copy()  # what the checked steps take
         rows[1::2] = rows[0::2]
-        trainer.dataset.rows = rows
+        trainer.dataset.first_rows = rows
 
     out = run_cell(fault=equal_rows)
     assert not out["correct"]
     assert out["compared"]["rows_distinct"]["value"] == 1.0
+
+
+def test_window_data_is_one_on_every_seed_and_the_checked_rows_are_the_seeds():
+    """`window_data` in the traffic's file: the first batches are the
+    seed's rows, whole and in turn; every later one comes from the one pool
+    by the rng's draw, which is drawn for the first batches too."""
+    pool = np.arange(8 * 3).reshape(8, 3)
+    seen = {}
+    for seed in (1, 2):
+        ds = runner.PoolTokens(pool, 100, first_rows=np.full((6, 3), -seed))
+        rng = np.random.RandomState(7)  # the traffic fixes `train.seed`
+        seen[seed] = [ds.sample_train(2, rng=rng)["tokens"] for _ in range(6)]
+        assert all((b == -seed).all() for b in seen[seed][:3])
+    for a, b in zip(seen[1][3:], seen[2][3:]):
+        np.testing.assert_array_equal(a, b)
+    # the pool alone, the same stream: its fourth batch on is the same
+    plain = runner.PoolTokens(pool, 100)
+    rng = np.random.RandomState(7)
+    alone = [plain.sample_train(2, rng=rng)["tokens"] for _ in range(6)]
+    for got, want in zip(alone[3:], seen[1][3:]):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_the_cells_traffic_fixes_the_windows_data():
+    ctx = toy_lm_context(CELL, seed=11)
+    assert ctx.traffic["window_data"]["pool_seed"] > 0
+    assert "train.seed" in ctx.traffic["set"]  # the order of the batches
+    cfg_a = runner.program_config(ctx, "unused")
+    cfg_b = runner.program_config(toy_lm_context(CELL, seed=12), "unused")
+    assert cfg_a.train.seed == cfg_b.train.seed == ctx.traffic["set"]["train.seed"]
 
 
 def test_control_put_in_the_programs_place_is_not_correct():
