@@ -870,24 +870,39 @@ class RecipeConfig:
 
 @dataclass(frozen=True)
 class LMConfig:
-    """A decoder-only language model of the latent-attention / sparse-expert
-    family (`models/lm/`), under the keys of the model's own public
-    `config.json` (`model_type: deepseek_v3`) and with their meaning.
+    """A decoder-only language model of one of the TWO families `models/lm/`
+    writes, under the keys of the model's own public `config.json` and with
+    their meaning. `model_type` names the family:
+
+      - `deepseek_v3`: latent attention (`kv_lora_rank`, `qk_nope_head_dim`,
+        `qk_rope_head_dim`, `v_head_dim`), sigmoid-routed experts with
+        shared ones after `first_k_dense_replace` dense layers, next-token
+        loss;
+      - `sdar_moe`: grouped-query attention (`num_key_value_heads`,
+        `head_dim`, per-head norms), softmax-routed experts with no shared
+        one on every `decoder_sparse_step`-th layer not in
+        `mlp_only_layers`, trained by diffusion over blocks of
+        `block_length` positions.
+
     `config_file` names a JSON file of that shape: `fill_lm_from_file`
     copies every key of the file that is a field here (the file may hold
-    more: a benchmark configuration keeps its notes beside the sizes).
+    more: a benchmark configuration keeps its notes beside the sizes), and
+    gives the keys a family's config.json does not write the values its
+    modeling code fixes (`LM_FAMILY_FIXED`).
 
     The chip's share of an expert-parallel deployment: `n_routed_experts`
-    counts the experts HELD here, `n_routed_experts_published` is the
-    router's width (0: all are held) and `first_expert` the index of the
-    first one held. The layer scores and chooses over all of them,
-    normalises over every chosen one and adds only what its own experts
-    give (`models/lm/layers.py::MoE`). `vocab_size` is the rows of the
+    (`num_experts` in an `sdar_moe` file: ONE field) counts the experts
+    HELD here, `n_routed_experts_published` is the router's width (0: all
+    are held) and `first_expert` the index of the first one held. The
+    layer scores and chooses over all of them, normalises over every
+    chosen one and adds only what its own experts give
+    (`models/lm/layers.py::MoE`). `vocab_size` is the rows of the
     embedding and of the head held here; ids, logits and loss are over them.
     """
 
     config_file: str = ""
     # --- published keys (defaults: a toy of the same shape, for tests) ---
+    model_type: str = "deepseek_v3"
     vocab_size: int = 256
     hidden_size: int = 64
     intermediate_size: int = 128  # the leading dense layers' SwiGLU width
@@ -918,6 +933,12 @@ class LMConfig:
     attention_bias: bool = False
     tie_word_embeddings: bool = False
     max_position_embeddings: int = 32768
+    # published by `sdar_moe` (a `deepseek_v3` file's are read by nothing)
+    num_key_value_heads: int = 2
+    head_dim: int = 16
+    decoder_sparse_step: int = 1
+    mlp_only_layers: tuple = ()
+    use_sliding_window: bool = False
     # --- the share held here ---
     n_routed_experts_published: int = 0
     first_expert: int = 0
@@ -933,17 +954,56 @@ class LMConfig:
     # kernels' query block on a TPU, `ops/attention.py`); scores never exist
     # for more than one block, and the backward recomputes them
     loss_block: int = 2048  # positions a block of the head and the loss
+    # ... of training by diffusion over blocks (arXiv:2503.09573; neither
+    # is in an `sdar_moe` config.json): positions a block; the id a noised
+    # position shows (None: the family has none; the token dataset never
+    # draws it); a block's share of noised positions is U(t_lo, t_hi)
+    block_length: int = 4
+    mask_token_id: int | None = None
+    noise_t_lo: float = 0.45
+    noise_t_hi: float = 0.95
+
+
+#: What a family's config.json does not write because its modeling code
+#: fixes it, under the names the other family publishes it by: the router
+#: (`sdar_moe`: softmax over all experts, the k largest, no bias buffer, no
+#: scaling), no shared expert, no leading dense layer, rotary channel j
+#: against j + head_dim / 2.
+LM_FAMILY_FIXED: dict[str, dict] = {
+    "deepseek_v3": {},
+    "sdar_moe": dict(scoring_func="softmax", topk_method="greedy",
+                     n_shared_experts=0, first_k_dense_replace=0,
+                     moe_layer_freq=1, routed_scaling_factor=1.0,
+                     rope_interleave=False),
+}
+#: one field, two published names
+_LM_KEY_ALIASES = {"num_experts": "n_routed_experts"}
+
+
+def lm_family_config(model_type: str, lm: LMConfig | None = None,
+                     **keys) -> LMConfig:
+    """`lm` (a toy where None) as a model of family `model_type`: what the
+    family fixes, then `keys`. An unknown family is refused by name."""
+    if model_type not in LM_FAMILY_FIXED:
+        raise ValueError(f"lm.model_type={model_type!r} is not a family "
+                         f"models/lm writes: {sorted(LM_FAMILY_FIXED)}")
+    return dataclasses.replace(lm or LMConfig(), model_type=model_type,
+                               **{**LM_FAMILY_FIXED[model_type], **keys})
 
 
 def fill_lm_from_file(lm: LMConfig, path: str) -> LMConfig:
-    """`lm` with every key of the JSON file at `path` that names a field."""
+    """`lm` with every key of the JSON file at `path` that names a field,
+    as a model of the file's `model_type`."""
     import json
 
     with open(path) as f:
         d = json.load(f)
+    d = {_LM_KEY_ALIASES.get(k, k): v for k, v in d.items()}
     names = {f.name for f in dataclasses.fields(LMConfig)} - {"config_file"}
-    return dataclasses.replace(
-        lm, config_file=path, **{k: v for k, v in d.items() if k in names})
+    keys = {k: tuple(v) if isinstance(v, list) else v
+            for k, v in d.items() if k in names}
+    return lm_family_config(keys.pop("model_type", lm.model_type), lm,
+                            config_file=path, **keys)
 
 
 @dataclass(frozen=True)
@@ -1042,8 +1102,9 @@ UCF101 = ExperimentConfig(
 )
 
 
-# A language model (`models/lm/`): next-token cross-entropy on rows of
-# `lm.seq_len` + 1 ids. The sizes come from `--set lm.config_file=FILE`
+# A language model (`models/lm/`, two families: `lm.model_type`) on rows of
+# `lm.seq_len` + 1 ids, by the family's own objective (next-token
+# cross-entropy, or diffusion over blocks). The sizes come from `--set lm.config_file=FILE`
 # (a JSON file of the public config.json's shape) or `--set lm.<key>=...`;
 # the defaults are a toy. Constant learning rate, no clipping: what a
 # config.json does not give is the job's to set.

@@ -712,7 +712,8 @@ class TokenData:
     """Rows of `seq_len + 1` token ids for a language model, made from a
     fixed seed: `POOL_ROWS` train rows, ids drawn from a Zipf law of
     exponent `ZIPF_EXPONENT` over the vocabulary held (frequent ids repeat,
-    as on text), packed with no padding. A batch is {"tokens": int32[b, seq_len + 1]}: position t's
+    as on text) less `lm.mask_token_id`, which the data never draws where
+    the family has one, packed with no padding. A batch is {"tokens": int32[b, seq_len + 1]}: position t's
     target is position t + 1's id. Same protocol as the frame datasets, so
     the pipeline, the staging and the `put` are theirs; `mean` is unused."""
 
@@ -723,13 +724,16 @@ class TokenData:
     def __init__(self, cfg: DataConfig, lm, num_val: int = 16, seed: int = 0):
         self.cfg = cfg
         self.num_train, self.num_val = self.POOL_ROWS, num_val
-        p = 1.0 / np.arange(1, lm.vocab_size + 1,
-                            dtype=np.float64) ** self.ZIPF_EXPONENT
+        mask_id = getattr(lm, "mask_token_id", None)
+        drawn = lm.vocab_size - (mask_id is not None)
+        p = 1.0 / np.arange(1, drawn + 1, dtype=np.float64) ** self.ZIPF_EXPONENT
         cdf = np.cumsum(p / p.sum())
         u = np.random.RandomState(seed).random_sample(
             (self.num_train + num_val, lm.seq_len + 1))
-        self.rows = np.minimum(np.searchsorted(cdf, u), lm.vocab_size - 1
-                               ).astype(np.int32)
+        rows = np.minimum(np.searchsorted(cdf, u), drawn - 1)
+        if mask_id is not None:  # the ids from the mask's on move up by one
+            rows = rows + (rows >= mask_id)
+        self.rows = rows.astype(np.int32)
 
     def sample_train(self, batch_size, iteration=None, rng=None):
         if iteration is not None:

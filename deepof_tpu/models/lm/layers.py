@@ -1,5 +1,8 @@
-"""The layers of the latent-attention / sparse-expert family
-(`model_type: deepseek_v3`), by their equations.
+"""The layers of the TWO language-model families `models/lm/` writes, by
+their equations. Each family's are in one place: the attention layer's
+class (`MLA`: `model_type: deepseek_v3`; `GQA`: `model_type: sdar_moe`),
+`route` (both routers) and `MoE` (ONE expert layer, told by the config
+which scoring it uses and whether a shared expert exists).
 
 Precision: norm statistics, rotary angles, router scores, softmax and loss
 in float32; every other matrix product takes operands in the compute dtype
@@ -10,11 +13,13 @@ paths (fused kernels on a TPU, XLA blocks elsewhere).
 
 Scopes (what the per-layer readers find in a profile; flax names a
 module's scope after the module, the rest are `jax.named_scope`s):
-`layer_<i>` > `mla` > `mla_proj`, `mla_scores`, `mla_out`; `dense_ffn`;
-`moe` > `moe_route`, `moe_dispatch`, `moe_experts`, `moe_shared`,
-`moe_combine`; beside them `embed`, `lm_head`, `loss_ce`, `optimizer`.
-On the chip `mla_scores` holds the Mosaic calls `mla_attn_fwd` and, under
-`transpose(jvp(...))`, `mla_attn_bwd`, with the layout copies around them.
+`layer_<i>` > `mla` > `mla_proj`, `mla_scores`, `mla_out` or `gqa` >
+`gqa_proj`, `gqa_scores`, `gqa_out`; `dense_ffn`; `moe` > `moe_route`,
+`moe_dispatch`, `moe_experts`, `moe_shared`, `moe_combine`; beside them
+`embed`, `bd_noise`, `lm_head`, `loss_ce`, `optimizer`. On the chip
+`mla_scores` holds the Mosaic calls `mla_attn_fwd` and, under
+`transpose(jvp(...))`, `mla_attn_bwd`, with the layout copies around them;
+`gqa_scores` the calls `bd_attn_fwd` and `bd_attn_bwd`.
 """
 
 from __future__ import annotations
@@ -29,7 +34,7 @@ from flax import linen as nn
 from jax import lax
 
 from ...core.config import LMConfig
-from ...ops.attention import causal_attention
+from ...ops.attention import CAUSAL, Mask, causal_attention, grouped_attention
 
 F32 = jnp.float32
 ragged_dot = lax.ragged_dot  # a name of this module's: a test stands in for the chip's
@@ -69,15 +74,45 @@ def rope(x, theta: float):
                      axis=-1).reshape(x.shape)
 
 
+def rope_halves(x, theta: float, positions):
+    """Rotary positions on x[b, s, h, d], float32, at `positions`[s];
+    channel j is rotated against channel j + d / 2 (`rope_interleave`
+    false: the `sdar_moe` family's own)."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
+    ang = positions.astype(F32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x = x.astype(F32)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
 class MLA(nn.Module):
-    """Latent attention without query compression (`q_lora_rank` null)."""
+    """Latent attention without query compression (`q_lora_rank` null),
+    always under the causal rule:
+    `q = h Wq` -> [heads, nope + rope]; `ckv = h Wkva` -> [latent + rope];
+    `k_rope = rope(ckv[latent:])`, one head's, shared by all; `kv =
+    RMSNorm(ckv[:latent]) Wkvb` -> [heads, nope + v]; scores `scale *
+    (q_nope . k_nope + rope(q_rope) . k_rope)`, scale 1/sqrt(nope + rope),
+    rotary pairs interleaved; softmax over the visible keys, times v,
+    then `Wo`."""
 
     cfg: LMConfig
     dtype: Any = F32
+    mask: Mask = CAUSAL
+
+    @staticmethod
+    def route_dims(c: LMConfig) -> tuple:
+        """The head sizes `ops/attention.py::attention_route` decides on."""
+        return c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
 
     @nn.compact
     def __call__(self, h):
         c, dt = self.cfg, self.dtype
+        if self.mask != CAUSAL:
+            raise NotImplementedError(
+                "models/lm: latent attention is written under the causal "
+                f"mask only, not {self.mask.rule!r}")
         if c.q_lora_rank is not None or not c.rope_interleave:
             raise NotImplementedError(
                 "models/lm: only uncompressed queries (q_lora_rank null) "
@@ -109,6 +144,59 @@ class MLA(nn.Module):
             return dot(o.reshape(b, s, nh * dv), wo, dt)
 
 
+class GQA(nn.Module):
+    """Grouped-query attention with per-head norms (`model_type:
+    sdar_moe`): `q = h Wq` -> [heads, head_dim]; `k = h Wk`, `v = h Wv` ->
+    [kv heads, head_dim]; `q <- RMSNorm(q)`, `k <- RMSNorm(k)` over the
+    head's channels with one learned scale each a layer; rotary positions
+    over the whole head (channel j against j + head_dim / 2) at the
+    position's index inside its copy of the row (`Mask.rope_positions`);
+    query head n reads key/value head n // (heads / kv heads); scores
+    scaled by 1/sqrt(head_dim), masked by `mask`'s rule, softmax over the
+    visible keys, times v, then `Wo`."""
+
+    cfg: LMConfig
+    dtype: Any = F32
+    mask: Mask = CAUSAL
+
+    @staticmethod
+    def route_dims(c: LMConfig) -> tuple:
+        """As `MLA.route_dims`: one head size, no separate rotary part."""
+        return c.head_dim, 0, c.head_dim
+
+    @nn.compact
+    def __call__(self, h):
+        c, dt = self.cfg, self.dtype
+        if c.rope_interleave or c.use_sliding_window:
+            raise NotImplementedError(
+                "models/lm: grouped-query attention is written with rotary "
+                "halves (rope_interleave false) and no sliding window "
+                "(use_sliding_window false)")
+        b, s, d = h.shape
+        nh, g, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        if nh % g:
+            raise ValueError(f"lm: {g} key/value heads do not divide {nh} heads")
+        init = _init(c)
+        wq = self.param("wq", init, (d, nh * hd), F32)
+        wk = self.param("wk", init, (d, g * hd), F32)
+        wv = self.param("wv", init, (d, g * hd), F32)
+        wo = self.param("wo", init, (nh * hd, d), F32)
+        with jax.named_scope("gqa_proj"):
+            pos = self.mask.rope_positions(s)
+            q = RMSNorm(c.rms_norm_eps, name="q_norm")(
+                dot(h, wq, dt).reshape(b, s, nh, hd))
+            k = RMSNorm(c.rms_norm_eps, name="k_norm")(
+                dot(h, wk, dt).reshape(b, s, g, hd))
+            q = rope_halves(q, c.rope_theta, pos).astype(dt)
+            k = rope_halves(k, c.rope_theta, pos).astype(dt)
+            v = dot(h, wv, dt).reshape(b, s, g, hd).astype(dt)
+        with jax.named_scope("gqa_scores"):
+            o = grouped_attention(q, k, v, 1.0 / math.sqrt(hd),
+                                  c.attn_block_q, dt, self.mask)
+        with jax.named_scope("gqa_out"):
+            return dot(o.reshape(b, s, nh * hd), wo, dt)
+
+
 def swiglu(h, w_gate, w_up, w_down, dtype):
     a = jax.nn.silu(dot(h, w_gate, dtype)) * dot(h, w_up, dtype)
     return dot(a, w_down, dtype)
@@ -130,18 +218,31 @@ class SwiGLU(nn.Module):
 
 def route(h, router, bias, cfg: LMConfig):
     """(chosen expert ids [t, k], their weights [t, k]) over ALL the
-    router's experts, float32. Sigmoid scores; the k largest of score +
-    bias are chosen (ties: the lower id), the bias is a buffer and the
-    gradient does not reach it; the weights are the scores without it,
-    divided by their sum over all k chosen, times the scaling factor."""
-    if (cfg.scoring_func != "sigmoid" or cfg.topk_method != "noaux_tc"
-            or cfg.n_group != 1 or cfg.topk_group != 1):
+    router's experts, float32, by the family's own router:
+
+      - `scoring_func=sigmoid`, `topk_method=noaux_tc`: sigmoid scores; the
+        k largest of score + bias are chosen, the bias is a buffer and the
+        gradient does not reach it; the weights are the scores without it;
+      - `scoring_func=softmax`, `topk_method=greedy`: `p = softmax(h Wr)`
+        over all experts; the k largest are chosen; no buffer (`bias` None).
+
+    Ties: the lower id. With `norm_topk_prob` the weights are divided by
+    their sum over all k chosen; then times `routed_scaling_factor`."""
+    kind = (cfg.scoring_func, cfg.topk_method)
+    if kind not in (("sigmoid", "noaux_tc"), ("softmax", "greedy")) \
+            or cfg.n_group != 1 or cfg.topk_group != 1:
         raise NotImplementedError(
-            "models/lm: the router written here is scoring_func=sigmoid, "
-            "topk_method=noaux_tc with n_group = topk_group = 1")
-    scores = jax.nn.sigmoid(jnp.dot(h.astype(F32), router,
-                                    precision=lax.Precision.HIGHEST))
-    _, idx = lax.top_k(scores + lax.stop_gradient(bias), cfg.num_experts_per_tok)
+            "models/lm: the routers written here are scoring_func=sigmoid "
+            "with topk_method=noaux_tc and scoring_func=softmax with "
+            f"topk_method=greedy, n_group = topk_group = 1; not {kind}")
+    logits = jnp.dot(h.astype(F32), router, precision=lax.Precision.HIGHEST)
+    if cfg.scoring_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = lax.top_k(scores + lax.stop_gradient(bias),
+                           cfg.num_experts_per_tok)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, idx = lax.top_k(scores, cfg.num_experts_per_tok)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if cfg.norm_topk_prob:
         w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
@@ -262,7 +363,8 @@ class MoE(nn.Module):
     holds experts `first_expert .. first_expert + n_routed_experts` of the
     router's `n_routed_experts_published`. It scores, chooses and
     normalises over all of them and adds only the products of the chosen
-    experts it holds, plus the whole shared expert; what absent experts
+    experts it holds, plus the whole shared expert where the family has
+    one (`n_shared_experts`; none: nothing is built); what absent experts
     would add is left out (their chips add it, after the exchange that one
     chip does not have). No capacity in the model's sense: the token-slots
     are sorted by expert and each projection is one grouped product over
@@ -290,7 +392,9 @@ class MoE(nn.Module):
                              f"{c.first_expert + held} are not among {width}")
         init = _init(c)
         router = self.param("router", init, (d, width), F32)
-        bias = self.param("bias", nn.initializers.normal(c.bias_std), (width,), F32)
+        # the sigmoid router's buffer; the softmax router has none
+        bias = self.param("bias", nn.initializers.normal(c.bias_std),
+                          (width,), F32) if c.topk_method == "noaux_tc" else None
         w_gate = self.param("experts_w_gate", init, (held, d, we), F32)
         w_up = self.param("experts_w_up", init, (held, d, we), F32)
         w_down = self.param("experts_w_down", init, (held, we, d), F32)
@@ -307,7 +411,8 @@ class MoE(nn.Module):
         with jax.named_scope("moe_dispatch"):
             order = jnp.argsort(gid, stable=True)
         with jax.named_scope("moe_shared"):
-            shared = SwiGLU(c, c.n_shared_experts * we, dt, name="shared")(x)
+            shared = SwiGLU(c, c.n_shared_experts * we, dt, name="shared")(x) \
+                if c.n_shared_experts else jnp.zeros((t, d), F32)
         args = (shared, x, w, order, sizes, (w_gate, w_up, w_down))
         cap = expert_row_cap(c, t)
         if cap < t * k:

@@ -1,8 +1,27 @@
-"""`LatentMoELM`: embedding, `num_hidden_layers` blocks (latent attention
-+ a dense SwiGLU in the first `first_k_dense_replace`, the expert layer in
-the rest), a final RMSNorm and an untied head; next-token cross-entropy
-taken in blocks of positions so that the logits of a whole batch never
-exist at once."""
+"""The two language-model families, one trunk (`MoELM`): embedding,
+`num_hidden_layers` blocks (the family's attention layer + a dense SwiGLU
+or the expert layer), a final RMSNorm and an untied head; the loss taken
+in blocks of positions so that the logits of a whole batch never exist at
+once. What differs is declared on the family's class, and nothing outside
+`models/registry.py` asks for a family by name:
+
+  - `LatentMoELM` (`model_type: deepseek_v3`): latent attention under the
+    causal mask; objective `next_token`: position t's logits against
+    position t + 1's id, the mean over a row's positions.
+  - `BlockDiffusionMoELM` (`model_type: sdar_moe`): grouped-query
+    attention; objective `block_diffusion` (arXiv:2503.09573, the form an
+    autoregressive model is adapted with). A row `x0` of L ids (the first
+    `seq_len` of the batch's `seq_len + 1`), blocks of B = `block_length`
+    positions, b(p) = p // B. For each block one `t_b ~ U(noise_t_lo,
+    noise_t_hi)`, for each position `m_p ~ Bernoulli(t_b(p))`; the noised
+    copy `xt_p = mask_token_id if m_p else x0_p`. The layers run over the
+    2L positions `[xt ; x0]` under the `block_diffusion` mask of
+    `ops/attention.py`, rotary positions counted inside each copy. Logits
+    are taken from the noised half only, each position's own logits
+    against its own id (no shift), and the row's loss is
+    `(1/L) sum_p m_p (1/t_b(p)) (-log softmax(z_p)[x0_p])`. A masked
+    position is one with `m_p = 1`, not one whose id is the mask's.
+"""
 
 from __future__ import annotations
 
@@ -13,8 +32,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ...core.config import LMConfig
-from ...ops.attention import RESIDUALS, attention_route
-from .layers import (F32, MLA, MoE, RMSNorm, SwiGLU, _init, dot,
+from ...ops.attention import CAUSAL, RESIDUALS, Mask, attention_route
+from .layers import (F32, GQA, MLA, MoE, RMSNorm, SwiGLU, _init, dot,
                      expert_row_cap)
 
 COUNTERS = ("moe_slots_held_share", "moe_load_max_over_mean",
@@ -22,19 +41,27 @@ COUNTERS = ("moe_slots_held_share", "moe_load_max_over_mean",
 
 
 def is_expert_layer(cfg: LMConfig, i: int) -> bool:
-    return i >= cfg.first_k_dense_replace and i % cfg.moe_layer_freq == 0
+    """Both families' published rules at once (each leaves the other's
+    keys at the values that say "every layer")."""
+    return (i >= cfg.first_k_dense_replace and i % cfg.moe_layer_freq == 0
+            and i not in cfg.mlp_only_layers
+            and (i + 1) % cfg.decoder_sparse_step == 0)
 
 
 class Block(nn.Module):
     cfg: LMConfig
     expert: bool
     dtype: Any = F32
+    attention: Any = MLA  # the family's attention layer
+    mask: Mask = CAUSAL
 
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        # flax scopes a module's call by its name: `layer_<i>`, `mla`, `moe`
-        x = x + MLA(c, self.dtype, name="mla")(
+        # flax scopes a module's call by its name: `layer_<i>`, `mla` or
+        # `gqa`, `moe`
+        x = x + self.attention(c, self.dtype, self.mask,
+                               name=self.attention.__name__.lower())(
             RMSNorm(c.rms_norm_eps, name="attn_norm")(x))
         h = RMSNorm(c.rms_norm_eps, name="ffn_norm")(x)
         if not self.expert:
@@ -45,54 +72,68 @@ class Block(nn.Module):
         return x + y, counters
 
 
-def cross_entropy_rows(h, kernel, targets, block: int, dtype):
-    """Mean over a row's positions of -log softmax(h W)[target], float32,
-    [b]. Blocks of `block` positions; the backward recomputes a block's
-    logits."""
+def cross_entropy_rows(h, kernel, targets, block: int, dtype, weights=None):
+    """Mean over a row's positions of weight * -log softmax(h W)[target]
+    (`weights` None: every weight 1), float32, [b]. Blocks of `block`
+    positions; the backward recomputes a block's logits."""
     b, s, d = h.shape
     blk = min(block, s)
     if s % blk:
         raise ValueError(f"lm.loss_block={block} does not divide {s} positions")
 
     @jax.checkpoint
-    def one(hb, tb):
+    def one(hb, tb, wb):
         with jax.named_scope("lm_head"):
             logits = dot(hb, kernel, dtype)
         with jax.named_scope("loss_ce"):
             lse = jax.nn.logsumexp(logits, axis=-1)
             hit = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
-            return jnp.sum(lse - hit, axis=-1)
+            nll = lse - hit
+            return jnp.sum(nll if wb is None else wb * nll, axis=-1)
 
     total = jnp.zeros((b,), F32)
     for p0 in range(0, s, blk):
-        total = total + one(h[:, p0:p0 + blk], targets[:, p0:p0 + blk])
+        total = total + one(h[:, p0:p0 + blk], targets[:, p0:p0 + blk],
+                            None if weights is None else weights[:, p0:p0 + blk])
     return total / s
 
 
-class LatentMoELM(nn.Module):
+class MoELM(nn.Module):
+    """The trunk both families share. A family's class declares
+    `model_type`, `objective`, `attention` and, as methods, `mask(s)` (the
+    rule for a row of `s` positions as the layers see it), `loss_positions`
+    (how many of them, from the first, bear logits) and `loss(tokens)`."""
+
     cfg: LMConfig = LMConfig()
     dtype: Any = F32
     remat: bool = False  # recompute each block in the backward
 
     task = "lm"  # what `models/registry.py` and the trainer dispatch on
 
+    def layer_positions(self) -> int:
+        """Positions the layers run over for a row of `lm.seq_len`."""
+        return self.cfg.seq_len
+
     def routes(self) -> dict:
         """The paths this model's layers take for rows of `lm.seq_len`, by
         the layers' own rule: what the trainer writes at step 0."""
-        c = self.cfg
-        return {"attention_route": attention_route(
-            c.seq_len, c.attn_block_q,
-            (c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim)),
-            # a row's share of the expert layer's sorted list: `cap` rows
-            # are gathered and scattered unless a step's held experts take
-            # more (`moe_full_width` 1.0); cap == slots: one width, by shape
-            "expert_rows": {"cap": expert_row_cap(c, c.seq_len),
-                            "slots": c.seq_len * c.num_experts_per_tok}}
+        c, s = self.cfg, self.layer_positions()
+        return {"objective": self.objective,
+                "attention_route": attention_route(
+                    s, c.attn_block_q, self.attention.route_dims(c), self.mask(s)),
+                # the expert layer's sorted list for the positions of a row:
+                # `cap` rows are gathered and scattered unless a step's held
+                # experts take more (`moe_full_width` 1.0); cap == slots: one
+                # width, by shape
+                "expert_rows": {"cap": expert_row_cap(c, s),
+                                "slots": s * c.num_experts_per_tok}}
 
     @nn.compact
-    def __call__(self, ids, targets=None):
-        """ids[b, s] int32 -> logits[b, s, v] float32, or with
-        targets[b, s]: {"loss_rows": [b], <counter>: [expert layers]}."""
+    def __call__(self, ids, targets=None, weights=None):
+        """ids[b, s] int32, the row as the layers see it -> logits[b, n, v]
+        float32 of its first n = `loss_positions(s)` positions, or with
+        targets[b, n] (and weights[b, n]): {"loss_rows": [b], <counter>:
+        [expert layers]}."""
         c = self.cfg
         if c.tie_word_embeddings or c.attention_bias or c.hidden_act != "silu" \
                 or c.rope_scaling is not None:
@@ -109,21 +150,93 @@ class LatentMoELM(nn.Module):
         block_cls = nn.remat(
             Block, policy=jax.checkpoint_policies.save_only_these_names(
                 RESIDUALS)) if self.remat else Block
+        mask = self.mask(ids.shape[1])
         per_layer = []
         for i in range(c.num_hidden_layers):
             expert = is_expert_layer(c, i)
-            x, counters = block_cls(c, expert, self.dtype,
+            x, counters = block_cls(c, expert, self.dtype, self.attention, mask,
                                     name=f"layer_{i}")(x)
             if expert:
                 per_layer.append(counters)
-        h = RMSNorm(c.rms_norm_eps, name="final_norm")(x)
+        n = self.loss_positions(ids.shape[1])
+        h = RMSNorm(c.rms_norm_eps, name="final_norm")(
+            x if n == ids.shape[1] else x[:, :n])
         head = self.param("lm_head", init, (c.hidden_size, c.vocab_size), F32)
         if targets is None:
             with jax.named_scope("lm_head"):
                 return dot(h, head, self.dtype)
         out = {"loss_rows": cross_entropy_rows(h, head, targets, c.loss_block,
-                                               self.dtype)}
+                                               self.dtype, weights)}
         for key in COUNTERS:
             out[key] = jnp.stack([cn[key] for cn in per_layer]) if per_layer \
                 else jnp.zeros((0,), F32)
+        return out
+
+
+class LatentMoELM(MoELM):
+    model_type = "deepseek_v3"
+    objective = "next_token"
+    attention = MLA
+
+    def mask(self, positions: int) -> Mask:
+        return CAUSAL
+
+    def loss_positions(self, positions: int) -> int:
+        return positions
+
+    def loss(self, tokens, rng=None):
+        """tokens[b, seq_len + 1]: position t's logits against position
+        t + 1's id. The objective draws nothing: `rng` is unused."""
+        return self(tokens[:, :-1], tokens[:, 1:])
+
+
+def block_noise(key, rows: int, positions: int, block: int, t_lo: float,
+                t_hi: float):
+    """(m[rows, positions] bool, t[rows, positions] float32): one
+    `t_b ~ U(t_lo, t_hi)` a block of `block` positions, `m_p ~
+    Bernoulli(t_b(p))`. The one draw of the objective: the plain reference
+    is handed the same key and calls the same two `jax.random` functions in
+    this order."""
+    kt, km = jax.random.split(key)
+    n_blocks = -(-positions // block)
+    t = jax.random.uniform(kt, (rows, n_blocks), F32, t_lo, t_hi)
+    t = jnp.repeat(t, block, axis=1)[:, :positions]
+    return jax.random.uniform(km, (rows, positions), F32) < t, t
+
+
+class BlockDiffusionMoELM(MoELM):
+    model_type = "sdar_moe"
+    objective = "block_diffusion"
+    attention = GQA
+
+    def layer_positions(self) -> int:
+        return 2 * self.cfg.seq_len
+
+    def mask(self, positions: int) -> Mask:
+        return Mask("block_diffusion", self.cfg.block_length, positions // 2)
+
+    def loss_positions(self, positions: int) -> int:
+        return positions // 2  # the noised half
+
+    def loss(self, tokens, rng=None, noise=None):
+        """tokens[b, seq_len + 1] (the last id is unused) -> {"loss_rows",
+        "bd_masked_share", counters}. The noise is `block_noise(rng, ...)`,
+        `rng` the key the trainer's step hands over (the key itself, not a
+        stream derived from it: the plain reference draws from the same),
+        or `noise` = (m, t) given outright."""
+        c = self.cfg
+        if c.mask_token_id is None or not 0 <= c.mask_token_id < c.vocab_size:
+            raise ValueError("lm.mask_token_id must name one of the "
+                             f"{c.vocab_size} ids held, got {c.mask_token_id}")
+        x0 = tokens[:, :c.seq_len]
+        with jax.named_scope("bd_noise"):
+            m, t = block_noise(rng, *x0.shape, c.block_length, c.noise_t_lo,
+                               c.noise_t_hi) if noise is None else noise
+            doubled = jnp.concatenate(
+                [jnp.where(m, jnp.int32(c.mask_token_id), x0), x0], axis=1)
+            weights = m.astype(F32) / t
+        out = self(doubled, x0, weights)
+        # loss-bearing positions over L, one value a row's batch: rides the
+        # loss fetch beside the expert layers' counters
+        out["bd_masked_share"] = jnp.mean(m.astype(F32))[None]
         return out

@@ -3,7 +3,9 @@
 
 What a model IS it declares on its class, and nothing outside asks for it
 by name: `task` ("flow" where absent; "action", "classify", "lm") picks the
-objective, the example input and the evaluation; `input_frames` the frames
+objective, the example input and the evaluation; a language model also
+declares the `model_type` it writes (the published key that `model_for`
+finds the family by) and its `objective`; `input_frames` the frames
 it takes (absent: the dataset's `time_step`); `smooth_border_mask` and
 `vgg16_trunk_path` what the trainer does around it. `model_for` and
 `example_input` are the one way from an ExperimentConfig to a model.
@@ -20,7 +22,7 @@ from .vgg16_flow import VGG16Flow
 from .inception_v3_flow import InceptionV3Flow
 from .flownet_c import FlowNetC
 from .flownet2 import FlowNetCS
-from .lm import LatentMoELM
+from .lm import BlockDiffusionMoELM, LatentMoELM
 from .two_stream import STBaseline, STSingle, UCF101Spatial
 
 MODELS = {
@@ -33,6 +35,7 @@ MODELS = {
     "st_baseline": STBaseline,
     "ucf101_spatial": UCF101Spatial,
     "latent_moe_lm": LatentMoELM,
+    "block_diffusion_moe_lm": BlockDiffusionMoELM,
 }
 
 
@@ -92,7 +95,15 @@ def model_for(cfg, dtype: Any = None):
         raise KeyError(f"unknown model {cfg.model!r}; available: {sorted(MODELS)}")
     dtype = compute_dtype(cfg) if dtype is None else dtype
     if task_of(MODELS[cfg.model]) == "lm":
-        return MODELS[cfg.model](cfg=cfg.lm, dtype=dtype, remat=cfg.train.remat)
+        # the `lm` section's published `model_type` says which family it is
+        # (the `lm` preset names one; a config.json of the other wins)
+        families = {m.model_type: m for m in MODELS.values()
+                    if task_of(m) == "lm"}
+        if cfg.lm.model_type not in families:
+            raise KeyError(f"lm.model_type={cfg.lm.model_type!r} is no family "
+                           f"models/lm writes; available: {sorted(families)}")
+        return families[cfg.lm.model_type](cfg=cfg.lm, dtype=dtype,
+                                           remat=cfg.train.remat)
     return build_model(cfg.model, flow_channels=2 * (cfg.data.time_step - 1),
                        dtype=dtype, width_mult=cfg.width_mult,
                        corr_max_disp=cfg.corr_max_disp,

@@ -1,5 +1,14 @@
-"""The `fused` path of `ops/attention.py`: the latent-attention layer's
-causal attention as two Mosaic kernels of the repo's own.
+"""The `fused` path of `ops/attention.py`: two pairs of Mosaic kernels of
+the repo's own on shared tile code (`_dot`, `_lanes`, the chunks of
+`COMPUTE_KV` keys, the online softmax's scratch layout). `mla_attn_fwd` /
+`mla_attn_bwd`: the latent-attention layer's causal attention, described
+first; `bd_attn_fwd` / `bd_attn_bwd`: grouped-query attention under a
+mask RULE (`ops/attention.py::Mask`: causal, or the block-diffusion mask
+of a doubled row), described at `_rule_fwd_kernel`. They stand beside each
+other, not one generalised into the other: the latent kernels' score is
+the sum of two products with a rotary key shared by all heads and their
+mask and skipping are the diagonal's, so a shared body would carry both
+sets of operands and both skipping rules through every line.
 
   o = softmax_k(scale * (qn . kn + qr . kr))[k <= q] . v
 
@@ -55,7 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...parallel.spatial import current_mesh, shard_over_batch
-from ..attention import _NEG, RESIDUALS
+from ..attention import _NEG, RESIDUALS, Mask
 
 F32 = jnp.float32
 LANES = 128
@@ -286,3 +295,222 @@ def fused_causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int,
 
     return shard_over_batch(rows, current_mesh(), qn.shape[0])(
         qn, qr, kn, kr, v)
+
+
+# ---- grouped-query attention under a mask rule ----------------------------
+#
+# Forward (`bd_attn_fwd`), grid (row, query head, query tile, key tile), the
+# key tiles innermost, online softmax as above. Query head n reads
+# key/value head n // r through the BlockSpec's index. Which key tiles a
+# query tile visits is the RULE's (`Mask.key_tile_ranges`: at most two
+# ranges; under `block_diffusion` a noised query tile visits the ONE noised
+# key-tile range that covers its own blocks and the clean key tiles that
+# start before its end, a clean query tile the clean key tiles up to its
+# own, no query tile a noised key tile of other blocks): a hidden tile's
+# block index is clamped to a visited one, which Pallas does not fetch
+# twice, and its chunks are not computed (`Mask.tile_visible`); a chunk the
+# rule shows whole skips the mask (`Mask.tile_wholly_visible`); elsewhere
+# the mask is position arithmetic inside the tile (`Mask.visible`).
+#
+# Backward (`bd_attn_bwd`), grid (row, key/value head, key tile, r x query
+# tiles), innermost the r query heads that read this key/value head times
+# the query tiles: a key/value tile is fetched ONCE for all of them, and
+# dk and dv accumulate in VMEM over heads and query tiles alike; which
+# query tiles see a key tile is `Mask.query_tile_ranges`; dq leaves as one
+# float32 part a key tile, summed outside.
+
+
+def _clamp2(x, lo1, hi1, lo2, hi2):
+    """x held inside [lo1, hi1] or [lo2, hi2] (lo > hi: empty; the first
+    range lies before the second): inside a range x itself, before one
+    its start, after the last its end. Consecutive grid steps that map to
+    one block fetch it once."""
+    first = (hi1 >= lo1) & ((x <= hi1) | (hi2 < lo2))
+    return jnp.where(first, jnp.clip(x, lo1, hi1), jnp.clip(x, lo2, hi2))
+
+
+def _rule_chunks(mask: Mask, q0, k0, bq: int, bkv: int, tile):
+    """Run `tile(keys, first_key, masked)` for every chunk of `COMPUTE_KV`
+    keys of the key tile at k0 that the query tile at q0 sees under `mask`:
+    not at all where the rule hides the chunk, without the mask where it
+    shows all of it."""
+    compute = min(bkv, COMPUTE_KV)
+    for c in range(bkv // compute):
+        c0 = k0 + c * compute
+        keys = pl.ds(c * compute, compute)
+        seen = mask.tile_visible(q0, q0 + bq, c0, c0 + compute)
+        whole = mask.tile_wholly_visible(q0, q0 + bq, c0, c0 + compute)
+        pl.when(seen & jnp.logical_not(whole))(
+            functools.partial(tile, keys, c0, True))
+        pl.when(seen & whole)(functools.partial(tile, keys, c0, False))
+
+
+def _rule_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                     acc_ref, *, scale: float, bq: int, bkv: int, mask: Mask):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(keys, first_key, masked):
+        s = _dot(q_ref[...], k_ref[keys, :], _NT) * scale
+        if masked:
+            qpos = i * bq + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            kpos = first_key + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(mask.visible(qpos, kpos), s, _NEG)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_next, s.shape[1]))
+        if masked:
+            # a query may see no key of this chunk at all (its block ends
+            # before the chunk's first key): exp(_NEG - _NEG) is not nought
+            p = jnp.where(s > 0.5 * _NEG, p, 0.0)
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = (acc_ref[...] * _lanes(alpha, acc_ref.shape[1])
+                        + _dot(p.astype(v_ref.dtype), v_ref[keys, :]))
+
+    _rule_chunks(mask, i * bq, j * bkv, bq, bkv, tile)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] * _lanes(1.0 / l, acc_ref.shape[1])
+                      ).astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
+
+
+def _rule_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
+                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float, bq: int,
+                     bkv: int, mask: Mask, positions: int):
+    j, x = pl.program_id(2), pl.program_id(3)
+    nq = positions // bq
+    i = x % nq
+    dt = q_ref.dtype
+
+    @pl.when(x == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    dq_ref[...] = jnp.zeros_like(dq_ref)  # this key tile's part of dq
+
+    def tile(keys, first_key, masked):
+        st = _dot(k_ref[keys, :], q_ref[...], _NT) * scale
+        pt = jnp.exp(st - lse_ref[:1, :])
+        if masked:
+            kpos = first_key + lax.broadcasted_iota(jnp.int32, st.shape, 0)
+            qpos = i * bq + lax.broadcasted_iota(jnp.int32, st.shape, 1)
+            pt = jnp.where(mask.visible(qpos, kpos), pt, 0.0)
+        do = do_ref[...]
+        dv_acc[keys, :] += _dot(pt.astype(dt), do)
+        dst = (_dot(v_ref[keys, :], do, _NT) - di_ref[:1, :]) * pt * scale
+        dk_acc[keys, :] += _dot(dst.astype(dt), q_ref[...])
+        dq_ref[...] += _dot(dst.T.astype(dt), k_ref[keys, :])
+
+    _rule_chunks(mask, i * bq, j * bkv, bq, bkv, tile)
+
+    @pl.when(x == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _rule_forward(q, k, v, scale, bq, bkv, mask, interpret):
+    b, h, s, d = q.shape
+    r = h // k.shape[1]
+    k_of = lambda i, j: _clamp2(j, *mask.key_tile_ranges(i * bq, bq, bkv))  # noqa: E731
+    qs = lambda w: pl.BlockSpec((None, None, bq, w),  # noqa: E731
+                                lambda b, n, i, j: (b, n, i, 0))
+    ks = pl.BlockSpec((None, None, bkv, d),
+                      lambda b, n, i, j: (b, n // r, k_of(i, j), 0))
+    o, lse = pl.pallas_call(
+        functools.partial(_rule_fwd_kernel, scale=scale, bq=bq, bkv=bkv,
+                          mask=mask),
+        grid=(b, h, s // bq, s // bkv),
+        in_specs=[qs(d), ks, ks],
+        out_specs=[qs(d), qs(LANES)],
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, s, LANES), F32)],
+        scratch_shapes=[pltpu.VMEM((bq, LANES), F32),
+                        pltpu.VMEM((bq, LANES), F32),
+                        pltpu.VMEM((bq, d), F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        name="bd_attn_fwd", interpret=interpret,
+    )(q, k, v)
+    return o, lse[..., 0]
+
+
+def _rule_backward(q, k, v, o, lse, do, scale, bq, bkv, mask, interpret):
+    b, h, s, d = q.shape
+    g = k.shape[1]
+    r, nq, nkv = h // g, s // bq, s // bkv
+    do = do.astype(q.dtype)
+    di = jnp.sum(o.astype(F32) * do.astype(F32), axis=-1)
+    rows = [jnp.broadcast_to(a[:, :, None, :], (b, h, SUBLANES, s))
+            for a in (lse, di)]
+    # innermost grid id x = (query head of the group, query tile)
+    head = lambda n, x: n * r + x // nq  # noqa: E731
+    q_of = lambda j, x: _clamp2(  # noqa: E731
+        x % nq, *mask.query_tile_ranges(j * bkv, bkv, bq, s))
+    qs = pl.BlockSpec((None, None, bq, d),
+                      lambda b, n, j, x: (b, head(n, x), q_of(j, x), 0))
+    ks = pl.BlockSpec((None, None, bkv, d), lambda b, n, j, x: (b, n, j, 0))
+    row = pl.BlockSpec((None, None, SUBLANES, bq),
+                       lambda b, n, j, x: (b, head(n, x), 0, q_of(j, x)))
+    part = pl.BlockSpec((None, None, None, bq, d),
+                        lambda b, n, j, x: (b, head(n, x), j, x % nq, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_rule_bwd_kernel, scale=scale, bq=bq, bkv=bkv,
+                          mask=mask, positions=s),
+        grid=(b, g, nkv, r * nq),
+        in_specs=[qs, ks, ks, qs, row, row],
+        out_specs=[part, ks, ks],
+        out_shape=[jax.ShapeDtypeStruct((b, h, nkv, s, d), F32),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bkv, d), F32), pltpu.VMEM((bkv, d), F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
+        name="bd_attn_bwd", interpret=interpret,
+    )(q, k, v, do, *rows)
+    return jnp.sum(dq, axis=2).astype(q.dtype), dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _rule_attend(q, k, v, scale, bq, bkv, mask, interpret):
+    return _rule_forward(q, k, v, scale, bq, bkv, mask, interpret)[0]
+
+
+def _rule_attend_fwd(q, k, v, scale, bq, bkv, mask, interpret):
+    o, lse = _rule_forward(q, k, v, scale, bq, bkv, mask, interpret)
+    o, lse = checkpoint_name(o, RESIDUALS), checkpoint_name(lse, RESIDUALS)
+    return o, (q, k, v, o, lse)
+
+
+def _rule_attend_bwd(scale, bq, bkv, mask, interpret, res, do):
+    return _rule_backward(*res, do, scale, bq, bkv, mask, interpret)
+
+
+_rule_attend.defvjp(_rule_attend_fwd, _rule_attend_bwd)
+
+
+def fused_grouped_attention(q, k, v, scale: float, block_q: int,
+                            block_kv: int, mask: Mask,
+                            interpret: bool = False):
+    """q[b,s,h,d] k, v[b,s,g,d] (g divides h), one dtype -> [b,s,h,d] in
+    it, under `mask`. `block_q` and `block_kv` are multiples of 128 that
+    divide s (and a copy of a doubled row); under `block_diffusion` the
+    rule's blocks are a power of two of positions that tile `block_q`.
+    Under a `mesh_context` the kernels run once per batch shard."""
+    def rows(q, k, v):
+        o = _rule_attend(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)), scale,
+                         block_q, block_kv, mask, interpret)
+        return jnp.swapaxes(o, 1, 2)
+
+    return shard_over_batch(rows, current_mesh(), q.shape[0])(q, k, v)

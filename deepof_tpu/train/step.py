@@ -10,9 +10,11 @@ session loop per dataset, SURVEY.md §2.2):
     cross-entropy weighted by the finest flow weight, matching
     `ucf101wrapFlow.py:186-188`;
   - spatial-only classifier: cross-entropy;
-  - language models (`models/lm/`, task "lm"): next-token cross-entropy
-    over the vocabulary held, float32; the model takes its loss itself, in
-    blocks of positions, and hands back its expert layers' counters.
+  - language models (`models/lm/`, task "lm"): the family's own objective
+    (next-token cross-entropy, or diffusion over blocks, whose noise is the
+    step's own rng stream) over the vocabulary held, float32; the model
+    takes its loss itself (`model.loss`), in blocks of positions, and
+    hands back its layers' counters.
 
 Data parallelism: the step is `jax.jit`-ed with the batch sharded over the
 mesh "data" axis and the state replicated; XLA inserts the gradient
@@ -69,11 +71,15 @@ def model_losses(
     loss dicts, finest flow, reconstruction, and optional action logits)."""
     rngs = {"dropout": dropout_rng} if (train and dropout_rng is not None) else None
     if task_of(model) == "lm":
-        # batch["tokens"][b, s + 1] int32: position t's logits against
-        # position t + 1's id. The model casts its float32 masters to its
-        # own compute dtype and recomputes per block (`model.remat`).
-        ids = batch["tokens"]
-        out = model.apply({"params": params}, ids[:, :-1], ids[:, 1:])
+        # batch["tokens"][b, s + 1] int32, by the model's own objective
+        # (`models/lm/model.py`). The model casts its float32 masters to its
+        # own compute dtype and recomputes per block (`model.remat`). An
+        # objective that draws noise draws it from the key the step splits
+        # off `state.rng` each step: a resumed run continues the stream and
+        # two steps never share a mask; an evaluation draws from a fixed key.
+        noise = dropout_rng if dropout_rng is not None else jax.random.PRNGKey(0)
+        out = model.apply({"params": params}, batch["tokens"], noise,
+                          method="loss")
         rows = out.pop("loss_rows")
         return jnp.mean(rows), {"loss_rows": rows, "counters": out}
     # Spatial context parallelism: shard H over the "spatial" mesh axis (if

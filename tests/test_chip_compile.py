@@ -331,6 +331,37 @@ def test_attention_kernels_compile_for_v5e(one_chip, which, block_kv):
             kernels=["mla_attn_fwd", "mla_attn_bwd"])
 
 
+@pytest.mark.parametrize("which", ["fwd", "vjp"])
+def test_block_mask_attention_kernels_compile_for_v5e(one_chip, which):
+    """The fused grouped-query attention under the block-diffusion mask at
+    the second language-model cell's layer (one doubled row of 8192, 32
+    query heads over 4 key/value heads of 128, blocks of 4, query tiles of
+    512, key tiles of 2048: the route's): the forward kernel alone, and the
+    custom VJP's pair under a gradient with respect to q, k and v. The
+    rule's position arithmetic (shifts, comparisons, logical operations on
+    masks; no vector divide, no select between masks) is what Mosaic has
+    to take."""
+    from deepof_tpu.ops.attention import Mask
+    from deepof_tpu.ops.pallas.attention import fused_grouped_attention
+
+    mask = Mask("block_diffusion", 4, 4096)
+
+    def of(heads):
+        return jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def attend(*o):
+        with jax.named_scope("gqa_scores"):  # as the layer calls it
+            return fused_grouped_attention(*o, 128 ** -0.5, 512, 2048, mask)
+
+    if which == "fwd":
+        _compiled_text(attend, of(32), of(4), of(4), kernels=["bd_attn_fwd"])
+    else:
+        _compiled_text(jax.grad(lambda *o: jnp.sum(attend(*o).astype(
+            jnp.float32) ** 2), argnums=range(3)), of(32), of(4), of(4),
+            kernels=["bd_attn_fwd", "bd_attn_bwd"])
+
+
 def test_attention_compiles_through_shard_map_on_four_chips(topo):
     """Under a mesh the kernels run per batch shard: 4 rows over the four
     described chips' "data" axis, no row gathered."""

@@ -82,7 +82,15 @@ ROUTES = [
 def test_route_is_decided_by_backend_and_shape(monkeypatch, backend, positions,
                                                block_q, dims, route):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert A.attention_route(positions, block_q, dims) == route
+    got = A.attention_route(positions, block_q, dims)
+    assert {k: got[k] for k in route} == route
+    # every route names its mask and, as static counts, the tiles its
+    # blocks visit under it: the lower triangle of the tiles, blocks wide
+    bq, bkv = got["block_q"], got.get("block_kv", got["block_q"])
+    nq, nk = positions // bq, positions // bkv
+    seen = sum(((i + 1) * bq - 1) // bkv + 1 for i in range(nq))
+    assert got["mask"] == "causal"
+    assert got["tiles"] == {"visited": seen, "all": nq * nk}
 
 
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
@@ -135,4 +143,110 @@ def test_trainer_writes_the_route_into_its_step_0_info_record(tmp_path):
     routes = [r for r in records if r.get("message") == "routes"]
     assert len(routes) == 1 and routes[0]["kind"] == "info"
     assert routes[0]["step"] == 0
-    assert routes[0]["attention_route"] == {"path": "xla_blocks", "block_q": 16}
+    assert routes[0]["objective"] == "next_token"
+    assert routes[0]["attention_route"] == {
+        "path": "xla_blocks", "block_q": 16, "mask": "causal",
+        "tiles": {"visited": 3, "all": 4}}
+
+
+# ---- grouped-query attention under a mask rule (`bd_attn_fwd` / `_bwd`) ----
+
+GROUPED = {
+    # rule, positions a copy, block, block_q, block_kv, chunk of keys
+    "bd_b4_q128_kv128": ("block_diffusion", 256, 4, 128, 128, 128),
+    "bd_b4_q128_kv256": ("block_diffusion", 256, 4, 128, 256, 128),
+    "bd_b32_q256_kv128": ("block_diffusion", 256, 32, 256, 128, 128),
+    "bd_b1_q128_kv256": ("block_diffusion", 256, 1, 128, 256, 256),
+    "causal_q256_kv128": ("causal", 512, 0, 256, 128, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED))
+@pytest.mark.parametrize("dtype", sorted(TOLERANCE))
+def test_fused_grouped_kernels_match_the_xla_blocks(monkeypatch, dtype, case):
+    """Output and the gradient with respect to q, k and v, 4 query heads
+    reading 2 key/value heads of 128, under the block-diffusion mask of a
+    doubled row (blocks of 1, 4 and 32; key tiles equal to, twice and half
+    the query tiles; tiles hidden, shown whole and cut by the rule all
+    occur, and with chunks of 128 keys a query that sees no key of a chunk
+    it is computed with) and under the causal rule."""
+    from deepof_tpu.ops.pallas import attention as K
+
+    rule, half, block, bq, bkv, compute = GROUPED[case]
+    monkeypatch.setattr(K, "COMPUTE_KV", compute)
+    dt = jnp.dtype(dtype)
+    mask = A.CAUSAL if rule == "causal" else A.Mask(rule, block, half)
+    s = half if rule == "causal" else 2 * half
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, weight = (jax.random.normal(kk, (2, s, h, 128))
+                       for kk, h in zip(keys, (4, 2, 2, 4)))
+    ops = tuple(o.astype(dt) for o in (q, k, v))
+
+    def loss(attend):
+        return lambda *o: jnp.sum(attend(*o).astype(jnp.float32) * weight)
+
+    def blocks_(*o):
+        return A.xla_blocks_grouped_attention(*o, 128 ** -0.5, 64, dt, mask)
+
+    def fused(*o):
+        return K.fused_grouped_attention(*o, 128 ** -0.5, bq, bkv, mask,
+                                         interpret=True)
+
+    assert fused(*ops).dtype == dt
+    pairs = [(blocks_(*ops), fused(*ops))] + list(zip(
+        jax.grad(loss(blocks_), argnums=range(3))(*ops),
+        jax.grad(loss(fused), argnums=range(3))(*ops)))
+    for name, (want, got) in zip(("o", "q", "k", "v"), pairs):
+        assert want.shape == got.shape, name
+        # k's and v's gradients sum over two query heads and up to 512
+        # queries: values of size 10-30, bfloat16 steps of 2^-4 there
+        scale = max(1.0, float(jnp.max(jnp.abs(want.astype(jnp.float32)))) / 4)
+        gap = float(jnp.max(jnp.abs(want.astype(jnp.float32)
+                                    - got.astype(jnp.float32))))
+        assert gap < TOLERANCE[dtype] * scale, (name, gap, scale)
+
+
+BD = A.Mask("block_diffusion", 4, 4096)
+GQA_DIMS = (128, 0, 128)
+BD_NAMED = {"rule": "block_diffusion", "block": 4, "half": 4096}
+
+
+def test_route_under_the_block_mask_names_it_and_counts_its_tiles(monkeypatch):
+    """The cell's layer: a doubled row of 8192 in query tiles of 512. On a
+    TPU key tiles of 2048: a noised query tile visits its own noised key
+    tile and the clean ones that start before its end, a clean one the
+    clean tiles up to its own: 8 x 1 + (1+1+1+1+2+2+2+2) + the same again
+    = 32 of 64. The XLA blocks count keys in blocks of 512: 8 + 36 + 36."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert A.attention_route(8192, 512, GQA_DIMS, BD) == {
+        "path": "fused", "block_q": 512, "block_kv": 2048, "mask": BD_NAMED,
+        "tiles": {"visited": 32, "all": 64}}
+    # blocks that are no power of two, or do not tile a query tile: XLA
+    for block in (3, 1024):
+        odd = A.Mask("block_diffusion", block, 4096)
+        assert A.attention_route(8192, 512, GQA_DIMS, odd)["path"] == "xla_blocks"
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert A.attention_route(8192, 512, GQA_DIMS, BD) == {
+        "path": "xla_blocks", "block_q": 512, "mask": BD_NAMED,
+        "tiles": {"visited": 80, "all": 256}}
+
+
+def test_the_grouped_layer_takes_the_fused_path_where_the_route_says(monkeypatch):
+    from deepof_tpu.ops.pallas import attention as K
+
+    calls, real = [], K.fused_grouped_attention
+
+    def recording(*a, **kw):
+        calls.append(a[4:])
+        return real(*a, interpret=True, **kw)
+
+    mask = A.Mask("block_diffusion", 4, 128)
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    q, k, v = (jax.random.normal(kk, (1, 256, h, 128))
+               for kk, h in zip(keys, (2, 1, 1)))
+    blocks = A.grouped_attention(q, k, v, 0.09, 128, jnp.float32, mask)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(K, "fused_grouped_attention", recording)
+    fused = A.grouped_attention(q, k, v, 0.09, 128, jnp.float32, mask)
+    assert calls == [(128, 128, mask)]  # a copy of 128: the key tile is the query's
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(blocks), atol=2e-5)

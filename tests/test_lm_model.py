@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 from flax.traverse_util import unflatten_dict
 
-from deepof_tpu.core.config import LMConfig
+from deepof_tpu.core.config import LMConfig, lm_family_config
 from deepof_tpu.models.lm import LatentMoELM
 from deepof_tpu.models.lm import layers as L
 
@@ -243,8 +243,18 @@ def test_compact_row_list_computes_what_the_full_width_computes(
             assert a.shape == b.shape and rel(a, b) < tol
 
 
-def uncut_weights():
-    vals = ref.make_params(as_dict(UNCUT), jax.random.PRNGKey(4))
+#: the two families' small models, each beside its own plain reference:
+#: the second has a softmax router, no bias buffer and no shared expert
+BD = lm_family_config("sdar_moe", LM)
+FAMILIES = {"deepseek_v3": (LM, ref),
+            "sdar_moe": (BD, importlib.import_module(
+                "benchmark.reference.sdar_30b_a3b_ep8"))}
+
+
+def uncut_weights(family: str = "deepseek_v3"):
+    lm, r = FAMILIES[family]
+    uncut = dataclasses.replace(lm, n_routed_experts=8, first_expert=0)
+    vals = r.make_params(as_dict(uncut), jax.random.PRNGKey(4))
     return {k[len("layer_1/moe/"):]: v for k, v in vals.items()
             if k.startswith("layer_1/moe/")}
 
@@ -252,24 +262,29 @@ def uncut_weights():
 def share_params(full: dict, first: int, held: int) -> dict:
     p = {k: (v[first:first + held] if k.startswith("experts_w_") else v)
          for k, v in full.items() if not k.startswith("shared/")}
-    p["shared"] = {k[len("shared/"):]: v for k, v in full.items()
-                   if k.startswith("shared/")}
-    return p
+    shared = {k[len("shared/"):]: v for k, v in full.items()
+              if k.startswith("shared/")}
+    return {**p, "shared": shared} if shared else p
 
 
+@pytest.mark.parametrize("family", sorted(FAMILIES))
 @pytest.mark.parametrize("held", [1, 2, 4, 8])
-def test_all_shares_add_up_to_the_uncut_layer(long_hidden, held):
-    """Over all shares of the small model, the routed parts summed with the
-    shared expert counted once equal the uncut reference layer."""
-    full, hidden = uncut_weights(), long_hidden
+def test_all_shares_add_up_to_the_uncut_layer(long_hidden, held, family):
+    """Over all shares of the small model, the routed parts summed with
+    what every chip computes alike (the shared expert, where the family has
+    one) counted once equal the uncut reference layer: ONE expert layer,
+    both families."""
+    lm0, r = FAMILIES[family]
+    full, hidden = uncut_weights(family), long_hidden
     flat = {f"layer_1/moe/{k}": v for k, v in full.items()}
-    c = as_dict(UNCUT)
-    shared = jnp.stack([ref.swiglu(hidden[i], full["shared/w_gate"],
-                                   full["shared/w_up"], full["shared/w_down"])
-                        for i in range(2)])
+    c = as_dict(dataclasses.replace(lm0, n_routed_experts=8, first_expert=0))
+    shared = jnp.stack([r.swiglu(hidden[i], full["shared/w_gate"],
+                                 full["shared/w_up"], full["shared/w_down"])
+                        for i in range(2)]) if lm0.n_shared_experts \
+        else jnp.zeros_like(hidden)
     total, slots = shared, 0.0
     for first in range(0, 8, held):
-        lm = dataclasses.replace(LM, n_routed_experts=held, first_expert=first)
+        lm = dataclasses.replace(lm0, n_routed_experts=held, first_expert=first)
         apply = lambda h: L.MoE(lm).apply(  # noqa: E731
             {"params": share_params(full, first, held)}, h)
         y, counters = apply(hidden)
@@ -282,14 +297,14 @@ def test_all_shares_add_up_to_the_uncut_layer(long_hidden, held):
         assert ("cond[" in str(jax.make_jaxpr(apply)(hidden))) != one_width
         if one_width:
             assert float(counters["moe_full_width"]) == 1.0
-    want = jnp.stack([ref.moe(flat, "layer_1", hidden[i], c) for i in range(2)])
+    want = jnp.stack([r.moe(flat, "layer_1", hidden[i], c) for i in range(2)])
     assert rel(total, want) < 2e-5
     assert slots == pytest.approx(1.0)  # every slot fell on exactly one share
     # and the reference's own share, summed the same way, says the same
     sliced = lambda f: {k: (v[f:f + held] if "experts_w_" in k else v)  # noqa: E731
                         for k, v in flat.items()}
-    parts = sum(ref.moe(sliced(f), "layer_1", hidden[0], c, first=f, held=held,
-                        shared=False) for f in range(0, 8, held))
+    parts = sum(r.moe(sliced(f), "layer_1", hidden[0], c, first=f, held=held,
+                      shared=False) for f in range(0, 8, held))
     assert rel(parts + shared[0], want[0]) < 2e-6
 
 

@@ -189,3 +189,152 @@ def test_predict_and_serve_refuse_the_family_by_name(path):
             "predict_action": lambda: predict.restore_action_params(cfg)}[path]
     with pytest.raises(registry.NoServingPath, match="language model"):
         call()
+
+
+# ---- the second family (`model_type: sdar_moe`) on the same path ----------
+
+SDAR = os.path.join(ROOT, "benchmark", "configs", "sdar_30b_a3b_ep8.json")
+#: a toy of the published file's shape: what `--set lm.config_file=` reads
+TOY_SDAR = dict(
+    model_type="sdar_moe", vocab_size=256, hidden_size=64, intermediate_size=128,
+    moe_intermediate_size=32, num_hidden_layers=2, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=16, num_experts=2, num_experts_per_tok=2,
+    n_routed_experts_published=8, first_expert=2, norm_topk_prob=True,
+    decoder_sparse_step=1, mlp_only_layers=[], rope_theta=10000,
+    use_sliding_window=False, block_length=4, mask_token_id=255,
+    noise_t_lo=0.45, noise_t_hi=0.95, notes="a file may hold more than fields")
+
+
+def sdar_cfg(tmp_path, log_dir, **train):
+    toy = tmp_path / "toy_sdar.json"
+    toy.write_text(json.dumps(TOY_SDAR))
+    argv = ["train", "--preset", "lm", "--log-dir", str(log_dir),
+            "--set", f"lm.config_file={toy}", "--set", "lm.seq_len=32",
+            "--set", "lm.attn_block_q=16", "--set", "lm.loss_block=16",
+            "--set", "train.log_every=1", "--set", "train.nan_guard=false"]
+    for k, v in train.items():
+        argv += ["--set", f"train.{k}={v}"]
+    return cli.config_for(argv)
+
+
+def train_records(log_dir):
+    with open(os.path.join(log_dir, "metrics.jsonl")) as f:
+        records = [json.loads(line) for line in f]
+    return records, [r for r in records if r["kind"] == "train"]
+
+
+def test_second_family_trains_from_its_config_file_and_resumes_its_noise(tmp_path):
+    """`train --preset lm` with a config.json of the other `model_type`:
+    the registry finds the family, the same Trainer fits it, checkpoints,
+    restores and evaluates it. Every step draws another mask, from the
+    trainer state's own rng: a run resumed from its checkpoint draws step
+    3's mask as the run that was never stopped did."""
+    from deepof_tpu.models.lm import BlockDiffusionMoELM
+    from deepof_tpu.train.loop import Trainer
+
+    cfg = sdar_cfg(tmp_path, tmp_path / "whole")
+    assert cfg.model == "latent_moe_lm"  # the preset's; the file's model_type wins
+    assert cfg.lm.scoring_func == "softmax" and cfg.lm.n_shared_experts == 0
+    assert cfg.lm.n_routed_experts == 2 and cfg.lm.mlp_only_layers == ()
+    whole = Trainer(cfg, mesh=one_device_mesh())
+    assert isinstance(whole.model, BlockDiffusionMoELM)
+    whole.fit(max_steps=3)
+    records, train = train_records(tmp_path / "whole")
+    assert [r["step"] for r in train] == [1, 2, 3]
+    for r in train:
+        assert np.isfinite(r["loss"]) and 2.0 < r["loss"] < 9.0  # ~ ln 256 by 1/t
+        assert len(r["bd_masked_share"]) == 1 and 0.4 < r["bd_masked_share"][0] < 1.0
+        assert len(r["moe_slots_held_share"]) == 2
+    shares = [r["bd_masked_share"][0] for r in train]
+    assert len(set(shares)) == 3  # no two steps share a mask
+    routes, = [r for r in records if r.get("message") == "routes"]
+    assert routes["objective"] == "block_diffusion"
+    assert routes["attention_route"] == {
+        "path": "xla_blocks", "block_q": 16,
+        "mask": {"rule": "block_diffusion", "block": 4, "half": 32},
+        "tiles": {"visited": 8, "all": 16}}
+    assert routes["expert_rows"] == {"cap": 128, "slots": 128}  # of 64 positions
+    ev = whole.evaluate()
+    assert set(ev) == {"val_loss", "val_perplexity"} and np.isfinite(ev["val_loss"])
+    # two steps, a checkpoint, and a new Trainer that goes on from it
+    cfg2 = sdar_cfg(tmp_path, tmp_path / "stopped")
+    Trainer(cfg2, mesh=one_device_mesh()).fit(max_steps=2)
+    again = Trainer(cfg2, mesh=one_device_mesh())
+    assert int(again.state.step) == 2
+    again.fit(max_steps=1)
+    _, resumed = train_records(tmp_path / "stopped")
+    last = [r for r in resumed if r["step"] == 3][-1]
+    assert last["bd_masked_share"][0] == shares[2]
+    # (the loss is another row's: the data's order is the pipeline's own)
+    assert np.array_equal(np.asarray(again.state.rng), np.asarray(whole.state.rng))
+
+
+def test_registry_finds_the_family_by_its_published_model_type():
+    from deepof_tpu.core.config import lm_family_config
+    from deepof_tpu.models.lm import BlockDiffusionMoELM, LatentMoELM
+
+    cfg = get_config("lm")
+    assert isinstance(registry.model_for(cfg), LatentMoELM)
+    bd = cfg.replace(lm=lm_family_config("sdar_moe", cfg.lm, mask_token_id=255))
+    for name in ("latent_moe_lm", "block_diffusion_moe_lm"):
+        model = registry.model_for(bd.replace(model=name))
+        assert isinstance(model, BlockDiffusionMoELM)
+        assert (model.task, model.objective) == ("lm", "block_diffusion")
+        assert registry.example_input(model, bd).shape == (2, bd.lm.seq_len)
+    with pytest.raises(KeyError, match="model_type='qwen9'"):
+        registry.model_for(cfg.replace(
+            lm=dataclasses.replace(cfg.lm, model_type="qwen9")))
+    declared = {m.model_type: m.objective for m in registry.MODELS.values()
+                if registry.task_of(m) == "lm"}
+    assert declared == {"deepseek_v3": "next_token", "sdar_moe": "block_diffusion"}
+
+
+def test_second_configuration_file_keeps_every_published_width():
+    """Every number of the catalog row's `config` under its own key;
+    `reduced` names what differs, within the floors (at least 4 layers, 8
+    experts, exactly an eighth of the vocabulary); the parameter table sums
+    to what the program holds."""
+    with open(SDAR) as f:
+        c = json.load(f)
+    catalog = dict(
+        attention_bias=False, decoder_sparse_step=1, head_dim=128,
+        hidden_act="silu", hidden_size=2048, intermediate_size=6144,
+        max_position_embeddings=32768, max_window_layers=48, mlp_only_layers=[],
+        model_type="sdar_moe", moe_intermediate_size=768, norm_topk_prob=True,
+        num_attention_heads=32, num_experts_per_tok=8, num_key_value_heads=4,
+        rms_norm_eps=1e-06, rope_scaling=None, rope_theta=1000000,
+        sliding_window=None, tie_word_embeddings=False, use_sliding_window=False)
+    assert {k: c[k] for k in catalog} == catalog
+    assert c["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert c["published"] == {"num_hidden_layers": 48, "num_experts": 128,
+                              "vocab_size": 151936}
+    assert (c["num_hidden_layers"], c["num_experts"], c["vocab_size"]) == (5, 16, 18992)
+    assert c["num_hidden_layers"] >= 4 and c["num_experts"] >= 8
+    assert c["vocab_size"] * 8 == 151936 and c["n_routed_experts_published"] == 128
+    assert c["mask_token_id"] == c["vocab_size"] - 1
+    assert len(c["assumed"]) >= 9 and c["deployment"].startswith("8 chips share")
+    lm = fill_lm_from_file(LMConfig(), SDAR)
+    assert (lm.model_type, lm.n_routed_experts, lm.num_key_value_heads) == ("sdar_moe", 16, 4)
+    assert (lm.scoring_func, lm.topk_method, lm.n_shared_experts) == ("softmax", "greedy", 0)
+    assert (lm.block_length, lm.noise_t_lo, lm.noise_t_hi) == (4, 0.45, 0.95)
+    cfg = get_config("lm").replace(lm=dataclasses.replace(lm, seq_len=64))
+    model = registry.model_for(cfg)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            registry.example_input(model, cfg))["params"]
+    held = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    t = c["parameter_table"]
+    assert held == t["all"] == c["parameters"] == 550984960
+    assert t["layer"] == (t["gqa_per_layer"] + t["norms_per_layer"]
+                          + t["router_per_layer"] + t["experts_held_per_layer"])
+    assert t["all"] == 5 * t["layer"] + t["embedding_and_head"] + t["final_norm"]
+    assert c["train_flops_per_pair"] == pytest.approx(1.11e13, rel=0.01)
+
+
+def test_token_dataset_never_draws_the_mask_id():
+    lm = LMConfig(vocab_size=100, seq_len=256, mask_token_id=3)
+    rows = build_dataset(DataConfig(dataset="tokens"), lm=lm).rows
+    counts = np.bincount(rows.reshape(-1), minlength=100)
+    assert counts[3] == 0 and rows.max() == 99 and counts[2] > counts[4] > 0
+    plain = build_dataset(DataConfig(dataset="tokens"),
+                          lm=LMConfig(vocab_size=100, seq_len=256)).rows
+    assert np.bincount(plain.reshape(-1), minlength=100)[3] > 0
