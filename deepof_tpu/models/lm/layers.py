@@ -11,6 +11,18 @@ stream stays float32. No bias anywhere. The attention's scores, mask and
 softmax are `ops/attention.py`'s, which states the same for both of its
 paths (fused kernels on a TPU, XLA blocks elsewhere).
 
+Between a projection and the scores `ops/attention.py::attention_route`
+decides a second time (`prep`), and each attention layer writes both
+ways of the same arithmetic. `xla`: the elementwise code below (`rope`,
+`rope_halves`, `RMSNorm`) on [b, s, h, d]. `fused` (on a TPU, wherever the
+scores are fused): what is normed or rotated goes from its product's
+float32 output through `ops/pallas/qk_prep.py` (norm statistic and angles
+float32, ONE cast, written head-major [b, h, s, d]); what is only cast
+(`qn`, `kn`, `v`) leaves its product head-major in the compute dtype
+(`heads_dot`: the same float32 accumulation rounded once), the nope / rope
+/ value split made on the WEIGHT's columns; the fused attention takes all
+of it as it comes.
+
 Scopes (what the per-layer readers find in a profile; flax names a
 module's scope after the module, the rest are `jax.named_scope`s):
 `layer_<i>` > `mla` > `mla_proj`, `mla_scores`, `mla_out` or `gqa` >
@@ -18,8 +30,10 @@ module's scope after the module, the rest are `jax.named_scope`s):
 `moe_dispatch`, `moe_experts`, `moe_shared`, `moe_combine`; beside them
 `embed`, `bd_noise`, `lm_head`, `loss_ce`, `optimizer`. On the chip
 `mla_scores` holds the Mosaic calls `mla_attn_fwd` and, under
-`transpose(jvp(...))`, `mla_attn_bwd`, with the layout copies around them;
-`gqa_scores` the calls `bd_attn_fwd` and `bd_attn_bwd`.
+`transpose(jvp(...))`, `mla_attn_bwd`; `gqa_scores` the calls
+`bd_attn_fwd` and `bd_attn_bwd`; `mla_proj` and `gqa_proj` the calls
+`qk_prep_fwd` and `qk_prep_bwd` (two a layer and direction) beside the
+projections' products.
 """
 
 from __future__ import annotations
@@ -34,7 +48,8 @@ from flax import linen as nn
 from jax import lax
 
 from ...core.config import LMConfig
-from ...ops.attention import CAUSAL, Mask, causal_attention, grouped_attention
+from ...ops.attention import (CAUSAL, Mask, attention_route, causal_attention,
+                              grouped_attention)
 
 F32 = jnp.float32
 ragged_dot = lax.ragged_dot  # a name of this module's: a test stands in for the chip's
@@ -51,6 +66,16 @@ def dot(x, w, dtype):
                            preferred_element_type=F32)
 
 
+def heads_dot(x, w, dtype):
+    """x[b, s, k] @ w[k, h, n] -> [b, h, s, n] in `dtype`: `dot`'s product
+    (operands in `dtype`, float32 accumulation) rounded once and laid out
+    head-major as it leaves, for an operand of the fused attention that
+    has no arithmetic between its product and its cast."""
+    return jnp.transpose(lax.dot_general(
+        x.astype(dtype), w.astype(dtype), (((2,), (0,)), ((), ())),
+        preferred_element_type=F32).astype(dtype), (0, 2, 1, 3))
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-6
 
@@ -59,6 +84,17 @@ class RMSNorm(nn.Module):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), F32)
         x = x.astype(F32)
         return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.eps) * scale
+
+
+class HeadScale(nn.Module):
+    """The learned scale of a per-head `RMSNorm`, alone and under the same
+    name, for the fused pass that norms inside its kernel."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.width,), F32)
 
 
 def rope(x, theta: float):
@@ -126,20 +162,41 @@ class MLA(nn.Module):
         wkva = self.param("wkva", init, (d, c.kv_lora_rank + dr), F32)
         wkvb = self.param("wkvb", init, (c.kv_lora_rank, nh * (dn + dv)), F32)
         wo = self.param("wo", init, (nh * dv, d), F32)
+        prep = attention_route(s, c.attn_block_q, self.route_dims(c),
+                               self.mask)["prep"]
+        fused = prep["path"] == "fused"
         with jax.named_scope("mla_proj"):
-            q = dot(h, wq, dt).reshape(b, s, nh, dn + dr)
-            qn = q[..., :dn].astype(dt)
-            qr = rope(q[..., dn:], c.rope_theta).astype(dt)
-            ckv = dot(h, wkva, dt)
-            kr = rope(ckv[:, :, None, c.kv_lora_rank:],
-                      c.rope_theta)[:, :, 0].astype(dt)
-            lat = RMSNorm(c.rms_norm_eps, name="kv_norm")(
-                ckv[..., :c.kv_lora_rank])
-            kv = dot(lat, wkvb, dt).reshape(b, s, nh, dn + dv)
-            kn, v = kv[..., :dn].astype(dt), kv[..., dn:].astype(dt)
+            if fused:
+                from ...ops.pallas.qk_prep import qk_prep
+
+                rot = functools.partial(
+                    qk_prep, positions=self.mask.rope_positions(s),
+                    theta=c.rope_theta, interleave=True, dtype=dt,
+                    block_s=prep["block_s"])
+                lr = c.kv_lora_rank
+                wq = wq.reshape(d, nh, dn + dr)
+                qn = heads_dot(h, wq[..., :dn], dt)
+                qr = rot(dot(h, wq[..., dn:].reshape(d, nh * dr), dt), heads=nh)
+                kr = rot(dot(h, wkva[:, lr:], dt), heads=1)[:, 0]
+                lat = RMSNorm(c.rms_norm_eps, name="kv_norm")(
+                    dot(h, wkva[:, :lr], dt))
+                wkvb = wkvb.reshape(lr, nh, dn + dv)
+                kn = heads_dot(lat, wkvb[..., :dn], dt)
+                v = heads_dot(lat, wkvb[..., dn:], dt)
+            else:
+                q = dot(h, wq, dt).reshape(b, s, nh, dn + dr)
+                qn = q[..., :dn].astype(dt)
+                qr = rope(q[..., dn:], c.rope_theta).astype(dt)
+                ckv = dot(h, wkva, dt)
+                kr = rope(ckv[:, :, None, c.kv_lora_rank:],
+                          c.rope_theta)[:, :, 0].astype(dt)
+                lat = RMSNorm(c.rms_norm_eps, name="kv_norm")(
+                    ckv[..., :c.kv_lora_rank])
+                kv = dot(lat, wkvb, dt).reshape(b, s, nh, dn + dv)
+                kn, v = kv[..., :dn].astype(dt), kv[..., dn:].astype(dt)
         with jax.named_scope("mla_scores"):
-            o = causal_attention(qn, qr, kn, kr, v,
-                                 1.0 / math.sqrt(dn + dr), c.attn_block_q, dt)
+            o = causal_attention(qn, qr, kn, kr, v, 1.0 / math.sqrt(dn + dr),
+                                 c.attn_block_q, dt, head_major=fused)
         with jax.named_scope("mla_out"):
             return dot(o.reshape(b, s, nh * dv), wo, dt)
 
@@ -181,18 +238,34 @@ class GQA(nn.Module):
         wk = self.param("wk", init, (d, g * hd), F32)
         wv = self.param("wv", init, (d, g * hd), F32)
         wo = self.param("wo", init, (nh * hd, d), F32)
+        prep = attention_route(s, c.attn_block_q, self.route_dims(c),
+                               self.mask)["prep"]
+        fused = prep["path"] == "fused"
         with jax.named_scope("gqa_proj"):
             pos = self.mask.rope_positions(s)
-            q = RMSNorm(c.rms_norm_eps, name="q_norm")(
-                dot(h, wq, dt).reshape(b, s, nh, hd))
-            k = RMSNorm(c.rms_norm_eps, name="k_norm")(
-                dot(h, wk, dt).reshape(b, s, g, hd))
-            q = rope_halves(q, c.rope_theta, pos).astype(dt)
-            k = rope_halves(k, c.rope_theta, pos).astype(dt)
-            v = dot(h, wv, dt).reshape(b, s, g, hd).astype(dt)
+            if fused:
+                from ...ops.pallas.qk_prep import qk_prep
+
+                normed = functools.partial(
+                    qk_prep, positions=pos, theta=c.rope_theta,
+                    interleave=False, dtype=dt, block_s=prep["block_s"],
+                    eps=c.rms_norm_eps)
+                q = normed(dot(h, wq, dt), heads=nh,
+                           scale=HeadScale(hd, name="q_norm")())
+                k = normed(dot(h, wk, dt), heads=g,
+                           scale=HeadScale(hd, name="k_norm")())
+                v = heads_dot(h, wv.reshape(d, g, hd), dt)
+            else:
+                q = RMSNorm(c.rms_norm_eps, name="q_norm")(
+                    dot(h, wq, dt).reshape(b, s, nh, hd))
+                k = RMSNorm(c.rms_norm_eps, name="k_norm")(
+                    dot(h, wk, dt).reshape(b, s, g, hd))
+                q = rope_halves(q, c.rope_theta, pos).astype(dt)
+                k = rope_halves(k, c.rope_theta, pos).astype(dt)
+                v = dot(h, wv, dt).reshape(b, s, g, hd).astype(dt)
         with jax.named_scope("gqa_scores"):
-            o = grouped_attention(q, k, v, 1.0 / math.sqrt(hd),
-                                  c.attn_block_q, dt, self.mask)
+            o = grouped_attention(q, k, v, 1.0 / math.sqrt(hd), c.attn_block_q,
+                                  dt, self.mask, head_major=fused)
         with jax.named_scope("gqa_out"):
             return dot(o.reshape(b, s, nh * hd), wo, dt)
 

@@ -38,6 +38,20 @@ rule) and is what the trainer writes into its step-0 info record, with the
 mask's name and, as static counts, the (query block, key block) tiles the
 rule lets the path visit over all of them; there is no option that picks
 a path.
+
+The route's SECOND decision, `prep`: how the layers get from a
+projection's float32 output to the operands above (per-head norm where the
+family has one, rotary positions, the cast).
+
+  - `fused` (`ops/pallas/qk_prep.py`, kernels `qk_prep_fwd` /
+    `qk_prep_bwd`): wherever the scores are fused and the rotated width is
+    one the pass's slabs hold. One pass reads the product's output and
+    writes the operand head-major, [b, h, s, d], which the fused attention
+    takes as it is (`head_major`); operands with no arithmetic before the
+    cast leave their products in that layout and dtype.
+  - `xla`: everywhere else; the layers' own elementwise code
+    (`models/lm/layers.py::rope`, `rope_halves`, `RMSNorm`) on [b, s, h, d],
+    and the fused path's oracle in the tests.
 """
 
 from __future__ import annotations
@@ -60,6 +74,9 @@ FUSED_BLOCK_MULTIPLE = 128
 #: float32 part a key block: fewer blocks, fewer parts). In the cell's
 #: step 2048 read 2.7% more tokens a second than 1024.
 FUSED_BLOCK_KV = 2048
+#: Positions a grid step of the fused preparation pass holds: the largest
+#: of these that divides the row (a fused row is a multiple of 128).
+PREP_BLOCKS_S = (512, 256, 128)
 #: `jax.ad_checkpoint.checkpoint_name` of the fused forward's output and
 #: logsumexp (64 MB + 1 MB a layer at the cell's size): a layer recomputed
 #: in the backward (`train.remat`) whose policy keeps them does not run the
@@ -198,13 +215,16 @@ def attention_route(positions: int, block_q: int, head_dims,
     (qk_nope, qk_rope, v) (a grouped layer: (head_dim, 0, head_dim)) and
     `mask`: {"path": "fused", "block_q", "block_kv"} or {"path":
     "xla_blocks", "block_q"}, with "mask" (the rule's name, and its blocks
-    where it has them) and "tiles" (`Mask.tiles` at the path's blocks; the
-    XLA blocks count keys in blocks of `block_q`). The one rule the layers
-    and the trainer's step-0 info record share. Fused: on a TPU, blocks of
-    whole 128-lane registers, head sizes the kernels' tiles hold (128s; the
-    rotary part 64s); under `block_diffusion` also blocks of a power of
-    two of positions that tile a query block, and query and key blocks
-    that tile a copy of the row."""
+    where it has them), "tiles" (`Mask.tiles` at the path's blocks; the
+    XLA blocks count keys in blocks of `block_q`) and "prep" ({"path":
+    "fused", "block_s"} or {"path": "xla"}: the module's docstring). The
+    one rule the layers and the trainer's step-0 info record share. Fused:
+    on a TPU, blocks of whole 128-lane registers, head sizes the kernels'
+    tiles hold (128s; the rotary part 64s); under `block_diffusion` also
+    blocks of a power of two of positions that tile a query block, and
+    query and key blocks that tile a copy of the row. The pass is fused
+    where the scores are and the rotated width (the rotary part, or the
+    whole head where there is none) is 64 or a power of two of 128s."""
     bq = min(block_q, mask.half or positions)
     if positions % bq or (mask.half and (mask.half % bq
                                          or positions != 2 * mask.half)):
@@ -221,10 +241,14 @@ def attention_route(positions: int, block_q: int, head_dims,
     if fused:
         row = mask.half or positions
         bkv = bq if row % FUSED_BLOCK_KV else FUSED_BLOCK_KV
+        rotated = dr or dn
+        prep = {"path": "xla"} if rotated & (rotated - 1) else {
+            "path": "fused",
+            "block_s": next(n for n in PREP_BLOCKS_S if positions % n == 0)}
         return {"path": "fused", "block_q": bq, "block_kv": bkv, **named,
-                "tiles": mask.tiles(positions, bq, bkv)}
+                "tiles": mask.tiles(positions, bq, bkv), "prep": prep}
     return {"path": "xla_blocks", "block_q": bq, **named,
-            "tiles": mask.tiles(positions, bq, bq)}
+            "tiles": mask.tiles(positions, bq, bq), "prep": {"path": "xla"}}
 
 
 def _attend_block(qn, qr, kn, kr, v, q0: int, scale: float, dtype):
@@ -255,18 +279,24 @@ def xla_blocks_attention(qn, qr, kn, kr, v, scale: float, block_q: int, dtype):
     return jnp.concatenate(outs, axis=1)
 
 
-def causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int, dtype):
+def causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int, dtype,
+                     head_major: bool = False):
     """qn[b,s,h,dn] qr[b,s,h,dr] kn[b,s,h,dn] kr[b,s,dr] v[b,s,h,dv], all in
     `dtype` -> [b,s,h,dv], by the path `attention_route` names: float32
     from the XLA blocks, `dtype` from the fused kernels (the output
-    projection casts to it anyway)."""
-    route = attention_route(qn.shape[1], block_q,
+    projection casts to it anyway). `head_major`: qn, qr, kn and v come
+    [b,h,s,d], from a layer whose route's `prep` is fused (so the scores
+    are: the XLA blocks take no such operands)."""
+    route = attention_route(kr.shape[1], block_q,
                             (qn.shape[-1], qr.shape[-1], v.shape[-1]))
     if route["path"] == "fused":
         from .pallas.attention import fused_causal_attention
 
         return fused_causal_attention(qn, qr, kn, kr, v, scale,
-                                      route["block_q"], route["block_kv"])
+                                      route["block_q"], route["block_kv"],
+                                      head_major=head_major)
+    if head_major:
+        raise ValueError("attention: head-major operands on the XLA blocks")
     return xla_blocks_attention(qn, qr, kn, kr, v, scale, route["block_q"],
                                 dtype)
 
@@ -303,16 +333,21 @@ def xla_blocks_grouped_attention(q, k, v, scale: float, block_q: int, dtype,
 
 
 def grouped_attention(q, k, v, scale: float, block_q: int, dtype,
-                      mask: Mask = CAUSAL):
+                      mask: Mask = CAUSAL, head_major: bool = False):
     """q[b,s,h,d] k, v[b,s,g,d] (g divides h), all in `dtype` -> [b,s,h,d],
     by the path `attention_route` names: float32 from the XLA blocks,
-    `dtype` from the fused kernels."""
+    `dtype` from the fused kernels. `head_major`: q[b,h,s,d] k, v[b,g,s,d],
+    as `causal_attention`."""
     d = q.shape[-1]
-    route = attention_route(q.shape[1], block_q, (d, 0, d), mask)
+    route = attention_route(q.shape[2 if head_major else 1], block_q,
+                            (d, 0, d), mask)
     if route["path"] == "fused":
         from .pallas.attention import fused_grouped_attention
 
         return fused_grouped_attention(q, k, v, scale, route["block_q"],
-                                       route["block_kv"], mask)
+                                       route["block_kv"], mask,
+                                       head_major=head_major)
+    if head_major:
+        raise ValueError("attention: head-major operands on the XLA blocks")
     return xla_blocks_grouped_attention(q, k, v, scale, route["block_q"],
                                         dtype, mask)

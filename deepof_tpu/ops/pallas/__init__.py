@@ -22,6 +22,13 @@ Kernel inventory (and why each op is/isn't a kernel):
     step; the kernels took the language-model cell's `mla_scores` from
     523 to 63 ms a step (PR 32).
 
+  - `qk_prep.py` — the pass between an attention layer's projections and
+    those kernels (`models/lm`), forward and backward: per-head norm,
+    rotary positions, the cast and the head-major layout in one read and
+    one write a tensor. XLA's elementwise chain (float32 norm and
+    rotation, slices, the rotation's gathers, a pad, the cast, layout
+    copies) took a query through HBM five times (PR 38).
+
   The expert layer's grouped products are XLA's own Mosaic fusion for
   `lax.ragged_dot`, not a kernel of this package.
 

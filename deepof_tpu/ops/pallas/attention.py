@@ -282,15 +282,19 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def fused_causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int,
-                           block_kv: int, interpret: bool = False):
+                           block_kv: int, interpret: bool = False,
+                           head_major: bool = False):
     """qn[b,s,h,dn] qr[b,s,h,dr] kn[b,s,h,dn] kr[b,s,dr] v[b,s,h,dv], one
-    dtype -> [b,s,h,dv] in it. `block_q` and `block_kv` are multiples of
-    128 that divide s. Under a `mesh_context` the kernels run once per
-    batch shard."""
+    dtype -> [b,s,h,dv] in it. `head_major`: the four operands with heads
+    come as the kernels take them, [b,h,s,d] (from `qk_prep` and the
+    products that write that layout), and no copy of them is made here;
+    their cotangents go back head-major too. `block_q` and `block_kv` are
+    multiples of 128 that divide s. Under a `mesh_context` the kernels run
+    once per batch shard."""
     def rows(qn, qr, kn, kr, v):
-        heads_first = [jnp.swapaxes(a, 1, 2) for a in (qn, qr, kn, v)]
-        o = _attend(*heads_first[:3], kr, heads_first[3], scale, block_q,
-                    block_kv, interpret)
+        if not head_major:
+            qn, qr, kn, v = (jnp.swapaxes(a, 1, 2) for a in (qn, qr, kn, v))
+        o = _attend(qn, qr, kn, kr, v, scale, block_q, block_kv, interpret)
         return jnp.swapaxes(o, 1, 2)
 
     return shard_over_batch(rows, current_mesh(), qn.shape[0])(
@@ -502,15 +506,18 @@ _rule_attend.defvjp(_rule_attend_fwd, _rule_attend_bwd)
 
 def fused_grouped_attention(q, k, v, scale: float, block_q: int,
                             block_kv: int, mask: Mask,
-                            interpret: bool = False):
+                            interpret: bool = False, head_major: bool = False):
     """q[b,s,h,d] k, v[b,s,g,d] (g divides h), one dtype -> [b,s,h,d] in
-    it, under `mask`. `block_q` and `block_kv` are multiples of 128 that
-    divide s (and a copy of a doubled row); under `block_diffusion` the
-    rule's blocks are a power of two of positions that tile `block_q`.
-    Under a `mesh_context` the kernels run once per batch shard."""
+    it, under `mask`. `head_major`: q[b,h,s,d] k, v[b,g,s,d], as the
+    kernels take them, no copy made here (`fused_causal_attention`).
+    `block_q` and `block_kv` are multiples of 128 that divide s (and a copy
+    of a doubled row); under `block_diffusion` the rule's blocks are a
+    power of two of positions that tile `block_q`. Under a `mesh_context`
+    the kernels run once per batch shard."""
     def rows(q, k, v):
-        o = _rule_attend(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)), scale,
-                         block_q, block_kv, mask, interpret)
+        if not head_major:
+            q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
+        o = _rule_attend(q, k, v, scale, block_q, block_kv, mask, interpret)
         return jnp.swapaxes(o, 1, 2)
 
     return shard_over_batch(rows, current_mesh(), q.shape[0])(q, k, v)

@@ -173,8 +173,10 @@ def pair_axis_constraint(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def shard_over_batch(fn, mesh: Mesh | None, batch: int,
-                     axes: tuple[str, ...] = ("data",)):
-    """Run `fn` (rank-4 (B, ...) arrays in, one out) once per batch shard.
+                     axes: tuple[str, ...] = ("data",), whole: int = 0):
+    """Run `fn` (rank-4 (B, ...) arrays in, one out) once per batch shard;
+    its last `whole` operands have no batch axis (a table, a learned
+    scale) and every shard sees all of each.
 
     The one multi-device form of the Pallas kernels: GSPMD cannot see
     inside a `pallas_call` and Mosaic refuses to be partitioned
@@ -200,8 +202,12 @@ def shard_over_batch(fn, mesh: Mesh | None, batch: int,
             f"Pallas kernel: leading axis {batch} is not divisible by mesh "
             f"axes {axes} = {shards}; every device runs the whole batch",
             stacklevel=2)
-    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                         check_vma=False)
+    if not whole:
+        return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                             check_vma=False)
+    return lambda *a: jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec,) * (len(a) - whole) + (P(),) * whole,
+        out_specs=spec, check_vma=False)(*a)
 
 
 def halo_exchange(x: jnp.ndarray, halo: int, axis_name: str = "spatial",

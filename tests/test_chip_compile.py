@@ -362,6 +362,53 @@ def test_block_mask_attention_kernels_compile_for_v5e(one_chip, which):
             kernels=["bd_attn_fwd", "bd_attn_bwd"])
 
 
+PREP = {
+    # the product's output [rows, positions, heads * d], heads, interleaved
+    # pairs, normed: what the two language-model cells' layers hand the pass
+    "kanana_q_rope_32x64": ((2, 4096, 32 * 64), 32, True, False),
+    "kanana_k_rope_1x64": ((2, 4096, 64), 1, True, False),
+    "sdar_q_32x128": ((1, 8192, 32 * 128), 32, False, True),
+    "sdar_k_4x128": ((1, 8192, 4 * 128), 4, False, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREP))
+@pytest.mark.parametrize("which", ["fwd", "vjp"])
+def test_qk_prep_kernels_compile_for_v5e(one_chip, which, case):
+    """The fused preparation pass between a projection and the attention
+    kernels at both cells' shapes and the route's block of 512 positions:
+    the latent layer's rotary parts (interleaved pairs; 32 heads of 64, two
+    a register, and the one shared key head, a block 64 lanes wide), the
+    grouped layer's q and k (per-head norm, rotary halves at the positions
+    of a doubled row); the forward kernel alone, and the custom VJP's pair
+    under a gradient with respect to the product's output and the scale.
+    Lane rolls by 1, 32, 64 and 127, the split of a register into two
+    heads, and a lane reduction a head are what Mosaic has to take."""
+    from deepof_tpu.ops.attention import CAUSAL, Mask
+    from deepof_tpu.ops.pallas.qk_prep import qk_prep
+
+    shape, heads, interleave, normed = PREP[case]
+    mask = CAUSAL if interleave else Mask("block_diffusion", 4, shape[1] // 2)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((shape[2] // heads,), jnp.float32,
+                                 sharding=one_chip)
+
+    def prep(x, scale):
+        with jax.named_scope("mla_proj" if interleave else "gqa_proj"):
+            return qk_prep(x, mask.rope_positions(shape[1]), heads, 1e6,
+                           interleave, jnp.bfloat16, 512,
+                           scale if normed else None,
+                           1e-6 if normed else None)
+
+    if which == "fwd":
+        text = _compiled_text(prep, x, scale, kernels=["qk_prep_fwd"])
+        assert f"bf16[{shape[0]},{heads},{shape[1]},{shape[2] // heads}]" in text
+    else:
+        _compiled_text(jax.grad(lambda x, g: jnp.sum(prep(x, g).astype(
+            jnp.float32) ** 2), argnums=(0, 1) if normed else 0), x, scale,
+            kernels=["qk_prep_fwd", "qk_prep_bwd"])
+
+
 def test_attention_compiles_through_shard_map_on_four_chips(topo):
     """Under a mesh the kernels run per batch shard: 4 rows over the four
     described chips' "data" axis, no row gathered."""

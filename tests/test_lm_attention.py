@@ -146,7 +146,7 @@ def test_trainer_writes_the_route_into_its_step_0_info_record(tmp_path):
     assert routes[0]["objective"] == "next_token"
     assert routes[0]["attention_route"] == {
         "path": "xla_blocks", "block_q": 16, "mask": "causal",
-        "tiles": {"visited": 3, "all": 4}}
+        "tiles": {"visited": 3, "all": 4}, "prep": {"path": "xla"}}
 
 
 # ---- grouped-query attention under a mask rule (`bd_attn_fwd` / `_bwd`) ----
@@ -220,7 +220,8 @@ def test_route_under_the_block_mask_names_it_and_counts_its_tiles(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert A.attention_route(8192, 512, GQA_DIMS, BD) == {
         "path": "fused", "block_q": 512, "block_kv": 2048, "mask": BD_NAMED,
-        "tiles": {"visited": 32, "all": 64}}
+        "tiles": {"visited": 32, "all": 64},
+        "prep": {"path": "fused", "block_s": 512}}
     # blocks that are no power of two, or do not tile a query tile: XLA
     for block in (3, 1024):
         odd = A.Mask("block_diffusion", block, 4096)
@@ -228,7 +229,7 @@ def test_route_under_the_block_mask_names_it_and_counts_its_tiles(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert A.attention_route(8192, 512, GQA_DIMS, BD) == {
         "path": "xla_blocks", "block_q": 512, "mask": BD_NAMED,
-        "tiles": {"visited": 80, "all": 256}}
+        "tiles": {"visited": 80, "all": 256}, "prep": {"path": "xla"}}
 
 
 def test_the_grouped_layer_takes_the_fused_path_where_the_route_says(monkeypatch):

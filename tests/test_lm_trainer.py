@@ -62,6 +62,7 @@ def test_trainer_fits_logs_counters_checkpoints_and_restores(tmp_path):
         assert "warp_sweep_rows_by_scale" not in r
     routes, = [r for r in records if r.get("message") == "routes"]
     assert routes["step"] == 0 and routes["attention_route"]["path"] == "xla_blocks"
+    assert routes["attention_route"]["prep"] == {"path": "xla"}  # the CPU's
     # a row's share: 2 of 8 held, twice even, up to the grouped product's tile
     assert routes["expert_rows"] == {"cap": 512, "slots": 1024}
     params = jax.device_get(trainer.state.params)
@@ -252,7 +253,7 @@ def test_second_family_trains_from_its_config_file_and_resumes_its_noise(tmp_pat
     assert routes["attention_route"] == {
         "path": "xla_blocks", "block_q": 16,
         "mask": {"rule": "block_diffusion", "block": 4, "half": 32},
-        "tiles": {"visited": 8, "all": 16}}
+        "tiles": {"visited": 8, "all": 16}, "prep": {"path": "xla"}}
     assert routes["expert_rows"] == {"cap": 128, "slots": 128}  # of 64 positions
     ev = whole.evaluate()
     assert set(ev) == {"val_loss", "val_perplexity"} and np.isfinite(ev["val_loss"])

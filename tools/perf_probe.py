@@ -27,6 +27,11 @@ Sections:
   attn     the latent attention's causal scores at the language-model
            cell's layer shape: XLA blocks vs the fused kernels by key
            block, forward and forward + backward, and their difference
+  proj     the projection block of either language-model family alone
+           (`mla_proj` / `gqa_proj`: the products, norm, rotary positions,
+           cast and layout, up to the attention's operands) at its cell's
+           layer shape: the XLA route vs the fused pass (`qk_prep_*`),
+           forward and forward + backward, and their difference
 """
 
 from __future__ import annotations
@@ -260,6 +265,138 @@ def sec_attn(block_kvs=(512, 1024, 2048), s=4096, h=32, bq=512,
               + " ".join(f"{e:.2e}" for e in err[1:]), flush=True)
 
 
+def sec_proj(families=("mla", "gqa"), configs=None, rows=None,
+             interpret=False, inner=10) -> None:
+    """The projection block of one attention layer of each language-model
+    cell (`kanana2_30b_a3b_ep8.train_4k`: 2 rows x 4096 positions into qn,
+    qr, kn, kr, v; `sdar_30b_a3b_ep8.train_bd_4k`: one doubled row of 8192
+    into q, k, v; bfloat16, float32 masters), exactly as the layer writes
+    it: the layer runs with its attention call replaced by a tap that
+    keeps the operands, so the products, the elementwise chain and the
+    layouts are the layer's own and nothing after them is. Both routes of
+    `ops/attention.py::attention_route`'s `prep`, the XLA one steered by
+    the backend's name: forward, forward + backward (cotangents handed in
+    as the attention's backward hands them), ms a layer, and the largest
+    difference between the routes' operands and gradients. The keywords
+    are for a rehearsal off the chip (`interpret=True`, toy configs)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepof_tpu.core.config import LMConfig, fill_lm_from_file
+    from deepof_tpu.models.lm import layers
+    from deepof_tpu.ops.attention import CAUSAL, Mask
+    from deepof_tpu.ops.pallas import qk_prep as prep_mod
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs")
+    cells = {  # layer, configuration, the call it taps, (rows, positions)
+        "mla": (layers.MLA, "kanana2_30b_a3b_ep8.json", "causal_attention",
+                (2, 4096)),
+        "gqa": (layers.GQA, "sdar_30b_a3b_ep8.json", "grouped_attention",
+                (1, 8192)),
+    }
+    dt = jnp.bfloat16
+    backend = jax.default_backend
+    real_prep = prep_mod.qk_prep
+    for family in families:
+        layer, file, attend, shape = cells[family]
+        cfg = (configs or {}).get(family) or fill_lm_from_file(
+            LMConfig(), os.path.join(root, file))
+        b, s = (rows or {}).get(family, shape)
+        mask = CAUSAL if family == "mla" else Mask(
+            "block_diffusion", cfg.block_length, s // 2)
+        module = layer(cfg, dt, mask)
+        kh, kp, kc = jax.random.split(jax.random.PRNGKey(0), 3)
+        h = jax.random.normal(kh, (b, s, cfg.hidden_size), jnp.float32)
+        params = jax.jit(lambda k: module.init(k, h)["params"])(kp)
+
+        def block(route):
+            """(params, h) -> the attention's operands on `route`, as the
+            layer hands them over ([b, s, h, d]; head-major on the fused
+            route)."""
+            def operands(p, h):
+                taken = []
+
+                def tap(*a, head_major=False, **kw):
+                    ops = a[:5 if family == "mla" else 3]
+                    taken.append(ops)
+                    rows_, heads = (0, 1) if head_major else (0, 2)
+                    return jnp.zeros((ops[0].shape[rows_], s,
+                                      ops[0].shape[heads], ops[-1].shape[-1]), dt)
+
+                held = getattr(layers, attend)
+                setattr(layers, attend, tap)
+                jax.default_backend = (lambda: "cpu") if route == "xla" else backend
+                prep_mod.qk_prep = (lambda *a, **kw: real_prep(
+                    *a, interpret=True, **kw)) if interpret else real_prep
+                try:
+                    module.apply({"params": p}, h)
+                finally:
+                    setattr(layers, attend, held)
+                    jax.default_backend = backend
+                    prep_mod.qk_prep = real_prep
+                return taken[0]
+
+            return operands
+
+        def looped(one):
+            """`inner` runs of `one(h, params, cts) -> scalar` chained through
+            `h` inside ONE program: a layer's block is 2-9 ms, a dispatch
+            with its perturbed leaves 2 ms of host time."""
+            def run(a):
+                def body(_, c):
+                    return one(a[1] + (c * 0).astype(a[1].dtype), a[0], a[2])
+                return jax.lax.fori_loop(0, inner, body, jnp.zeros(()))
+            return jax.jit(run)
+
+        # every result whole behind a barrier, then one element of each: a
+        # sum or a slice of a product's output is rewritten by XLA into a
+        # product of sums or of slices, and the probe would time that
+        whole = lambda t: sum(  # noqa: E731
+            o.ravel()[0].astype(jnp.float32) for o in
+            jax.tree_util.tree_leaves(jax.lax.optimization_barrier(t)))
+        outs, grads = {}, {}
+        for route in ("xla", "fused"):
+            ops_of = block(route)
+            outs[route] = jax.jit(ops_of)(params, h)
+            cts = tuple(jax.random.normal(k, o.shape).astype(dt) for k, o in
+                        zip(jax.random.split(kc, len(outs[route])), outs[route]))
+            grads[route] = jax.jit(lambda p, hh, ct, f=ops_of: jax.vjp(
+                f, p, hh)[1](ct))
+
+            def both(hh, p, ct, f=ops_of):
+                # the forward's results too: alone, the gradients of
+                # products need no forward, and this would time a backward
+                res, back = jax.vjp(f, p, hh)
+                return whole((res, back(ct)))
+
+            t_f = timeit(f"proj {family} {route} fwd x{inner}",
+                         looped(lambda hh, p, _, f=ops_of: whole(f(p, hh))),
+                         (params, h, cts), steps=2, windows=2) / inner
+            t_g = timeit(f"proj {family} {route} fwd+bwd x{inner}", looped(both),
+                         (params, h, cts), steps=2, windows=2) / inner
+            print(f"proj {family} {route}: a layer fwd {1e3 * t_f:.2f} ms, "
+                  f"fwd+bwd {1e3 * t_g:.2f} ms; a step's 5 x (2 fwd + bwd) = "
+                  f"{5e3 * (t_f + t_g):.1f} ms", flush=True)
+        # the routes compared on the SAME cotangents: the xla route's, laid
+        # out head-major for the fused one
+        major = lambda t: tuple(  # noqa: E731
+            a if a.ndim == 3 else jnp.swapaxes(a, 1, 2) for a in t)
+        cts = tuple(jax.random.normal(k, o.shape).astype(dt) for k, o in
+                    zip(jax.random.split(kc, len(outs["xla"])), outs["xla"]))
+        gap = lambda a, r: float(jnp.max(jnp.abs(  # noqa: E731
+            a.astype(jnp.float32) - r.astype(jnp.float32))))
+        print(f"proj {family}: max |fused - xla| operands " + " ".join(
+            f"{gap(a, r):.2e}" for a, r in zip(outs["fused"], major(outs["xla"]))),
+            flush=True)
+        gx = grads["xla"](params, h, cts)
+        gf = grads["fused"](params, h, major(cts))
+        print(f"proj {family}: max |fused - xla| / max |xla| gradients " + " ".join(
+            f"{jax.tree_util.keystr(k)}={gap(a, r) / max(float(jnp.max(jnp.abs(r))), 1e-30):.2e}"
+            for (k, r), a in zip(jax.tree_util.tree_leaves_with_path(gx),
+                                 jax.tree_util.tree_leaves(gf))), flush=True)
+
+
 def sec_decomp() -> None:
     import jax
     import jax.numpy as jnp
@@ -383,6 +520,7 @@ SECTIONS = {
     "multiframe": sec_multiframe,
     "warp": sec_warp,
     "attn": sec_attn,
+    "proj": sec_proj,
 }
 
 
