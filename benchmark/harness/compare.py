@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import statistics
 
+import numpy as np
+
 
 def worst_leaf_gap(prog: dict, ref: dict, keep=None,
                    gaps_out: list | None = None) -> tuple[float, str]:
@@ -51,11 +53,22 @@ def train_numbers(prog: dict, ref: dict) -> dict:
     level_gap = max(level_gaps, default=float("nan"))
     # their root mean square: no one level's chance cancellation hides a
     # lower precision, and no one level's tail sets the reading
-    level_rms = (sum(g * g for g in level_gaps) / len(level_gaps)) ** 0.5 \
-        if level_gaps else float("nan")
+    rms = lambda gaps: (sum(g * g for g in gaps) / len(gaps)) ** 0.5 \
+        if gaps else float("nan")  # noqa: E731
+    level_rms = rms(level_gaps)
     if [len(x) for x in prog["level_losses"]] != [len(x) for x in ref["level_losses"]]:
         level_gap = level_rms = float("nan")
-    return {"loss_gap": loss_gap, "loss_gap_step1": first,
+    # the levels' smoothness parts alone, a function of the flow field's
+    # neighbouring differences and of nothing else: where the photometric
+    # mean averages a pixel's rounding away, this keeps it. The first
+    # step's only: the later steps' follow Adam's first sign-like updates
+    smooth_rms = rms([rel(a, abs(b)) for a, b in zip(
+        prog.get("level_smooth_losses", [[]])[0],
+        ref.get("level_smooth_losses", [[]])[0]) if abs(b) > 1e-6])
+    share_gaps, share_leaves = grad_share_gaps(prog, ref)
+    return {**share_gaps,
+            "loss_gap": loss_gap, "loss_gap_step1": first,
+            "level_smooth_rms_gap_step1": smooth_rms,
             "level_loss_gap": level_gap, "level_loss_rms_gap": level_rms,
             "grad_norm_gap": grad_gap, "dparam_norm_gap": dp_gap,
             # the norms over all leaves together: what the big leaves do
@@ -64,7 +77,36 @@ def train_numbers(prog: dict, ref: dict) -> dict:
             # steadier companions of the two worst-leaf numbers
             "grad_norm_gap_median": statistics.median(g_all),
             "dparam_norm_gap_median": statistics.median(d_all),
-            "_where": {"grad_norm_gap": grad_leaf, "dparam_norm_gap": dp_leaf}}
+            "_where": {"grad_norm_gap": grad_leaf, "dparam_norm_gap": dp_leaf,
+                       **share_leaves}}
+
+
+def grad_share_gaps(prog: dict, ref: dict) -> tuple[dict, dict]:
+    """For each part whose backward the reference cut at the first step
+    (`grad_cuts`, by leaf: `reference/_common.py::flow_through`): d is the
+    part of the reference's first gradient that flows back through it,
+    taken at a right angle to the rest of the leaf's gradient, and the
+    number |sum_k w_k <g_program - g_reference, d>_k| / sum_k w_k <d, d>_k
+    over the leaves k that d reaches, each weighted by 1 / |g_reference|_k^2:
+    0 where the program's gradient holds that part whole, 1 where it holds
+    none of it, a half where it holds half. A norm's gap cannot see a part
+    that is a few hundredths of a leaf's gradient; of the rounding of the
+    rest of the gradient only what falls on the one direction d is read.
+    Returns (numbers, for each a line with every leaf's own reading)."""
+    out, where = {}, {}
+    for name, cut in ref.get("grad_cuts", {}).items():
+        key = "grad_share_gap_" + name
+        have = prog.get("first_grads") or {}
+        if not cut["d"] or any(k not in have for k in cut["d"]):
+            out[key] = float("nan")
+            continue
+        off = {k: float(np.vdot(np.asarray(have[k], np.float64), d)) - cut["ref_dot"][k]
+               for k, d in cut["d"].items()}
+        out[key] = abs(sum(cut["w"][k] * off[k] for k in off)) / sum(
+            cut["w"][k] * cut["dd"][k] for k in off)
+        where[key] = " ".join(f"{k} off={off[k]:.6g} dd={cut['dd'][k]:.6g} "
+                              f"w={cut['w'][k]:.6g}" for k in off)
+    return out, where
 
 
 def judge(numbers: dict, limits: dict) -> tuple[bool, dict]:

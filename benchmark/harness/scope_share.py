@@ -7,12 +7,29 @@ runner without it gives the readers nothing to read."""
 
 from __future__ import annotations
 
+import functools
 import re
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name=\"([^\"]*)\"")
 _EVENT = re.compile(r"^%?([\w.\-]+)")
 #: containers: their own event covers their bodies' events, which count
 CONTAINERS = ("while", "conditional", "call")
+
+
+def arg_specs(args):
+    """Shapes, types and shardings of a step call's arguments: enough to
+    lower the step again once the arguments are gone."""
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+        args)
+
+
+def executable_scopes(step, specs) -> dict:
+    """`op_scopes` of the step's executable, compiled again from the specs
+    of its first call (a load from the compile cache)."""
+    return op_scopes(step.lower(*specs).compile().as_text())
 
 
 def op_scopes(hlo_text: str) -> dict:
@@ -31,14 +48,15 @@ def under(op_name: str, scope: str) -> bool:
     return re.search(r"(?<![\w])" + re.escape(scope) + r"(?![\w])", op_name) is not None
 
 
-def seconds_by_scope(obs: dict, scopes) -> tuple[dict, float] | None:
-    """({scope: device seconds}, seconds of all events) of the traced
-    window, containers left out. None where the runner gave no map or no
-    event found its instruction."""
+def seconds_matching(obs: dict, tests: dict) -> tuple[dict, float] | None:
+    """({name: device seconds of the events whose `op_name` the name's test
+    accepts}, seconds of all events) of the traced window, containers left
+    out. None where the runner gave no map or no event found its
+    instruction."""
     table = obs.get("op_scopes")
     if not table:
         return None
-    out, total, found = {s: 0.0 for s in scopes}, 0.0, 0
+    out, total, found = {n: 0.0 for n in tests}, 0.0, 0
     for name, (sec, _) in obs["device"]["ops"].items():
         m = _EVENT.match(name)
         instr = m.group(1) if m else ""
@@ -49,10 +67,24 @@ def seconds_by_scope(obs: dict, scopes) -> tuple[dict, float] | None:
         if op is None:
             continue
         found += 1
-        for s in scopes:
-            if under(op, s):
-                out[s] += sec
+        for n, accepts in tests.items():
+            if accepts(op):
+                out[n] += sec
     return (out, total) if found and total > 0 else None
+
+
+def seconds_by_scope(obs: dict, scopes) -> tuple[dict, float] | None:
+    """`seconds_matching` with a scope's name as its test: the events
+    traced under it, forward or transposed."""
+    return seconds_matching(
+        obs, {s: functools.partial(under, scope=s) for s in scopes})
+
+
+def breakdown(obs: dict, scopes) -> dict | None:
+    """The traced line's `breakdown.scopes`: every scope's device seconds
+    beside the seconds of all events."""
+    got = seconds_by_scope(obs, scopes)
+    return None if got is None else {"seconds": got[0], "all_events_s": got[1]}
 
 
 def share_pct(obs: dict, scope: str) -> float | None:

@@ -7,9 +7,15 @@ generalized Charbonnier, first-order smoothness) and Adam. Nothing here
 imports the program, and nothing takes a value the program made: weights
 come from `make_params` (seeded), inputs from the harness's generator.
 
-The only hook is `Params.q`: a function applied to every convolution's
-input and kernel. `None` for the reference; a quantiser for the control
+The hooks are two. `Params.q`: a function applied to every convolution's
+input and kernel; `None` for the reference, a quantiser for the control
 that decides whether the comparison can see a lower precision.
+`Params.through`: for each named part a factor on the gradient that flows
+BACK through it (its operands pass through `Params.backward_scaled`, values
+unchanged); `make_trainer(cuts=...)` hands 1.0 and, for the second gradient
+it takes at the first step, 0.0, as an argument of the one compiled
+program, and the comparison learns from the two what part of the gradient
+flows back through that part.
 """
 
 from __future__ import annotations
@@ -33,10 +39,11 @@ class Params:
     with `spec`, a list, it records (path, shape, kind) of every parameter
     a forward pass asks for and hands out zeros."""
 
-    def __init__(self, values=None, q=None, spec=None):
+    def __init__(self, values=None, q=None, spec=None, through=None):
         self.values = {} if values is None else values
         self.q = q
         self.spec = spec
+        self.through = through or {}
 
     def get(self, path: str, shape: tuple, kind: str):
         if self.spec is not None:
@@ -48,6 +55,15 @@ class Params:
 
     def quant(self, x):
         return x if self.q is None else self.q(x)
+
+    def backward_scaled(self, part: str, *xs):
+        """The operands of `part`, their values as they are; where
+        `through` names the part, the gradient that flows back into them is
+        multiplied by its factor (x + 0 forward, factor * cotangent back)."""
+        if part not in self.through:
+            return xs
+        held = [lax.stop_gradient(x) for x in xs]
+        return tuple(h + self.through[part] * (x - h) for x, h in zip(xs, held))
 
 
 def bilinear_kernel(shape):
@@ -229,7 +245,8 @@ def charbonnier(x, eps: float, alpha: float):
 
 
 def level_loss(flow, inputs, outputs, flow_scale: float, hp: dict):
-    """Photometric + smoothness loss of one pyramid level."""
+    """(photometric + smoothness loss of one pyramid level, its smoothness
+    part alone)."""
     b, h, w, c = inputs.shape
     scaled = flow * flow_scale
     recon = backward_warp(outputs, scaled)
@@ -249,13 +266,13 @@ def level_loss(flow, inputs, outputs, flow_scale: float, hp: dict):
     on = 1.0 if n_interior > 0 else 0.0
     smooth = on * (jnp.sum(charbonnier(du, eps, hp["alpha_s"]))
                    + jnp.sum(charbonnier(dv, eps, hp["alpha_s"]))) / num_valid
-    return photo + hp["lambda_smooth"] * smooth
+    return photo + hp["lambda_smooth"] * smooth, smooth
 
 
 def pyramid_loss(flows, flow_scales, src, tgt, hp: dict):
-    """Weighted sum over the levels, finest first, and the levels' own
-    (unweighted) losses. `src`/`tgt` are raw BGR images; the LRN copies are
-    resized to every level."""
+    """Weighted sum over the levels, finest first, and [2, levels]: the
+    levels' own (unweighted) losses and their smoothness parts. `src`/`tgt`
+    are raw BGR images; the LRN copies are resized to every level."""
     li = lrn(preprocess(src, hp["mean"]))
     lo = lrn(preprocess(tgt, hp["mean"]))
     total, levels = 0.0, []
@@ -265,14 +282,15 @@ def pyramid_loss(flows, flow_scales, src, tgt, hp: dict):
         weight = weights[k] if k < len(weights) else weights[-1]
         levels.append(level_loss(flow, resize(li, h, w), resize(lo, h, w),
                                  scale, hp))
-        total = total + weight * levels[-1]
-    return total, jnp.stack(levels)
+        total = total + weight * levels[-1][0]
+    return total, jnp.stack([jnp.stack(part) for part in zip(*levels)])
 
 
-def model_loss(forward, flow_scales, values, src, tgt, hp, q=None):
+def model_loss(forward, flow_scales, values, src, tgt, hp, q=None,
+               through=None):
     pair = jnp.concatenate([preprocess(src, hp["mean"]),
                             preprocess(tgt, hp["mean"])], axis=-1)
-    flows = forward(Params(values=values, q=q), pair)
+    flows = forward(Params(values=values, q=q, through=through), pair)
     return pyramid_loss(flows, flow_scales, src, tgt, hp)
 
 
@@ -285,20 +303,31 @@ def leaf_norms(tree: dict) -> dict:
 
 
 def make_trainer(forward, flow_scales, hp: dict, block: int, q=None,
-                 rows=None):
+                 rows=None, cuts=(), keep_grads: bool = False, watch=()):
     """Returns `steps(values, batches) -> readings`: three (or len(batches))
     Adam steps in float32, gradients accumulated over blocks of `block`
     rows so that the full batch fits beside nothing else.
 
     `rows`: only these many leading rows of each batch are used, the mean
     taken over them: the planted fault "half of the batch left out".
+    `cuts`: for each named part (`Params.through`) the first step's gradient
+    is taken a second time with that part's backward cut, by the same
+    compiled program; `grad_cuts[name]`
+    holds, by leaf, the part of the first gradient that flows back through
+    it (`flow_through`).
+    `keep_grads`: the whole first gradient on the host (`first_grads`), for
+    the calibration's control and faults, which stand in the program's
+    place. `watch`: leaves whose value after every step is kept
+    (`watched`), for the calibration's look at one leaf.
     """
 
     @jax.jit
-    def block_grad(values, src, tgt):
+    def block_grad(values, src, tgt, through):
         return jax.value_and_grad(
-            lambda v: model_loss(forward, flow_scales, v, src, tgt, hp, q),
+            lambda v: model_loss(forward, flow_scales, v, src, tgt, hp, q, through),
             has_aux=True)(values)
+
+    whole = {name: jnp.float32(1.0) for name in cuts}
 
     @jax.jit
     def adam(values, m, v, g, t):
@@ -319,6 +348,10 @@ def make_trainer(forward, flow_scales, hp: dict, block: int, q=None,
     norms = jax.jit(leaf_norms)
     diff_norms = jax.jit(lambda a, b: leaf_norms({k: a[k] - b[k] for k in a}))
 
+    def blocks_of(src, tgt, n):
+        for i in range(0, n, block):
+            yield jnp.asarray(src[i:i + block]), jnp.asarray(tgt[i:i + block])
+
     def steps(values0: dict, batches: list) -> dict:
         import time
 
@@ -327,15 +360,15 @@ def make_trainer(forward, flow_scales, hp: dict, block: int, q=None,
         values = values0
         m = {k: jnp.zeros_like(x) for k, x in values.items()}
         v = {k: jnp.zeros_like(x) for k, x in values.items()}
-        losses, level_losses, grad_norms = [], [], None
+        losses, level_losses, level_smooth, grad_norms = [], [], [], None
+        out = {"watched": {k: [np.asarray(values[k])] for k in watch}}
         for t, (src, tgt) in enumerate(batches, start=1):
             n = src.shape[0] if rows is None else rows
             assert n % block == 0, (n, block)
             g = {k: jnp.zeros_like(x) for k, x in values.items()}
             loss = levels = 0.0
-            for i in range(0, n, block):
-                (lb, lv), gb = block_grad(values, jnp.asarray(src[i:i + block]),
-                                          jnp.asarray(tgt[i:i + block]))
+            for sb, tb in blocks_of(src, tgt, n):
+                (lb, lv), gb = block_grad(values, sb, tb, whole)
                 g = accumulate(g, gb, block / n)
                 loss = loss + lb * (block / n)
                 levels = levels + lv * (block / n)
@@ -343,16 +376,58 @@ def make_trainer(forward, flow_scales, hp: dict, block: int, q=None,
                     float(lb)
                     first_block_s = time.perf_counter() - t_start
             losses.append(float(loss))
-            level_losses.append([float(x) for x in levels])
+            level_losses.append([float(x) for x in levels[0]])
+            level_smooth.append([float(x) for x in levels[1]])
             if t == 1:
                 grad_norms = {k: float(x) for k, x in norms(g).items()}
+                if keep_grads:
+                    out["first_grads"] = {k: np.asarray(x) for k, x in g.items()}
+                for name in cuts:
+                    gc = {k: jnp.zeros_like(x) for k, x in values.items()}
+                    without = dict(whole, **{name: jnp.float32(0.0)})
+                    for sb, tb in blocks_of(src, tgt, n):
+                        gc = accumulate(gc, block_grad(values, sb, tb, without)[1],
+                                        block / n)
+                    out.setdefault("grad_cuts", {})[name] = flow_through(g, gc)
             values, m, v = adam(values, m, v, g, float(t))
+            for k in watch:
+                out["watched"][k].append(np.asarray(values[k]))
         dparam = {k: float(x) for k, x in diff_norms(values, values0).items()}
-        return {"losses": losses, "level_losses": level_losses,
-                "grad_norms": grad_norms, "dparam_norms": dparam,
-                "timing": {"first_block_s": first_block_s}}
+        out.update({"losses": losses, "level_losses": level_losses,
+                    "level_smooth_losses": level_smooth,
+                    "grad_norms": grad_norms, "dparam_norms": dparam,
+                    "timing": {"first_block_s": first_block_s}})
+        return out
 
     return steps
+
+
+def flow_through(g: dict, g_cut: dict, reach: float = 1e-3) -> dict:
+    """By leaf (float64, on the host), for the leaves the cut part reaches
+    (|g - g_cut| over a thousandth of |g|: elsewhere the two differ by the
+    compiler's order of sums alone): `d`, of g - g_cut the part that stands
+    at a right angle to the leaf's g_cut (a gradient that is a little too
+    long or too short all along, as a lower precision or a changed mean
+    leaves it, has no share in that part, and a gradient that lacks g -
+    g_cut lacks all of it); `dd` = <d, d>; `ref_dot` = <g, d>; `w` = 1 /
+    <g, g>, the weight the comparison gives the leaf: a leaf's rounding is
+    in proportion to its whole gradient, the part that flows through is of
+    one size in every leaf it reaches."""
+    size = jax.jit(lambda a, b: {k: (jnp.sum(jnp.square(a[k] - b[k])),
+                                     jnp.sum(jnp.square(a[k]))) for k in a})(g, g_cut)
+    out = {"d": {}, "dd": {}, "ref_dot": {}, "w": {}}
+    for k, (part2, whole2) in size.items():
+        if not float(part2) > reach * reach * float(whole2):
+            continue
+        full, rest = np.asarray(g[k], np.float64), np.asarray(g_cut[k], np.float64)
+        part = full - rest
+        if np.any(rest):
+            part = part - (np.vdot(part, rest) / np.vdot(rest, rest)) * rest
+        out["d"][k] = part
+        out["dd"][k] = float(np.vdot(part, part))
+        out["ref_dot"][k] = float(np.vdot(full, part))
+        out["w"][k] = 1.0 / float(np.vdot(full, full))
+    return out
 
 
 # ------------------------------------------------------------------ control

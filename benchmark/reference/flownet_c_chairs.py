@@ -42,9 +42,10 @@ def forward(p: Params, pair):
 
     c1, c2, f1 = tower(img1)
     _, _, f2 = tower(img2)
-    # the correlation's operands go through the hook too: it is the one
-    # product of activations in the model
-    corr = elu(correlation(p.quant(f1), p.quant(f2)))
+    # the correlation's operands go through the hooks too: it is the one
+    # product of activations in the model, and `corr` the part whose
+    # backward the comparison can cut
+    corr = elu(correlation(*p.backward_scaled("corr", p.quant(f1), p.quant(f2))))
     redir = conv(p, "conv_redir", f1, 32, (1, 1), act=elu)
     net = jnp.concatenate([corr, redir], -1)
     c3_1 = conv(p, "conv3_1", net, 256, act=elu)

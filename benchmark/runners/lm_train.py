@@ -121,8 +121,6 @@ class StepTap:
         return self.inner.lower(*a, **k)
 
     def __call__(self, state, batch):
-        import jax
-
         from deepof_tpu.obs import trace as obs_trace
 
         if self.stop.is_set():
@@ -133,10 +131,7 @@ class StepTap:
             obs_trace.instant(span_tools.CLOCK_MARK,
                               perf_counter=time.perf_counter())
             if self.keep_specs:
-                self.specs = jax.tree_util.tree_map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                                   sharding=x.sharding),
-                    (state, batch))
+                self.specs = scope_share.arg_specs((state, batch))
         if i < N_CHECK_STEPS:
             self.batches.append(np.asarray(batch["tokens"]))
         state, metrics = self.inner(state, batch)
@@ -366,8 +361,7 @@ def run(ctx, step_fault=None, also=None, agree: bool | None = None,
         if ctx.trace and tap.specs is not None:
             # the step's executable again (a load from the compile cache):
             # its text names every instruction's scope
-            scopes = scope_share.op_scopes(
-                tap.inner.lower(*tap.specs).compile().as_text())
+            scopes = scope_share.executable_scopes(tap.inner, tap.specs)
         # free the program before the reference touches the chip
         # (deleted, not only dropped: on the chip `del` + `gc.collect()` left
         # the state's 6.9 GB in use although nothing but this frame held the
@@ -446,11 +440,7 @@ def observe(ctx, ctl, marks, trace_dir, host_spans, batch, scopes,
     """`runners/train.py::observe`, and beside it what the scope and the
     counter readers read: the instruction-to-scope map and the window's
     records."""
-    out = base.observe(ctx, ctl, marks, trace_dir, host_spans, batch)
-    obs = out["observed"]
-    obs["op_scopes"], obs["records"] = scopes, records
-    by_scope = scope_share.seconds_by_scope(obs, BREAKDOWN_SCOPES)
-    if by_scope is not None:
-        out["breakdown"]["scopes"] = {
-            "seconds": by_scope[0], "all_events_s": by_scope[1]}
+    out = base.observe(ctx, ctl, marks, trace_dir, host_spans, batch, scopes,
+                       BREAKDOWN_SCOPES)
+    out["observed"]["records"] = records
     return out

@@ -138,8 +138,7 @@ def run(ctx, step_fault=None, also=None, agree: bool | None = None,
     if "observed" in out:
         obs = out["observed"]
         obs["records"] = in_window
-        by_scope = scope_share.seconds_by_scope(obs, BREAKDOWN_SCOPES)
+        by_scope = scope_share.breakdown(obs, BREAKDOWN_SCOPES)
         if by_scope is not None:
-            out["breakdown"]["scopes"] = {
-                "seconds": by_scope[0], "all_events_s": by_scope[1]}
+            out["breakdown"]["scopes"] = by_scope
     return out

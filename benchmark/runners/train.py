@@ -6,12 +6,16 @@ and a pool of pairs made from the seed, and lets `fit` run. The first
 steps go through that same `fit`: a tap around the trainer's step callable
 keeps the first three batches, their losses, the per-leaf norms of Adam's
 first moment after step one (the first gradient as the optimizer got it)
-and of the parameters' change after step three. After `warm_steps` the
+and of the parameters' change after step three; where the cell's file
+names `grad_cuts`, the first moment itself, on the host. After `warm_steps` the
 window opens on a step record (a loss fetched to the host) and closes on
 the first record `--seconds` later; the tap then ends `fit` from inside
 its next dispatch, so no final checkpoint is written. Only then is the
 peak memory read, the program freed and the plain reference run over the
-same three batches.
+same three batches. A traced run joins the profile's events to the
+program's scopes through the step executable's own text
+(`harness/scope_share.py`), for the readers that go by scope and for the
+line's `breakdown.scopes`.
 """
 
 from __future__ import annotations
@@ -27,9 +31,14 @@ import time
 
 import numpy as np
 
-from ..harness import compare, spans as span_tools, trace_reduce, traffic as gen
+from ..harness import compare, scope_share, spans as span_tools
+from ..harness import trace_reduce, traffic as gen
 
 N_CHECK_STEPS = 3
+#: the flow step's scopes in a traced line's `breakdown.scopes`; `corr` is
+#: the cost volume's where the program names it (it does not yet)
+BREAKDOWN_SCOPES = ("preprocess", "forward", "corr") + tuple(
+    f"loss_level_{k}" for k in range(6)) + ("optimizer",)
 
 
 class WindowClosed(BaseException):
@@ -65,18 +74,23 @@ class StepTap:
     """Stands where the trainer's step callable stands. Passes every call
     through; observes the first N_CHECK_STEPS; ends `fit` when told to."""
 
-    def __init__(self, inner, params0_copy, beta1: float):
+    def __init__(self, inner, params0_copy, beta1: float,
+                 keep_specs: bool = False, keep_grads: bool = False):
         import jax
         import jax.numpy as jnp
 
         self.inner, self.p0, self.beta1 = inner, params0_copy, beta1
+        self.keep_specs, self.specs = keep_specs, None
+        self.keep_grads, self.mu_host = keep_grads, None
         self.calls = 0
         self.stop = threading.Event()
         self.batches, self.losses, self.level_losses = [], [], []
+        self.level_smooth = []
         self.mu_norms = self.dparam_norms = None
         norm = lambda t: jax.tree_util.tree_map(  # noqa: E731
             lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), t)
         self._norms = jax.jit(norm)
+        self._to_host = jax.device_get
         self._diff_norms = jax.jit(lambda a, b: norm(
             jax.tree_util.tree_map(lambda x, y: x - y, a, b)))
 
@@ -93,6 +107,8 @@ class StepTap:
         if i == 0:
             obs_trace.instant(span_tools.CLOCK_MARK,
                               perf_counter=time.perf_counter())
+            if self.keep_specs:
+                self.specs = scope_share.arg_specs((state, batch))
         if i < N_CHECK_STEPS:
             self.batches.append((np.asarray(batch["source"]),
                                  np.asarray(batch["target"])))
@@ -100,8 +116,11 @@ class StepTap:
         if i < N_CHECK_STEPS:
             self.losses.append(metrics["total"])
             self.level_losses.append(metrics["scale_total"])
+            self.level_smooth.append(metrics["scale_smooth"])
         if i == 0:
             self.mu_norms = self._norms(_adam_mu(state.opt_state))
+            if self.keep_grads:
+                self.mu_host = self._to_host(_adam_mu(state.opt_state))
         if i == N_CHECK_STEPS - 1:
             self.dparam_norms = self._diff_norms(state.params, self.p0)
             self.p0 = None
@@ -112,12 +131,19 @@ class StepTap:
 
         flat = lambda t: {"/".join(k): float(v)  # noqa: E731
                           for k, v in flatten_dict(t).items()}
-        return {"losses": [float(x) for x in self.losses],
-                "level_losses": [[float(v) for v in np.asarray(x).reshape(-1)]
-                                 for x in self.level_losses],
-                "grad_norms": {k: v / (1.0 - self.beta1)
-                               for k, v in flat(self.mu_norms).items()},
-                "dparam_norms": flat(self.dparam_norms)}
+        rows = lambda xs: [[float(v) for v in np.asarray(x).reshape(-1)]  # noqa: E731
+                           for x in xs]
+        out = {"losses": [float(x) for x in self.losses],
+               "level_losses": rows(self.level_losses),
+               "level_smooth_losses": rows(self.level_smooth),
+               "grad_norms": {k: v / (1.0 - self.beta1)
+                              for k, v in flat(self.mu_norms).items()},
+               "dparam_norms": flat(self.dparam_norms)}
+        if self.mu_host is not None:
+            out["first_grads"] = {
+                "/".join(k): np.asarray(v) / np.float32(1.0 - self.beta1)
+                for k, v in flatten_dict(self.mu_host).items()}
+        return out
 
 
 def _adam_mu(opt_state):
@@ -224,7 +250,9 @@ def build_trainer(ctx, log_dir: str):
     trainer.state = trainer.state.replace(params=placed)
     jax.block_until_ready(placed)
     phases["weights_s"] = time.perf_counter() - t
-    tap = StepTap(trainer.train_step, p0_copy, cfgj["optim"]["beta1"])
+    tap = StepTap(trainer.train_step, p0_copy, cfgj["optim"]["beta1"],
+                  keep_specs=ctx.trace,
+                  keep_grads=bool(ctx.cell.get("grad_cuts")))
     trainer.train_step = tap
     return trainer, tap, ref
 
@@ -250,13 +278,21 @@ def make_weights(ctx, ref) -> dict:
                           gen.jax_key(ctx.seed, 2), float(wcfg["seed_jitter"]))
 
 
-def run_reference(ctx, ref, batches, q=None, rows=None) -> dict:
+def run_reference(ctx, ref, batches, cuts=None, **how) -> dict:
+    """The plain reference over the checked batches. `cuts`: the parts whose
+    share of the first gradient the cell's file has compared (`grad_cuts`);
+    `how`: what the calibration puts in the program's place (`q`, `rows`,
+    `keep_grads`) and its `watch`, as `reference/_common.py::make_trainer`
+    takes them."""
     from ..reference import _common as rc
 
     t0 = time.perf_counter()
+    if cuts is None:
+        cuts = tuple(ctx.cell.get("grad_cuts", ()))
     steps = rc.make_trainer(ref.forward, tuple(ctx.config["flow_scales"]),
                             reference_hp(ctx.config),
-                            block=ctx.traffic["reference_block"], q=q, rows=rows)
+                            block=ctx.traffic["reference_block"], cuts=cuts,
+                            **how)
     values = make_weights(ctx, ref)
     t1 = time.perf_counter()
     out = steps(values, batches)
@@ -336,11 +372,18 @@ def run(ctx, step_fault=None, also=None) -> dict:
         prog = tap.readings() if tap.dparam_norms is not None else None
         batches = tap.batches
         batch = tr["batch_per_chip"] * ctx.chips
-        distinct = all(len({float(s[i].sum()) for i in range(len(s))}) == len(s)
+        # on the rows' bytes: the float32 sum of a row moves in steps of 4 at
+        # 5e7, and two distinct rows of 64 shared a sum in one run of 24
+        distinct = all(len({row.tobytes() for row in s}) == len(s)
                        for s, _ in batches)
         span_file = os.path.join(work, "run", "trace.json")
         host_spans = span_tools.load_spans(span_file) if ctx.trace and \
             os.path.exists(span_file) else []
+        scopes = {}
+        if ctx.trace and tap.specs is not None:
+            # the step's executable again (a load from the compile cache):
+            # its text names every instruction's scope
+            scopes = scope_share.executable_scopes(tap.inner, tap.specs)
         # free the program before the reference touches the chip
         trainer.train_step = None
         del trainer
@@ -389,15 +432,19 @@ def run(ctx, step_fault=None, also=None) -> dict:
                       **extra_readings},
         }
         if ctx.trace:
-            out.update(observe(ctx, ctl, marks, trace_dir, host_spans, batch))
+            out.update(observe(ctx, ctl, marks, trace_dir, host_spans, batch,
+                               scopes, BREAKDOWN_SCOPES))
         return out
     finally:
         shutil.rmtree(work, ignore_errors=True)
 
 
-def observe(ctx, ctl, marks, trace_dir, host_spans, batch) -> dict:
+def observe(ctx, ctl, marks, trace_dir, host_spans, batch, scopes,
+            breakdown_scopes) -> dict:
     """What the per-layer readers read: the device trace reduced, the host
-    spans, the window and its counts."""
+    spans, the window and its counts, and `scopes`, the step executable's
+    {instruction: op_name} (`harness/scope_share.py`), by which the trace's
+    events find the program's scopes."""
     planes = trace_reduce.load_xplane(trace_dir)
     if os.environ.get("BENCH_TRACE_DUMP"):
         from ..harness.trace_dump import dump, record_small
@@ -418,8 +465,10 @@ def observe(ctx, ctl, marks, trace_dir, host_spans, batch) -> dict:
         "trace_window_host": (marks.get("start"), marks.get("end")),
         "steps": (ctl.s1 - ctl.s0) if windowed else 0,
         "pairs": ((ctl.s1 - ctl.s0) * batch) if windowed else 0,
-        "batch": batch,
+        "batch": batch, "op_scopes": scopes,
     }
-    return {"observed": observed,
-            "breakdown": {"device_ops": dev["device_ops"],
-                          "idle_gaps": dev["idle_gaps"]}}
+    breakdown = {"device_ops": dev["device_ops"], "idle_gaps": dev["idle_gaps"]}
+    by_scope = scope_share.breakdown(observed, breakdown_scopes)
+    if by_scope is not None:
+        breakdown["scopes"] = by_scope
+    return {"observed": observed, "breakdown": breakdown}

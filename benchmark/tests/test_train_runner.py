@@ -6,22 +6,28 @@ import pytest
 
 from toy import toy_train_context
 
+from benchmark.harness.flow_faults import FAULTS, ONLY_IN
 from benchmark.runners import train as runner
 
-TOY_LIMITS = {"loss_gap": 2e-4, "grad_norm_gap": 5e-3, "dparam_norm_gap": 5e-2}
-CELL = "inception_v3_chairs.train"
-CONFIGS = ["inception_v3_chairs", "flownet_c_chairs"]
+TOY_LIMITS = {"loss_gap": 2e-4, "level_smooth_rms_gap_step1": 1e-4,
+              "grad_norm_gap": 5e-3, "dparam_norm_gap": 5e-2}
+#: the cost volume's cell also holds the share of the first gradient that
+#: flows back through the correlation (the cell's `grad_cuts`)
+TOY_SHARE_LIMIT = {"grad_share_gap_corr": 0.1}
+CELLS = ["inception_v3_chairs.train", "flownet_c_chairs.train"]
 
 
-def run_cell(config=None, fault=None, **kw):
-    ctx = toy_train_context(CELL, config=config, **kw)
+def run_cell(cell, fault=None, **kw):
+    ctx = toy_train_context(cell, **kw)
     ctx.cell["limits"] = dict(TOY_LIMITS)
+    if ctx.cell.get("grad_cuts"):
+        ctx.cell["limits"].update(TOY_SHARE_LIMIT)
     return runner.run(ctx, step_fault=fault)
 
 
-@pytest.mark.parametrize("config", CONFIGS)
-def test_sound_run_is_correct_and_counts_steps(config):
-    out = run_cell(config)
+@pytest.mark.parametrize("cell", CELLS)
+def test_sound_run_is_correct_and_counts_steps(cell):
+    out = run_cell(cell)
     assert out["correct"], out["compared"]
     assert out["attempted"] == out["extra"]["steps"] > 0
     rate = out["end_to_end"]["train_pairs_per_s"]
@@ -30,40 +36,28 @@ def test_sound_run_is_correct_and_counts_steps(config):
     assert out["extra"]["window_s"] >= 0.5
 
 
-def unchanged_state(tap):
-    inner = tap.inner
-    tap.inner = lambda state, batch: (state, inner(_copy(state), batch)[1])
+FAULT_CASES = [(cell, fault) for cell in CELLS for fault in sorted(FAULTS)
+               if cell.split(".")[0] in ONLY_IN.get(fault, (cell.split(".")[0],))]
 
 
-def _copy(state):
-    import jax
-    import jax.numpy as jnp
-
-    return jax.tree_util.tree_map(jnp.copy, state)
-
-
-def half_batch(tap):
-    import jax.numpy as jnp
-
-    inner = tap.inner
-
-    def step(state, batch):
-        n = batch["source"].shape[0] // 2
-        halved = {k: jnp.concatenate([v[:n], v[:n]]) for k, v in batch.items()}
-        return inner(state, halved)
-
-    tap.inner = step
-
-
-@pytest.mark.parametrize("fault", [unchanged_state, half_batch])
-def test_planted_fault_is_not_correct(fault):
-    out = run_cell(fault=fault)
+@pytest.mark.parametrize("cell, fault", FAULT_CASES)
+def test_planted_fault_is_not_correct(cell, fault):
+    out = run_cell(cell, fault=FAULTS[fault])
     assert not out["correct"], out["compared"]
     over = [k for k, c in out["compared"].items() if not c["value"] <= c["limit"]]
     assert over, out["compared"]
+    if fault.startswith("corr_bwd"):
+        # a fault in the backward alone: the first step's loss and levels
+        # are the sound run's, and no leaf's norm moves by a hundredth; the
+        # share of the gradient that flows through the correlation sees it
+        assert "grad_share_gap_corr" in over, out["compared"]
+        assert not {"grad_norm_gap", "level_smooth_rms_gap_step1"} & set(over)
+        assert out["compared"]["grad_share_gap_corr"]["value"] > \
+            2 * TOY_SHARE_LIMIT["grad_share_gap_corr"]
 
 
-def test_control_put_in_the_programs_place_is_not_correct():
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_put_in_the_programs_place_is_not_correct(cell):
     """The reference in the nearest precision below the toy program's
     float32, bfloat16 operands, read against the reference itself."""
     import importlib
@@ -73,7 +67,7 @@ def test_control_put_in_the_programs_place_is_not_correct():
     from benchmark.harness import compare, traffic as gen
     from benchmark.reference import _common as rc
 
-    ctx = toy_train_context(CELL)
+    ctx = toy_train_context(cell)
     ref = importlib.import_module("benchmark.reference." + ctx.config["reference"])
     h, w = ctx.config["image_size"]
     src, tgt = (np.asarray(x) for x in gen.textured_frames(

@@ -13,17 +13,12 @@ import run as bench_run  # noqa: E402
 
 
 def toy_train_context(cell: str, seed: int = 5, seconds: float = 0.5,
-                      trace: bool = False, size=(64, 96), batch: int = 4,
-                      config: str | None = None):
-    """`config`: another configuration's file in the cell's place (a
-    configuration whose cell is not in BENCHMARK.json yet still has its
-    plain reference checked against the program here)."""
+                      trace: bool = False, size=(64, 96), batch: int = 4):
     bench_run.prepare_environment()
     os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
         bench_run.ROOT, ".bench_cache", "xla_cpu_tests")
     ctx = bench_run.build_context(cell, seed, seconds, trace)
-    ctx.config = copy.deepcopy(
-        ctx.config if config is None else bench_run.load_json("configs", config + ".json"))
+    ctx.config = copy.deepcopy(ctx.config)
     ctx.traffic = copy.deepcopy(ctx.traffic)
     ctx.cell = copy.deepcopy(ctx.cell)
     ctx.config["image_size"] = list(size)
