@@ -11,8 +11,10 @@ out-of-range f2 positions contribute zero. Implemented as a `vmap` over the
 displacement grid with `dynamic_slice` into a zero-padded f2 — static
 shapes, data-parallel across displacements so XLA can fuse/parallelize (a
 `lax.scan` here would serialize the 441 steps). The output is (n*n, B, H, W)
-either way, so peak memory is unchanged. A fused Pallas kernel is planned in
-`ops/pallas/corr.py`.
+either way, so peak memory is unchanged. This is the route off the chip and
+the tests' reference; on a TPU the forward and its backward are the Mosaic
+kernels of `ops/pallas/corr.py`. Both routes run under `jax.named_scope("corr")`,
+so a profile finds the correlation, forward and transposed, by that scope.
 """
 
 from __future__ import annotations
@@ -36,25 +38,26 @@ def correlation(
     """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas":
-        from .pallas.corr import correlation_pallas
+    with jax.named_scope("corr"):
+        if impl == "pallas":
+            from .pallas.corr import correlation_pallas
 
-        return correlation_pallas(f1, f2, max_disp, stride)
-    b, h, w, c = f1.shape
-    k = max_disp // stride
-    n = 2 * k + 1
-    pad = k * stride
-    f2p = jnp.pad(f2, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+            return correlation_pallas(f1, f2, max_disp, stride)
+        b, h, w, c = f1.shape
+        k = max_disp // stride
+        n = 2 * k + 1
+        pad = k * stride
+        f2p = jnp.pad(f2, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
 
-    offsets = jnp.arange(n) * stride  # dy/dx offsets into the padded array
-    dydx = jnp.stack(jnp.meshgrid(offsets, offsets, indexing="ij"), -1).reshape(-1, 2)
+        offsets = jnp.arange(n) * stride  # dy/dx offsets into the padded array
+        dydx = jnp.stack(jnp.meshgrid(offsets, offsets, indexing="ij"), -1).reshape(-1, 2)
 
-    def one(off):
-        sl = lax.dynamic_slice(f2p, (0, off[0], off[1], 0), (b, h, w, c))
-        return jnp.mean(f1 * sl, axis=-1)
+        def one(off):
+            sl = lax.dynamic_slice(f2p, (0, off[0], off[1], 0), (b, h, w, c))
+            return jnp.mean(f1 * sl, axis=-1)
 
-    maps = jax.vmap(one)(dydx)  # (n*n, B, H, W)
-    return jnp.moveaxis(maps, 0, -1)
+        maps = jax.vmap(one)(dydx)  # (n*n, B, H, W)
+        return jnp.moveaxis(maps, 0, -1)
 
 
 def correlation_oracle(f1, f2, max_disp=20, stride=2):

@@ -174,9 +174,9 @@ def pair_axis_constraint(x: jnp.ndarray) -> jnp.ndarray:
 
 def shard_over_batch(fn, mesh: Mesh | None, batch: int,
                      axes: tuple[str, ...] = ("data",), whole: int = 0):
-    """Run `fn` (rank-4 (B, ...) arrays in, one out) once per batch shard;
-    its last `whole` operands have no batch axis (a table, a learned
-    scale) and every shard sees all of each.
+    """Run `fn` ((B, ...) arrays in, one (B, ...) array or a tuple of them
+    out) once per batch shard; its last `whole` operands have no batch axis
+    (a table, a learned scale) and every shard sees all of each.
 
     The one multi-device form of the Pallas kernels: GSPMD cannot see
     inside a `pallas_call` and Mosaic refuses to be partitioned

@@ -110,18 +110,27 @@ def test_warp_kernels_compile_for_v5e(one_chip, which, hw):
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("hw", [(40, 56), (48, 64)])
-def test_corr_kernel_compiles_for_v5e(one_chip, hw, dtype):
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_corr_kernel_compiles_for_v5e(one_chip, which, hw, dtype):
     """FlowNet-C's cost volume at its real shapes: conv3 features of
     320x448 and 384x512 inputs (1/8 resolution), C=256, max_disp=20,
-    stride=2. Before the dx sweep was unrolled Mosaic refused every one of
-    these: 'cannot statically prove that index in dimension 1 is a
+    stride=2, the forward kernel and the backward's (the padded image and
+    its float32 df2 accumulator resident in VMEM, the band products on the
+    MXU). Before the dx sweep was unrolled Mosaic refused every forward
+    here: 'cannot statically prove that index in dimension 1 is a
     multiple of 8'."""
-    from deepof_tpu.ops.pallas.corr import _pallas_corr_fwd
+    from deepof_tpu.ops.pallas.corr import _pallas_corr_bwd, _pallas_corr_fwd
 
     h, w = hw
     f = jax.ShapeDtypeStruct((BATCH, h, w, 256), dtype, sharding=one_chip)
-    _compiled_text(lambda a, b: _pallas_corr_fwd(a, b, 20, 2, 8, False), f, f,
-                   kernels=["corr_fwd"])
+    if which == "fwd":
+        _compiled_text(lambda a, b: _pallas_corr_fwd(a, b, 20, 2, 8, False),
+                       f, f, kernels=["corr_fwd"])
+        return
+    g = jax.ShapeDtypeStruct((BATCH, h, w, 441), dtype, sharding=one_chip)
+    _compiled_text(lambda a, b, ct: _pallas_corr_bwd(a, b, ct, 20, 2, 8,
+                                                     False),
+                   f, f, g, kernels=["corr_bwd"])
 
 
 def _auto_flow_grad(hw):
@@ -170,7 +179,11 @@ def test_warp_vjp_compiles_through_shard_map(topo, n_dev, time, hw):
             is not None) == two_tiles
 
 
-def test_corr_compiles_through_shard_map_on_four_chips(topo):
+@pytest.mark.parametrize("which", ["fwd", "vjp"])
+def test_corr_compiles_through_shard_map_on_four_chips(topo, which):
+    """The correlation on the four described chips with the batch sharded
+    over "data", alone and with its VJP (both kernels per shard through
+    `shard_over_batch`): no shard gathers another's rows."""
     from deepof_tpu.ops.pallas.corr import correlation_pallas
     from deepof_tpu.parallel.mesh import batch_sharding
     from deepof_tpu.parallel.spatial import mesh_context
@@ -178,10 +191,18 @@ def test_corr_compiles_through_shard_map_on_four_chips(topo):
     mesh = _mesh(topo, 4)
     f = jax.ShapeDtypeStruct((BATCH, 40, 56, 256), jnp.bfloat16,
                              sharding=batch_sharding(mesh))
+
+    def corr(a, b):
+        return correlation_pallas(a, b, 20, 2, 8, False)
+
+    def grad(a, b):
+        return jax.grad(lambda x, y: jnp.sum(corr(x, y).astype(
+            jnp.float32) ** 2), argnums=(0, 1))(a, b)
+
     with mesh_context(mesh):
-        text = _compiled_text(
-            lambda a, b: correlation_pallas(a, b, 20, 2, 8, False), f, f,
-            kernels=["corr_fwd"])
+        text = _compiled_text(corr if which == "fwd" else grad, f, f,
+                              kernels=["corr_fwd"] if which == "fwd"
+                              else ["corr_fwd", "corr_bwd"])
     assert "all-gather" not in text
 
 

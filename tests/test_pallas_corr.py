@@ -35,20 +35,68 @@ def test_pallas_corr_stride_and_ragged_height(feats):
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
-def test_pallas_corr_grad_matches_xla(feats):
-    f1, f2 = feats
-    f1, f2 = jnp.asarray(f1[:1, :8, :8]), jnp.asarray(f2[:1, :8, :8])
+def _vjp(corr, f1, f2, g):
+    return jax.vjp(corr, f1, f2)[1](g)
 
-    def loss_pallas(a, b):
-        return jnp.sum(correlation_pallas(a, b, 2, 1, 4, True) ** 2)
 
-    def loss_xla(a, b):
-        return jnp.sum(correlation(a, b, max_disp=2, stride=1) ** 2)
+def _xla_vjp(f1, f2, g, max_disp, stride):
+    """Autodiff of the XLA sweep in float32 on the same values."""
+    f1, f2, g = (jnp.asarray(x, jnp.float32) for x in (f1, f2, g))
+    return _vjp(lambda a, b: correlation(a, b, max_disp, stride, impl="xla"),
+                f1, f2, g)
 
-    g1p, g2p = jax.grad(loss_pallas, argnums=(0, 1))(f1, f2)
-    g1x, g2x = jax.grad(loss_xla, argnums=(0, 1))(f1, f2)
-    np.testing.assert_allclose(np.asarray(g1p), np.asarray(g1x), atol=1e-4)
-    np.testing.assert_allclose(np.asarray(g2p), np.asarray(g2x), atol=1e-4)
+
+def _close(got, want, rtol):
+    """Each element within `rtol` of itself, plus a float32 summation's
+    share of the largest (an element that cancels to near zero)."""
+    got, want = np.asarray(got, np.float32), np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=rtol,
+                               atol=1e-6 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape, max_disp, stride, tile_h", [
+    ((2, 11, 16, 8), 2, 1, 4),
+    ((2, 11, 16, 8), 4, 2, 4),
+    ((1, 48, 64, 4), 20, 2, 10),  # the cell's sweep: 441 maps, pad 20
+])
+def test_pallas_corr_grad_matches_xla(rng, shape, max_disp, stride, tile_h,
+                                      dtype):
+    """The backward kernel's df1 and df2 against autodiff of the XLA
+    sweep, H never a multiple of `tile_h` (the padded rows' zero cotangent
+    must add nothing). bfloat16: the kernel's products and sums are
+    float32 and only its output is rounded, so each element is within one
+    bfloat16 step (2^-8) of the float32 reference on the same values."""
+    n = 2 * (max_disp // stride) + 1
+    f1, f2 = (jnp.asarray(rng.randn(*shape), dtype) for _ in range(2))
+    g = jnp.asarray(rng.randn(*shape[:3], n * n), dtype)
+    got = _vjp(lambda a, b: correlation_pallas(a, b, max_disp, stride, tile_h,
+                                               True), f1, f2, g)
+    want = _xla_vjp(f1, f2, g, max_disp, stride)
+    for x, y in zip(got, want):
+        assert x.dtype == jnp.dtype(dtype)
+        _close(x, y, 1e-5 if dtype == "float32" else 2.0 ** -8)
+
+
+def test_pallas_corr_grad_books_each_displacement_to_its_own_offset(rng):
+    """Features that are NOT near-constant over a small image, with the
+    displacements reaching most of it (max_disp 8 on 10 x 12): a
+    cotangent booked to the mirrored displacement (map i read as map
+    n*n-1-i, the fault `benchmark/harness/flow_faults.py` calls
+    `flipped`) moves df2 by far more than the tolerance here. The cell's
+    `correct` cannot see that fault (its features are all but constant
+    over the image: PERF.md section 7); this test is what guards it."""
+    shape, max_disp, stride = (1, 10, 12, 4), 8, 2
+    f1, f2 = (jnp.asarray(rng.randn(*shape), jnp.float32) for _ in range(2))
+    g = jnp.asarray(rng.randn(*shape[:3], 81), jnp.float32)
+    got = _vjp(lambda a, b: correlation_pallas(a, b, max_disp, stride, 4,
+                                               True), f1, f2, g)
+    want = _xla_vjp(f1, f2, g, max_disp, stride)
+    mirrored = _xla_vjp(f1, f2, g[..., ::-1], max_disp, stride)
+    for x, y, m in zip(got, want, mirrored):
+        _close(x, y, 1e-5)
+        scale = np.abs(np.asarray(y)).max()
+        assert np.abs(np.asarray(m) - np.asarray(y)).max() > 0.3 * scale
 
 
 def test_pallas_corr_sharded_over_batch_mesh(feats):
@@ -73,6 +121,30 @@ def test_pallas_corr_sharded_over_batch_mesh(feats):
     assert got.sharding.spec[0] == "data"
     want = correlation_oracle(f1, f2, max_disp=2, stride=1)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
+
+
+def test_pallas_corr_grad_sharded_over_batch_mesh(feats, rng):
+    """The backward kernel under the 8-device mesh: each shard launches
+    `corr_bwd` on its own rows (`shard_over_batch`, mesh carried as a
+    static argument of the VJP), so both gradients stay sharded over
+    "data" and match the XLA sweep's."""
+    from deepof_tpu.parallel.mesh import batch_sharding, local_mesh
+    from deepof_tpu.parallel.spatial import mesh_context
+
+    f1, f2 = (np.concatenate([x] * 4) for x in feats)  # batch 8 over 8
+    g = rng.randn(*f1.shape[:3], 25).astype(np.float32)
+    mesh = local_mesh()
+    sharding = batch_sharding(mesh)
+    fn = jax.jit(lambda a, b, ct: _vjp(
+        lambda x, y: correlation_pallas(x, y, 2, 1, 4, True), a, b, ct),
+        in_shardings=(sharding,) * 3)
+    with mesh_context(mesh):
+        got = fn(*(jax.device_put(jnp.asarray(x), sharding)
+                   for x in (f1, f2, g)))
+    want = _xla_vjp(f1, f2, g, 2, 1)
+    for x, y in zip(got, want):
+        assert x.sharding.spec[0] == "data"
+        _close(x, y, 1e-5)
 
 
 def test_pallas_corr_bf16_inputs(feats):

@@ -19,8 +19,9 @@ Sections:
   warpsweep the warp kernels' time against the rows their sweep visits,
            beside the XLA gather, at 160x224 (two lane tiles) and 80x112,
            batch 64: the measurement behind `PALLAS_AUTO_MAX_SWEEP`
-  corr     XLA vs Pallas correlation kernel, fwd + grad, FlowNet-C
-           shapes
+  corr     the correlation's kernels at `flownet_c_chairs.train`'s shapes:
+           forward, backward, both, and the backward's error
+           against autodiff of the XLA sweep
   batch    batch-size throughput curve (16/96)
   multiframe Sintel-shaped T=10 volume train step
   warp     per-call XLA vs Pallas warp table (includes dispatch)
@@ -471,23 +472,49 @@ def sec_headline() -> None:
 
 
 def sec_corr() -> None:
-    """XLA sweep vs Pallas correlation kernel at the FlowNet-C shapes
-    (320x448 input -> conv3 features 40x56x256, 441 displacement maps)."""
+    """The correlation at `flownet_c_chairs.train`'s shapes (384x512 input
+    -> conv3 features 48x64x256, 441 maps, batch 64, bfloat16): the
+    forward kernel, the backward kernel (with its XLA transposes and
+    pads), and forward + backward as the step takes them.
+    Then the backward's error on one image against autodiff of the XLA
+    sweep in float32 on the same bfloat16 values, as a share of the
+    reference's largest element."""
     import jax
+    import jax.numpy as jnp
+    import numpy as np
 
     from deepof_tpu.ops.corr import correlation
+    from deepof_tpu.ops.pallas.corr import _pallas_corr_bwd
 
-    key = jax.random.PRNGKey(0)
-    f1 = jax.random.normal(key, (16, 40, 56, 256)) * 0.1
-    f2 = jax.random.normal(jax.random.PRNGKey(1), (16, 40, 56, 256)) * 0.1
-    for impl in ("xla", "pallas"):
-        f = jax.jit(lambda a, b, impl=impl:
-                    correlation(a, b, impl=impl).sum())
-        timeit(f"corr fwd {impl} 40x56x256", f, f1, f2)
-        g = jax.jit(lambda a, b, impl=impl: sum(
-            x.sum() for x in jax.grad(
-                lambda q: correlation(q[0], q[1], impl=impl).sum())((a, b))))
-        timeit(f"corr grad {impl} 40x56x256", g, f1, f2)
+    shape = b, h, w, c = (64, 48, 64, 256)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    f1, f2 = (jax.random.normal(k, shape, jnp.bfloat16) for k in keys[:2])
+    g = jax.random.normal(keys[2], shape[:3] + (441,), jnp.bfloat16)
+    n_ops = 4 * b * h * w * 441 * c
+    fwd = jax.jit(lambda a, b: correlation(a, b, impl="pallas"))
+    timeit(f"corr fwd pallas {b}x{h}x{w}x{c}", fwd, f1, f2)
+    bwd = jax.jit(lambda a, b, ct: sum(
+        x.ravel()[0].astype(jnp.float32)
+        for x in _pallas_corr_bwd(a, b, ct, 20, 2, 8, False)))
+    per = timeit("corr bwd kernel", bwd, f1, f2, g)
+    print(f"  {n_ops / per / 1e12:.2f} TOP/s (4*b*h*w*n^2*c)", flush=True)
+
+    def both(a, b, ct):
+        out, vjp = jax.vjp(lambda p, q: correlation(p, q, impl="pallas"), a, b)
+        return sum(x.ravel()[0].astype(jnp.float32) for x in (out, *vjp(ct)))
+
+    timeit("corr fwd + bwd pallas", jax.jit(both), f1, f2, g)
+
+    a, b, ct = f1[:1], f2[:1], g[:1]
+    got = jax.jit(lambda p, q, c: jax.vjp(
+        lambda x, y: correlation(x, y, impl="pallas"), p, q)[1](c))(a, b, ct)
+    want = jax.jit(lambda p, q, c: jax.vjp(
+        lambda x, y: correlation(x, y, impl="xla"), p, q)[1](c))(
+            *(x.astype(jnp.float32) for x in (a, b, ct)))
+    for name, x, y in zip(("df1", "df2"), got, want):
+        x, y = np.asarray(x, np.float32), np.asarray(y)
+        print(f"  {name} max |err| / max |ref| "
+              f"{np.abs(x - y).max() / np.abs(y).max():.3e}", flush=True)
 
 
 def sec_multiframe() -> None:
