@@ -109,28 +109,50 @@ def test_warp_kernels_compile_for_v5e(one_chip, which, hw):
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-@pytest.mark.parametrize("hw", [(40, 56), (48, 64)])
+@pytest.mark.parametrize("hw", [(40, 56), (48, 64), (28, 60)])
 @pytest.mark.parametrize("which", ["fwd", "bwd"])
 def test_corr_kernel_compiles_for_v5e(one_chip, which, hw, dtype):
     """FlowNet-C's cost volume at its real shapes: conv3 features of
-    320x448 and 384x512 inputs (1/8 resolution), C=256, max_disp=20,
-    stride=2, the forward kernel and the backward's (the padded image and
-    its float32 df2 accumulator resident in VMEM, the band products on the
-    MXU). Before the dx sweep was unrolled Mosaic refused every forward
-    here: 'cannot statically prove that index in dimension 1 is a
-    multiple of 8'."""
+    320x448 and 384x512 inputs and of the Sintel crop 224x480 (1/8
+    resolution; W 60 is no multiple of 16), C=256, max_disp=20, stride=2,
+    the forward kernel (the image's phase-split padded f2 resident in
+    VMEM, the products on the MXU, the diagonals turned to their lanes by
+    a strided roll) and the backward's (the padded image and its float32
+    df2 accumulator resident in VMEM, the band products on the MXU).
+    Mosaic refuses a sublane slice it cannot prove tile-aligned ('cannot
+    statically prove that index in dimension 1 is a multiple of 8') and
+    a strided load or store of 16-bit data."""
     from deepof_tpu.ops.pallas.corr import _pallas_corr_bwd, _pallas_corr_fwd
 
     h, w = hw
     f = jax.ShapeDtypeStruct((BATCH, h, w, 256), dtype, sharding=one_chip)
     if which == "fwd":
-        _compiled_text(lambda a, b: _pallas_corr_fwd(a, b, 20, 2, 8, False),
+        _compiled_text(lambda a, b: _pallas_corr_fwd(a, b, 20, 2, False),
                        f, f, kernels=["corr_fwd"])
         return
     g = jax.ShapeDtypeStruct((BATCH, h, w, 441), dtype, sharding=one_chip)
     _compiled_text(lambda a, b, ct: _pallas_corr_bwd(a, b, ct, 20, 2, 8,
                                                      False),
                    f, f, g, kernels=["corr_bwd"])
+
+
+def test_corr_forward_writes_the_models_layout(one_chip):
+    """The public forward at the cell's shapes compiles to the kernel and
+    the pad of f2 before it, and nothing after it: the kernel writes the
+    (B, H, W, 441) volume in bfloat16 itself, so no transpose, copy or
+    convert of a 441-wide volume follows `corr_fwd`."""
+    from deepof_tpu.ops.pallas.corr import correlation_pallas
+
+    f = jax.ShapeDtypeStruct((BATCH, 48, 64, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compiled_text(lambda a, b: correlation_pallas(a, b, 20, 2, 8,
+                                                          False),
+                          f, f, kernels=["corr_fwd"])
+    wide = [ln.strip() for ln in text.splitlines()
+            if re.match(r"\s*(ROOT )?%", ln) and "441" in ln
+            and "tpu_custom_call" not in ln]
+    assert not wide, wide
+    assert re.search(r"ROOT %corr_fwd\.\d+ = bf16\[16,48,64,441\]", text)
 
 
 def _auto_flow_grad(hw):

@@ -18,21 +18,39 @@ def feats(rng):
     return f1, f2
 
 
-def test_pallas_corr_matches_oracle(feats):
-    f1, f2 = feats
-    got = np.asarray(correlation_pallas(
-        jnp.asarray(f1), jnp.asarray(f2), 2, 1, 4, True))
-    want = correlation_oracle(f1, f2, max_disp=2, stride=1)
-    np.testing.assert_allclose(got, want, atol=1e-5)
-
-
-def test_pallas_corr_stride_and_ragged_height(feats):
-    f1, f2 = feats
-    f1, f2 = f1[:, :11], f2[:, :11]  # H=11 not divisible by tile_h=4
-    got = np.asarray(correlation_pallas(
-        jnp.asarray(f1), jnp.asarray(f2), 4, 2, 4, True))
-    want = correlation_oracle(f1, f2, max_disp=4, stride=2)
-    np.testing.assert_allclose(got, want, atol=1e-5)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape, max_disp, stride, tile_h", [
+    ((2, 12, 16, 8), 2, 1, 4),
+    ((2, 11, 16, 8), 4, 2, 4),   # H=11: one row tile of 12, padded
+    ((1, 18, 12, 8), 8, 2, 4),   # two row tiles of 16, the second padded
+    ((1, 6, 12, 8), 8, 2, 4),    # W 12: one sublane tile a phase
+    ((1, 6, 12, 8), 8, 1, 4),    # stride 1: one phase
+    ((1, 5, 56, 4), 20, 2, 4),   # conv3 of 320x448's width; H ragged
+    ((1, 4, 60, 4), 20, 2, 4),   # the Sintel crop's: W not a multiple of 16
+    ((1, 4, 60, 4), 8, 1, 4),
+    ((2, 7, 12, 8), 20, 1, 4),   # 41 x 41 offsets, most of them off the image
+])
+def test_pallas_corr_forward_matches_oracle_and_xla(rng, shape, max_disp,
+                                                    stride, tile_h, dtype):
+    """The forward kernel (products on the MXU, diagonals turned to their
+    lanes by a strided roll) against the numpy oracle and the XLA sweep in
+    float32 on the same values. float32: to 1e-5 of the largest element;
+    bfloat16: the kernel's sums are float32 and only its output is
+    rounded, so each element is within one bfloat16 step of the reference."""
+    f1, f2 = (jnp.asarray(rng.randn(*shape), dtype) for _ in range(2))
+    got = correlation_pallas(f1, f2, max_disp, stride, tile_h, True)
+    assert got.dtype == jnp.dtype(dtype)
+    a, b = (np.asarray(x, np.float32) for x in (f1, f2))
+    want = np.asarray(correlation(jnp.asarray(a), jnp.asarray(b), max_disp,
+                                  stride, impl="xla"))
+    np.testing.assert_allclose(
+        correlation_oracle(a, b, max_disp, stride), want,
+        atol=1e-5 * np.abs(want).max())
+    if dtype == "float32":
+        np.testing.assert_allclose(np.asarray(got), want,
+                                   atol=1e-5 * np.abs(want).max())
+    else:
+        _close(got, want, 2.0 ** -8)
 
 
 def _vjp(corr, f1, f2, g):

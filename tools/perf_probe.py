@@ -20,8 +20,8 @@ Sections:
            beside the XLA gather, at 160x224 (two lane tiles) and 80x112,
            batch 64: the measurement behind `PALLAS_AUTO_MAX_SWEEP`
   corr     the correlation's kernels at `flownet_c_chairs.train`'s shapes:
-           forward, backward, both, and the backward's error
-           against autodiff of the XLA sweep
+           forward, backward, both, and the error of each direction
+           against the XLA sweep
   batch    batch-size throughput curve (16/96)
   multiframe Sintel-shaped T=10 volume train step
   warp     per-call XLA vs Pallas warp table (includes dispatch)
@@ -474,44 +474,56 @@ def sec_headline() -> None:
 def sec_corr() -> None:
     """The correlation at `flownet_c_chairs.train`'s shapes (384x512 input
     -> conv3 features 48x64x256, 441 maps, batch 64, bfloat16): the
-    forward kernel, the backward kernel (with its XLA transposes and
-    pads), and forward + backward as the step takes them.
-    Then the backward's error on one image against autodiff of the XLA
-    sweep in float32 on the same bfloat16 values, as a share of the
-    reference's largest element."""
+    forward kernel alone and as the public route (with its pads and the
+    phase split of f2), the backward kernel (with its XLA transposes and
+    pads), and forward + backward as the step takes them; each whole
+    result behind `lax.optimization_barrier`, so XLA computes all of it.
+    Then both directions' error on one image against the XLA sweep (its
+    autodiff for the backward) in float32 on the same bfloat16 values, as
+    a share of the reference's largest element."""
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax import lax
 
     from deepof_tpu.ops.corr import correlation
-    from deepof_tpu.ops.pallas.corr import _pallas_corr_bwd
+    from deepof_tpu.ops.pallas.corr import _pallas_corr_bwd, _pallas_corr_fwd
 
     shape = b, h, w, c = (64, 48, 64, 256)
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     f1, f2 = (jax.random.normal(k, shape, jnp.bfloat16) for k in keys[:2])
     g = jax.random.normal(keys[2], shape[:3] + (441,), jnp.bfloat16)
     n_ops = 4 * b * h * w * 441 * c
-    fwd = jax.jit(lambda a, b: correlation(a, b, impl="pallas"))
-    timeit(f"corr fwd pallas {b}x{h}x{w}x{c}", fwd, f1, f2)
-    bwd = jax.jit(lambda a, b, ct: sum(
-        x.ravel()[0].astype(jnp.float32)
-        for x in _pallas_corr_bwd(a, b, ct, 20, 2, 8, False)))
-    per = timeit("corr bwd kernel", bwd, f1, f2, g)
+
+    def first(*outs):
+        return sum(x.ravel()[0].astype(jnp.float32)
+                   for x in lax.optimization_barrier(outs))
+
+    per = timeit(f"corr fwd kernel {b}x{h}x{w}x{c}", jax.jit(
+        lambda a, b: first(_pallas_corr_fwd(a, b, 20, 2, False))), f1, f2)
+    print(f"  {n_ops / 2 / per / 1e12:.2f} TOP/s (2*b*h*w*n^2*c)", flush=True)
+    timeit("corr fwd pallas (public route)", jax.jit(
+        lambda a, b: first(correlation(a, b, impl="pallas"))), f1, f2)
+    per = timeit("corr bwd kernel", jax.jit(
+        lambda a, b, ct: first(*_pallas_corr_bwd(a, b, ct, 20, 2, 8, False))),
+        f1, f2, g)
     print(f"  {n_ops / per / 1e12:.2f} TOP/s (4*b*h*w*n^2*c)", flush=True)
 
     def both(a, b, ct):
         out, vjp = jax.vjp(lambda p, q: correlation(p, q, impl="pallas"), a, b)
-        return sum(x.ravel()[0].astype(jnp.float32) for x in (out, *vjp(ct)))
+        return first(out, *vjp(ct))
 
     timeit("corr fwd + bwd pallas", jax.jit(both), f1, f2, g)
 
     a, b, ct = f1[:1], f2[:1], g[:1]
-    got = jax.jit(lambda p, q, c: jax.vjp(
-        lambda x, y: correlation(x, y, impl="pallas"), p, q)[1](c))(a, b, ct)
-    want = jax.jit(lambda p, q, c: jax.vjp(
-        lambda x, y: correlation(x, y, impl="xla"), p, q)[1](c))(
-            *(x.astype(jnp.float32) for x in (a, b, ct)))
-    for name, x, y in zip(("df1", "df2"), got, want):
+    ref = [x.astype(jnp.float32) for x in (a, b, ct)]
+    got = jax.jit(lambda p, q, c: (correlation(p, q, impl="pallas"),)
+                  + jax.vjp(lambda x, y: correlation(x, y, impl="pallas"),
+                            p, q)[1](c))(a, b, ct)
+    want = jax.jit(lambda p, q, c: (correlation(p, q, impl="xla"),)
+                   + jax.vjp(lambda x, y: correlation(x, y, impl="xla"),
+                             p, q)[1](c))(*ref)
+    for name, x, y in zip(("corr", "df1", "df2"), got, want):
         x, y = np.asarray(x, np.float32), np.asarray(y)
         print(f"  {name} max |err| / max |ref| "
               f"{np.abs(x - y).max() / np.abs(y).max():.3e}", flush=True)
