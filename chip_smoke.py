@@ -185,7 +185,7 @@ def phase_step_text(cli, argv: list[str], on_chip: bool) -> None:
     say(f"step text: compiled train step holds {n_comp} tpu_custom_call(s); "
         f"cache {d.stats()}; memory {compiled.memory_analysis()}")
     say("step text: flops per step by XLA's cost analysis: of the lowering "
-        f"(what the train loop logs) {lowered_flops(lowered)}, of the "
+        f"{lowered_flops(lowered)}, of the "
         f"compiled executable {lowered_flops(compiled)}")
     check(n_comp > 0, "no tpu_custom_call in the compiled train step")
     check(d.stats()["hits"] >= 1 and d.stats()["misses"] == 0,

@@ -20,4 +20,10 @@ Layout:
   utils/    metrics (EPE/AAE), flow color viz
 """
 
+import time as _time
+
+#: `time.perf_counter()` on the package's first line: where set-up's
+#: `boot` span ends and its `import` span starts (`obs.trace.record_setup`)
+T_PACKAGE_START = _time.perf_counter()
+
 __version__ = "0.1.0"

@@ -240,7 +240,9 @@ class ObsConfig:
     # Ring-buffered span tracer: fit() writes a Perfetto/chrome://tracing
     # loadable Chrome trace-event timeline to <log_dir>/trace.json
     # (main-thread dispatch/eval/ckpt, prefetch put, fetcher fetch,
-    # pipeline-worker assemble — the thread overlap made visible).
+    # pipeline-worker assemble — the thread overlap made visible), from
+    # the process's start: boot, import, the Trainer's set-up, every
+    # compile and Pallas kernel trace, the ledger's lowering.
     trace: bool = False
     # Max retained span events (bounded memory; newest win — the window
     # leading into a stall is the one that matters).
@@ -258,10 +260,6 @@ class ObsConfig:
     # trace ring flushed. Observe-and-report only — never kills the run.
     watchdog_factor: float = 20.0
     watchdog_min_s: float = 60.0
-    # XLA cost-analysis FLOPs at first step (lower-only, no extra
-    # compile): every periodic train record then carries model_tflops +
-    # nominal MFU — the bench-only telemetry, promoted into training.
-    flops: bool = True
     # Executable ledger (obs/ledger.py, DESIGN.md "Executable ledger"):
     # every lowering (train step, eval, the serve bucket x tier x mode
     # lattice, quality scorers) appends a provenance row — StableHLO
@@ -271,6 +269,8 @@ class ObsConfig:
     # /metrics. Costs nothing on the request hot path (rows are written
     # at compile time); tools/ledger_diff.py + `tail` rc 8 turn the
     # rows into a perf-regression gate against a committed baseline.
+    # The trainer's row costs a second trace and lowering of the step
+    # after its first run (the `ledger_lower` span); off, nothing runs.
     ledger: bool = True
     # --- Fleet observability plane (obs/export.py + obs/aggregate.py,
     # DESIGN.md "Fleet observability") ---

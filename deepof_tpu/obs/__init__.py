@@ -9,22 +9,26 @@ holds the instruments that can:
                 trace-event JSON (load artifacts' trace.json in Perfetto
                 / chrome://tracing) — the cross-thread timeline that
                 makes dispatch/put/fetch/assemble overlap visible
-                instead of inferred from phase totals. Set-up
-                (trainer_init, first_step, relower), compiles
-                (jax_trace, jax_lower, xla_compile, xla_cache_load) and
-                the main thread's waits (submit_wait, drain) are spans
-                of the same file, and while a tracer is installed every
-                span is mirrored as a jax.profiler.TraceAnnotation
-                (dispatch as a numbered step), so a profiler trace holds
-                them beside the device's operations.
+                instead of inferred from phase totals. Set-up from the
+                process's start, the trace's epoch (boot, import,
+                trainer_init, first_step, ledger_lower), compiles
+                (jax_trace, jax_lower, xla_compile, xla_cache_load),
+                each Pallas kernel's trace (kernel_trace) and the main
+                thread's waits (submit_wait, drain) are spans of the
+                same file, and while a tracer is installed every span
+                is mirrored as a jax.profiler.TraceAnnotation (dispatch
+                as a numbered step), so a profiler trace holds them
+                beside the device's operations.
   heartbeat.py  background thread atomically rewriting heartbeat.json
                 (step, rates, queue depths, device memory, RSS) plus a
                 wedge watchdog: no step within k x a robust recent
                 step-time estimate => all thread stacks dumped to the
                 log and the trace ring flushed.
   telemetry.py  process/device sampling shared by training and bench:
-                XLA cost-analysis FLOPs (model TFLOP/s + nominal MFU),
-                per-device memory_stats, process RSS.
+                per-device memory_stats and process RSS (every train
+                record); XLA cost-analysis FLOPs and the peak table
+                (bench.py, chip_smoke.py, the ledger; the train loop
+                logs no FLOPs, which the TPU's lowering never reports).
   export.py     the scrapeable face (DESIGN.md "Fleet observability"):
                 fixed log-spaced latency histograms that merge EXACTLY
                 across processes, Prometheus text rendering/parsing for

@@ -1,9 +1,10 @@
 """Process + device telemetry sampling, shared by training and bench.
 
-Promotes what used to be bench-only instrumentation into the training
-loop: XLA's own cost-analysis FLOPs (so every fit() can log model
-TFLOP/s and a nominal MFU, not just bench.py), per-device
-`memory_stats()` (HBM bytes-in-use / peak), and process RSS.
+Per-device `memory_stats()` (HBM bytes-in-use / peak) and process RSS,
+which every train record and the heartbeat carry; XLA's own
+cost-analysis FLOPs and the peak table, which bench.py, chip_smoke.py
+and the executable ledger read (the train loop logs no FLOPs: on the
+TPU a lowering's cost analysis reports none).
 
 Import discipline: jax is imported lazily inside the functions —
 importing this module must stay side-effect free (the heartbeat thread
@@ -18,8 +19,8 @@ import os
 #: Published dense bf16 peak per chip, TFLOP/s, keyed by
 #: `jax.devices()[0].device_kind`. Source: Google Cloud documentation,
 #: "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s). The single table
-#: behind every `mfu_nominal` / `roofline_s` (bench.py, the train loop,
-#: obs/ledger.py). A kind that is not listed — the CPU included — has no
+#: behind every `mfu_nominal` / `roofline_s` (bench.py, obs/ledger.py).
+#: A kind that is not listed — the CPU included — has no
 #: peak: callers then report no MFU rather than one against a chip that
 #: is not there.
 PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0}
@@ -38,9 +39,9 @@ def peak_bf16_tflops(device_kind: str | None = None) -> float | None:
 def lowered_flops(lowered) -> float | None:
     """FLOPs from an already-lowered module's cost analysis — the
     shared extraction behind step_flops, split out so a caller that
-    holds a `jax.stages.Lowered` (the train loop reuses one lowering
-    for FLOPs AND the executable ledger's provenance row) never pays a
-    second trace. None when the backend does not report it."""
+    holds a `jax.stages.Lowered` or `Compiled` (chip_smoke.py reads
+    both) never pays a second trace. None when the backend does not
+    report it."""
     try:
         ca = lowered.cost_analysis()
         if isinstance(ca, (list, tuple)):

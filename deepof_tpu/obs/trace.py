@@ -18,8 +18,9 @@ Design constraints, in order:
     keeps the newest `ring_size` spans (the window that matters when a
     watchdog fires).
   - Timestamps come from `time.perf_counter()` (CLOCK_MONOTONIC —
-    comparable across threads of one process), rebased to the tracer's
-    construction so `ts` starts near zero.
+    comparable across threads of one process), rebased to the process's
+    start where the OS tells it (`process_start`), else to the tracer's
+    construction, so `ts` >= 0 for everything the process did since.
   - `flush()` writes the Chrome trace-event format (JSON object with a
     `traceEvents` list of "X" complete events + "M" thread-name
     metadata) atomically (tmp + rename), so a viewer — or the watchdog,
@@ -36,6 +37,11 @@ given `step_trace=(name, n)` (the loop's `dispatch`) is wrapped in a
 mirror costs about a microsecond a span and nothing with no tracer
 installed; `flush()` exports the tracer's `perf_counter` epoch so
 `trace.json` can be laid over any other `perf_counter` record.
+
+Set-up before the first tracer: `record_setup` turns two marks into
+spans after the fact, once a process: `boot` (process start -> the
+package's first line) and `import` (-> the end of `train/loop.py`'s
+import).
 
 Stdlib-only at import (see obs/__init__ docstring): the mirror finds jax
 in `sys.modules` and never imports it, so a process that has not loaded
@@ -61,6 +67,24 @@ from collections import deque
 #: while explicitly-named threads (prefetch, serve-batcher,
 #: pipeline-worker-N, ...) keep the full recycle-split fix.
 _AUTO_THREAD_NAME = re.compile(r"^Thread-\d+( \(.*\))?$")
+
+_PROC_STAT = "/proc/self/stat"
+
+
+def process_start() -> float | None:
+    """This process's start on the `time.perf_counter` clock, to the
+    kernel's clock tick (10 ms): field 22 of `/proc/self/stat` (clock
+    ticks after boot) against CLOCK_BOOTTIME now. None where either is
+    missing (not Linux)."""
+    try:
+        with open(_PROC_STAT) as f:
+            # field 2, the command, may hold spaces: count after its ")"
+            after_comm = f.read().rpartition(")")[2].split()
+        started = int(after_comm[22 - 3]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.perf_counter() - age
 
 
 class _NullSpan:
@@ -167,8 +191,12 @@ class Tracer:
         self.role = role
         self.index = index
         self._events: deque = deque(maxlen=self.ring_size)
-        self._epoch = time.perf_counter()
-        self._epoch_unix = time.time()
+        # ts 0: the process's start, so set-up's `boot` span fits on the
+        # timeline; where it is unknown, this construction
+        self.process_start = process_start()
+        now = time.perf_counter()
+        self._epoch = now if self.process_start is None else self.process_start
+        self._epoch_unix = time.time() - (now - self._epoch)
         # tid -> thread name registry (historical record: a thread whose
         # every event was evicted from the ring is still named in the
         # metadata). NOT the source of truth for event->name binding —
@@ -289,8 +317,9 @@ class Tracer:
             "displayTimeUnit": "ms",
             "otherData": {
                 "trace_epoch_unix": self._epoch_unix,
-                # ts 0 on time.perf_counter(): lays this file over any
-                # other perf_counter record without a marker instant
+                # ts 0 on time.perf_counter() (the process's start where
+                # known): lays this file over any other perf_counter
+                # record without a marker instant
                 "trace_epoch_perf_counter": self._epoch,
                 "ring_size": self.ring_size,
                 "dropped_spans": self._dropped,
@@ -382,6 +411,28 @@ def record_span(name: str, t0: float, t1: float, **args) -> None:
     No-op when no tracer is installed; not mirrored into the profiler."""
     if _current is not _NULL:
         _current._record(name, t0, t1, args or None)
+
+
+_setup_recorded = False
+
+
+def record_setup(package_start: float, imports_done: float) -> None:
+    """Set-up from before any tracer existed, as two spans on the calling
+    thread, once a process: `boot`, the process's start to
+    `package_start` (the package's first line: the interpreter and what
+    the entry point did before it imported the package; in the benchmark
+    `import jax` and the chip's start-up), and `import`, from there to
+    `imports_done` (the end of the trainer's imports). Only into an
+    installed tracer whose epoch is the process's start: where the start
+    is unknown, both would lie before ts 0 and neither is recorded. Later
+    calls (a recipe's later Trainers) record nothing."""
+    global _setup_recorded
+    if _setup_recorded or _current is _NULL:
+        return
+    _setup_recorded = True
+    if _current.process_start is not None:
+        record_span("boot", _current.process_start, package_start)
+        record_span("import", package_start, imports_done)
 
 
 def instant(name: str, **args) -> None:

@@ -38,8 +38,37 @@ Kernel inventory (and why each op is/isn't a kernel):
 
 Under a mesh every kernel runs per batch shard through
 `parallel.spatial.shard_over_batch`.
+
+Every kernel is called through `pallas_call` below (the kernel files
+take it and `pl` from here): its `name=` is the HLO instruction's (so a
+profile's events and the benchmark's rooflines find it), and the call,
+where the body is traced into a jaxpr, is a span `kernel_trace` of the
+program's trace. Mosaic's lowering of that jaxpr to MLIR happens later,
+inside the step's own `jax_lower`.
 """
 
-from .corr import correlation_pallas
+from jax.experimental import pallas as pl
 
-__all__ = ["correlation_pallas"]
+from ...obs import trace as obs_trace
+
+
+def pallas_call(kernel, *, name: str, **kwargs):
+    """`pl.pallas_call(kernel, name=name, **kwargs)`, whose call runs
+    inside a span `kernel_trace` with `kernel=name`. The span stands
+    around the call, not inside the body: the body's own lines stay the
+    innermost frame of every location in the Mosaic payload, so no
+    payload and no compile-cache key changes. With no tracer installed a
+    call costs one global read; a step's compiled program calls nothing
+    here."""
+    call = pl.pallas_call(kernel, name=name, **kwargs)
+
+    def traced(*args):
+        with obs_trace.span("kernel_trace", kernel=name):
+            return call(*args)
+
+    return traced
+
+
+from .corr import correlation_pallas  # noqa: E402 - needs pallas_call
+
+__all__ = ["correlation_pallas", "pallas_call"]

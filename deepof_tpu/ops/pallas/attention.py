@@ -60,9 +60,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
-from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_call, pl
 from ...parallel.spatial import current_mesh, shard_over_batch
 from ..attention import _NEG, RESIDUALS, Mask
 
@@ -203,7 +203,7 @@ def _forward(qn, qr, kn, kr, v, scale, bq, bkv, interpret):
     # the last key block a query block sees: later grid steps fetch nothing
     q, _, ins = _specs(bq, bkv, (dn, dr, dv), lambda i, j: i,
                        lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bkv))
-    o, lse = pl.pallas_call(
+    o, lse = pallas_call(
         functools.partial(_fwd_kernel, scale=scale, bq=bq, bkv=bkv),
         grid=(b, h, s // bq, s // bkv),
         in_specs=ins,
@@ -242,7 +242,7 @@ def _backward(qn, qr, kn, kr, v, o, lse, do, scale, bq, bkv, interpret):
         return pl.BlockSpec((None, None, None, bq, d),
                             lambda b, h, j, i: (b, h, j, i, 0))
 
-    dqn, dqr, dkn, dkr, dvv = pl.pallas_call(
+    dqn, dqr, dkn, dkr, dvv = pallas_call(
         functools.partial(_bwd_kernel, scale=scale, bq=bq, bkv=bkv),
         grid=(b, h, nkv, s // bq),
         in_specs=ins + [q(dv), row, row],
@@ -432,7 +432,7 @@ def _rule_forward(q, k, v, scale, bq, bkv, mask, interpret):
                                 lambda b, n, i, j: (b, n, i, 0))
     ks = pl.BlockSpec((None, None, bkv, d),
                       lambda b, n, i, j: (b, n // r, k_of(i, j), 0))
-    o, lse = pl.pallas_call(
+    o, lse = pallas_call(
         functools.partial(_rule_fwd_kernel, scale=scale, bq=bq, bkv=bkv,
                           mask=mask),
         grid=(b, h, s // bq, s // bkv),
@@ -469,7 +469,7 @@ def _rule_backward(q, k, v, o, lse, do, scale, bq, bkv, mask, interpret):
                        lambda b, n, j, x: (b, head(n, x), 0, q_of(j, x)))
     part = pl.BlockSpec((None, None, None, bq, d),
                         lambda b, n, j, x: (b, head(n, x), j, x % nq, 0))
-    dq, dk, dv = pl.pallas_call(
+    dq, dk, dv = pallas_call(
         functools.partial(_rule_bwd_kernel, scale=scale, bq=bq, bkv=bkv,
                           mask=mask, positions=s),
         grid=(b, g, nkv, r * nq),

@@ -78,9 +78,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_call, pl
 from ...parallel.spatial import current_mesh, shard_over_batch
 
 
@@ -240,7 +240,7 @@ def _pallas_corr_fwd(f1: jnp.ndarray, f2: jnp.ndarray, max_disp: int,
 
     kernel = functools.partial(_corr_kernel, n=n, stride=s, tile_h=tile_h,
                                wh=wh, vp=vp, c=c, group=group)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel, name="corr_fwd",
         grid=(b, hp // tile_h),
         in_specs=[
@@ -341,7 +341,7 @@ def _pallas_corr_bwd(f1, f2, g, max_disp: int, stride: int, tile_h: int,
                                tile_h=tile_h, h=h, w=w, pad=pad, c=c)
     tile = pl.BlockSpec((1, tile_h, w, c), lambda bi, ti: (bi, ti, 0, 0))
     image = lambda shape: pl.BlockSpec(shape, lambda bi, ti: (bi, 0, 0, 0))
-    df1, df2 = pl.pallas_call(
+    df1, df2 = pallas_call(
         kernel, name="corr_bwd",
         grid=(b, hp // tile_h),
         in_specs=[

@@ -44,9 +44,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_call, pl
 from ...parallel.spatial import current_mesh, shard_over_batch
 
 F32 = jnp.float32
@@ -186,7 +186,7 @@ def _forward(x, cos, sin, scale, heads, off, eps, bs, dtype, interpret):
     d = w // heads
     hb, xs, ys, table, gs = _specs(heads, d, bs)
     norm = () if eps is None else (scale.reshape(1, d),)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_fwd_kernel, d=d, off=off, eps=eps),
         grid=(b, s // bs, heads // hb),
         in_specs=[xs, table, table] + [gs] * len(norm),
@@ -202,7 +202,7 @@ def _backward(x, cos, sin, scale, dy, off, eps, bs, interpret):
     ns, nj = s // bs, heads // hb
     dx = jax.ShapeDtypeStruct((b, s, heads * d), dy.dtype)
     if eps is None:  # the rotation's transpose needs only the tables
-        return pl.pallas_call(
+        return pallas_call(
             functools.partial(_bwd_kernel, d=d, off=off, eps=None),
             grid=(b, ns, nj), in_specs=[ys, table, table],
             out_specs=xs, out_shape=dx, compiler_params=_PARALLEL,
@@ -210,7 +210,7 @@ def _backward(x, cos, sin, scale, dy, off, eps, bs, interpret):
         )(dy, cos, sin), None
     part = pl.BlockSpec((None, None, None, SUBLANES, d),
                         lambda b, i, j: (b, i, j, 0, 0))
-    dx, dg = pl.pallas_call(
+    dx, dg = pallas_call(
         functools.partial(_bwd_kernel, d=d, off=off, eps=eps),
         grid=(b, ns, nj), in_specs=[xs, ys, table, table, gs],
         out_specs=[xs, part],

@@ -54,9 +54,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_call, pl
 from ...parallel.spatial import current_mesh, shard_over_batch
 
 LANES = 128
@@ -217,7 +217,7 @@ def _pallas_warp_flow_grad(image: jnp.ndarray, flow: jnp.ndarray,
     tiles = _lane_tiles(w)
     kernel = functools.partial(_warp_flow_grad_kernel, h=h, w=w, c=c, hp=hp,
                                tiles=tiles)
-    out = pl.pallas_call(  # name=: the HLO instruction's, so a trace event's
+    out = pallas_call(
         kernel, name="warp_flow_grad",
         grid=(b,),
         in_specs=[_plane_spec(c, hp, tiles), _plane_spec(2, hp, tiles),
@@ -242,7 +242,7 @@ def _pallas_warp_fwd(image: jnp.ndarray, flow: jnp.ndarray,
     hp = -(-h // 8) * 8
     kernel = functools.partial(_warp_kernel, h=h, w=w, c=c, hp=hp,
                                tiles=tiles)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel, name="warp_fwd",
         grid=(b,),
         in_specs=[_plane_spec(c, hp, tiles), _plane_spec(2, hp, tiles)],

@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import T_PACKAGE_START
 from ..core.config import ExperimentConfig
 from ..data import InputPipeline, Prefetcher, build_dataset, derive_batch_rng
 from ..models.registry import example_input, model_for, task_of
@@ -29,12 +30,7 @@ from ..obs import incident as obs_incident
 from ..obs import trace as obs_trace
 from ..obs.heartbeat import Heartbeat
 from ..obs.ledger import ExecutableLedger
-from ..obs.telemetry import (
-    device_memory_summary,
-    lowered_flops,
-    peak_bf16_tflops,
-    process_rss_bytes,
-)
+from ..obs.telemetry import device_memory_summary, process_rss_bytes
 from ..parallel.mesh import batch_sharding, build_mesh, replicated_sharding
 from ..resilience.faults import build_injector
 from ..resilience.healing import HealingSampler
@@ -182,14 +178,18 @@ class Trainer:
         one. Single-writer (primary process only), same rationale as
         MetricsLogger. (role, index) stamp the trace so obs/aggregate.py
         can merge an elastic pool's per-host timelines; host_index < 0
-        (plain single-process training) stamps trainer-0."""
+        (plain single-process training) stamps trainer-0. The process's
+        set-up before it, `boot` and `import`, enters the first such
+        tracer of the process."""
         if not cfg.obs.trace or jax.process_index() != 0:
             return None
         install_cache_counters()  # compiles become spans from here on
-        return obs_trace.install(obs_trace.Tracer(
+        tracer = obs_trace.install(obs_trace.Tracer(
             path=os.path.join(cfg.train.log_dir, "trace.json"),
             ring_size=cfg.obs.trace_ring, role="trainer",
             index=max(cfg.elastic.host_index, 0)))
+        obs_trace.record_setup(T_PACKAGE_START, T_IMPORTS_DONE)
+        return tracer
 
     @staticmethod
     def _stop_tracer(tracer) -> None:
@@ -244,10 +244,6 @@ class Trainer:
         self.logger = MetricsLogger(cfg.train.log_dir)
         self.profiler = ProfilerSession(cfg.train.log_dir, enabled=profile,
                                         steps=profile_steps)
-        # XLA cost-analysis FLOPs per optimizer step, computed once at
-        # the first dispatch (obs/telemetry.py) — None until then, and on
-        # backends without a cost model.
-        self._flops_per_step: float | None = None
         self.steps_per_epoch = max(self.dataset.num_train // cfg.data.batch_size, 1)
         schedule = step_decay_schedule(cfg.optim, self.steps_per_epoch)
         self.schedule = schedule
@@ -602,8 +598,8 @@ class Trainer:
         # Executable ledger (obs/ledger.py): the live run's train-step
         # provenance row — StableHLO fingerprint, first-step compile
         # wall, persistent-cache hit/miss, cost analysis, donation map —
-        # appended to <log_dir>/ledger.jsonl at the first step, from the
-        # same lower-only retrace the FLOPs telemetry already pays.
+        # appended to <log_dir>/ledger.jsonl at the first step, from a
+        # lower-only retrace (`ledger_lower`) that nothing else needs.
         # Memory-analysis fields stay None here (the jit-dispatch path
         # has no AOT Compiled object; `warmup` rows carry them).
         ledger = (ExecutableLedger(cfg.train.log_dir,
@@ -796,7 +792,7 @@ class Trainer:
                            if key.startswith(LAYER_METRIC_PREFIX)},
                         **timer.rates(), **timer.phases(),
                         **timer.counters(), **resilience_stats(),
-                        **cache_kw, **self._telemetry(timer))
+                        **cache_kw, **self._telemetry())
 
             gstep = start_step
             final_ckpt_step = None
@@ -865,7 +861,8 @@ class Trainer:
                         jax.block_until_ready(metrics["total"])
                     dc = cache_watch.stats()
                     first_wall = time.perf_counter() - t0
-                    self._relower(ledger, batch, first_wall, dc)
+                    if ledger is not None and not self._injected_step:
+                        self._ledger_lower(ledger, batch, first_wall, dc)
                     # hit/miss counters surfaced in metrics: a warmed
                     # process shows compile_cache_misses == 0 here
                     self.logger.log(
@@ -874,8 +871,7 @@ class Trainer:
                                 f"{time.perf_counter() - t0:.1f}s",
                         compile_cache_requests=dc["requests"],
                         compile_cache_hits=dc["hits"],
-                        compile_cache_misses=dc["misses"],
-                        flops_per_step=self._flops_per_step)
+                        compile_cache_misses=dc["misses"])
                     first_step = False
                     first_span.close()
                 else:
@@ -1135,59 +1131,43 @@ class Trainer:
                 # the one-line run summary, from the same merge the
                 # heartbeat and train records use
                 **resilience_stats(),
-                # telemetry (model_tflops/mfu_nominal/dev mem/rss);
-                # None-valued fields dropped — the summary stays
-                # float()-able for CLI printing
-                **{k: v for k, v in self._telemetry(timer).items()
+                # telemetry (dev mem/rss); None-valued fields dropped —
+                # the summary stays float()-able for CLI printing
+                **{k: v for k, v in self._telemetry().items()
                    if v is not None}}
 
-    def _relower(self, ledger, batch, first_wall: float, cache: dict) -> None:
-        """After the first step: ONE lower-only retrace (no second
-        backend compile) serves both the FLOPs telemetry and the
-        ledger's provenance row. A span of its own, `relower`: it is
-        part of what a user waits for before the second step."""
-        cfg = self.cfg
-        with obs_trace.span("relower"):
-            lowered = None
-            if cfg.obs.flops or ledger is not None:
-                try:
-                    lowered = self.train_step.lower(self.state, batch)
-                except Exception:  # noqa: BLE001 - telemetry only
-                    lowered = None
-            if cfg.obs.flops and lowered is not None:
-                # every periodic record then carries model_tflops
-                self._flops_per_step = lowered_flops(lowered)
-            if ledger is not None and not self._injected_step:
-                # compile_kind="first_step": first_wall includes one
-                # EXECUTED step, a different unit from warmup's
-                # pure lower+compile "aot" rows — diff_ledgers only
-                # bounds like against like. An INJECTED pre-compiled
-                # step (recipe engine) records nothing: its compile
-                # already owns an "aot" row (train_step_stage<i>) and
-                # its first dispatch is execution, not compile —
-                # keeping the ledger a pure compile record is what
-                # makes "a stage switch added zero rows" provable from it
-                ledger.record("train_step", lowered=lowered,
-                              compile_s=first_wall,
-                              compile_kind="first_step", cache=cache)
+    def _ledger_lower(self, ledger, batch, first_wall: float,
+                      cache: dict) -> None:
+        """After the first step: the ledger's provenance row, from a
+        lower-only retrace of the step (no second backend compile). A
+        span of its own, `ledger_lower`: every process traces and lowers
+        the step a second time for it before the second step.
 
-    def _telemetry(self, timer: StepTimer) -> dict:
-        """Device-memory / RSS / model-FLOP fields for a train record
-        (obs/telemetry.py — the bench-only instrumentation, promoted).
-        Keys are schema-stable across backends: values the backend
-        cannot report serialize as null in metrics.jsonl."""
+        compile_kind="first_step": first_wall includes one EXECUTED step,
+        a different unit from warmup's pure lower+compile "aot" rows —
+        diff_ledgers only bounds like against like. An INJECTED
+        pre-compiled step (recipe engine) is never lowered here: its
+        compile already owns an "aot" row (train_step_stage<i>) and its
+        first dispatch is execution, not compile — keeping the ledger a
+        pure compile record is what makes "a stage switch added zero
+        rows" provable from it."""
+        with obs_trace.span("ledger_lower"):
+            try:
+                lowered = self.train_step.lower(self.state, batch)
+            except Exception:  # noqa: BLE001 - the row records None
+                lowered = None
+            ledger.record("train_step", lowered=lowered,
+                          compile_s=first_wall,
+                          compile_kind="first_step", cache=cache)
+
+    @staticmethod
+    def _telemetry() -> dict:
+        """Device-memory / RSS fields for a train record
+        (obs/telemetry.py). Keys are schema-stable across backends:
+        values the backend cannot report serialize as null in
+        metrics.jsonl."""
         out = dict(device_memory_summary())
         out["rss_bytes"] = process_rss_bytes()
-        if self._flops_per_step:
-            sps = timer.rates()["steps_per_sec"]
-            if sps > 0:
-                tfs = self._flops_per_step * sps / timer.n_chips / 1e12
-                # significant figures, not decimals: a cpu smoke's 1e-5
-                # TFLOP/s must not round to a meaningless 0.0
-                out["model_tflops"] = float(f"{tfs:.4g}")
-                peak = peak_bf16_tflops()
-                if peak:  # a device outside the table gets no MFU
-                    out["mfu_nominal"] = float(f"{tfs / peak:.4g}")
         return out
 
     def _rollback(self, step: int) -> None:
@@ -1210,3 +1190,8 @@ class Trainer:
         self.logger.log("warn", step,
                         message=f"divergence at step {step}; rolled back "
                                 f"to step {int(restored.step)}")
+
+
+#: `time.perf_counter()` once the trainer's imports are done (this
+#: module's last line): where set-up's `import` span ends
+T_IMPORTS_DONE = time.perf_counter()

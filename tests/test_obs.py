@@ -202,7 +202,13 @@ def test_flush_exports_the_perf_counter_epoch(tmp_path):
         pass
     payload = _strict_loads(open(tr.flush()).read())
     epoch = payload["otherData"]["trace_epoch_perf_counter"]
-    assert before <= epoch <= after
+    if tr.process_start is None:  # the epoch is the tracer's construction
+        assert before <= epoch <= after
+    else:  # the process's start, before anything it did
+        assert epoch == tr.process_start <= before
+    # the wall clock's epoch is the same instant
+    assert payload["otherData"]["trace_epoch_unix"] == pytest.approx(
+        time.time() - (time.perf_counter() - epoch), abs=0.05)
     (span,) = [e for e in payload["traceEvents"] if e["ph"] == "X"]
     # ts is microseconds after the epoch, on perf_counter
     assert epoch + span["ts"] * 1e-6 == pytest.approx(t0, abs=1e-3)
@@ -458,8 +464,10 @@ def test_trace_summary_tool(tmp_path):
 def test_toy_fit_spans_setup_first_step_and_nests(tmp_path):
     """A 4-step fit at the smallest size that walks fit() end to end
     (one cpu device; tests/test_resilience.py's recipe), tracing on:
-    `Trainer.__init__` and the first step are spans with their children,
-    compiles are spans, and the main thread's spans nest: no two of them
+    the process's `boot` and `import` lead the timeline, `Trainer.__init__`
+    and the first step are spans with their children, compiles are spans,
+    the ledger's second lowering is `ledger_lower`, no record carries the
+    FLOPs telemetry, and the main thread's spans nest: no two of them
     partially overlap, which is what lets a reader take their union."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="",
                PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH",
@@ -492,13 +500,18 @@ def test_toy_fit_spans_setup_first_step_and_nests(tmp_path):
         return parent[1] - slack <= child[1] and child[2] <= parent[2] + slack
 
     init, first = one("trainer_init"), one("first_step")
+    boot, imports = one("boot"), one("import")
+    assert boot[1] >= 0 and boot[1] < 0.2  # ts 0 is the process's start
+    assert abs(boot[2] - imports[1]) <= 0.2  # adjacent: the package's mark
+    assert imports[2] <= init[1]
     for child in ("model_init", "ckpt_restore", "state_place", "step_build"):
         assert inside(one(child), init), child
     assert init[2] <= first[1]
     compiling = [s for s in main if s[0] == "dispatch"
                  and s[3].get("compile")]
     assert len(compiling) == 1 and inside(compiling[0], first)
-    assert inside(one("relower"), first)
+    assert inside(one("ledger_lower"), first)
+    assert not any(s[0] == "relower" for s in main)
     assert any(s[0] == "input_wait" and inside(s, first) for s in main)
     assert sum(s[0] == "dispatch" for s in main) == 4
     # the state's jitted init compiled inside model_init, the step inside
@@ -508,13 +521,17 @@ def test_toy_fit_spans_setup_first_step_and_nests(tmp_path):
                for s in main)
     assert any(s[0] == "xla_compile" and inside(s, compiling[0])
                and "step" in s[3]["fun_name"] for s in main)
-    in_relower = {s[0] for s in main if inside(s, one("relower"))}
-    assert "jax_trace" in in_relower and "xla_compile" not in in_relower
+    in_ledger = {s[0] for s in main if inside(s, one("ledger_lower"))}
+    assert "jax_trace" in in_ledger and "xla_compile" not in in_ledger
     assert any(s[0] == "drain" for s in main)  # the bounded one at the end
     for i, a in enumerate(main):
         for b in main[i + 1:]:
             apart = a[2] <= b[1] + 0.2 or b[2] <= a[1] + 0.2
             assert apart or inside(a, b) or inside(b, a), (a, b)
+    records = [_strict_loads(line)
+               for line in open(str(tmp_path / "metrics.jsonl"))]
+    for key in ("flops_per_step", "model_tflops", "mfu_nominal"):
+        assert not any(key in r for r in records), key
 
 
 # ------------------------------------- names inside the device program
@@ -565,8 +582,8 @@ def test_lowered_train_step_carries_the_scopes(time_step, loss_fn):
 def test_fit_writes_trace_heartbeat_and_telemetry(tmp_path):
     """The ISSUE acceptance: a cpu fit() with tracing on produces a
     strict-JSON Chrome trace with >= 3 distinct named threads and
-    overlapping spans, a fresh heartbeat.json at exit, and model_tflops
-    + device-memory fields in periodic train records.
+    overlapping spans, a fresh heartbeat.json at exit, and device-memory
+    fields in periodic train records.
 
     Runs the CLI in a SUBPROCESS, deliberately: the test exercises the
     real `--trace` entry path, in a process whose signal handlers,
@@ -622,5 +639,3 @@ def test_fit_writes_trace_heartbeat_and_telemetry(tmp_path):
     last = train[-1]
     for key in ("dev_mem_bytes_in_use", "dev_mem_peak_bytes", "rss_bytes"):
         assert key in last, key
-    assert any(isinstance(r.get("model_tflops"), (int, float))
-               for r in train), "model_tflops never logged"
