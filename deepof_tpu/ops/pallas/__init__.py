@@ -6,11 +6,11 @@ Kernel inventory (and why each op is/isn't a kernel):
     displacement sweep re-reads the second feature map hundreds of times;
     the XLA `dynamic_slice` formulation pays HBM traffic per displacement,
     while the kernel holds one haloed row-window of f2 in VMEM and sweeps
-    all displacements from on-chip memory. Its backward (`corr_bwd`)
-    holds the image's padded f2 and float32 df2 accumulator in VMEM and
-    takes each row offset's column offsets as one band product on the
-    MXU; it replaced an XLA scan that was nine tenths of the FlowNet-C
-    step (PR 40).
+    all displacements from on-chip memory. Its backward (`corr_bwd`) is
+    the forward's transpose (its blocked MXU products, the cotangent put
+    where the forward reads its diagonals, df2 accumulated in VMEM); it
+    replaced an XLA scan that was nine tenths of the FlowNet-C step (PR
+    40), then a serial band loop (PR 43).
 
   - `warp.py` — the bilinear backward warp and its flow gradient at
     every pyramid level of up to two lane tiles (W <= 256), as a sweep

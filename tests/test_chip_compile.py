@@ -117,11 +117,12 @@ def test_corr_kernel_compiles_for_v5e(one_chip, which, hw, dtype):
     resolution; W 60 is no multiple of 16), C=256, max_disp=20, stride=2,
     the forward kernel (the image's phase-split padded f2 resident in
     VMEM, the products on the MXU, the diagonals turned to their lanes by
-    a strided roll) and the backward's (the padded image and its float32
-    df2 accumulator resident in VMEM, the band products on the MXU).
-    Mosaic refuses a sublane slice it cannot prove tile-aligned ('cannot
-    statically prove that index in dimension 1 is a multiple of 8') and
-    a strided load or store of 16-bit data."""
+    a strided roll) and the backward, its transpose (f2's layout and the
+    float32 df2 accumulator in it resident in VMEM, the cotangent's
+    blocks placed by a strided roll, the same products transposed, f1
+    transposed in VMEM). Mosaic refuses a sublane slice it cannot prove
+    tile-aligned ('cannot statically prove that index in dimension 1 is a
+    multiple of 8') and a strided load or store of 16-bit data."""
     from deepof_tpu.ops.pallas.corr import _pallas_corr_bwd, _pallas_corr_fwd
 
     h, w = hw
@@ -131,8 +132,7 @@ def test_corr_kernel_compiles_for_v5e(one_chip, which, hw, dtype):
                        f, f, kernels=["corr_fwd"])
         return
     g = jax.ShapeDtypeStruct((BATCH, h, w, 441), dtype, sharding=one_chip)
-    _compiled_text(lambda a, b, ct: _pallas_corr_bwd(a, b, ct, 20, 2, 8,
-                                                     False),
+    _compiled_text(lambda a, b, ct: _pallas_corr_bwd(a, b, ct, 20, 2, False),
                    f, f, g, kernels=["corr_bwd"])
 
 
@@ -145,7 +145,7 @@ def test_corr_forward_writes_the_models_layout(one_chip):
 
     f = jax.ShapeDtypeStruct((BATCH, 48, 64, 256), jnp.bfloat16,
                              sharding=one_chip)
-    text = _compiled_text(lambda a, b: correlation_pallas(a, b, 20, 2, 8,
+    text = _compiled_text(lambda a, b: correlation_pallas(a, b, 20, 2,
                                                           False),
                           f, f, kernels=["corr_fwd"])
     wide = [ln.strip() for ln in text.splitlines()
@@ -153,6 +153,41 @@ def test_corr_forward_writes_the_models_layout(one_chip):
             and "tpu_custom_call" not in ln]
     assert not wide, wide
     assert re.search(r"ROOT %corr_fwd\.\d+ = bf16\[16,48,64,441\]", text)
+
+
+def test_corr_backward_reads_the_models_layout(one_chip, monkeypatch):
+    """The public VJP at the cell's shapes (`ops.corr.correlation`, the
+    model's call, its kernels' mode steered to the chip's) compiles to
+    `corr_fwd` and `corr_bwd` and nothing between them and the arguments:
+    the backward reads the cotangent in the volume's own (B, H, W, 441)
+    layout and f1 and f2 as they are, so no transpose, copy or convert of
+    a 441-wide array and no pad of f2 stands in the program (until PR 43
+    XLA laid the cotangent out as (B, H, 21, 21, W) and padded f2 to
+    88 x 112)."""
+    from deepof_tpu.ops.corr import correlation
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    f = jax.ShapeDtypeStruct((BATCH, 48, 64, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((BATCH, 48, 64, 441), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd_and_vjp(a, b, ct):
+        out, vjp = jax.vjp(lambda x, y: correlation(x, y, 20, 2,
+                                                    impl="pallas"), a, b)
+        return (out, *vjp(ct))
+
+    text = _compiled_text(fwd_and_vjp, f, f, g,
+                          kernels=["corr_fwd", "corr_bwd"])
+    ops = [ln.strip() for ln in text.splitlines()
+           if re.match(r"\s*(ROOT )?%", ln)]
+    moved = [ln for ln in ops if re.search(r"441|21,21", ln) and not re.search(
+        r"tpu_custom_call| parameter\(| tuple\(| get-tuple-element\(", ln)]
+    assert not moved, moved
+    assert not [ln for ln in ops if re.search(r" pad\(", ln)]
+    assert re.search(r"%corr_bwd\.\d+ = \(bf16\[16,48,64,256\]\S*, "
+                     r"bf16\[16,48,64,256\]\S*\) custom-call\(", text)
 
 
 def _auto_flow_grad(hw):
@@ -215,7 +250,7 @@ def test_corr_compiles_through_shard_map_on_four_chips(topo, which):
                              sharding=batch_sharding(mesh))
 
     def corr(a, b):
-        return correlation_pallas(a, b, 20, 2, 8, False)
+        return correlation_pallas(a, b, 20, 2, False)
 
     def grad(a, b):
         return jax.grad(lambda x, y: jnp.sum(corr(x, y).astype(

@@ -18,27 +18,33 @@ def feats(rng):
     return f1, f2
 
 
+# (shape, max_disp, stride): the geometries both kernels must take, at
+# their row tile of 8 (H where that is less, rounded up to whole product
+# groups of each row phase)
+GEOMETRY = [
+    ((2, 12, 16, 8), 2, 1),    # two row tiles, the second padded
+    ((2, 11, 16, 8), 4, 2),    # H=11: two row tiles, the second padded
+    ((1, 18, 12, 8), 8, 2),    # three row tiles, the third padded
+    ((1, 6, 12, 8), 8, 2),     # W 12: one sublane tile a phase
+    ((1, 6, 12, 8), 8, 1),     # stride 1: one phase
+    ((1, 5, 56, 4), 20, 2),    # conv3 of 320x448's width; H ragged
+    ((1, 4, 60, 4), 20, 2),    # the Sintel crop's: W not a multiple of 16
+    ((1, 4, 60, 4), 8, 1),
+    ((2, 7, 12, 8), 20, 1),    # 41 x 41 offsets, most of them off the image
+]
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("shape, max_disp, stride, tile_h", [
-    ((2, 12, 16, 8), 2, 1, 4),
-    ((2, 11, 16, 8), 4, 2, 4),   # H=11: one row tile of 12, padded
-    ((1, 18, 12, 8), 8, 2, 4),   # two row tiles of 16, the second padded
-    ((1, 6, 12, 8), 8, 2, 4),    # W 12: one sublane tile a phase
-    ((1, 6, 12, 8), 8, 1, 4),    # stride 1: one phase
-    ((1, 5, 56, 4), 20, 2, 4),   # conv3 of 320x448's width; H ragged
-    ((1, 4, 60, 4), 20, 2, 4),   # the Sintel crop's: W not a multiple of 16
-    ((1, 4, 60, 4), 8, 1, 4),
-    ((2, 7, 12, 8), 20, 1, 4),   # 41 x 41 offsets, most of them off the image
-])
+@pytest.mark.parametrize("shape, max_disp, stride", GEOMETRY)
 def test_pallas_corr_forward_matches_oracle_and_xla(rng, shape, max_disp,
-                                                    stride, tile_h, dtype):
+                                                    stride, dtype):
     """The forward kernel (products on the MXU, diagonals turned to their
     lanes by a strided roll) against the numpy oracle and the XLA sweep in
     float32 on the same values. float32: to 1e-5 of the largest element;
     bfloat16: the kernel's sums are float32 and only its output is
     rounded, so each element is within one bfloat16 step of the reference."""
     f1, f2 = (jnp.asarray(rng.randn(*shape), dtype) for _ in range(2))
-    got = correlation_pallas(f1, f2, max_disp, stride, tile_h, True)
+    got = correlation_pallas(f1, f2, max_disp, stride, True)
     assert got.dtype == jnp.dtype(dtype)
     a, b = (np.asarray(x, np.float32) for x in (f1, f2))
     want = np.asarray(correlation(jnp.asarray(a), jnp.asarray(b), max_disp,
@@ -73,23 +79,22 @@ def _close(got, want, rtol):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("shape, max_disp, stride, tile_h", [
-    ((2, 11, 16, 8), 2, 1, 4),
-    ((2, 11, 16, 8), 4, 2, 4),
-    ((1, 48, 64, 4), 20, 2, 10),  # the cell's sweep: 441 maps, pad 20
+@pytest.mark.parametrize("shape, max_disp, stride", GEOMETRY + [
+    ((1, 48, 64, 4), 20, 2),  # the cell's sweep: 441 maps, pad 20, 6 tiles
 ])
-def test_pallas_corr_grad_matches_xla(rng, shape, max_disp, stride, tile_h,
-                                      dtype):
+def test_pallas_corr_grad_matches_xla(rng, shape, max_disp, stride, dtype):
     """The backward kernel's df1 and df2 against autodiff of the XLA
-    sweep, H never a multiple of `tile_h` (the padded rows' zero cotangent
-    must add nothing). bfloat16: the kernel's products and sums are
+    sweep over the forward's geometries: the same row tiles, product
+    groups and f2 layout, the cotangent's blocks placed where the forward
+    reads its diagonals (a ragged H's padded rows carry a zero cotangent
+    and must add nothing). bfloat16: the kernel's products and sums are
     float32 and only its output is rounded, so each element is within one
     bfloat16 step (2^-8) of the float32 reference on the same values."""
     n = 2 * (max_disp // stride) + 1
     f1, f2 = (jnp.asarray(rng.randn(*shape), dtype) for _ in range(2))
     g = jnp.asarray(rng.randn(*shape[:3], n * n), dtype)
-    got = _vjp(lambda a, b: correlation_pallas(a, b, max_disp, stride, tile_h,
-                                               True), f1, f2, g)
+    got = _vjp(lambda a, b: correlation_pallas(a, b, max_disp, stride, True),
+               f1, f2, g)
     want = _xla_vjp(f1, f2, g, max_disp, stride)
     for x, y in zip(got, want):
         assert x.dtype == jnp.dtype(dtype)
@@ -107,8 +112,8 @@ def test_pallas_corr_grad_books_each_displacement_to_its_own_offset(rng):
     shape, max_disp, stride = (1, 10, 12, 4), 8, 2
     f1, f2 = (jnp.asarray(rng.randn(*shape), jnp.float32) for _ in range(2))
     g = jnp.asarray(rng.randn(*shape[:3], 81), jnp.float32)
-    got = _vjp(lambda a, b: correlation_pallas(a, b, max_disp, stride, 4,
-                                               True), f1, f2, g)
+    got = _vjp(lambda a, b: correlation_pallas(a, b, max_disp, stride, True),
+               f1, f2, g)
     want = _xla_vjp(f1, f2, g, max_disp, stride)
     mirrored = _xla_vjp(f1, f2, g[..., ::-1], max_disp, stride)
     for x, y, m in zip(got, want, mirrored):
@@ -131,7 +136,7 @@ def test_pallas_corr_sharded_over_batch_mesh(feats):
     mesh = local_mesh()
     sharding = batch_sharding(mesh)
 
-    fn = jax.jit(lambda a, b: correlation_pallas(a, b, 2, 1, 4, True),
+    fn = jax.jit(lambda a, b: correlation_pallas(a, b, 2, 1, True),
                  in_shardings=(sharding, sharding))
     with mesh_context(mesh):  # read at trace time, as the step builders do
         got = fn(jax.device_put(jnp.asarray(f1), sharding),
@@ -154,7 +159,7 @@ def test_pallas_corr_grad_sharded_over_batch_mesh(feats, rng):
     mesh = local_mesh()
     sharding = batch_sharding(mesh)
     fn = jax.jit(lambda a, b, ct: _vjp(
-        lambda x, y: correlation_pallas(x, y, 2, 1, 4, True), a, b, ct),
+        lambda x, y: correlation_pallas(x, y, 2, 1, True), a, b, ct),
         in_shardings=(sharding,) * 3)
     with mesh_context(mesh):
         got = fn(*(jax.device_put(jnp.asarray(x), sharding)
@@ -169,7 +174,7 @@ def test_pallas_corr_bf16_inputs(feats):
     f1, f2 = feats
     got = correlation_pallas(
         jnp.asarray(f1, jnp.bfloat16), jnp.asarray(f2, jnp.bfloat16),
-        2, 1, 4, True)
+        2, 1, True)
     # f32 accumulation inside, but input dtype out (same as the XLA sweep,
     # so `auto` dispatch is not backend-dependent under bf16 compute)
     assert got.dtype == jnp.bfloat16

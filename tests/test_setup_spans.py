@@ -97,7 +97,7 @@ def _corr(grad):
     from deepof_tpu.ops.pallas.corr import correlation_pallas
 
     f1 = jnp.ones((1, 8, 8, 4))
-    f = lambda a, b: jnp.sum(correlation_pallas(a, b, 2, 1, 4, True))  # noqa: E731
+    f = lambda a, b: jnp.sum(correlation_pallas(a, b, 2, 1, True))  # noqa: E731
     return (jax.grad(f, argnums=(0, 1)) if grad else f), (f1, f1)
 
 

@@ -20,7 +20,9 @@ Sections:
            beside the XLA gather, at 160x224 (two lane tiles) and 80x112,
            batch 64: the measurement behind `PALLAS_AUTO_MAX_SWEEP`
   corr     the correlation's kernels at `flownet_c_chairs.train`'s shapes:
-           forward, backward, both, and the error of each direction
+           forward, backward, both, each kernel's TOP/s and roofline share,
+           the backward's block builders (strided roll / selects) by row
+           tile and product group, and the error of each direction
            against the XLA sweep
   batch    batch-size throughput curve (16/96)
   multiframe Sintel-shaped T=10 volume train step
@@ -471,49 +473,111 @@ def sec_headline() -> None:
                      for k, v in res.items()}, flush=True)
 
 
-def sec_corr() -> None:
+def _corr_place_by_select(n):
+    """The select-built alternative to `ops/pallas/corr.py::_place_block`
+    (the same block of P-bar, the same arguments): the window's n diagonals
+    turned to lanes 0..n-1 by one plain roll, then each put on its own
+    diagonal of the block's columns (from `base` on) by a select of its
+    lane broadcast over the row, n selects a block where the wired builder
+    has one strided roll."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental.pallas import tpu as pltpu
+
+    from deepof_tpu.ops.pallas.corr import _fold
+
+    def place(win, sel, lo, base):
+        f = _fold(win, sel)
+        rows, lanes = f.shape
+        f = pltpu.roll(f, (lanes - lo) % lanes, 1)  # diagonal j on lane j
+        d = (lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+             - lax.broadcasted_iota(jnp.int32, (rows, lanes), 0) - base)
+        out = jnp.zeros_like(f)
+        for j in range(n):
+            out = jnp.where(d == j, f[:, j:j + 1], out)
+        return out
+
+    return place
+
+
+def sec_corr(tiles=(8, 16), groups=(1, 2, 4)) -> None:
     """The correlation at `flownet_c_chairs.train`'s shapes (384x512 input
     -> conv3 features 48x64x256, 441 maps, batch 64, bfloat16): the
-    forward kernel alone and as the public route (with its pads and the
-    phase split of f2), the backward kernel (with its XLA transposes and
-    pads), and forward + backward as the step takes them; each whole
-    result behind `lax.optimization_barrier`, so XLA computes all of it.
-    Then both directions' error on one image against the XLA sweep (its
+    forward kernel alone and as the public route, the backward kernel
+    alone, and forward + backward as the step takes them; each whole
+    result behind `lax.optimization_barrier`, so XLA computes all of it,
+    each kernel's TOP/s and share of its roofline by the benchmark's own
+    counts (`benchmark/kernels/corr.py`, `corr_bwd.py`; peaks of
+    `benchmark/harness/peaks.py`). Then the backward's block builders: the
+    wired strided roll against n selects (`_corr_place_by_select`), each
+    at the row tiles and product groups given (the module's `_TILE_H` /
+    `_GROUP`, which the forward shares, set for the table only). Last,
+    both directions' error on one image against the XLA sweep (its
     autodiff for the backward) in float32 on the same bfloat16 values, as
     a share of the reference's largest element."""
+    from unittest import mock
+
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax import lax
 
+    from benchmark.harness.peaks import peaks_for
+    from benchmark.kernels import corr as count_fwd, corr_bwd as count_bwd
+    from benchmark.kernels.roofline import least_seconds
     from deepof_tpu.ops.corr import correlation
-    from deepof_tpu.ops.pallas.corr import _pallas_corr_bwd, _pallas_corr_fwd
+    from deepof_tpu.ops.pallas import corr as K
 
     shape = b, h, w, c = (64, 48, 64, 256)
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     f1, f2 = (jax.random.normal(k, shape, jnp.bfloat16) for k in keys[:2])
     g = jax.random.normal(keys[2], shape[:3] + (441,), jnp.bfloat16)
-    n_ops = 4 * b * h * w * 441 * c
+    peaks = peaks_for(jax.devices()[0].device_kind)
+    sizes = dict(b=b, h=h, w=w, c=c, max_disp=20, stride=2)
 
     def first(*outs):
         return sum(x.ravel()[0].astype(jnp.float32)
                    for x in lax.optimization_barrier(outs))
 
+    def rate(per, count):
+        least, bound = least_seconds(count, peaks)
+        print(f"  {count['ops'] / per / 1e12:.2f} TOP/s, "
+              f"{100 * least / per:.2f}% of its roofline ({bound}-bound, "
+              f"{least * 1e3:.4f} ms least)", flush=True)
+
+    def bwd_kernel():
+        return jax.jit(lambda a, b, ct: first(
+            *K._pallas_corr_bwd(a, b, ct, 20, 2, False)))
+
     per = timeit(f"corr fwd kernel {b}x{h}x{w}x{c}", jax.jit(
-        lambda a, b: first(_pallas_corr_fwd(a, b, 20, 2, False))), f1, f2)
-    print(f"  {n_ops / 2 / per / 1e12:.2f} TOP/s (2*b*h*w*n^2*c)", flush=True)
+        lambda a, b: first(K._pallas_corr_fwd(a, b, 20, 2, False))), f1, f2)
+    rate(per, count_fwd.forward(**sizes))
     timeit("corr fwd pallas (public route)", jax.jit(
         lambda a, b: first(correlation(a, b, impl="pallas"))), f1, f2)
-    per = timeit("corr bwd kernel", jax.jit(
-        lambda a, b, ct: first(*_pallas_corr_bwd(a, b, ct, 20, 2, 8, False))),
-        f1, f2, g)
-    print(f"  {n_ops / per / 1e12:.2f} TOP/s (4*b*h*w*n^2*c)", flush=True)
+    per = timeit("corr bwd kernel", bwd_kernel(), f1, f2, g)
+    rate(per, count_bwd.backward(**sizes))
 
     def both(a, b, ct):
         out, vjp = jax.vjp(lambda p, q: correlation(p, q, impl="pallas"), a, b)
         return first(out, *vjp(ct))
 
     timeit("corr fwd + bwd pallas", jax.jit(both), f1, f2, g)
+
+    builders = {"roll": K._place_block, "select": _corr_place_by_select(21)}
+    for name, place in builders.items():
+        for tile in tiles:
+            for group in groups:
+                with mock.patch.multiple(K, _TILE_H=tile, _GROUP=group,
+                                         _place_block=place):
+                    timeit(f"corr bwd {name:6s} tile {tile:2d} group {group}",
+                           bwd_kernel(), f1, f2, g)
+    # what the kernel costs with each window loaded and nothing placed
+    # (P-bar all zeros; no select, fold or roll): its products,
+    # accumulators, permutations and f2's layout
+    with mock.patch.object(K, "_place_block",
+                           lambda win, sel, lo, base: 0.0 * win[:, :-128]):
+        timeit(f"corr bwd none   tile {K._TILE_H:2d} group {K._GROUP}",
+               bwd_kernel(), f1, f2, g)
 
     a, b, ct = f1[:1], f2[:1], g[:1]
     ref = [x.astype(jnp.float32) for x in (a, b, ct)]
