@@ -870,9 +870,9 @@ class RecipeConfig:
 
 @dataclass(frozen=True)
 class LMConfig:
-    """A decoder-only language model of one of the TWO families `models/lm/`
-    writes, under the keys of the model's own public `config.json` and with
-    their meaning. `model_type` names the family:
+    """A decoder-only language model of one of the THREE families
+    `models/lm/` writes, under the keys of the model's own public
+    `config.json` and with their meaning. `model_type` names the family:
 
       - `deepseek_v3`: latent attention (`kv_lora_rank`, `qk_nope_head_dim`,
         `qk_rope_head_dim`, `v_head_dim`), sigmoid-routed experts with
@@ -882,7 +882,14 @@ class LMConfig:
         `head_dim`, per-head norms), softmax-routed experts with no shared
         one on every `decoder_sparse_step`-th layer not in
         `mlp_only_layers`, trained by diffusion over blocks of
-        `block_length` positions.
+        `block_length` positions;
+      - `nemotron_h`: one mixer a layer, picked by the first
+        `num_hidden_layers` characters of `hybrid_override_pattern` (`M` a
+        Mamba-2 state-space layer, `*` grouped-query attention with no
+        rotary positions and no per-head norm, `E` sigmoid-routed relu^2
+        experts with a shared one), trained by diffusion over blocks.
+        `n_groups` is the state-space layer's B/C groups; `n_group` the
+        router's group count.
 
     `config_file` names a JSON file of that shape: `fill_lm_from_file`
     copies every key of the file that is a field here (the file may hold
@@ -939,6 +946,25 @@ class LMConfig:
     decoder_sparse_step: int = 1
     mlp_only_layers: tuple = ()
     use_sliding_window: bool = False
+    # published by `nemotron_h` (the other families' files write none)
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 4
+    mamba_head_dim: int = 8
+    ssm_state_size: int = 16
+    n_groups: int = 2
+    conv_kernel: int = 4
+    chunk_size: int = 8
+    mamba_hidden_act: str = "silu"
+    mamba_proj_bias: bool = False
+    use_conv_bias: bool = True
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    time_step_limit: tuple = (0.0, None)  # (0, inf): nothing clamped
+    # "": the experts are SwiGLU of `hidden_act`; "relu2": W_down relu(W_up h)^2
+    mlp_hidden_act: str = ""
+    # the shared expert's width (0: n_shared_experts x moe_intermediate_size)
+    moe_shared_expert_intermediate_size: int = 0
     # --- the share held here ---
     n_routed_experts_published: int = 0
     first_expert: int = 0
@@ -975,9 +1001,14 @@ LM_FAMILY_FIXED: dict[str, dict] = {
                      n_shared_experts=0, first_k_dense_replace=0,
                      moe_layer_freq=1, routed_scaling_factor=1.0,
                      rope_interleave=False),
+    # the router: sigmoid scores, the k largest of score + its buffer
+    "nemotron_h": dict(scoring_func="sigmoid", topk_method="noaux_tc",
+                       first_k_dense_replace=0, moe_layer_freq=1,
+                       rope_interleave=False),
 }
 #: one field, two published names
-_LM_KEY_ALIASES = {"num_experts": "n_routed_experts"}
+_LM_KEY_ALIASES = {"num_experts": "n_routed_experts",
+                   "layer_norm_epsilon": "rms_norm_eps"}
 
 
 def lm_family_config(model_type: str, lm: LMConfig | None = None,
@@ -1102,7 +1133,7 @@ UCF101 = ExperimentConfig(
 )
 
 
-# A language model (`models/lm/`, two families: `lm.model_type`) on rows of
+# A language model (`models/lm/`, three families: `lm.model_type`) on rows of
 # `lm.seq_len` + 1 ids, by the family's own objective (next-token
 # cross-entropy, or diffusion over blocks). The sizes come from `--set lm.config_file=FILE`
 # (a JSON file of the public config.json's shape) or `--set lm.<key>=...`;

@@ -1,8 +1,11 @@
-"""The layers of the TWO language-model families `models/lm/` writes, by
+"""The layers of the THREE language-model families `models/lm/` writes, by
 their equations. Each family's are in one place: the attention layer's
-class (`MLA`: `model_type: deepseek_v3`; `GQA`: `model_type: sdar_moe`),
-`route` (both routers) and `MoE` (ONE expert layer, told by the config
-which scoring it uses and whether a shared expert exists).
+class (`MLA`: `model_type: deepseek_v3`; `GQA`: `model_type: sdar_moe`,
+and with `plain` the `nemotron_h` family's, q, k and v only cast), the
+state-space mixer `Mamba2` (`nemotron_h`), `route` (both routers) and
+`MoE` (ONE expert layer, told by the config which scoring it uses, which
+expert it runs (SwiGLU, or `mlp_hidden_act: relu2`'s W_down relu(W_up
+h)^2) and whether a shared expert exists).
 
 Precision: norm statistics, rotary angles, router scores, softmax and loss
 in float32; every other matrix product takes operands in the compute dtype
@@ -26,9 +29,16 @@ of it as it comes.
 Scopes (what the per-layer readers find in a profile; flax names a
 module's scope after the module, the rest are `jax.named_scope`s):
 `layer_<i>` > `mla` > `mla_proj`, `mla_scores`, `mla_out` or `gqa` >
-`gqa_proj`, `gqa_scores`, `gqa_out`; `dense_ffn`; `moe` > `moe_route`,
-`moe_dispatch`, `moe_experts`, `moe_shared`, `moe_combine`; beside them
-`embed`, `bd_noise`, `lm_head`, `loss_ce`, `optimizer`. On the chip
+`gqa_proj`, `gqa_scores`, `gqa_out` or `mamba` > `mamba_proj` (the input
+projection, the step sizes' softplus), `mamba_conv` (the depthwise
+convolution and its silu), `mamba_scan` (`ops/ssm.py`'s chunked scan and
+the skip D x), `mamba_out` (gate, grouped norm, output projection);
+`dense_ffn`; `moe` > `moe_route`, `moe_dispatch`, `moe_experts`,
+`moe_shared`, `moe_combine`; beside them `embed`, `bd_noise`, `lm_head`,
+`loss_ce`, `optimizer`. Counters, one value a layer riding the loss
+fetch: the expert layer's `moe_*`, and the state-space layer's
+`ssm_decay_mean`, the mean over positions and heads of exp(dt A): how
+far the state carries from one position to the next. On the chip
 `mla_scores` holds the Mosaic calls `mla_attn_fwd` and, under
 `transpose(jvp(...))`, `mla_attn_bwd`; `gqa_scores` the calls
 `bd_attn_fwd` and `bd_attn_bwd`; `mla_proj` and `gqa_proj` the calls
@@ -210,11 +220,13 @@ class GQA(nn.Module):
     position's index inside its copy of the row (`Mask.rope_positions`);
     query head n reads key/value head n // (heads / kv heads); scores
     scaled by 1/sqrt(head_dim), masked by `mask`'s rule, softmax over the
-    visible keys, times v, then `Wo`."""
+    visible keys, times v, then `Wo`. With `plain` (`model_type:
+    nemotron_h`) q, k and v are only cast: no norm, no rotary positions."""
 
     cfg: LMConfig
     dtype: Any = F32
     mask: Mask = CAUSAL
+    plain: bool = False
 
     @staticmethod
     def route_dims(c: LMConfig) -> tuple:
@@ -224,7 +236,7 @@ class GQA(nn.Module):
     @nn.compact
     def __call__(self, h):
         c, dt = self.cfg, self.dtype
-        if c.rope_interleave or c.use_sliding_window:
+        if c.use_sliding_window or (c.rope_interleave and not self.plain):
             raise NotImplementedError(
                 "models/lm: grouped-query attention is written with rotary "
                 "halves (rope_interleave false) and no sliding window "
@@ -238,14 +250,21 @@ class GQA(nn.Module):
         wk = self.param("wk", init, (d, g * hd), F32)
         wv = self.param("wv", init, (d, g * hd), F32)
         wo = self.param("wo", init, (nh * hd, d), F32)
-        prep = attention_route(s, c.attn_block_q, self.route_dims(c),
-                               self.mask)["prep"]
-        fused = prep["path"] == "fused"
+        route = attention_route(s, c.attn_block_q, self.route_dims(c),
+                                self.mask)
+        prep = route["prep"]
+        # the plain layer has no prep pass: its operands go head-major
+        # wherever the attention takes the fused kernels
+        fused = (route if self.plain else prep)["path"] == "fused"
         with jax.named_scope("gqa_proj"):
-            pos = self.mask.rope_positions(s)
-            if fused:
+            if self.plain:
+                q, k, v = (heads_dot(h, w.reshape(d, -1, hd), dt) if fused
+                           else dot(h, w, dt).reshape(b, s, -1, hd).astype(dt)
+                           for w in (wq, wk, wv))
+            elif fused:
                 from ...ops.pallas.qk_prep import qk_prep
 
+                pos = self.mask.rope_positions(s)
                 normed = functools.partial(
                     qk_prep, positions=pos, theta=c.rope_theta,
                     interleave=False, dtype=dt, block_s=prep["block_s"],
@@ -256,6 +275,7 @@ class GQA(nn.Module):
                            scale=HeadScale(hd, name="k_norm")())
                 v = heads_dot(h, wv.reshape(d, g, hd), dt)
             else:
+                pos = self.mask.rope_positions(s)
                 q = RMSNorm(c.rms_norm_eps, name="q_norm")(
                     dot(h, wq, dt).reshape(b, s, nh, hd))
                 k = RMSNorm(c.rms_norm_eps, name="k_norm")(
@@ -268,6 +288,102 @@ class GQA(nn.Module):
                                   dt, self.mask, head_major=fused)
         with jax.named_scope("gqa_out"):
             return dot(o.reshape(b, s, nh * hd), wo, dt)
+
+
+class Mamba2(nn.Module):
+    """The state-space mixer (`model_type: nemotron_h`), on the row h[b, s,
+    d]: `in_proj` gives z[H P], xBC[H P + 2 G N] and dt[H]; `xBC <-
+    silu(causal depthwise conv(xBC))` (kernel `conv_kernel`, a bias); x[H,
+    P], B[G, N], C[G, N]; `dt <- softplus(dt + dt_bias)`; `A = -exp(A_log)`;
+    the recurrence of `ops/ssm.py` per head, `y = h C + D x`; `y <-
+    RMSNorm_grouped(y silu(z))` over `n_groups` groups of channels with a
+    learned scale; `out_proj`. No projection bias. The row is the doubled
+    one of the `block_diffusion` mask, the one objective of the family: the
+    convolution and the scan follow `ops/ssm.py`'s rule for its noised half.
+
+    Returns (y[b, s, d] float32, {"ssm_decay_mean": scalar})."""
+
+    cfg: LMConfig
+    dtype: Any = F32
+    mask: Mask = CAUSAL
+
+    @nn.compact
+    def __call__(self, h):
+        from ...ops import ssm  # only a state-space family reaches it
+
+        c, dt = self.cfg, self.dtype
+        if c.mamba_proj_bias or not c.use_conv_bias \
+                or c.mamba_hidden_act != "silu" \
+                or tuple(c.time_step_limit) not in ((0, None), (0.0, None)) \
+                or self.mask.rule != "block_diffusion":
+            raise NotImplementedError(
+                "models/lm: the state-space layer is written with no "
+                "projection bias, a convolution bias, silu and no step "
+                "limit, on the doubled row of diffusion over blocks")
+        b, s, d = h.shape
+        H, P, N, G, K = (c.mamba_num_heads, c.mamba_head_dim, c.ssm_state_size,
+                         c.n_groups, c.conv_kernel)
+        inner, width = H * P, H * P + 2 * G * N
+        init = _init(c)
+        w_in = self.param("in_proj", init, (d, inner + width + H), F32)
+        conv_w = self.param("conv_w", _conv_init(K), (K, width), F32)
+        conv_b = self.param("conv_b", _conv_init(K), (width,), F32)
+        dt_bias = self.param("dt_bias", _dt_bias_init(c), (H,), F32)
+        a_log = self.param("A_log", _a_log_init, (H,), F32)
+        skip = self.param("D", nn.initializers.ones, (H,), F32)
+        w_out = self.param("out_proj", init, (inner, d), F32)
+        with jax.named_scope("mamba_proj"):
+            zxd = dot(h, w_in, dt)
+            z, xbc = zxd[..., :inner], zxd[..., inner:inner + width]
+            step = jax.nn.softplus(zxd[..., inner + width:] + dt_bias)
+        L = self.mask.half
+        with jax.named_scope("mamba_conv"):
+            xbc = jax.nn.silu(jnp.concatenate([
+                ssm.doubled_conv(xbc[:, :L], xbc[:, L:], conv_w, conv_b,
+                                 self.mask.block),
+                ssm.causal_conv(xbc[:, L:], conv_w, conv_b)], axis=1))
+        x = xbc[..., :inner].reshape(b, s, H, P)
+        bm = xbc[..., inner:inner + G * N].reshape(b, s, G, N)
+        cm = xbc[..., inner + G * N:].reshape(b, s, G, N)
+        A = -jnp.exp(a_log)
+        with jax.named_scope("mamba_scan"):
+            noised, clean = zip(*((a[:, :L], a[:, L:])
+                                  for a in (x, step, bm, cm)))
+            y = jnp.concatenate(ssm.doubled_scan(
+                *noised, *clean, A, c.chunk_size, self.mask.block, dt), axis=1)
+            y = y + skip[:, None] * x
+            decay = jnp.mean(jnp.exp(step * A))
+        with jax.named_scope("mamba_out"):
+            y = y.reshape(b, s, inner) * jax.nn.silu(z)
+            y = y.reshape(b, s, G, inner // G)
+            y = y * lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + c.rms_norm_eps)
+            scale = self.param("norm_scale", nn.initializers.ones, (inner,), F32)
+            return dot(y.reshape(b, s, inner) * scale, w_out, dt), \
+                {"ssm_decay_mean": decay}
+
+
+def _conv_init(kernel: int):
+    """The framework's default for a depthwise convolution's weight and
+    bias: uniform within 1 / sqrt(fan in), fan in = the kernel's width."""
+    bound = 1.0 / math.sqrt(kernel)
+    return lambda key, shape, dtype=F32: jax.random.uniform(
+        key, shape, dtype, -bound, bound)
+
+
+def _a_log_init(key, shape, dtype=F32):
+    """A = exp(A_log) uniform on [1, 16]."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(c: LMConfig):
+    """softplus^-1 of dt, dt log-uniform on [time_step_min, time_step_max],
+    floored at time_step_floor."""
+    def init(key, shape, dtype=F32):
+        lo, hi = math.log(c.time_step_min), math.log(c.time_step_max)
+        step = jnp.maximum(jnp.exp(jax.random.uniform(key, shape, dtype, lo, hi)),
+                           c.time_step_floor)
+        return step + jnp.log(-jnp.expm1(-step))
+    return init
 
 
 def swiglu(h, w_gate, w_up, w_down, dtype):
@@ -287,6 +403,22 @@ class SwiGLU(nn.Module):
                       self.param("w_up", init, (d, self.width), F32),
                       self.param("w_down", init, (self.width, d), F32),
                       self.dtype)
+
+
+class Relu2MLP(nn.Module):
+    """W_down relu(W_up h)^2: the expert of `mlp_hidden_act: relu2`."""
+
+    cfg: LMConfig
+    width: int
+    dtype: Any = F32
+
+    @nn.compact
+    def __call__(self, h):
+        d, init = h.shape[-1], _init(self.cfg)
+        a = jnp.square(jax.nn.relu(dot(
+            h, self.param("w_up", init, (d, self.width), F32), self.dtype)))
+        return dot(a, self.param("w_down", init, (self.width, d), F32),
+                   self.dtype)
 
 
 def route(h, router, bias, cfg: LMConfig):
@@ -364,9 +496,9 @@ def add_routed(shared, x, w, order, sizes, experts, rows: int, dtype):
     experts' slots first, in expert order). Exact whenever `sum(sizes) <=
     rows`: the rows left out are no held expert's and would be selected to
     zero before they were added. x[t, d], w[t, k], sizes[held],
-    experts = (w_gate, w_up, w_down)."""
+    experts = (w_gate, w_up, w_down) of SwiGLU experts or (w_up, w_down)
+    of relu^2 ones."""
     k = w.shape[-1]
-    w_gate, w_up, w_down = experts
     with jax.named_scope("moe_dispatch"):
         order = order[:rows]
         tok = order // k
@@ -382,8 +514,11 @@ def add_routed(shared, x, w, order, sizes, experts, rows: int, dtype):
     with jax.named_scope("moe_experts"):
         grouped = lambda a, m: jnp.where(held_row, ragged_dot(  # noqa: E731
             a, m.astype(dtype), sizes, preferred_element_type=F32), 0.0)
-        a = (jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)).astype(dtype)
-        o = grouped(a, w_down)
+        if len(experts) == 3:
+            a = jax.nn.silu(grouped(xs, experts[0])) * grouped(xs, experts[1])
+        else:
+            a = jnp.square(jax.nn.relu(grouped(xs, experts[0])))
+        o = grouped(a.astype(dtype), experts[-1])
     with jax.named_scope("moe_combine"):
         return shared.at[tok].add(o * ws[:, None])
 
@@ -468,9 +603,15 @@ class MoE(nn.Module):
         # the sigmoid router's buffer; the softmax router has none
         bias = self.param("bias", nn.initializers.normal(c.bias_std),
                           (width,), F32) if c.topk_method == "noaux_tc" else None
-        w_gate = self.param("experts_w_gate", init, (held, d, we), F32)
-        w_up = self.param("experts_w_up", init, (held, d, we), F32)
-        w_down = self.param("experts_w_down", init, (held, we, d), F32)
+        relu2 = c.mlp_hidden_act == "relu2"
+        if c.mlp_hidden_act not in ("", "relu2"):
+            raise NotImplementedError("models/lm: experts are SwiGLU or "
+                                      f"relu2, not {c.mlp_hidden_act!r}")
+        experts = tuple(
+            self.param("experts_" + n, init,
+                       (held, we, d) if n == "w_down" else (held, d, we), F32)
+            for n in (("w_up", "w_down") if relu2 else
+                      ("w_gate", "w_up", "w_down")))
         x = h.reshape(b * s, d)
         t = b * s
         with jax.named_scope("moe_route"):
@@ -484,9 +625,11 @@ class MoE(nn.Module):
         with jax.named_scope("moe_dispatch"):
             order = jnp.argsort(gid, stable=True)
         with jax.named_scope("moe_shared"):
-            shared = SwiGLU(c, c.n_shared_experts * we, dt, name="shared")(x) \
+            shared = (Relu2MLP if relu2 else SwiGLU)(
+                c, c.moe_shared_expert_intermediate_size
+                or c.n_shared_experts * we, dt, name="shared")(x) \
                 if c.n_shared_experts else jnp.zeros((t, d), F32)
-        args = (shared, x, w, order, sizes, (w_gate, w_up, w_down))
+        args = (shared, x, w, order, sizes, experts)
         cap = expert_row_cap(c, t)
         if cap < t * k:
             fits = jnp.sum(sizes) <= cap

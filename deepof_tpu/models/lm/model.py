@@ -1,6 +1,7 @@
-"""The two language-model families, one trunk (`MoELM`): embedding,
-`num_hidden_layers` blocks (the family's attention layer + a dense SwiGLU
-or the expert layer), a final RMSNorm and an untied head; the loss taken
+"""The language-model families, one trunk (`MoELM`): embedding,
+`num_hidden_layers` blocks (the family's `block`: here the attention layer
++ a dense SwiGLU or the expert layer; `hybrid.py`'s one mixer a layer),
+a final RMSNorm and an untied head; the loss taken
 in blocks of positions so that the logits of a whole batch never exist at
 once. What differs is declared on the family's class, and nothing outside
 `models/registry.py` asks for a family by name:
@@ -99,16 +100,24 @@ def cross_entropy_rows(h, kernel, targets, block: int, dtype, weights=None):
 
 
 class MoELM(nn.Module):
-    """The trunk both families share. A family's class declares
+    """The trunk the families share. A family's class declares
     `model_type`, `objective`, `attention` and, as methods, `mask(s)` (the
     rule for a row of `s` positions as the layers see it), `loss_positions`
-    (how many of them, from the first, bear logits) and `loss(tokens)`."""
+    (how many of them, from the first, bear logits) and `loss(tokens)`;
+    where it differs from these two, its `block` (called as `Block` is,
+    with `layer_kind(i)` in the place of `expert`) and the `counters` its
+    layers report."""
 
     cfg: LMConfig = LMConfig()
     dtype: Any = F32
     remat: bool = False  # recompute each block in the backward
 
     task = "lm"  # what `models/registry.py` and the trainer dispatch on
+    block = Block
+    counters = COUNTERS
+
+    def layer_kind(self, i: int):
+        return is_expert_layer(self.cfg, i)
 
     def layer_positions(self) -> int:
         """Positions the layers run over for a row of `lm.seq_len`."""
@@ -148,16 +157,14 @@ class MoELM(nn.Module):
         # a recomputed layer keeps what the fused attention names (its
         # output and logsumexp), so its forward kernel runs once
         block_cls = nn.remat(
-            Block, policy=jax.checkpoint_policies.save_only_these_names(
-                RESIDUALS)) if self.remat else Block
+            self.block, policy=jax.checkpoint_policies.save_only_these_names(
+                RESIDUALS)) if self.remat else self.block
         mask = self.mask(ids.shape[1])
         per_layer = []
         for i in range(c.num_hidden_layers):
-            expert = is_expert_layer(c, i)
-            x, counters = block_cls(c, expert, self.dtype, self.attention, mask,
-                                    name=f"layer_{i}")(x)
-            if expert:
-                per_layer.append(counters)
+            x, counters = block_cls(c, self.layer_kind(i), self.dtype,
+                                    self.attention, mask, name=f"layer_{i}")(x)
+            per_layer.append(counters)
         n = self.loss_positions(ids.shape[1])
         h = RMSNorm(c.rms_norm_eps, name="final_norm")(
             x if n == ids.shape[1] else x[:, :n])
@@ -167,9 +174,9 @@ class MoELM(nn.Module):
                 return dot(h, head, self.dtype)
         out = {"loss_rows": cross_entropy_rows(h, head, targets, c.loss_block,
                                                self.dtype, weights)}
-        for key in COUNTERS:
-            out[key] = jnp.stack([cn[key] for cn in per_layer]) if per_layer \
-                else jnp.zeros((0,), F32)
+        for key in self.counters:
+            got = [cn[key] for cn in per_layer if key in cn]
+            out[key] = jnp.stack(got) if got else jnp.zeros((0,), F32)
         return out
 
 

@@ -51,6 +51,12 @@ _OPTIONAL_KNOBS = (
 )
 
 
+#: language-model families imported only where a configuration names one
+#: (`lm.model_type`): their modules cost every other job nothing
+LAZY_LM_FAMILIES = {"nemotron_h": ("deepof_tpu.models.lm.hybrid",
+                                   "HybridBlockDiffusionLM")}
+
+
 def build_model(name: str, flow_channels: int = 2, dtype: Any = jnp.float32,
                 width_mult: float = 1.0, corr_max_disp: int = 20,
                 corr_stride: int = 2, **kw):
@@ -99,9 +105,16 @@ def model_for(cfg, dtype: Any = None):
         # (the `lm` preset names one; a config.json of the other wins)
         families = {m.model_type: m for m in MODELS.values()
                     if task_of(m) == "lm"}
+        if cfg.lm.model_type in LAZY_LM_FAMILIES:
+            import importlib
+
+            module, name = LAZY_LM_FAMILIES[cfg.lm.model_type]
+            families[cfg.lm.model_type] = getattr(
+                importlib.import_module(module), name)
         if cfg.lm.model_type not in families:
             raise KeyError(f"lm.model_type={cfg.lm.model_type!r} is no family "
-                           f"models/lm writes; available: {sorted(families)}")
+                           "models/lm writes; available: "
+                           f"{sorted([*families, *LAZY_LM_FAMILIES])}")
         return families[cfg.lm.model_type](cfg=cfg.lm, dtype=dtype,
                                            remat=cfg.train.remat)
     return build_model(cfg.model, flow_channels=2 * (cfg.data.time_step - 1),

@@ -1,6 +1,7 @@
 """The benchmark's language-model weights (`weights` in a configuration's
-file: one base draw moved by the seed, PERF.md section 4), for BOTH plain
-references at a toy size on the CPU: the law a leaf is drawn from stays
+file: one base draw moved by the seed, PERF.md section 4), for every
+language-model family's plain reference at a toy size on the CPU (the
+state-space layer's uniform leaves among them): the law a leaf is drawn from stays
 N(0, sigma^2), the seed moves every weight a little and a base key redraws
 it, and any leaf can be made again alone (what the runner's tap measures
 the parameters' change against). `benchmark/tests/test_lm_weights.py`
@@ -29,6 +30,13 @@ FAMILIES = {
     "sdar_30b_a3b_ep8": dict(
         COMMON, num_key_value_heads=2, head_dim=16, num_experts=2,
         block_length=4, mask_token_id=255, noise_t_lo=0.45, noise_t_hi=0.95),
+    "nemotron_twotower_30b_a3b_ep16": dict(
+        COMMON, hybrid_override_pattern="ME*", num_key_value_heads=2,
+        head_dim=16, n_routed_experts=2, n_shared_experts=1,
+        moe_shared_expert_intermediate_size=48, mamba_num_heads=4,
+        mamba_head_dim=8, ssm_state_size=16, n_groups=2, conv_kernel=4,
+        time_step_min=0.001, time_step_max=0.1, time_step_floor=1e-4,
+        bias_std=0.01),
 }
 STD = {"normal": "init_std", "embed": "embed_std", "bias": "bias_std"}
 
