@@ -77,5 +77,8 @@ class HybridBlockDiffusionLM(BlockDiffusionMoELM):
         k and v only cast), and the state-space scan's path."""
         out = super().routes()
         out["attention_route"]["prep"] = {"path": "cast"}
-        out["ssm"] = ssm.route(self.layer_positions(), self.cfg.chunk_size)
+        c = self.cfg
+        out["ssm"] = ssm.route(self.layer_positions(), c.chunk_size,
+                               c.block_length, c.ssm_state_size,
+                               c.mamba_num_heads // c.n_groups * c.mamba_head_dim)
         return out

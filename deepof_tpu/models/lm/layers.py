@@ -31,8 +31,8 @@ module's scope after the module, the rest are `jax.named_scope`s):
 `layer_<i>` > `mla` > `mla_proj`, `mla_scores`, `mla_out` or `gqa` >
 `gqa_proj`, `gqa_scores`, `gqa_out` or `mamba` > `mamba_proj` (the input
 projection, the step sizes' softplus), `mamba_conv` (the depthwise
-convolution and its silu), `mamba_scan` (`ops/ssm.py`'s chunked scan and
-the skip D x), `mamba_out` (gate, grouped norm, output projection);
+convolution and its silu), `mamba_scan` (`ops/ssm.py`'s scan and the skip
+D x), `mamba_out` (gate, grouped norm, output projection);
 `dense_ffn`; `moe` > `moe_route`, `moe_dispatch`, `moe_experts`,
 `moe_shared`, `moe_combine`; beside them `embed`, `bd_noise`, `lm_head`,
 `loss_ce`, `optimizer`. Counters, one value a layer riding the loss
@@ -43,7 +43,8 @@ far the state carries from one position to the next. On the chip
 `transpose(jvp(...))`, `mla_attn_bwd`; `gqa_scores` the calls
 `bd_attn_fwd` and `bd_attn_bwd`; `mla_proj` and `gqa_proj` the calls
 `qk_prep_fwd` and `qk_prep_bwd` (two a layer and direction) beside the
-projections' products.
+projections' products; `mamba_scan` the calls `ssd_fwd` (forward and
+recomputed) and `ssd_bwd` (transposed) of `ops/pallas/ssd.py`.
 """
 
 from __future__ import annotations
@@ -342,19 +343,23 @@ class Mamba2(nn.Module):
                 ssm.doubled_conv(xbc[:, :L], xbc[:, L:], conv_w, conv_b,
                                  self.mask.block),
                 ssm.causal_conv(xbc[:, L:], conv_w, conv_b)], axis=1))
-        x = xbc[..., :inner].reshape(b, s, H, P)
+        x = xbc[..., :inner]  # [b, s, H P]
         bm = xbc[..., inner:inner + G * N].reshape(b, s, G, N)
         cm = xbc[..., inner + G * N:].reshape(b, s, G, N)
         A = -jnp.exp(a_log)
         with jax.named_scope("mamba_scan"):
-            noised, clean = zip(*((a[:, :L], a[:, L:])
-                                  for a in (x, step, bm, cm)))
-            y = jnp.concatenate(ssm.doubled_scan(
-                *noised, *clean, A, c.chunk_size, self.mask.block, dt), axis=1)
-            y = y + skip[:, None] * x
+            # x takes its heads of P only at the scan's call, a copy at a
+            # time: the chip lays a whole [.., H, 64] array out positions
+            # minor, and the kernels' operands were copied out of it and back
+            noised, clean = ((x[:, h].reshape(b, L, H, P), step[:, h],
+                              bm[:, h], cm[:, h])
+                             for h in (slice(0, L), slice(L, s)))
+            y = jnp.concatenate([y.reshape(b, L, inner) for y in ssm.doubled_scan(
+                *noised, *clean, A, c.chunk_size, self.mask.block, dt)], axis=1)
+            y = y + jnp.repeat(skip, P) * x
             decay = jnp.mean(jnp.exp(step * A))
         with jax.named_scope("mamba_out"):
-            y = y.reshape(b, s, inner) * jax.nn.silu(z)
+            y = y * jax.nn.silu(z)
             y = y.reshape(b, s, G, inner // G)
             y = y * lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + c.rms_norm_eps)
             scale = self.param("norm_scale", nn.initializers.ones, (inner,), F32)

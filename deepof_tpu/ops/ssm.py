@@ -1,7 +1,12 @@
 """The state-space scan of the Mamba-2 layer (`models/lm/layers.py::Mamba2`),
 in its chunked form, on a row and on the doubled row of training by
 diffusion over blocks; and the layer's causal depthwise convolution under
-the same two rules. The same code on the CPU and the chip; no kernel.
+the same two rules. Two paths of `doubled_scan`, by `route`: on a TPU,
+where the chunk, the state and a B/C group's heads are whole 128-lane
+tiles, the Mosaic kernels of `ops/pallas/ssd.py` (`kernel`: the same
+terms, a chunk's matrices only in VMEM, the clean state carried from
+chunk to chunk); everywhere else, the CPU and every toy shape, the XLA
+chunked form below (`chunked`, the kernels' oracle in the tests).
 
 The recurrence, per head n (state P x N, head n reading group n // (H/G)):
 
@@ -44,7 +49,8 @@ and `highest`). Rows are padded to a whole number of chunks with dt = 0
 `jax.checkpoint` around each scan keeps only its chunk states (`STATES`)
 for the backward: the [chunks, heads, Q, Q] decay and score matrices are
 computed again there, not kept (a layer's recomputation under
-`train.remat` would otherwise hold several of 134 MB each).
+`train.remat` would otherwise hold several of 134 MB each). The kernels'
+custom VJP keeps the same states, under the same name.
 """
 
 from __future__ import annotations
@@ -58,13 +64,24 @@ F32 = jnp.float32
 #: `checkpoint_name` of what a scan keeps for its backward
 STATES = "ssm_chunk_states"
 _KEEP = jax.checkpoint_policies.save_only_these_names(STATES)
+#: lanes the kernel path's chunk, state and group of heads come in whole of
+KERNEL_TILE = 128
 
 
-def route(positions: int, chunk: int) -> dict:
-    """What the layer's scan does with a doubled row of `positions` (2L):
-    the step-0 `routes` record's `ssm` entry."""
-    return {"path": "chunked", "chunk": chunk, "rule": "block_diffusion",
-            "chunks": 2 * -(-(positions // 2) // chunk)}
+def route(positions: int, chunk: int, block: int, state: int,
+          group_width: int) -> dict:
+    """What the layer's scan does with a doubled row of `positions` (2L)
+    in chunks of `chunk` holding blocks of `block`, a state of `state` and
+    `group_width` = heads of a B/C group x head size: the step-0 `routes`
+    record's `ssm` entry, and the one rule `doubled_scan` follows.
+    `kernel` (`ops/pallas/ssd.py`) on a TPU where the chunk holds whole
+    blocks of a power of two and the chunk, the state and a group's heads
+    are whole 128-lane tiles; `chunked` (this module) elsewhere."""
+    kernel = (jax.default_backend() == "tpu" and chunk % block == 0
+              and block & (block - 1) == 0
+              and all(n % KERNEL_TILE == 0 for n in (chunk, state, group_width)))
+    return {"path": "kernel" if kernel else "chunked", "chunk": chunk,
+            "rule": "block_diffusion", "chunks": 2 * -(-(positions // 2) // chunk)}
 
 
 def _padded(a, q: int):
@@ -129,6 +146,17 @@ def _causal(x, dt, A, b, c, q: int, dtype):
     return y.reshape(nb, -1, nh, p)[:, :s], (xdt, run, bb, h0)
 
 
+def path_sums(run_c, run_n, block: int):
+    """P_t = L^clean_{bB-1} + L^noised_t - L^noised_{bB-1}: the running sums
+    of the path a noised position t of block b sees, from the running sums
+    of both copies inside each chunk, run[b, c, q, ...] (the position
+    before a chunk's first reads 0)."""
+    start = (jnp.arange(run_c.shape[2]) // block) * block
+    pad = [(0, 0), (0, 0), (1, 0)] + [(0, 0)] * (run_c.ndim - 3)
+    before = lambda r: jnp.pad(r, pad)[:, :, start]  # noqa: E731
+    return before(run_c) + run_n - before(run_n)
+
+
 def _doubled(xn, dtn, bn, cn, xc, dtc, bc, cc, A, q: int, block: int, dtype):
     L, (nb, _, nh, p) = xn.shape[1], xn.shape
     yc, (xdt_c, run_c, bb_c, h0_c) = _causal(xc, dtc, A, bc, cc, q, dtype)
@@ -136,11 +164,7 @@ def _doubled(xn, dtn, bn, cn, xc, dtc, bc, cc, A, q: int, block: int, dtype):
     xdt_n = _padded(xn.reshape(nb, L, g, -1, p) * _heads(dtn, g)[..., None], q)
     run_n = jnp.cumsum(_padded(_heads(dtn * A, g), q), axis=2)
     bb_n, cc_n = _padded(bn, q), _padded(cn, q)
-    # the running sums at the position before each position's block start
-    start = (jnp.arange(q) // block) * block
-    before = lambda r: jnp.pad(r, [(0, 0), (0, 0), (1, 0), (0, 0), (0, 0)])[  # noqa: E731
-        :, :, start]
-    path = before(run_c) + run_n - before(run_n)  # P_t [b,c,q,g,r]
+    path = path_sums(run_c, run_n, block)  # P_t [b,c,q,g,r]
     pt, rn, rc = (jnp.moveaxis(a, 2, -1) for a in (path, run_n, run_c))
     blk = jnp.arange(q) // block
     own_block = (blk[:, None] == blk[None, :]) & (
@@ -166,6 +190,12 @@ def doubled_scan(xn, dtn, bn, cn, xc, dtc, bc, cc, A, chunk: int, block: int,
     if chunk % block:
         raise ValueError(f"ssm: chunks of {chunk} do not hold whole blocks "
                          f"of {block}")
+    heads, groups = xn.shape[2], bn.shape[2]
+    if route(2 * xn.shape[1], chunk, block, bn.shape[3],
+             heads // groups * xn.shape[3])["path"] == "kernel":
+        from .pallas import ssd  # only a scan on the chip reaches it
+        return ssd.doubled_scan(xn, dtn, bn, cn, xc, dtc, bc, cc, A, chunk,
+                                block, dtype)
     return jax.checkpoint(
         lambda *a: _doubled(*a, chunk, block, dtype), policy=_KEEP)(
             xn, dtn, bn, cn, xc, dtc, bc, cc, A)

@@ -597,3 +597,33 @@ def test_language_model_step_fits_one_v5e(topo, monkeypatch):
                for ln in kernels) == 5, kernels
     assert len(kernels) == 10  # the layer's recomputation reran no forward
     assert "f32[2,32,512," not in text  # a block of scores in HBM
+
+
+@pytest.mark.parametrize("which", ["fwd", "vjp"])
+def test_state_space_scan_kernels_compile_for_v5e(one_chip, which):
+    """The state-space scan's kernels at the third language-model cell's
+    layer (one row of 4096 positions a copy, 64 heads of 64 in 8 groups, a
+    state of 128, chunks of 128 holding blocks of 4, bfloat16 products on
+    the float32 activations the layer hands over): the forward kernel
+    alone, and the custom VJP's pair under a gradient with respect to all
+    nine inputs, each called inside the layer's scope."""
+    from deepof_tpu.ops.pallas.ssd import doubled_scan
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    half = (of(1, 4096, 64, 64), of(1, 4096, 64), of(1, 4096, 8, 128),
+            of(1, 4096, 8, 128))
+    args = (*half, *half, of(64))
+
+    def scan(*a):
+        with jax.named_scope("mamba_scan"):  # as the layer calls it
+            return doubled_scan(*a, 128, 4, jnp.bfloat16)
+
+    if which == "fwd":
+        text = _compiled_text(scan, *args, kernels=["ssd_fwd"])
+    else:
+        text = _compiled_text(jax.grad(lambda *a: sum(
+            jnp.sum(y * y) for y in scan(*a)), argnums=range(9)), *args,
+            kernels=["ssd_fwd", "ssd_bwd"])
+    assert "mamba_scan" in text  # the scope is in op_name

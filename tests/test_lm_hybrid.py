@@ -18,6 +18,7 @@ and accumulates in float32: 3e-4 on the loss, 5e-2 on a gradient.
 """
 
 import dataclasses
+import functools
 import importlib
 import json
 import os
@@ -104,6 +105,28 @@ def reference_scan(rule, noised, clean, A):
     return jax.vmap(lambda *a: ref.scan_doubled(*a, A, COPY, BLOCK))(*both)
 
 
+def on_both_routes(cases):
+    """`parametrize` arguments: each case on the scan's two routes, the
+    chunked form under the case's own id, the chip's kernels
+    (`ops/pallas/ssd.py`, interpret mode, steered in where
+    `ssm.doubled_scan` stands) under `<id>-kernel`."""
+    routes = ("chunked", "kernel")
+    return {"argvalues": [(c, r) for r in routes for c in cases],
+            "ids": [f"{c}{'' if r == 'chunked' else '-kernel'}"
+                    for r in routes for c in cases],
+            "indirect": ["route"]}
+
+
+@pytest.fixture
+def route(request, monkeypatch):
+    if request.param == "kernel":
+        from deepof_tpu.ops.pallas import ssd
+
+        monkeypatch.setattr(ssm, "doubled_scan", functools.partial(
+            ssd.doubled_scan, interpret=True))
+    return request.param
+
+
 def program_scan(rule, noised, clean, A):
     if rule == "causal":  # the clean half: its own recurrence from zero
         return ssm.doubled_scan(*noised, *clean, A, CHUNK, BLOCK)[1]
@@ -111,11 +134,11 @@ def program_scan(rule, noised, clean, A):
         ssm.doubled_scan(*noised, *clean, A, CHUNK, BLOCK), axis=1)
 
 
-@pytest.mark.parametrize("rule", ["causal", "doubled"])
-def test_chunked_scan_is_the_per_position_recurrence(rule):
+@pytest.mark.parametrize("rule,route", **on_both_routes(["causal", "doubled"]))
+def test_chunked_scan_is_the_per_position_recurrence(rule, route):
     """Values and the gradients of every input: the chunked form (chunks
-    of 8, rows not a whole number of them) against the reference's
-    recurrence, on a row and on the doubled row."""
+    of 8, rows not a whole number of them) and the kernels against the
+    reference's recurrence, on a row and on the doubled row."""
     noised, clean, A = scan_inputs()
     probe = jax.random.normal(jax.random.PRNGKey(9),
                               (2, COPY * (1 if rule == "causal" else 2), H, P))
@@ -130,13 +153,14 @@ def test_chunked_scan_is_the_per_position_recurrence(rule):
         assert rel(g, w) < 1e-4
 
 
-@pytest.mark.parametrize("fault", [None, *sorted(faults.FAULTS)])
-def test_one_noised_block_starts_from_the_clean_state_at_its_start(fault):
+@pytest.mark.parametrize("fault,route",
+                         **on_both_routes([None, *sorted(faults.FAULTS)]))
+def test_one_noised_block_starts_from_the_clean_state_at_its_start(fault, route):
     """Block 2 (positions 8..11) by hand: the recurrence from the clean
     copy's state at position 7 over the block's noised positions is the
     noised half's output there; under each planted fault (the scan's, or
     the convolution's that feeds it) the output there is another, and the
-    clean half's is its own."""
+    clean half's is its own. On either route of the scan."""
     noised, clean, A = scan_inputs()
     first = 2 * BLOCK
     block = slice(first, first + BLOCK)
@@ -259,6 +283,46 @@ def test_the_steps_scan_is_the_chunked_form_with_no_scan_over_positions(weights)
                                      "rule": "block_diffusion", "chunks": 6}
 
 
+#: (doubled row, chunk, block, state, a B/C group's heads x head size)
+ROUTE_SHAPES = {"cell": (8192, 128, 4, 128, 512), "toy": (64, 12, 4, 16, 16),
+                "blocks_of_3": (8192, 384, 3, 128, 512),
+                "state_of_64": (8192, 128, 4, 64, 512),
+                "group_of_192": (8192, 128, 4, 128, 192),
+                "chunk_of_64": (8192, 64, 4, 128, 512)}
+
+
+@pytest.mark.parametrize("backend,shape,path", [
+    ("tpu", "cell", "kernel"), ("cpu", "cell", "chunked"),
+    ("tpu", "toy", "chunked"), ("tpu", "blocks_of_3", "chunked"),
+    ("tpu", "state_of_64", "chunked"), ("tpu", "group_of_192", "chunked"),
+    ("tpu", "chunk_of_64", "chunked")])
+def test_route_takes_the_kernels_on_a_tpu_at_whole_tiles(monkeypatch, backend,
+                                                         shape, path):
+    """The kernels where the backend is a TPU, the chunk holds whole
+    blocks of a power of two, and the chunk, the state and a group's heads
+    are whole 128-lane tiles; the chunked form anywhere else. The backend
+    is steered, never read from the host."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    got = ssm.route(*ROUTE_SHAPES[shape])
+    assert got["path"] == path
+    assert got["rule"] == "block_diffusion"
+
+
+@pytest.mark.parametrize("backend,path", [("tpu", "kernel"), ("cpu", "chunked")])
+def test_the_cells_routes_record_names_the_kernels_on_a_tpu(monkeypatch,
+                                                            backend, path):
+    """The step-0 `routes` record of the benchmark cell's configuration
+    (rows of 4096 doubled, `lm.attn_block_q` 512 as the cell sets it)."""
+    from deepof_tpu.core.config import LMConfig, fill_lm_from_file
+    from deepof_tpu.models.lm.hybrid import HybridBlockDiffusionLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    lm = dataclasses.replace(fill_lm_from_file(LMConfig(), NEMOTRON),
+                             seq_len=4096, attn_block_q=512)
+    assert HybridBlockDiffusionLM(lm).routes()["ssm"] == {
+        "path": path, "chunk": 128, "rule": "block_diffusion", "chunks": 64}
+
+
 # ------------------------------------------------------ the normal path
 
 
@@ -303,10 +367,13 @@ def test_family_trains_through_trainer_fit_from_its_config_file(tmp_path):
 
 def test_the_family_is_imported_only_where_a_configuration_names_it():
     """What the accepted cells import does not grow: the trainer's modules
-    import neither the family nor its scan."""
-    code = ("import sys, deepof_tpu.train.loop; "
+    and the other families' models import neither the family, nor its
+    scan, nor the scan's kernels."""
+    code = ("import sys, deepof_tpu.train.loop, deepof_tpu.models.registry, "
+            "deepof_tpu.models.lm.model; "
             "print([m for m in ('deepof_tpu.models.lm.hybrid', "
-            "'deepof_tpu.ops.ssm') if m in sys.modules])")
+            "'deepof_tpu.ops.ssm', 'deepof_tpu.ops.pallas.ssd') "
+            "if m in sys.modules])")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, cwd=ROOT, env={**os.environ,
                                                    "JAX_PLATFORMS": "cpu"})
