@@ -870,7 +870,7 @@ class RecipeConfig:
 
 @dataclass(frozen=True)
 class LMConfig:
-    """A decoder-only language model of one of the THREE families
+    """A decoder-only language model of one of the FOUR families
     `models/lm/` writes, under the keys of the model's own public
     `config.json` and with their meaning. `model_type` names the family:
 
@@ -890,6 +890,13 @@ class LMConfig:
         experts with a shared one), trained by diffusion over blocks.
         `n_groups` is the state-space layer's B/C groups; `n_group` the
         router's group count.
+      - `afmoe`: gated grouped-query attention with per-head norms, each
+        layer a window of `sliding_window` keys with rotary positions or
+        a full one with none, as `layer_types` says; sandwich norms;
+        after `num_dense_layers` dense layers sigmoid-routed experts with
+        `num_shared_experts` shared ones; next-token loss. Its router keys
+        (`score_func`, `route_norm`, `route_scale`) are read as the
+        others' (`scoring_func`, `norm_topk_prob`, `routed_scaling_factor`).
 
     `config_file` names a JSON file of that shape: `fill_lm_from_file`
     copies every key of the file that is a field here (the file may hold
@@ -946,6 +953,12 @@ class LMConfig:
     decoder_sparse_step: int = 1
     mlp_only_layers: tuple = ()
     use_sliding_window: bool = False
+    # published by `afmoe` (read by no other family): each layer's
+    # "sliding_attention" or "full_attention", the window's keys, the
+    # embedding times sqrt(hidden_size)
+    layer_types: tuple = ()
+    sliding_window: int | None = None
+    mup_enabled: bool = False
     # published by `nemotron_h` (the other families' files write none)
     hybrid_override_pattern: str = ""
     mamba_num_heads: int = 4
@@ -968,6 +981,9 @@ class LMConfig:
     # --- the share held here ---
     n_routed_experts_published: int = 0
     first_expert: int = 0
+    # the published layer each layer is, where a cut keeps some and not
+    # the first ones (empty: layer i is published layer i)
+    published_layers: tuple = ()
     # --- not in config.json: the job's own sizes ---
     seq_len: int = 32  # positions a row trains on (a row holds seq_len + 1 ids)
     init_std: float = 0.02  # normal initialisation of every matrix
@@ -1005,10 +1021,19 @@ LM_FAMILY_FIXED: dict[str, dict] = {
     "nemotron_h": dict(scoring_func="sigmoid", topk_method="noaux_tc",
                        first_k_dense_replace=0, moe_layer_freq=1,
                        rope_interleave=False),
+    # the router: sigmoid scores (`score_func`), the k largest of score +
+    # its buffer; rotary channel j against j + head_dim / 2
+    "afmoe": dict(topk_method="noaux_tc", moe_layer_freq=1,
+                  rope_interleave=False),
 }
 #: one field, two published names
 _LM_KEY_ALIASES = {"num_experts": "n_routed_experts",
-                   "layer_norm_epsilon": "rms_norm_eps"}
+                   "layer_norm_epsilon": "rms_norm_eps",
+                   "num_dense_layers": "first_k_dense_replace",
+                   "num_shared_experts": "n_shared_experts",
+                   "score_func": "scoring_func",
+                   "route_norm": "norm_topk_prob",
+                   "route_scale": "routed_scaling_factor"}
 
 
 def lm_family_config(model_type: str, lm: LMConfig | None = None,
@@ -1133,7 +1158,7 @@ UCF101 = ExperimentConfig(
 )
 
 
-# A language model (`models/lm/`, three families: `lm.model_type`) on rows of
+# A language model (`models/lm/`, four families: `lm.model_type`) on rows of
 # `lm.seq_len` + 1 ids, by the family's own objective (next-token
 # cross-entropy, or diffusion over blocks). The sizes come from `--set lm.config_file=FILE`
 # (a JSON file of the public config.json's shape) or `--set lm.<key>=...`;

@@ -1,6 +1,6 @@
-"""Decoder-only language models of two families on one trunk: latent
-attention or grouped-query attention, SwiGLU, and a sparse expert layer
-that is told which experts it holds; next-token loss or diffusion over
-blocks."""
+"""Decoder-only language models of four families on one trunk: latent,
+grouped-query or gated window/full attention (or state-space mixers),
+SwiGLU, and a sparse expert layer that is told which experts it holds;
+next-token loss or diffusion over blocks."""
 
-from .model import BlockDiffusionMoELM, LatentMoELM  # noqa: F401
+from .model import BlockDiffusionMoELM, LatentMoELM, WindowedMoELM  # noqa: F401

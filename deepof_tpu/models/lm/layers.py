@@ -1,7 +1,8 @@
-"""The layers of the THREE language-model families `models/lm/` writes, by
+"""The layers of the FOUR language-model families `models/lm/` writes, by
 their equations. Each family's are in one place: the attention layer's
 class (`MLA`: `model_type: deepseek_v3`; `GQA`: `model_type: sdar_moe`,
-and with `plain` the `nemotron_h` family's, q, k and v only cast), the
+with `plain` the `nemotron_h` family's, q, k and v only cast, and with
+`gated` the `afmoe` family's, rotated on its window layers only), the
 state-space mixer `Mamba2` (`nemotron_h`), `route` (both routers) and
 `MoE` (ONE expert layer, told by the config which scoring it uses, which
 expert it runs (SwiGLU, or `mlp_hidden_act: relu2`'s W_down relu(W_up
@@ -29,7 +30,8 @@ of it as it comes.
 Scopes (what the per-layer readers find in a profile; flax names a
 module's scope after the module, the rest are `jax.named_scope`s):
 `layer_<i>` > `mla` > `mla_proj`, `mla_scores`, `mla_out` or `gqa` >
-`gqa_proj`, `gqa_scores`, `gqa_out` or `mamba` > `mamba_proj` (the input
+`gqa_proj`, `gqa_scores`, (`gqa_gate`,) `gqa_out` or `swa` > `swa_proj`,
+`swa_scores`, `swa_gate`, `swa_out` or `mamba` > `mamba_proj` (the input
 projection, the step sizes' softplus), `mamba_conv` (the depthwise
 convolution and its silu), `mamba_scan` (`ops/ssm.py`'s scan and the skip
 D x), `mamba_out` (gate, grouped norm, output projection);
@@ -41,7 +43,8 @@ fetch: the expert layer's `moe_*`, and the state-space layer's
 far the state carries from one position to the next. On the chip
 `mla_scores` holds the Mosaic calls `mla_attn_fwd` and, under
 `transpose(jvp(...))`, `mla_attn_bwd`; `gqa_scores` the calls
-`bd_attn_fwd` and `bd_attn_bwd`; `mla_proj` and `gqa_proj` the calls
+`bd_attn_fwd` and `bd_attn_bwd`, `swa_scores` `swa_attn_fwd` and
+`swa_attn_bwd`; `mla_proj`, `gqa_proj` and `swa_proj` the calls
 `qk_prep_fwd` and `qk_prep_bwd` (two a layer and direction) beside the
 projections' products; `mamba_scan` the calls `ssd_fwd` (forward and
 recomputed) and `ssd_bwd` (transposed) of `ops/pallas/ssd.py`.
@@ -222,12 +225,18 @@ class GQA(nn.Module):
     query head n reads key/value head n // (heads / kv heads); scores
     scaled by 1/sqrt(head_dim), masked by `mask`'s rule, softmax over the
     visible keys, times v, then `Wo`. With `plain` (`model_type:
-    nemotron_h`) q, k and v are only cast: no norm, no rotary positions."""
+    nemotron_h`) q, k and v are only cast: no norm, no rotary positions.
+    With `gated` (`model_type: afmoe`) the output is gated before `Wo`, `o
+    <- o * sigmoid(h Wg)` per head and channel; without `rotary` (that
+    family's full layers) q and k are normed and not rotated. The scopes
+    take the module's name: `<name>_proj`, `_scores`, `_gate`, `_out`."""
 
     cfg: LMConfig
     dtype: Any = F32
     mask: Mask = CAUSAL
     plain: bool = False
+    gated: bool = False
+    rotary: bool = True
 
     @staticmethod
     def route_dims(c: LMConfig) -> tuple:
@@ -257,7 +266,7 @@ class GQA(nn.Module):
         # the plain layer has no prep pass: its operands go head-major
         # wherever the attention takes the fused kernels
         fused = (route if self.plain else prep)["path"] == "fused"
-        with jax.named_scope("gqa_proj"):
+        with jax.named_scope(f"{self.name}_proj"):
             if self.plain:
                 q, k, v = (heads_dot(h, w.reshape(d, -1, hd), dt) if fused
                            else dot(h, w, dt).reshape(b, s, -1, hd).astype(dt)
@@ -265,7 +274,10 @@ class GQA(nn.Module):
             elif fused:
                 from ...ops.pallas.qk_prep import qk_prep
 
-                pos = self.mask.rope_positions(s)
+                # unrotated: the pass's angles at position 0, cos 1 and
+                # sin 0, exact
+                pos = self.mask.rope_positions(s) if self.rotary else \
+                    jnp.zeros((s,), jnp.int32)
                 normed = functools.partial(
                     qk_prep, positions=pos, theta=c.rope_theta,
                     interleave=False, dtype=dt, block_s=prep["block_s"],
@@ -281,13 +293,19 @@ class GQA(nn.Module):
                     dot(h, wq, dt).reshape(b, s, nh, hd))
                 k = RMSNorm(c.rms_norm_eps, name="k_norm")(
                     dot(h, wk, dt).reshape(b, s, g, hd))
-                q = rope_halves(q, c.rope_theta, pos).astype(dt)
-                k = rope_halves(k, c.rope_theta, pos).astype(dt)
+                q = (rope_halves(q, c.rope_theta, pos) if self.rotary
+                     else q).astype(dt)
+                k = (rope_halves(k, c.rope_theta, pos) if self.rotary
+                     else k).astype(dt)
                 v = dot(h, wv, dt).reshape(b, s, g, hd).astype(dt)
-        with jax.named_scope("gqa_scores"):
+        with jax.named_scope(f"{self.name}_scores"):
             o = grouped_attention(q, k, v, 1.0 / math.sqrt(hd), c.attn_block_q,
                                   dt, self.mask, head_major=fused)
-        with jax.named_scope("gqa_out"):
+        if self.gated:
+            wg = self.param("wg", init, (d, nh * hd), F32)
+            with jax.named_scope(f"{self.name}_gate"):
+                o = o.reshape(b, s, nh * hd) * jax.nn.sigmoid(dot(h, wg, dt))
+        with jax.named_scope(f"{self.name}_out"):
             return dot(o.reshape(b, s, nh * hd), wo, dt)
 
 

@@ -22,6 +22,9 @@ once. What differs is declared on the family's class, and nothing outside
     against its own id (no shift), and the row's loss is
     `(1/L) sum_p m_p (1/t_b(p)) (-log softmax(z_p)[x0_p])`. A masked
     position is one with `m_p = 1`, not one whose id is the mask's.
+  - `WindowedMoELM` (`model_type: afmoe`): objective `next_token`; each
+    layer's attention and mask its own (`layer_fields`): three window
+    layers to one full one, sandwich norms (`Block`'s `post_norms`).
 """
 
 from __future__ import annotations
@@ -50,27 +53,42 @@ def is_expert_layer(cfg: LMConfig, i: int) -> bool:
 
 
 class Block(nn.Module):
+    """`x <- x + A(RMSNorm(x))`, `x <- x + F(RMSNorm(x))`, F the dense
+    SwiGLU or the expert layer; with `post_norms` (sandwich norms) each
+    branch's output is normed again before it is added. A is
+    `attention(cfg, dtype, mask, **attention_kw)`, named after its class
+    where `attention_kw` gives no `name`."""
+
     cfg: LMConfig
     expert: bool
     dtype: Any = F32
-    attention: Any = MLA  # the family's attention layer
+    attention: Any = MLA  # the layer's attention class
     mask: Mask = CAUSAL
+    attention_kw: tuple = ()  # (key, value) pairs: the attention's settings
+    post_norms: bool = False
 
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        # flax scopes a module's call by its name: `layer_<i>`, `mla` or
-        # `gqa`, `moe`
-        x = x + self.attention(c, self.dtype, self.mask,
-                               name=self.attention.__name__.lower())(
+        # flax scopes a module's call by its name: `layer_<i>`, `mla`,
+        # `gqa` or `swa`, `moe`
+        kw = {"name": self.attention.__name__.lower(), **dict(self.attention_kw)}
+        y = self.attention(c, self.dtype, self.mask, **kw)(
             RMSNorm(c.rms_norm_eps, name="attn_norm")(x))
+        if self.post_norms:
+            y = RMSNorm(c.rms_norm_eps, name="attn_post_norm")(y)
+        x = x + y
         h = RMSNorm(c.rms_norm_eps, name="ffn_norm")(x)
         if not self.expert:
             with jax.named_scope("dense_ffn"):
-                return x + SwiGLU(c, c.intermediate_size, self.dtype,
-                                  name="ffn")(h), {}
+                return x + self.post_ffn(SwiGLU(c, c.intermediate_size,
+                                                self.dtype, name="ffn")(h)), {}
         y, counters = MoE(c, self.dtype, name="moe")(h)
-        return x + y, counters
+        return x + self.post_ffn(y), counters
+
+    def post_ffn(self, y):
+        return RMSNorm(self.cfg.rms_norm_eps, name="ffn_post_norm")(y) \
+            if self.post_norms else y
 
 
 def cross_entropy_rows(h, kernel, targets, block: int, dtype, weights=None):
@@ -119,6 +137,12 @@ class MoELM(nn.Module):
     def layer_kind(self, i: int):
         return is_expert_layer(self.cfg, i)
 
+    def layer_fields(self, i: int, mask: Mask) -> dict:
+        """Layer i's fields of `block` past cfg, kind and dtype, `mask` the
+        family's rule for the row: the family's one attention under it on
+        every layer."""
+        return {"attention": self.attention, "mask": mask}
+
     def layer_positions(self) -> int:
         """Positions the layers run over for a row of `lm.seq_len`."""
         return self.cfg.seq_len
@@ -154,6 +178,8 @@ class MoELM(nn.Module):
                          (c.vocab_size, c.hidden_size), F32)
         with jax.named_scope("embed"):
             x = emb[ids]
+            if c.mup_enabled:  # the row of the embedding times sqrt(d)
+                x = x * jnp.float32(c.hidden_size ** 0.5)
         # a recomputed layer keeps what the fused attention names (its
         # output and logsumexp), so its forward kernel runs once
         block_cls = nn.remat(
@@ -163,7 +189,8 @@ class MoELM(nn.Module):
         per_layer = []
         for i in range(c.num_hidden_layers):
             x, counters = block_cls(c, self.layer_kind(i), self.dtype,
-                                    self.attention, mask, name=f"layer_{i}")(x)
+                                    name=f"layer_{i}",
+                                    **self.layer_fields(i, mask))(x)
             per_layer.append(counters)
         n = self.loss_positions(ids.shape[1])
         h = RMSNorm(c.rms_norm_eps, name="final_norm")(
@@ -246,4 +273,58 @@ class BlockDiffusionMoELM(MoELM):
         # loss-bearing positions over L, one value a row's batch: rides the
         # loss fetch beside the expert layers' counters
         out["bd_masked_share"] = jnp.mean(m.astype(F32))[None]
+        return out
+
+
+#: `layer_types`' names of a window layer and of a full one
+LAYER_TYPES = {"sliding_attention": True, "full_attention": False}
+
+
+class WindowedMoELM(LatentMoELM):
+    """`model_type: afmoe`, objective `next_token`: grouped-query attention
+    with per-head norms and an output gate (`GQA` with `gated`), on each
+    layer as `layer_types` says either under the sliding window of
+    `sliding_window` keys with rotary positions (flax name `swa`) or over
+    all earlier keys with no positional encoding (`rotary` off, `gqa`);
+    sandwich norms (`post_norms`); the embedding times sqrt(hidden)
+    (`mup_enabled`); a dense SwiGLU on the first `num_dense_layers` layers,
+    then sigmoid-routed experts with a shared one. A cut configuration says
+    by `published_layers` which published layer each of its layers is; its
+    type and whether it is dense are that layer's."""
+
+    model_type = "afmoe"
+    attention = GQA
+
+    def published(self, i: int) -> int:
+        return self.cfg.published_layers[i] if self.cfg.published_layers else i
+
+    def layer_kind(self, i: int) -> bool:
+        return is_expert_layer(self.cfg, self.published(i))
+
+    def layer_fields(self, i: int, mask: Mask) -> dict:
+        c = self.cfg
+        kind = c.layer_types[self.published(i)] \
+            if self.published(i) < len(c.layer_types) else None
+        if kind not in LAYER_TYPES or not c.sliding_window:
+            raise ValueError(f"lm: layer {i} (published {self.published(i)}) "
+                             f"has no type of {sorted(LAYER_TYPES)} in "
+                             "layer_types, or sliding_window is not set")
+        window = LAYER_TYPES[kind]
+        return {"attention": GQA,
+                "mask": Mask("window", window=c.sliding_window) if window else mask,
+                "attention_kw": (("name", "swa" if window else "gqa"),
+                                 ("gated", True), ("rotary", window)),
+                "post_norms": True}
+
+    def routes(self) -> dict:
+        """`MoELM.routes` with the attention's route a layer, and whether
+        the layer rotates its q and k."""
+        c, s = self.cfg, self.layer_positions()
+        out = super().routes()
+        out["attention_route"] = [
+            {**attention_route(s, c.attn_block_q, f["attention"].route_dims(c),
+                               f["mask"]),
+             "rotary": dict(f["attention_kw"])["rotary"]}
+            for f in (self.layer_fields(i, self.mask(s))
+                      for i in range(c.num_hidden_layers))]
         return out

@@ -22,7 +22,7 @@ from .vgg16_flow import VGG16Flow
 from .inception_v3_flow import InceptionV3Flow
 from .flownet_c import FlowNetC
 from .flownet2 import FlowNetCS
-from .lm import BlockDiffusionMoELM, LatentMoELM
+from .lm import BlockDiffusionMoELM, LatentMoELM, WindowedMoELM
 from .two_stream import STBaseline, STSingle, UCF101Spatial
 
 MODELS = {
@@ -36,6 +36,7 @@ MODELS = {
     "ucf101_spatial": UCF101Spatial,
     "latent_moe_lm": LatentMoELM,
     "block_diffusion_moe_lm": BlockDiffusionMoELM,
+    "windowed_moe_lm": WindowedMoELM,
 }
 
 

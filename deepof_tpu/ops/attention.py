@@ -19,7 +19,9 @@ a noised copy [0, L) beside the clean one [L, 2L), in blocks of B
 positions, b(p) = p // B inside a copy; a noised query sees the noised
 keys of its own block and the clean keys of EARLIER blocks; a clean query
 sees the clean keys of its own and earlier blocks; no query sees a noised
-key of another block. Every query sees itself, so no row is empty.
+key of another block. `window` (sliding-window layers, W = `window`): key
+k <= query q and q - k < W, the W latest keys. Every query sees itself,
+so no row is empty.
 
   - `fused` (`ops/pallas/attention.py`): on a TPU, where the kernel's
     blocks divide the row. Queries and keys both blocked, a running max
@@ -91,16 +93,19 @@ class Mask:
     layers run over: for `block_diffusion` the doubled row of 2 * `half`.
     Hashable: a static argument of the layers and of the kernels."""
 
-    rule: str = "causal"  # | "block_diffusion"
+    rule: str = "causal"  # | "block_diffusion" | "window"
     block: int = 0  # B: positions a block
     half: int = 0  # L: positions a copy of the row
+    window: int = 0  # W: keys a query of a window layer sees
 
     def __post_init__(self):
-        if self.rule not in ("causal", "block_diffusion"):
+        if self.rule not in ("causal", "block_diffusion", "window"):
             raise ValueError(f"attention: no mask rule {self.rule!r}")
         if self.rule == "block_diffusion" and (self.block < 1 or self.half < 1):
             raise ValueError("attention: block_diffusion needs block >= 1 "
                              f"and half >= 1, got {self.block}, {self.half}")
+        if self.rule == "window" and self.window < 1:
+            raise ValueError(f"attention: window needs window >= 1, got {self.window}")
 
     def _block_of(self, p):
         """Block index of in-copy positions `p` (a shift where B is a power
@@ -112,6 +117,8 @@ class Mask:
         """bool, broadcast over integer position arrays q and k."""
         if self.rule == "causal":
             return q >= k
+        if self.rule == "window":
+            return (q >= k) & (q - k < self.window)
         qc, kc = q >= self.half, k >= self.half  # in the clean copy?
         qb = self._block_of(jnp.where(qc, q - self.half, q))
         kb = self._block_of(jnp.where(kc, k - self.half, k))
@@ -128,6 +135,8 @@ class Mask:
         """Does any query of [q0, q1) see any key of [k0, k1)?"""
         if self.rule == "causal":
             return k0 <= q1 - 1
+        if self.rule == "window":
+            return (k0 <= q1 - 1) & (k1 - 1 > q0 - self.window)
         L = self.half
         blocks_meet = (self._block_of(k0) <= self._block_of(q1 - 1)) \
             & (self._block_of(k1 - 1) >= self._block_of(q0))
@@ -139,6 +148,8 @@ class Mask:
         not complete: where false the kernel applies the mask.)"""
         if self.rule == "causal":
             return q0 >= k1 - 1
+        if self.rule == "window":
+            return (q0 >= k1 - 1) & (q1 - 1 - k0 < self.window)
         return (k0 >= self.half) & self.visible(q0, k1 - 1)
 
     def key_tile_ranges(self, q0, bq: int, bkv: int):
@@ -147,6 +158,8 @@ class Mask:
         q1 = q0 + bq
         if self.rule == "causal":
             return 0, (q1 - 1) // bkv, 1, 0
+        if self.rule == "window":
+            return jnp.maximum(q0 - self.window + 1, 0) // bkv, (q1 - 1) // bkv, 1, 0
         L, B = self.half, self.block
         clean = q0 >= L
         lo1 = jnp.where(clean, L, q0) // bkv
@@ -162,6 +175,9 @@ class Mask:
         nq = positions // bq
         if self.rule == "causal":
             return k0 // bq, nq - 1, 1, 0
+        if self.rule == "window":  # the last key's W - 1 later queries
+            return k0 // bq, jnp.minimum((k0 + bkv + self.window - 2) // bq,
+                                         nq - 1), 1, 0
         L, B = self.half, self.block
         clean = k0 >= L
         kc = k0 - L
@@ -178,6 +194,8 @@ class Mask:
         [q0, q1) sees a key; [q0, q1) lies in one copy of the row."""
         if self.rule == "causal":
             return ((0, q1),)
+        if self.rule == "window":
+            return ((max(0, q0 - self.window + 1), q1),)
         L, B = self.half, self.block
         if q0 >= L:  # clean queries: the clean blocks up to their own
             return ((L, L + min(L, -(-(q1 - L) // B) * B)),)
@@ -222,7 +240,8 @@ def attention_route(positions: int, block_q: int, head_dims,
     on a TPU, blocks of whole 128-lane registers, head sizes the kernels'
     tiles hold (128s; the rotary part 64s); under `block_diffusion` also
     blocks of a power of two of positions that tile a query block, and
-    query and key blocks that tile a copy of the row. The pass is fused
+    query and key blocks that tile a copy of the row; under `window` key
+    blocks of whole query blocks. The pass is fused
     where the scores are and the rotated width (the rotary part, or the
     whole head where there is none) is 64 or a power of two of 128s."""
     bq = min(block_q, mask.half or positions)
@@ -232,15 +251,18 @@ def attention_route(positions: int, block_q: int, head_dims,
                          f"the {mask.half or positions} positions of a row")
     dn, dr, dv = head_dims
     named = {"mask": mask.rule if mask.rule == "causal" else
-             {"rule": mask.rule, "block": mask.block, "half": mask.half}}
+             {"rule": mask.rule, "window": mask.window} if mask.rule == "window"
+             else {"rule": mask.rule, "block": mask.block, "half": mask.half}}
     fused = (jax.default_backend() == "tpu"
              and all(n % FUSED_BLOCK_MULTIPLE == 0 for n in (bq, dn, dv))
              and dr % (FUSED_BLOCK_MULTIPLE // 2) == 0)
     if fused and mask.rule == "block_diffusion":
         fused = (mask.block & (mask.block - 1)) == 0 and bq % mask.block == 0
+    row = mask.half or positions
+    bkv = bq if row % FUSED_BLOCK_KV else FUSED_BLOCK_KV
+    if fused and mask.rule == "window":
+        fused = bkv % bq == 0  # a key tile's first query starts a query tile
     if fused:
-        row = mask.half or positions
-        bkv = bq if row % FUSED_BLOCK_KV else FUSED_BLOCK_KV
         rotated = dr or dn
         prep = {"path": "xla"} if rotated & (rotated - 1) else {
             "path": "fused",

@@ -4,7 +4,9 @@ the repo's own on shared tile code (`_dot`, `_lanes`, the chunks of
 `mla_attn_bwd`: the latent-attention layer's causal attention, described
 first; `bd_attn_fwd` / `bd_attn_bwd`: grouped-query attention under a
 mask RULE (`ops/attention.py::Mask`: causal, or the block-diffusion mask
-of a doubled row), described at `_rule_fwd_kernel`. They stand beside each
+of a doubled row), described at `_rule_fwd_kernel`, and the same bodies
+as `swa_attn_fwd` / `swa_attn_bwd` under the sliding window, on grids
+that walk only the tiles a window reaches. They stand beside each
 other, not one generalised into the other: the latent kernels' score is
 the sum of two products with a rotary key shared by all heads and their
 mask and skipping are the diagonal's, so a shared body would carry both
@@ -322,6 +324,15 @@ def fused_causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int,
 # dk and dv accumulate in VMEM over heads and query tiles alike; which
 # query tiles see a key tile is `Mask.query_tile_ranges`; dq leaves as one
 # float32 part a key tile, summed outside.
+#
+# Under `window` the same bodies run as `swa_attn_fwd` / `swa_attn_bwd` on
+# grids whose innermost axis covers only what the window reaches: the
+# forward the key tiles from a query tile's first (`_window_reach`: 2 of
+# 2048 for a query tile of 512 under W = 2048), the backward the query
+# tiles from a key tile's first (8 of 512); steps past the last do
+# nothing. dq's part of a key tile holds only those query tiles, so the
+# parts are as many rows as the reach, not the row, and are added into
+# place outside (`_sum_window_parts`).
 
 
 def _clamp2(x, lo1, hi1, lo2, hi2):
@@ -350,7 +361,8 @@ def _rule_chunks(mask: Mask, q0, k0, bq: int, bkv: int, tile):
 
 
 def _rule_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                     acc_ref, *, scale: float, bq: int, bkv: int, mask: Mask):
+                     acc_ref, *, scale: float, bq: int, bkv: int, mask: Mask,
+                     key_tile=None):
     i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
@@ -378,7 +390,8 @@ def _rule_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         acc_ref[...] = (acc_ref[...] * _lanes(alpha, acc_ref.shape[1])
                         + _dot(p.astype(v_ref.dtype), v_ref[keys, :]))
 
-    _rule_chunks(mask, i * bq, j * bkv, bq, bkv, tile)
+    _rule_chunks(mask, i * bq, (j if key_tile is None else key_tile(i, j)) * bkv,
+                 bq, bkv, tile)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -390,10 +403,13 @@ def _rule_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
 def _rule_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
                      dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float, bq: int,
-                     bkv: int, mask: Mask, positions: int):
+                     bkv: int, mask: Mask, positions: int, query_tiles=None,
+                     reach: int = 0):
     j, x = pl.program_id(2), pl.program_id(3)
     nq = positions // bq
-    i = x % nq
+    # the query tile; under `window` the `reach` tiles from the key tile's
+    # first (`query_tiles(j)`: its first and last), those past the last idle
+    i = x % nq if query_tiles is None else query_tiles(j)[0] + x % reach
     dt = q_ref.dtype
 
     @pl.when(x == 0)
@@ -416,7 +432,11 @@ def _rule_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
         dk_acc[keys, :] += _dot(dst.astype(dt), q_ref[...])
         dq_ref[...] += _dot(dst.T.astype(dt), k_ref[keys, :])
 
-    _rule_chunks(mask, i * bq, j * bkv, bq, bkv, tile)
+    if query_tiles is None:
+        _rule_chunks(mask, i * bq, j * bkv, bq, bkv, tile)
+    else:
+        pl.when(i <= query_tiles(j)[1])(functools.partial(
+            _rule_chunks, mask, i * bq, j * bkv, bq, bkv, tile))
 
     @pl.when(x == pl.num_programs(3) - 1)
     def _():
@@ -424,18 +444,37 @@ def _rule_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _window_reach(mask: Mask, bq: int, bkv: int, s: int):
+    """(key tiles a query tile visits, query tiles a key tile is visited
+    by), the most over the row, under `window`: the extents of the grids'
+    innermost axes, where the other rules walk the whole row."""
+    keys = max((q1 - 1) // bkv - k0 // bkv + 1 for k0, q1 in (
+        mask.key_ranges(i, i + bq)[0] for i in range(0, s, bq)))
+    # a key tile at j is seen by the queries j .. j + bkv - 1 + W - 1
+    queries = max(min(s - 1, j + bkv + mask.window - 2) // bq - j // bq + 1
+                  for j in range(0, s, bkv))
+    return keys, queries
+
+
 def _rule_forward(q, k, v, scale, bq, bkv, mask, interpret):
     b, h, s, d = q.shape
     r = h // k.shape[1]
-    k_of = lambda i, j: _clamp2(j, *mask.key_tile_ranges(i * bq, bq, bkv))  # noqa: E731
+    ranges = lambda i: mask.key_tile_ranges(i * bq, bq, bkv)  # noqa: E731
+    if mask.rule == "window":  # the key tiles from the query tile's first
+        nk, name = _window_reach(mask, bq, bkv, s)[0], "swa_attn_fwd"
+        key_tile = lambda i, j: ranges(i)[0] + j  # noqa: E731
+        k_of = lambda i, j: jnp.minimum(key_tile(i, j), ranges(i)[1])  # noqa: E731
+    else:
+        nk, name, key_tile = s // bkv, "bd_attn_fwd", None
+        k_of = lambda i, j: _clamp2(j, *ranges(i))  # noqa: E731
     qs = lambda w: pl.BlockSpec((None, None, bq, w),  # noqa: E731
                                 lambda b, n, i, j: (b, n, i, 0))
     ks = pl.BlockSpec((None, None, bkv, d),
                       lambda b, n, i, j: (b, n // r, k_of(i, j), 0))
     o, lse = pallas_call(
         functools.partial(_rule_fwd_kernel, scale=scale, bq=bq, bkv=bkv,
-                          mask=mask),
-        grid=(b, h, s // bq, s // bkv),
+                          mask=mask, key_tile=key_tile),
+        grid=(b, h, s // bq, nk),
         in_specs=[qs(d), ks, ks],
         out_specs=[qs(d), qs(LANES)],
         out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
@@ -445,7 +484,7 @@ def _rule_forward(q, k, v, scale, bq, bkv, mask, interpret):
                         pltpu.VMEM((bq, d), F32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
-        name="bd_attn_fwd", interpret=interpret,
+        name=name, interpret=interpret,
     )(q, k, v)
     return o, lse[..., 0]
 
@@ -458,32 +497,58 @@ def _rule_backward(q, k, v, o, lse, do, scale, bq, bkv, mask, interpret):
     di = jnp.sum(o.astype(F32) * do.astype(F32), axis=-1)
     rows = [jnp.broadcast_to(a[:, :, None, :], (b, h, SUBLANES, s))
             for a in (lse, di)]
+    ranges = lambda j: mask.query_tile_ranges(j * bkv, bkv, bq, s)  # noqa: E731
+    if mask.rule == "window":
+        # the query tiles from the key tile's first; dq's part of key tile
+        # j holds those `nx` tiles alone, from position j * bkv
+        nx, name = _window_reach(mask, bq, bkv, s)[1], "swa_attn_bwd"
+        query_tiles = lambda j: ranges(j)[:2]  # noqa: E731
+        q_of = lambda j, x: jnp.minimum(  # noqa: E731
+            ranges(j)[0] + x % nx, ranges(j)[1])
+    else:
+        nx, name, query_tiles = nq, "bd_attn_bwd", None
+        q_of = lambda j, x: _clamp2(x % nq, *ranges(j))  # noqa: E731
     # innermost grid id x = (query head of the group, query tile)
-    head = lambda n, x: n * r + x // nq  # noqa: E731
-    q_of = lambda j, x: _clamp2(  # noqa: E731
-        x % nq, *mask.query_tile_ranges(j * bkv, bkv, bq, s))
+    head = lambda n, x: n * r + x // nx  # noqa: E731
     qs = pl.BlockSpec((None, None, bq, d),
                       lambda b, n, j, x: (b, head(n, x), q_of(j, x), 0))
     ks = pl.BlockSpec((None, None, bkv, d), lambda b, n, j, x: (b, n, j, 0))
     row = pl.BlockSpec((None, None, SUBLANES, bq),
                        lambda b, n, j, x: (b, head(n, x), 0, q_of(j, x)))
     part = pl.BlockSpec((None, None, None, bq, d),
-                        lambda b, n, j, x: (b, head(n, x), j, x % nq, 0))
+                        lambda b, n, j, x: (b, head(n, x), j, x % nx, 0))
     dq, dk, dv = pallas_call(
         functools.partial(_rule_bwd_kernel, scale=scale, bq=bq, bkv=bkv,
-                          mask=mask, positions=s),
-        grid=(b, g, nkv, r * nq),
+                          mask=mask, positions=s, query_tiles=query_tiles,
+                          reach=nx),
+        grid=(b, g, nkv, r * nx),
         in_specs=[qs, ks, ks, qs, row, row],
         out_specs=[part, ks, ks],
-        out_shape=[jax.ShapeDtypeStruct((b, h, nkv, s, d), F32),
+        out_shape=[jax.ShapeDtypeStruct((b, h, nkv, nx * bq, d), F32),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bkv, d), F32), pltpu.VMEM((bkv, d), F32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary", "arbitrary")),
-        name="bd_attn_bwd", interpret=interpret,
+        name=name, interpret=interpret,
     )(q, k, v, do, *rows)
-    return jnp.sum(dq, axis=2).astype(q.dtype), dk, dv
+    if query_tiles is None:
+        return jnp.sum(dq, axis=2).astype(q.dtype), dk, dv
+    return _sum_window_parts(dq, bkv).astype(q.dtype), dk, dv
+
+
+def _sum_window_parts(parts, bkv: int):
+    """dq[b, h, s, d] from parts[b, h, nkv, n, d]: part j's n rows hold the
+    queries from j * bkv on (past the row's end: nought). Each part cut
+    into key tiles, the t-th of part j lands on key tile j + t."""
+    b, h, nkv, n, d = parts.shape
+    m = -(-n // bkv)
+    parts = jnp.pad(parts, ((0, 0),) * 3 + ((0, m * bkv - n), (0, 0)))
+    parts = parts.reshape(b, h, nkv, m, bkv, d)
+    dq = sum(jnp.pad(parts[:, :, :nkv - t, t], ((0, 0), (0, 0), (t, 0),
+                                                 (0, 0), (0, 0)))
+             for t in range(min(m, nkv)))
+    return dq.reshape(b, h, nkv * bkv, d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

@@ -440,6 +440,35 @@ def test_block_mask_attention_kernels_compile_for_v5e(one_chip, which):
             kernels=["bd_attn_fwd", "bd_attn_bwd"])
 
 
+@pytest.mark.parametrize("which", ["fwd", "vjp"])
+def test_window_attention_kernels_compile_for_v5e(one_chip, which):
+    """The same kernel bodies under the sliding window at the window cell's
+    layer (one row of 16384, 32 query heads over 4 key/value heads of 128,
+    W = 2048, query tiles of 512, key tiles of 2048), on their own grids:
+    the key tiles and query tiles a window reaches, counted from a tile's
+    first, whose index arithmetic (a floor of the window's first key, a
+    clamp to the last tile) runs on the scalar core."""
+    from deepof_tpu.ops.attention import Mask
+    from deepof_tpu.ops.pallas.attention import fused_grouped_attention
+
+    mask = Mask("window", window=2048)
+
+    def of(heads):
+        return jax.ShapeDtypeStruct((1, 16384, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def attend(*o):
+        with jax.named_scope("swa_scores"):  # as the layer calls it
+            return fused_grouped_attention(*o, 128 ** -0.5, 512, 2048, mask)
+
+    if which == "fwd":
+        _compiled_text(attend, of(32), of(4), of(4), kernels=["swa_attn_fwd"])
+    else:
+        _compiled_text(jax.grad(lambda *o: jnp.sum(attend(*o).astype(
+            jnp.float32) ** 2), argnums=range(3)), of(32), of(4), of(4),
+            kernels=["swa_attn_fwd", "swa_attn_bwd"])
+
+
 PREP = {
     # the product's output [rows, positions, heads * d], heads, interleaved
     # pairs, normed: what the two language-model cells' layers hand the pass

@@ -287,7 +287,8 @@ def test_registry_finds_the_family_by_its_published_model_type():
             lm=dataclasses.replace(cfg.lm, model_type="qwen9")))
     declared = {m.model_type: m.objective for m in registry.MODELS.values()
                 if registry.task_of(m) == "lm"}
-    assert declared == {"deepseek_v3": "next_token", "sdar_moe": "block_diffusion"}
+    assert declared == {"deepseek_v3": "next_token", "sdar_moe": "block_diffusion",
+                        "afmoe": "next_token"}
 
 
 def test_second_configuration_file_keeps_every_published_width():
