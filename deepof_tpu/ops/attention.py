@@ -38,8 +38,9 @@ so no row is empty.
 `attention_route` decides from what the code can observe (backend, shape,
 rule) and is what the trainer writes into its step-0 info record, with the
 mask's name and, as static counts, the (query block, key block) tiles the
-rule lets the path visit over all of them; there is no option that picks
-a path.
+rule lets the path visit over all of them and, on the fused path, the
+chunks it computes in the rule's narrow diagonal band (`Mask.band`);
+there is no option that picks a path.
 
 The route's SECOND decision, `prep`: how the layers get from a
 projection's float32 output to the operands above (per-head norm where the
@@ -152,6 +153,31 @@ class Mask:
             return (q0 >= k1 - 1) & (q1 - 1 - k0 < self.window)
         return (k0 >= self.half) & self.visible(q0, k1 - 1)
 
+    def band(self, bq: int, bkv: int, chunk: int) -> int:
+        """The width of the diagonal band that holds every pair a noised
+        query tile of `bq` sees among the noised keys (its own block's
+        only): the block in whole 128-lane tiles, where that is narrower
+        than the kernels' chunk of keys and a key tile of `bkv` holds
+        whole query tiles; else 0. The fused kernels then compute a noised
+        query tile's own positions as `bq / width` diagonal sub-blocks of
+        width x width, not as masked bq x chunk tiles."""
+        if self.rule != "block_diffusion" or bkv % bq:
+            return 0
+        width = -(-self.block // FUSED_BLOCK_MULTIPLE) * FUSED_BLOCK_MULTIPLE
+        return width if width < chunk and bq % width == 0 else 0
+
+    def in_band(self, q0, k0):
+        """Do the query tile at q0 and the key chunk or tile at k0 both lie
+        in the noised copy, where `band` holds every pair the rule shows?"""
+        return (q0 < self.half) & (k0 < self.half)
+
+    def narrow(self, block_q: int, block_kv: int, chunk: int) -> dict:
+        """{"chunks", "width"}: the noised query tiles of a row and head
+        that the fused kernels compute in the band, one pass a tile in
+        place of masked chunks, and its width (`band`)."""
+        width = self.band(block_q, block_kv, chunk)
+        return {"chunks": self.half // block_q if width else 0, "width": width}
+
     def key_tile_ranges(self, q0, bq: int, bkv: int):
         """(lo1, hi1, lo2, hi2): the key tiles of `bkv` a query tile at q0
         can see lie in [lo1, hi1] or [lo2, hi2]; lo > hi: empty."""
@@ -234,7 +260,9 @@ def attention_route(positions: int, block_q: int, head_dims,
     `mask`: {"path": "fused", "block_q", "block_kv"} or {"path":
     "xla_blocks", "block_q"}, with "mask" (the rule's name, and its blocks
     where it has them), "tiles" (`Mask.tiles` at the path's blocks; the
-    XLA blocks count keys in blocks of `block_q`) and "prep" ({"path":
+    XLA blocks count keys in blocks of `block_q`), on the fused path
+    "narrow" (`Mask.narrow`: the chunks a row and head computes in the
+    rule's diagonal band, and its width) and "prep" ({"path":
     "fused", "block_s"} or {"path": "xla"}: the module's docstring). The
     one rule the layers and the trainer's step-0 info record share. Fused:
     on a TPU, blocks of whole 128-lane registers, head sizes the kernels'
@@ -263,12 +291,16 @@ def attention_route(positions: int, block_q: int, head_dims,
     if fused and mask.rule == "window":
         fused = bkv % bq == 0  # a key tile's first query starts a query tile
     if fused:
+        from .pallas.attention import COMPUTE_KV
+
         rotated = dr or dn
         prep = {"path": "xla"} if rotated & (rotated - 1) else {
             "path": "fused",
             "block_s": next(n for n in PREP_BLOCKS_S if positions % n == 0)}
         return {"path": "fused", "block_q": bq, "block_kv": bkv, **named,
-                "tiles": mask.tiles(positions, bq, bkv), "prep": prep}
+                "tiles": mask.tiles(positions, bq, bkv),
+                "narrow": mask.narrow(bq, bkv, min(bkv, COMPUTE_KV)),
+                "prep": prep}
     return {"path": "xla_blocks", "block_q": bq, **named,
             "tiles": mask.tiles(positions, bq, bq), "prep": {"path": "xla"}}
 

@@ -318,6 +318,25 @@ def fused_causal_attention(qn, qr, kn, kr, v, scale: float, block_q: int,
 # rule shows whole skips the mask (`Mask.tile_wholly_visible`); elsewhere
 # the mask is position arithmetic inside the tile (`Mask.visible`).
 #
+# The band (`Mask.band`): under `block_diffusion` a noised query sees only
+# the noised keys of its own block, so every pair a noised query tile sees
+# among the noised keys lies in a diagonal band of the block's width in
+# whole 128-lane tiles. Where that width is under the chunk and a key tile
+# holds whole query tiles, the noised chunks are not computed as chunks:
+# at the key tile that holds the query tile, ONE pass (`_band`) runs
+# `bq / width` sub-blocks of width x width on the diagonal, masked as any
+# other, each updating only its own query rows (forward: m, l and acc;
+# backward: dq, and dk / dv on its own keys). Never under `causal` or
+# `window`, nor in the latent kernels. The cell's layer (a doubled row of
+# 8192, query tiles of 512, key tiles of 2048, blocks of 4): of 80 chunks
+# of 512 x 512 a head, 24 masked, the 8 noised query tiles' own chunks
+# hold 16,384 of the 16,793,600 visible pairs; a head now computes 72
+# chunks and 8 bands of four 128 x 128 sub-blocks, each a quarter of such
+# a chunk's products and mask arithmetic. The band is one pass a key tile,
+# not one a chunk: every sub-block body is traced and lowered in every
+# process, and a band a chunk (16 bodies a kernel) added 13 s to the
+# block-diffusion cell's set-up on a v5e host.
+#
 # Backward (`bd_attn_bwd`), grid (row, key/value head, key tile, r x query
 # tiles), innermost the r query heads that read this key/value head times
 # the query tiles: a key/value tile is fetched ONCE for all of them, and
@@ -345,19 +364,37 @@ def _clamp2(x, lo1, hi1, lo2, hi2):
 
 
 def _rule_chunks(mask: Mask, q0, k0, bq: int, bkv: int, tile):
-    """Run `tile(keys, first_key, masked)` for every chunk of `COMPUTE_KV`
-    keys of the key tile at k0 that the query tile at q0 sees under `mask`:
-    not at all where the rule hides the chunk, without the mask where it
-    shows all of it."""
+    """Run `tile(queries, keys, first_query, first_key, masked)` for every
+    chunk of `COMPUTE_KV` keys of the key tile at k0 that the query tile at
+    q0 sees under `mask` (rows of the query tile and of the key tile, and
+    their first positions): not at all where the rule hides the chunk,
+    without the mask where it shows all of it; the chunks of the rule's
+    band, once, as its diagonal sub-blocks (`Mask.band`, `_band`)."""
     compute = min(bkv, COMPUTE_KV)
+    width = mask.band(bq, bkv, compute)
+    every = pl.ds(0, bq)
     for c in range(bkv // compute):
         c0 = k0 + c * compute
         keys = pl.ds(c * compute, compute)
         seen = mask.tile_visible(q0, q0 + bq, c0, c0 + compute)
         whole = mask.tile_wholly_visible(q0, q0 + bq, c0, c0 + compute)
+        if width:
+            seen = seen & jnp.logical_not(mask.in_band(q0, c0))
         pl.when(seen & jnp.logical_not(whole))(
-            functools.partial(tile, keys, c0, True))
-        pl.when(seen & whole)(functools.partial(tile, keys, c0, False))
+            functools.partial(tile, every, keys, q0, c0, True))
+        pl.when(seen & whole)(functools.partial(tile, every, keys, q0, c0, False))
+    if width:
+        own = mask.in_band(q0, k0) & (k0 <= q0) & (q0 < k0 + bkv)
+        pl.when(own)(functools.partial(_band, q0, k0, bq, width, tile))
+
+
+def _band(q0, k0, bq: int, width: int, tile):
+    """The noised query tile at q0 against its own positions in the key
+    tile at k0, the only keys it sees there: `bq / width` sub-blocks of
+    width x width on the diagonal, each masked."""
+    at = pl.multiple_of(q0 - k0, bq)  # the query tile's first key row
+    for r in range(0, bq, width):
+        tile(pl.ds(r, width), pl.ds(at + r, width), q0 + r, q0 + r, True)
 
 
 def _rule_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
@@ -371,13 +408,13 @@ def _rule_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def tile(keys, first_key, masked):
-        s = _dot(q_ref[...], k_ref[keys, :], _NT) * scale
+    def tile(rows, keys, first_query, first_key, masked):
+        s = _dot(q_ref[rows, :], k_ref[keys, :], _NT) * scale
         if masked:
-            qpos = i * bq + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            qpos = first_query + lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kpos = first_key + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(mask.visible(qpos, kpos), s, _NEG)
-        m_prev = m_ref[...]
+        m_prev = m_ref[rows, :]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_next, s.shape[1]))
         if masked:
@@ -385,10 +422,11 @@ def _rule_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
             # before the chunk's first key): exp(_NEG - _NEG) is not nought
             p = jnp.where(s > 0.5 * _NEG, p, 0.0)
         alpha = jnp.exp(m_prev - m_next)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_next
-        acc_ref[...] = (acc_ref[...] * _lanes(alpha, acc_ref.shape[1])
-                        + _dot(p.astype(v_ref.dtype), v_ref[keys, :]))
+        l_ref[rows, :] = alpha * l_ref[rows, :] + jnp.sum(p, axis=1,
+                                                         keepdims=True)
+        m_ref[rows, :] = m_next
+        acc_ref[rows, :] = (acc_ref[rows, :] * _lanes(alpha, acc_ref.shape[1])
+                            + _dot(p.astype(v_ref.dtype), v_ref[keys, :]))
 
     _rule_chunks(mask, i * bq, (j if key_tile is None else key_tile(i, j)) * bkv,
                  bq, bkv, tile)
@@ -419,18 +457,18 @@ def _rule_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
 
     dq_ref[...] = jnp.zeros_like(dq_ref)  # this key tile's part of dq
 
-    def tile(keys, first_key, masked):
-        st = _dot(k_ref[keys, :], q_ref[...], _NT) * scale
-        pt = jnp.exp(st - lse_ref[:1, :])
+    def tile(rows, keys, first_query, first_key, masked):
+        st = _dot(k_ref[keys, :], q_ref[rows, :], _NT) * scale
+        pt = jnp.exp(st - lse_ref[:1, rows])
         if masked:
             kpos = first_key + lax.broadcasted_iota(jnp.int32, st.shape, 0)
-            qpos = i * bq + lax.broadcasted_iota(jnp.int32, st.shape, 1)
+            qpos = first_query + lax.broadcasted_iota(jnp.int32, st.shape, 1)
             pt = jnp.where(mask.visible(qpos, kpos), pt, 0.0)
-        do = do_ref[...]
+        do = do_ref[rows, :]
         dv_acc[keys, :] += _dot(pt.astype(dt), do)
-        dst = (_dot(v_ref[keys, :], do, _NT) - di_ref[:1, :]) * pt * scale
-        dk_acc[keys, :] += _dot(dst.astype(dt), q_ref[...])
-        dq_ref[...] += _dot(dst.T.astype(dt), k_ref[keys, :])
+        dst = (_dot(v_ref[keys, :], do, _NT) - di_ref[:1, rows]) * pt * scale
+        dk_acc[keys, :] += _dot(dst.astype(dt), q_ref[rows, :])
+        dq_ref[rows, :] += _dot(dst.T.astype(dt), k_ref[keys, :])
 
     if query_tiles is None:
         _rule_chunks(mask, i * bq, j * bkv, bq, bkv, tile)

@@ -418,7 +418,24 @@ def test_block_mask_attention_kernels_compile_for_v5e(one_chip, which):
     custom VJP's pair under a gradient with respect to q, k and v. The
     rule's position arithmetic (shifts, comparisons, logical operations on
     masks; no vector divide, no select between masks) is what Mosaic has
-    to take."""
+    to take, and a noised query tile's own chunk in four diagonal
+    sub-blocks of 128 (`Mask.band`) inside the same two kernels."""
+    _compile_block_mask_attention(one_chip, which, 4, 512)
+
+
+@pytest.mark.parametrize("which", ["fwd", "vjp"])
+@pytest.mark.parametrize("groups,block_q", [(2, 512), (4, 256)],
+                         ids=["nemotron_g2", "q256"])
+def test_block_mask_attention_kernels_compile_at_other_shapes_for_v5e(
+        one_chip, which, groups, block_q):
+    """As above over the third language-model cell's 2 key/value heads,
+    and with query tiles of 256: two of them a chunk of 512 keys, the
+    band's keys at a query tile's offset in its chunk (a traced, aligned
+    sublane offset into the key tile)."""
+    _compile_block_mask_attention(one_chip, which, groups, block_q)
+
+
+def _compile_block_mask_attention(one_chip, which, groups, block_q):
     from deepof_tpu.ops.attention import Mask
     from deepof_tpu.ops.pallas.attention import fused_grouped_attention
 
@@ -430,14 +447,16 @@ def test_block_mask_attention_kernels_compile_for_v5e(one_chip, which):
 
     def attend(*o):
         with jax.named_scope("gqa_scores"):  # as the layer calls it
-            return fused_grouped_attention(*o, 128 ** -0.5, 512, 2048, mask)
+            return fused_grouped_attention(*o, 128 ** -0.5, block_q, 2048,
+                                           mask)
 
     if which == "fwd":
-        _compiled_text(attend, of(32), of(4), of(4), kernels=["bd_attn_fwd"])
+        _compiled_text(attend, of(32), of(groups), of(groups),
+                       kernels=["bd_attn_fwd"])
     else:
         _compiled_text(jax.grad(lambda *o: jnp.sum(attend(*o).astype(
-            jnp.float32) ** 2), argnums=range(3)), of(32), of(4), of(4),
-            kernels=["bd_attn_fwd", "bd_attn_bwd"])
+            jnp.float32) ** 2), argnums=range(3)), of(32), of(groups),
+            of(groups), kernels=["bd_attn_fwd", "bd_attn_bwd"])
 
 
 @pytest.mark.parametrize("which", ["fwd", "vjp"])

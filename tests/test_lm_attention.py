@@ -152,51 +152,60 @@ def test_trainer_writes_the_route_into_its_step_0_info_record(tmp_path):
 # ---- grouped-query attention under a mask rule (`bd_attn_fwd` / `_bwd`) ----
 
 GROUPED = {
-    # rule, positions a copy, block, block_q, block_kv, chunk of keys
-    "bd_b4_q128_kv128": ("block_diffusion", 256, 4, 128, 128, 128),
-    "bd_b4_q128_kv256": ("block_diffusion", 256, 4, 128, 256, 128),
-    "bd_b32_q256_kv128": ("block_diffusion", 256, 32, 256, 128, 128),
-    "bd_b1_q128_kv256": ("block_diffusion", 256, 1, 128, 256, 256),
-    "causal_q256_kv128": ("causal", 512, 0, 256, 128, 128),
+    # rule, positions a copy, block, block_q, block_kv, chunk of keys, and
+    # the width of the band a noised query tile's own chunk is computed in
+    # (`Mask.band`; 0: the whole chunk, masked)
+    "bd_b4_q128_kv128": ("block_diffusion", 256, 4, 128, 128, 128, 0),
+    "bd_b4_q128_kv256": ("block_diffusion", 256, 4, 128, 256, 128, 0),
+    "bd_b32_q256_kv128": ("block_diffusion", 256, 32, 256, 128, 128, 0),
+    "bd_b1_q128_kv256": ("block_diffusion", 256, 1, 128, 256, 256, 128),
+    "causal_q256_kv128": ("causal", 512, 0, 256, 128, 128, 0),
+    # the band's sub-blocks: two a chunk of 256 (four a chunk of 512, the
+    # cell's: `CELL_BAND`), none where the block is the chunk
+    "bd_b4_q256_kv256": ("block_diffusion", 256, 4, 256, 256, 256, 128),
+    "bd_b32_q256_kv512": ("block_diffusion", 512, 32, 256, 512, 256, 128),
+    "bd_b256_q256_kv256": ("block_diffusion", 256, 256, 256, 256, 256, 0),
 }
 
 
-@pytest.mark.parametrize("case", sorted(GROUPED))
-@pytest.mark.parametrize("dtype", sorted(TOLERANCE))
-def test_fused_grouped_kernels_match_the_xla_blocks(monkeypatch, dtype, case):
-    """Output and the gradient with respect to q, k and v, 4 query heads
-    reading 2 key/value heads of 128, under the block-diffusion mask of a
-    doubled row (blocks of 1, 4 and 32; key tiles equal to, twice and half
-    the query tiles; tiles hidden, shown whole and cut by the rule all
-    occur, and with chunks of 128 keys a query that sees no key of a chunk
-    it is computed with) and under the causal rule."""
+def grouped_results(monkeypatch, case, dtype, attend):
+    """o and the gradient with respect to q, k and v, 4 query heads reading
+    2 key/value heads of 128, by `attend` ("xla": the XLA blocks; "fused":
+    the kernels in interpret mode; "whole": the kernels with no band, each
+    chunk computed whole) under `case`."""
     from deepof_tpu.ops.pallas import attention as K
 
-    rule, half, block, bq, bkv, compute = GROUPED[case]
+    rule, half, block, bq, bkv, compute, band = case
     monkeypatch.setattr(K, "COMPUTE_KV", compute)
     dt = jnp.dtype(dtype)
     mask = A.CAUSAL if rule == "causal" else A.Mask(rule, block, half)
+    assert mask.band(bq, bkv, min(bkv, compute)) == band
     s = half if rule == "causal" else 2 * half
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v, weight = (jax.random.normal(kk, (2, s, h, 128))
                        for kk, h in zip(keys, (4, 2, 2, 4)))
     ops = tuple(o.astype(dt) for o in (q, k, v))
 
-    def loss(attend):
-        return lambda *o: jnp.sum(attend(*o).astype(jnp.float32) * weight)
-
-    def blocks_(*o):
-        return A.xla_blocks_grouped_attention(*o, 128 ** -0.5, 64, dt, mask)
-
-    def fused(*o):
+    def run(*o):
+        if attend == "xla":
+            return A.xla_blocks_grouped_attention(*o, 128 ** -0.5, 64, dt, mask)
         return K.fused_grouped_attention(*o, 128 ** -0.5, bq, bkv, mask,
                                          interpret=True)
 
-    assert fused(*ops).dtype == dt
-    pairs = [(blocks_(*ops), fused(*ops))] + list(zip(
-        jax.grad(loss(blocks_), argnums=range(3))(*ops),
-        jax.grad(loss(fused), argnums=range(3))(*ops)))
-    for name, (want, got) in zip(("o", "q", "k", "v"), pairs):
+    def loss(*o):
+        return jnp.sum(run(*o).astype(jnp.float32) * weight)
+
+    with monkeypatch.context() as patch:
+        if attend == "whole":
+            patch.setattr(A.Mask, "band", lambda self, bq, bkv, chunk: 0)
+        out = run(*ops)
+        if attend != "xla":
+            assert out.dtype == dt
+        return [out, *jax.grad(loss, argnums=range(3))(*ops)]
+
+
+def assert_close(wants, gots, dtype):
+    for name, want, got in zip(("o", "q", "k", "v"), wants, gots):
         assert want.shape == got.shape, name
         # k's and v's gradients sum over two query heads and up to 512
         # queries: values of size 10-30, bfloat16 steps of 2^-4 there
@@ -204,6 +213,45 @@ def test_fused_grouped_kernels_match_the_xla_blocks(monkeypatch, dtype, case):
         gap = float(jnp.max(jnp.abs(want.astype(jnp.float32)
                                     - got.astype(jnp.float32))))
         assert gap < TOLERANCE[dtype] * scale, (name, gap, scale)
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED))
+@pytest.mark.parametrize("dtype", sorted(TOLERANCE))
+def test_fused_grouped_kernels_match_the_xla_blocks(monkeypatch, dtype, case):
+    """Output and the gradient with respect to q, k and v, 4 query heads
+    reading 2 key/value heads of 128, under the block-diffusion mask of a
+    doubled row (blocks of 1, 4, 32 and 256; key tiles equal to, twice and
+    half the query tiles; tiles hidden, shown whole and cut by the rule all
+    occur, and with chunks of 128 keys a query that sees no key of a chunk
+    it is computed with; a noised query tile's own chunk in the band's
+    diagonal sub-blocks, a query tile of half a chunk at either of its
+    offsets in it) and under the causal rule."""
+    assert_close(grouped_results(monkeypatch, GROUPED[case], dtype, "xla"),
+                 grouped_results(monkeypatch, GROUPED[case], dtype, "fused"),
+                 dtype)
+
+
+#: A query tile of 512 in four sub-blocks of 128, in the cell's one chunk
+#: of 512 keys or across two of 256.
+CELL_BAND = {
+    "compute512": ("block_diffusion", 512, 4, 512, 512, 512, 128),
+    "compute256": ("block_diffusion", 512, 4, 512, 512, 256, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CELL_BAND))
+@pytest.mark.parametrize("dtype", sorted(TOLERANCE))
+def test_the_cells_band_of_four_sub_blocks(monkeypatch, dtype, case):
+    """The cell's shape of band, a doubled row of 1024: in float32 against
+    the XLA blocks; in bfloat16 against the kernels that compute the chunks
+    whole and masked. (The XLA blocks are no bfloat16 oracle at this row:
+    a clean key's dk sums over 1022 queries of two heads, and there the
+    whole-chunk kernels already differ from them by three bfloat16 steps
+    at values under 4, outside the band.)"""
+    want = "xla" if dtype == "float32" else "whole"
+    assert_close(grouped_results(monkeypatch, CELL_BAND[case], dtype, want),
+                 grouped_results(monkeypatch, CELL_BAND[case], dtype, "fused"),
+                 dtype)
 
 
 BD = A.Mask("block_diffusion", 4, 4096)
@@ -216,12 +264,21 @@ def test_route_under_the_block_mask_names_it_and_counts_its_tiles(monkeypatch):
     TPU key tiles of 2048: a noised query tile visits its own noised key
     tile and the clean ones that start before its end, a clean one the
     clean tiles up to its own: 8 x 1 + (1+1+1+1+2+2+2+2) + the same again
-    = 32 of 64. The XLA blocks count keys in blocks of 512: 8 + 36 + 36."""
+    = 32 of 64. The XLA blocks count keys in blocks of 512: 8 + 36 + 36.
+    Of the kernels' chunks of 512 keys a noised query tile's own (8 a row
+    and head) is computed in the band of 128 the blocks of 4 need; no
+    chunk is under the causal rule or the window (the window cell's
+    layers: a row of 16384, W = 2048)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert A.attention_route(8192, 512, GQA_DIMS, BD) == {
         "path": "fused", "block_q": 512, "block_kv": 2048, "mask": BD_NAMED,
         "tiles": {"visited": 32, "all": 64},
+        "narrow": {"chunks": 8, "width": 128},
         "prep": {"path": "fused", "block_s": 512}}
+    for mask in (A.CAUSAL, A.Mask("window", window=2048)):
+        route = A.attention_route(16384, 512, GQA_DIMS, mask)
+        assert route["path"] == "fused"
+        assert route["narrow"] == {"chunks": 0, "width": 0}
     # blocks that are no power of two, or do not tile a query tile: XLA
     for block in (3, 1024):
         odd = A.Mask("block_diffusion", block, 4096)
